@@ -14,8 +14,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
 if "--cpu" in __import__("sys").argv:
-    # hosts whose TPU platform is registered but unreachable hang at
-    # backend init; lazy backends make this config update effective
     jax.config.update("jax_platforms", "cpu")
 import numpy as np
 

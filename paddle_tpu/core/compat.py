@@ -1,30 +1,9 @@
-"""Version compatibility shims for the underlying JAX installation.
+"""The one JAX surface the codebase imports through this module.
 
-The codebase targets the current JAX API surface; this module papers over
-renames so the same call sites run on the older releases still found in
-hermetic containers.
+The installation is jax 0.9.0 / jaxlib 0.9.0 / libtpu 0.0.34:
+``jax.shard_map`` is public there and takes ``check_vma``, which every
+call site passes.
 """
-from __future__ import annotations
+from jax import shard_map
 
-import functools
-
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-@functools.wraps(_shard_map)
-def shard_map(f, /, *args, **kwargs):
-    # check_rep (<= 0.4) was renamed check_vma (>= 0.5); translate whichever
-    # spelling the installed jax does not understand, drop it if unknown.
-    for old, new in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if old in kwargs and old not in _PARAMS:
-            val = kwargs.pop(old)
-            if new in _PARAMS:
-                kwargs.setdefault(new, val)
-    return _shard_map(f, *args, **kwargs)
+__all__ = ["shard_map"]

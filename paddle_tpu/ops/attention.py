@@ -72,6 +72,54 @@ def _flash_block(t: int):
     return None
 
 
+def _flash_per_shard(q, k, v, kv_len, **kw):
+    """The flash kernel under the ambient mesh (``jax.set_mesh`` —
+    ``DataParallel.step`` runs under one). A Mosaic kernel cannot be
+    partitioned automatically, so on a mesh of more than one device it
+    runs per shard through ``shard_map``: the batch splits over ``data``
+    and the heads over ``model``/``tp`` where the axis divides them, any
+    other dim is replicated (the ragged-tail step feeds its batch
+    replicated by design). With no mesh, or inside a ``shard_map`` that
+    already made every axis manual (ring/ulysses/pipeline), the call is
+    bare. There is no XLA-attention way out of here."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.core.compat import shard_map
+    from paddle_tpu.ops.pallas import flash_attention
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    mesh = jax.sharding.get_abstract_mesh()
+    manual = set(mesh.manual_axes)
+    auto = [a for a in mesh.axis_names
+            if mesh.shape[a] > 1 and a not in manual]
+    if not auto:
+        return flash_attention(q, k, v, kv_len=kv_len, **kw)
+    if manual:
+        raise NotImplementedError(
+            f"flash attention inside a shard_map over {sorted(manual)} with "
+            f"mesh axes {auto} left automatic cannot be mapped; make those "
+            "axes manual too or call it outside the shard_map")
+
+    def pick(names, *sizes):
+        for a in names:
+            if a in auto and all(s % mesh.shape[a] == 0 for s in sizes):
+                return a
+        return None
+
+    b_axis = pick((mesh_mod.DATA_AXIS,), q.shape[0])
+    h_axis = pick((mesh_mod.MODEL_AXIS, mesh_mod.TP_AXIS), q.shape[1], k.shape[1])
+    spec = P(b_axis, h_axis, None, None)
+
+    def body(q_, k_, v_, *kl):
+        return flash_attention(q_, k_, v_, kv_len=kl[0] if kl else None, **kw)
+
+    args = (q, k, v) + ((kv_len,) if kv_len is not None else ())
+    in_specs = (spec,) * 3 + ((P(b_axis),) if kv_len is not None else ())
+    return shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
+    )(*args)
+
+
 def scaled_dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -130,17 +178,16 @@ def scaled_dot_product_attention(
         bk = _flash_block(k.shape[-2])
         if bq and bk:
             from paddle_tpu.core.dtypes import mxu_operands
-            from paddle_tpu.ops.pallas import flash_attention
 
             out_dtype = q.dtype
             q, k, v = mxu_operands(q, k, v)  # bf16 halves K/V HBM traffic
             # 128-divisible lengths defer to the kernel's chip-measured
             # tuned_blocks table; shorter sequences pin the largest divisor
-            return flash_attention(
-                q, k, v, causal=causal, sm_scale=scale,
+            return _flash_per_shard(
+                q, k, v, kv_len, causal=causal, sm_scale=scale,
                 block_q=None if bq == 128 else bq,
                 block_k=None if bk == 128 else bk,
-                kv_len=kv_len, window=window,
+                window=window,
             ).astype(out_dtype)
     if kv_len is not None:
         from paddle_tpu.core.dtypes import NEG_INF

@@ -210,8 +210,8 @@ def _flash_fwd_kernel_resident(
 # K+V per (batch, head) beyond this stays in HBM and streams via the grid
 _VMEM_RESIDENT_BYTES = 4 * 1024 * 1024
 
-# Chip-measured (block_q, block_k) table, keyed by minimum sequence length —
-# populated from tests/tpu_flash_tune.py sweeps (FLASH_TUNE_TPU.json).
+# Chip-measured (block_q, block_k) table, keyed by minimum sequence length;
+# no sweep has run on a chip yet (ROADMAP A7), so it is empty.
 # An empty or non-matching table -> the 128/128 MXU-aligned default. Rows are
 # ascending by min_T; the last row whose min_T <= T and whose blocks divide
 # the sequence lengths wins.

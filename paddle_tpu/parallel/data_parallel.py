@@ -288,7 +288,7 @@ class DataParallel:
                 donate=(0, 1) if self.donate else (),
             )
         self._validate_batch(batch, self._batch_shardings(batch))
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._step_fn(variables, opt_state, rng, *batch)
 
     # distinct ragged tail shapes a variable-batch reader may produce; the
@@ -320,7 +320,7 @@ class DataParallel:
             self._ragged_step_fns[key] = self._build_step_fn(
                 variables, opt_state, tuple(rep for _ in batch), donate=(),
             )
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._ragged_step_fns[key](variables, opt_state, rng, *batch)
 
     def eval_step(self, variables: Variables, *batch, rng=None):
@@ -335,7 +335,7 @@ class DataParallel:
             )
             in_sh = (var_sh, replicated(self.mesh)) + self._batch_shardings(batch)
             self._eval_fn = jax.jit(raw, in_shardings=in_sh)
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._eval_fn(variables, rng, *batch)
 
     # -- elastic resize ------------------------------------------------------

@@ -313,11 +313,9 @@ class ServingEngine:
 
         base_place = place or cfg.default_place()
         platform = base_place.platform
-        local = [
-            d
-            for d in jax.devices()
-            if cfg._platform_matches(d, platform)
-        ] or jax.devices()
+        # an empty list is not papered over: _ReplicaPlace(...).device()
+        # below raises when the asked platform has no device here
+        local = [d for d in jax.devices() if d.platform == platform]
         n_rep = self.config.num_replicas or len(local)
         n_rep = max(1, min(n_rep, len(local)))
 

@@ -356,6 +356,10 @@ class Trainer:
                                 self._record_step(
                                     epoch_id, batch, time.perf_counter() - t_step,
                                     metrics)
+                            # drop the step's outputs (lm_large: 1 GB of logits)
+                            # before the next step runs: still referenced, they
+                            # sit in HBM beside that step's whole program
+                            out = None
                             handler(EndStepEvent(epoch_id, step_id, metrics))
                             if self._preempt_requested:
                                 with tracing.start_span("trainer.checkpoint",

@@ -1,0 +1,178 @@
+"""Compiles of the main path at real size for a described TPU v5e.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached. These are the programs
+``chip_smoke.py`` runs — the flash kernel, the ``lm_large`` train step, the
+paged serving steps and the four-chip data-parallel step — so what the
+chip's compiler would refuse (a kernel that cannot be partitioned, a
+program that does not fit HBM) fails here, at no chip time. Nothing runs:
+a passing compile says nothing about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU's library, and every xdist worker imports this
+file. The tests stay in this one file for the same reason.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import bench
+from paddle_tpu import models
+from paddle_tpu.core.config import flags, set_flags
+
+HBM_BYTES = 15.75 * 2**30  # what one v5e chip's allocator reports usable
+# chip_smoke.py's phase-2 engine: DecodeConfig(max_slots=16, page_size=16,
+# max_context=2048), default prefill_chunk
+SLOTS, PAGE, CONTEXT, CHUNK = 16, 16, 2048, 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Steer the code that asks ``jax.default_backend()`` (interpret-mode
+    selection in ops/pallas/flash_attention.py) onto its TPU branch, with
+    the bench's flags on and the persistent compile cache off: an entry
+    written for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prev = (flags().use_flash_attention, flags().use_bf16_compute)
+    prev_cache = jax.config.jax_enable_compilation_cache
+    set_flags(use_flash_attention=True, use_bf16_compute=True)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev_cache)
+    compilation_cache.reset_cache()
+    set_flags(use_flash_attention=prev[0], use_bf16_compute=prev[1])
+
+
+def _shapes(tree, sharding):
+    """ShapeDtypeStructs placed by ``sharding`` (one, or a matching tree):
+    ``jax.device_put`` to a described device fails."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+
+def _program_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _lm_large():
+    return models.get_model("transformer_lm", **bench.LM_LARGE_KWARGS)
+
+
+def _abstract_state(spec, batch):
+    v = jax.eval_shape(lambda: spec.model.init(0, *batch))
+    opt = spec.optimizer()
+    return opt, v, jax.eval_shape(opt.create_state, v.params)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_kernel_compiles(one_chip, as_tpu, backward):
+    from paddle_tpu.ops.pallas import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    fn = jax.grad(fwd, argnums=(0, 1, 2)) if backward else fwd
+    x = jax.ShapeDtypeStruct((4, 16, 2048, 64), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    # forward only: one kernel; with the fused backward: fwd + dq + dkv
+    assert text.count("tpu_custom_call") >= (3 if backward else 1)
+
+
+def test_lm_large_train_step_compiles(one_chip, as_tpu):
+    """The Trainer's step (no donation) at batch 2: the flash kernel is in
+    it — neither interpret mode nor the fall-through to XLA attention —
+    and the program fits the chip."""
+    spec = _lm_large()
+    batch = spec.synth_batch(2, np.random.RandomState(0))
+    opt, v, o = _abstract_state(spec, batch)
+    compiled = jax.jit(opt.minimize(spec.model)).lower(
+        _shapes(v, one_chip), _shapes(o, one_chip), *_shapes(batch, one_chip)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _program_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_paged_serving_steps_compile(one_chip, as_tpu, which):
+    """chip_smoke.py's phase-2 engine: 16 slots x 2048 positions of f32
+    pages. The pages are not donated (ROADMAP A3), so the step holds two
+    copies of the cache; it must still fit."""
+    from paddle_tpu.models.transformer_lm import (
+        paged_cache_shape, paged_decode_step, paged_prefill_chunk,
+    )
+
+    spec = _lm_large()
+    cfg = dict(spec.extra["cfg"], scan_layers=False)
+    params = jax.eval_shape(
+        lambda: spec.model.init(0, *spec.synth_batch(1, np.random.RandomState(0)))
+    ).params
+    per_slot = CONTEXT // PAGE
+    pages = jax.ShapeDtypeStruct(
+        paged_cache_shape(cfg, 1 + SLOTS * per_slot, PAGE), jnp.float32,
+        sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    if which == "decode_step":
+        fn, args = paged_decode_step, (i32(SLOTS), i32(SLOTS), i32(SLOTS, per_slot))
+    else:
+        fn, args = paged_prefill_chunk, (i32(CHUNK), i32(), i32(), i32(per_slot))
+    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=PAGE)).lower(
+        _shapes(params, one_chip), *args, pages, pages, None).compile()
+    assert _program_bytes(compiled) < HBM_BYTES
+
+
+def test_data_parallel_step_compiles_on_four_chips(topo, as_tpu):
+    """A Mosaic kernel cannot be partitioned automatically: under the
+    mesh, ops/attention.py runs the flash kernel per shard through
+    shard_map. On the virtual CPU mesh the kernel is interpreted into
+    plain XLA ops that partition freely, so only this compile sees it."""
+    from paddle_tpu.parallel import DataParallel
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    spec = _lm_large()
+    batch = spec.synth_batch(4, np.random.RandomState(0))
+    opt, v, o = _abstract_state(spec, batch)
+    mesh = make_mesh(data=4, devices=topo.devices)
+    dp = DataParallel(spec.model, opt, mesh=mesh)
+    batch_sh = dp._batch_shardings(batch)
+    var_sh, opt_sh = dp._state_shardings(v, o)
+    step = dp._build_step_fn(v, o, batch_sh, donate=(0, 1))
+    with jax.set_mesh(mesh):
+        compiled = step.lower(
+            _shapes(v, var_sh), _shapes(o, opt_sh), None,
+            *[_shapes(b, s) for b, s in zip(batch, batch_sh)],
+        ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert batch_sh[0].spec[0] == "data"  # one sequence per chip
+    assert _program_bytes(compiled) < HBM_BYTES

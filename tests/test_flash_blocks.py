@@ -1,9 +1,8 @@
 """Consistency pins for the flash kernel's tuned-block table.
 
-VERDICT r4 #8: ``_TUNED_BLOCKS`` is populated from chip measurement
-(``tests/tpu_flash_tune.py`` → ``FLASH_TUNE_TPU.json``) — but a bad
-checked-in tuple must fail HERE, on CPU, not crash the next scarce chip
-window. The constraints mirror what the kernel actually enforces
+VERDICT r4 #8: ``_TUNED_BLOCKS`` is to be populated from chip measurement
+(ROADMAP A7) — but a bad checked-in tuple must fail HERE, on CPU, not
+crash the next chip run. The constraints mirror what the kernel actually enforces
 (divisibility at ``_flash_fwd``, ``flash_attention.py:228-231``) plus the
 VMEM arithmetic a (block_q, block_k) tile implies. The reference's
 analogue is cuDNN algo selection with a fallback guarantee

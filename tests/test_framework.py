@@ -230,6 +230,38 @@ def test_persistent_compile_cache_flag(tmp_path, rng):
         cfg_mod._compile_cache_applied = prev_applied
 
 
+def test_compile_cache_env_var_wins(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no directory in
+    code (JAX reads the variable itself); unset, bench.py / chip_smoke.py
+    fall to the default they pass."""
+    cfg_mod = pt.core.config
+    monkeypatch.setattr(cfg_mod, "_compile_cache_applied", False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert cfg_mod.apply_compile_cache(default_dir=str(tmp_path / "d")) == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        monkeypatch.setattr(cfg_mod, "_compile_cache_applied", False)
+        assert cfg_mod.apply_compile_cache(default_dir=str(tmp_path / "d")) == str(tmp_path / "d")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "d")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+
+
+def test_place_raises_for_a_device_that_is_not_there():
+    """TPUPlace on a host without that chip must not stand in the CPU (or
+    another chip) and say nothing."""
+    assert pt.CPUPlace().device().platform == "cpu"
+    with pytest.raises(RuntimeError, match="no tpu device 0"):
+        pt.TPUPlace(0).device()
+    from paddle_tpu.serving.engine import _ReplicaPlace
+
+    with pytest.raises(RuntimeError, match="no cpu device 99"):
+        _ReplicaPlace("cpu", 99).device()
+
+
 def test_inferencer_dict_feed_in_feed_order(tmp_path, rng):
     """Dict feeds must be unpacked in feed_order (FeedSpec order), not raw
     insertion order — clients over the wire give no ordering guarantee."""

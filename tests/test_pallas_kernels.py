@@ -96,6 +96,58 @@ def test_flag_routes_sdpa_through_flash(rng):
     np.testing.assert_allclose(np.asarray(base), np.asarray(flashed), rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("axes,batch", [
+    (dict(data=4), 4), (dict(data=2, model=2), 4), (dict(data=4), 3),
+], ids=["data4", "data2_model2", "ragged_batch_replicated"])
+def test_flash_runs_per_shard_under_a_mesh(rng, axes, batch):
+    """Under an ambient mesh (jax.set_mesh, as DataParallel.step sets it)
+    sdpa wraps the kernel in shard_map — a Mosaic kernel cannot be
+    partitioned automatically on real chips (tests/test_chip_compile.py
+    holds that compile) — and the result equals the bare call."""
+    from paddle_tpu.core import config
+    from paddle_tpu.ops import attention as oattn
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    H, T, d = 4, 32, 8
+    q, k, v = (jnp.asarray(rng.randn(batch, H, T, d).astype(np.float32))
+               for _ in range(3))
+    kv_len = jnp.asarray(rng.randint(1, T + 1, size=(batch,)).astype(np.int32))
+    fn = lambda a, b, c, n: oattn.scaled_dot_product_attention(
+        a, b, c, causal=True, kv_len=n)
+    config.set_flags(use_flash_attention=True)
+    try:
+        bare = jax.jit(fn)(q, k, v, kv_len)
+        with jax.set_mesh(make_mesh(devices=jax.devices()[:4], **axes)):
+            text = str(jax.make_jaxpr(fn)(q, k, v, kv_len))
+            sharded = jax.jit(fn)(q, k, v, kv_len)
+    finally:
+        config.set_flags(use_flash_attention=False)
+    assert "shard_map" in text
+    np.testing.assert_allclose(np.asarray(sharded), np.asarray(bare), rtol=1e-5, atol=1e-6)
+
+
+def test_flash_under_partly_manual_mesh_raises(rng):
+    """No quiet way out to XLA attention: a mapping the wrapper cannot
+    make is an error."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.core import config
+    from paddle_tpu.core.compat import shard_map
+    from paddle_tpu.ops import attention as oattn
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    q = jnp.asarray(rng.randn(4, 4, 32, 8).astype(np.float32))
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    inner = lambda a: oattn.scaled_dot_product_attention(a, a, a, causal=True)
+    config.set_flags(use_flash_attention=True)
+    try:
+        with jax.set_mesh(mesh), pytest.raises(NotImplementedError, match="cannot be mapped"):
+            jax.jit(shard_map(inner, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                              axis_names={"data"}, check_vma=False))(q)
+    finally:
+        config.set_flags(use_flash_attention=False)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("streamed", [False, True])
 def test_flash_fused_backward_matches_reference(rng, causal, streamed, monkeypatch):
