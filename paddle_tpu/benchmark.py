@@ -140,7 +140,7 @@ def _make_batch(args, spec, rng):
 def run_benchmark(args) -> dict:
     import jax
 
-    from paddle_tpu import models, optimizer as opt_mod
+    from paddle_tpu import models, optimizer as opt_mod, tracing
     from paddle_tpu.core import profiler as prof
 
     if args.update_method in ("collective", "nccl2"):
@@ -240,7 +240,7 @@ def run_benchmark(args) -> dict:
                 float(jax.device_get(out.loss))
         dt = time.perf_counter() - t0
         if profiled:
-            timeline = prof.export_chrome_trace(
+            timeline = tracing.export_chrome_trace(
                 os.path.join(args.profile_dir, "timeline.chrome.json")
             )
             breakdown = prof.step_breakdown()

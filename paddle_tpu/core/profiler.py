@@ -7,16 +7,16 @@ Reference: ``platform/profiler.h:73-91`` (RecordEvent/RecordBlock RAII),
 
 TPU-native mapping: device-side tracing is jax.profiler (XPlane/Perfetto,
 viewable in TensorBoard/xprof); host-side step breakdown keeps the RAII
-annotation idiom via ``record_event`` which both feeds a host aggregation
-table and emits a TraceAnnotation visible in device traces.
+annotation idiom via ``record_event``, which feeds a host aggregation
+table and opens a ``paddle_tpu.tracing`` span: that span is the one place
+that writes a TraceAnnotation into device traces and the one store that
+holds host spans (``tracing.export_chrome_trace`` writes the timeline).
 """
 
 from __future__ import annotations
 
 import contextlib
-import json
 import os
-import threading
 import time
 from collections import defaultdict
 from typing import Iterator, Optional
@@ -24,12 +24,6 @@ from typing import Iterator, Optional
 import jax
 
 _events: dict[str, list[float]] = defaultdict(list)
-# correlated spans for the timeline export: (name, start_us, dur_us, tid)
-_spans: list[tuple[str, float, float, int]] = []
-# thread ident -> thread name, captured the first time a span lands on a
-# thread so export_chrome_trace can emit ph:"M" thread_name metadata
-_thread_names: dict[int, str] = {}
-_MAX_SPANS = 1_000_000
 _enabled: bool = False
 
 # -- counters/gauges: monotonically-increasing totals and last-value gauges
@@ -81,60 +75,28 @@ def reset_metrics() -> None:
 
 @contextlib.contextmanager
 def record_event(name: str) -> Iterator[None]:
-    """RAII host annotation (RecordEvent parity). Cheap when disabled."""
-    if not _enabled:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-        return
+    """RAII host annotation (RecordEvent parity): a ``tracing`` span of that
+    name, and a row of the aggregation table while the profiler is enabled."""
+    from paddle_tpu.tracing import context as spans  # it imports this module
+
     t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
+    with spans.start_span(name):
         yield
-    t1 = time.perf_counter()
-    _events[name].append(t1 - t0)
-    if len(_spans) < _MAX_SPANS:  # bound timeline memory on long runs
-        tid = threading.get_ident()
-        if tid not in _thread_names:
-            _thread_names[tid] = threading.current_thread().name
-        _spans.append((name, t0 * 1e6, (t1 - t0) * 1e6, tid))
-    else:
-        # the cap protects memory, but a silently truncated timeline is a
-        # debugging trap — count every drop and say so once per window
-        inc_counter("profiler.spans_dropped")
-        from paddle_tpu.core import logging as ptlog
-
-        ptlog.warn_once(
-            "profiler.spans_dropped",
-            "profiler: span buffer full (%d spans); further spans dropped — "
-            "the exported timeline is truncated (reset_profiler() or export "
-            "more often)",
-            _MAX_SPANS,
-        )
-
-
-def spans() -> list[tuple[str, float, float, int]]:
-    """Snapshot of recorded host spans as (name, start_us, dur_us, tid) —
-    consumed by the merged exporter in ``paddle_tpu.tracing.export``."""
-    return list(_spans)
-
-
-def thread_names() -> dict[int, str]:
-    """Snapshot of the tid → thread-name map captured alongside spans."""
-    return dict(_thread_names)
+    if _enabled:
+        _events[name].append(time.perf_counter() - t0)
 
 
 def enable_profiler() -> None:
     global _enabled
     _enabled = True
     _events.clear()
-    _spans.clear()
 
 
 def disable_profiler() -> dict[str, dict[str, float]]:
     """Stop host profiling and return the aggregation table
     (name → {calls, total_s, mean_s, min_s, max_s}), mirroring the sorted
-    summary of reference ``profiler.cc:476``. Clears the recorded events
-    AND spans so the next profiling window starts empty — a second
-    ``export_chrome_trace()`` must not replay this window's spans."""
+    summary of reference ``profiler.cc:476``. Clears the recorded events so
+    the next profiling window starts empty."""
     global _enabled
     _enabled = False
     table = {}
@@ -147,8 +109,6 @@ def disable_profiler() -> dict[str, dict[str, float]]:
             "max_s": max(times),
         }
     _events.clear()
-    _spans.clear()
-    _thread_names.clear()
     return table
 
 
@@ -159,40 +119,6 @@ def summary_string(table: Optional[dict] = None) -> str:
     for name, s in rows:
         lines.append(f"{name:40s} {s['calls']:8d} {s['total_s']:10.4f} {s['mean_s'] * 1e3:10.3f}")
     return "\n".join(lines)
-
-
-def export_chrome_trace(path: str) -> str:
-    """Write recorded host spans as a Chrome Trace Event Format file,
-    loadable in chrome://tracing / Perfetto UI — the consumable-timeline
-    artifact the reference's DeviceTracer emitted as a protobuf
-    (``platform/device_tracer.h:49-103`` GenProfile → proto timeline).
-    Device-side kernel timelines come from the jax.profiler XPlane trace;
-    this file carries the correlated host-side step phases."""
-    tids = {}
-    events = []
-    for name, start_us, dur_us, tid in _spans:
-        tids.setdefault(tid, len(tids))
-        events.append({
-            "name": name, "ph": "X", "cat": "host",
-            "ts": start_us, "dur": dur_us,
-            "pid": os.getpid(), "tid": tids[tid],
-        })
-    for tid, idx in tids.items():  # ph:"M" so Perfetto labels host threads
-        events.append({
-            "name": "thread_name", "ph": "M", "pid": os.getpid(), "tid": idx,
-            "args": {"name": _thread_names.get(tid, f"thread-{idx}")},
-        })
-    doc = {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "metadata": {"producer": "paddle_tpu.core.profiler"},
-    }
-    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(doc, f)
-    os.rename(tmp, path)
-    return path
 
 
 def step_breakdown(table: Optional[dict] = None) -> dict[str, float]:
@@ -215,8 +141,10 @@ def profiler(log_dir: Optional[str] = None) -> Iterator[None]:
     enable_profiler()
     with jax.profiler.trace(log_dir):
         yield
-    timeline = export_chrome_trace(os.path.join(log_dir, "timeline.chrome.json"))
     from paddle_tpu.core import logging as ptlog
+    from paddle_tpu.tracing import export
+
+    timeline = export.export_chrome_trace(os.path.join(log_dir, "timeline.chrome.json"))
 
     ptlog.info(
         "profiler trace written to %s (host timeline: %s)\n%s",
@@ -237,13 +165,10 @@ def stop_profiler() -> dict:
 
 
 def reset_profiler() -> None:
-    """Clear recorded host events AND timeline spans (reference
-    ``profiler.py:104`` — works for start/stop/``profiler``, not the CUDA
-    runtime profiler). Leaving ``_spans`` behind made a later
-    ``export_chrome_trace()`` replay the previous window."""
+    """Clear recorded host events (reference ``profiler.py:104`` — works
+    for start/stop/``profiler``, not the CUDA runtime profiler). The spans
+    live in ``paddle_tpu.tracing``: ``tracing.reset_tracing()`` clears them."""
     _events.clear()
-    _spans.clear()
-    _thread_names.clear()
 
 
 @contextlib.contextmanager

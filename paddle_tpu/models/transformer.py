@@ -51,6 +51,7 @@ def _proj(x, size, *, shard_out: bool, name: str, bias: bool = True):
     )
 
 
+@jax.named_scope("attention")
 def multi_head_attention(
     queries,
     keys,
@@ -122,6 +123,7 @@ def multi_head_attention(
         return _proj(out, d_model, shard_out=False, name="out")
 
 
+@jax.named_scope("ffn")
 def positionwise_ffn(x, d_inner: int, d_model: int, dropout_rate: float,
                      name: str = "ffn", activation: str = "relu"):
     """``activation='swiglu'`` gates the up-projection with a SiLU branch
@@ -154,6 +156,7 @@ def sinusoid_position_encoding(max_len: int, d_model: int, dtype=jnp.float32):
     return jnp.asarray(enc, dtype)
 
 
+@jax.named_scope("embed")
 def prepare_embedding(ids, vocab_size, d_model, max_len, dropout_rate, name,
                       pos_offset=0, add_position_encoding=True):
     """token embedding * sqrt(d) (+ fixed sinusoid position encoding unless
@@ -285,7 +288,7 @@ def decode(trg_ids, trg_pad, enc_out, src_pad, cfg, caches=None, pos_offset=0):
                 x, enc_out, self_mask, cross_mask, cfg, name=f"dec_layer_{i}",
                 cache=cache, self_causal=structural, cross_kv_len=cross_len,
             )
-    with name_scope("project"):
+    with jax.named_scope("head"), name_scope("project"):
         logits = _proj(x, cfg["trg_vocab"], shard_out=True, name="logits", bias=False)
     return logits
 
@@ -299,13 +302,14 @@ def transformer_forward(src_ids, src_pad, trg_ids, trg_pad, labels, label_pad, *
     logits = decode(trg_ids, trg_pad, enc_out, src_pad, cfg)
     vocab = cfg["trg_vocab"]
     eps = cfg["label_smooth_eps"]
-    onehot = jax.nn.one_hot(labels, vocab, dtype=jnp.float32)
-    smooth = onehot * (1 - eps) + eps / vocab
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    tok_loss = -jnp.sum(smooth * logp, axis=-1)  # [B, T]
-    weight = 1.0 - label_pad.astype(jnp.float32)
-    n_tok = jnp.maximum(jnp.sum(weight), 1.0)
-    avg_loss = jnp.sum(tok_loss * weight) / n_tok
+    with jax.named_scope("loss"):
+        onehot = jax.nn.one_hot(labels, vocab, dtype=jnp.float32)
+        smooth = onehot * (1 - eps) + eps / vocab
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        tok_loss = -jnp.sum(smooth * logp, axis=-1)  # [B, T]
+        weight = 1.0 - label_pad.astype(jnp.float32)
+        n_tok = jnp.maximum(jnp.sum(weight), 1.0)
+        avg_loss = jnp.sum(tok_loss * weight) / n_tok
     return avg_loss, n_tok, logits
 
 

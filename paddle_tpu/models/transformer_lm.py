@@ -149,12 +149,13 @@ def lm_block(x, cfg, name, kv_len=None):
                 token_mask = (
                     jnp.arange(x.shape[-2])[None, :] < kv_len[:, None]
                 )
-            mo = moe_ffn(
-                x, num_experts=cfg["moe_experts"], d_ff=cfg["d_inner"],
-                capacity_factor=cfg.get("moe_capacity_factor", 1.25),
-                router=cfg.get("moe_router", "top1"), name="moe_ffn",
-                token_mask=token_mask,
-            )
+            with jax.named_scope("ffn"):
+                mo = moe_ffn(
+                    x, num_experts=cfg["moe_experts"], d_ff=cfg["d_inner"],
+                    capacity_factor=cfg.get("moe_capacity_factor", 1.25),
+                    router=cfg.get("moe_router", "top1"), name="moe_ffn",
+                    token_mask=token_mask,
+                )
             ffn, aux = mo.output, mo.aux_loss
         else:
             ffn = positionwise_ffn(
@@ -337,11 +338,13 @@ def lm_forward(ids, labels, seq_lens=None, *, cfg):
         for i in range(cfg["n_layers"]):
             x, aux = block(x, name=f"layer_{i}", kv_len=seq_lens)
             aux_total = aux_total + aux
-    x = layers.layer_norm(x, begin_norm_axis=x.ndim - 1)
-    with name_scope("project"):
-        logits = _proj(x, cfg["vocab"], shard_out=True, name="logits", bias=False)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    with jax.named_scope("head"):
+        x = layers.layer_norm(x, begin_norm_axis=x.ndim - 1)
+        with name_scope("project"):
+            logits = _proj(x, cfg["vocab"], shard_out=True, name="logits", bias=False)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     # MoE router load-balance term (0 for dense-FFN configs) — a TRAINING
     # regularizer only: eval loss must stay the pure NLL so perplexity and
     # dense-baseline comparisons are unbiased
@@ -787,15 +790,16 @@ def paged_prefill_chunk(
     cdt = k_pages.dtype
     p, ln, proj, ffn, logits_of, sample = _paged_ops(params, cfg)
 
-    e = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
-    if rope:
-        from paddle_tpu.ops.attention import apply_rope, rope_tables
+    with jax.named_scope("embed"):
+        e = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
+        if rope:
+            from paddle_tpu.ops.attention import apply_rope, rope_tables
 
-        rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], t_eff))
-    else:
-        pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
-        e = e + jax.lax.dynamic_slice_in_dim(pe, pos0, C, axis=0)
-    x = e[None]  # [1, C, D]
+            rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], t_eff))
+        else:
+            pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
+            e = e + jax.lax.dynamic_slice_in_dim(pe, pos0, C, axis=0)
+        x = e[None]  # [1, C, D]
     pos = pos0 + jnp.arange(C, dtype=jnp.int32)
     phys = page_table[pos // page_size]  # [C] physical page per position
     off = pos % page_size
@@ -806,35 +810,42 @@ def paged_prefill_chunk(
 
     for i in range(L):
         pfx = f"layer_{i}/self_attn"
-        q = heads(proj(x, f"{pfx}/q"), H)
-        k = heads(proj(x, f"{pfx}/k"), H_kv)
-        v = heads(proj(x, f"{pfx}/v"), H_kv)
-        if rope:
-            cos = jax.lax.dynamic_slice_in_dim(rope_cos, pos0, C, axis=0)
-            sin = jax.lax.dynamic_slice_in_dim(rope_sin, pos0, C, axis=0)
-            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            q = heads(proj(x, f"{pfx}/q"), H)
+            k = heads(proj(x, f"{pfx}/k"), H_kv)
+            v = heads(proj(x, f"{pfx}/v"), H_kv)
+            if rope:
+                cos = jax.lax.dynamic_slice_in_dim(rope_cos, pos0, C, axis=0)
+                sin = jax.lax.dynamic_slice_in_dim(rope_sin, pos0, C, axis=0)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         # scatter the chunk's K/V into this sequence's pages (pre-rotated
         # K, exactly as generate() stores it)
-        k_pages = k_pages.at[i, phys, :, off].set(
-            k[0].transpose(1, 0, 2).astype(cdt))
-        v_pages = v_pages.at[i, phys, :, off].set(
-            v[0].transpose(1, 0, 2).astype(cdt))
+        with jax.named_scope("page_write"):
+            k_pages = k_pages.at[i, phys, :, off].set(
+                k[0].transpose(1, 0, 2).astype(cdt))
+            v_pages = v_pages.at[i, phys, :, off].set(
+                v[0].transpose(1, 0, 2).astype(cdt))
         # gather the sequence's whole logical context back through the
         # table (includes the chunk just written) and mask by position
-        kl = k_pages[i][page_table].transpose(1, 0, 2, 3).reshape(
-            H_kv, t_eff, dh)[None]
-        vl = v_pages[i][page_table].transpose(1, 0, 2, 3).reshape(
-            H_kv, t_eff, dh)[None]
-        qg = q.reshape(1, H_kv, G, C, dh)
-        s = jnp.einsum("bkgqd,bktd->bkgqt", qg, kl) * scale
-        s = jnp.where(live[None, None, None], s, -1e9)
-        ctx = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, -1), vl)
-        ctx = ctx.reshape(1, H, C, dh).transpose(0, 2, 1, 3).reshape(1, C, D)
-        x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
+        with jax.named_scope("attention"):
+            kl = k_pages[i][page_table].transpose(1, 0, 2, 3).reshape(
+                H_kv, t_eff, dh)[None]
+            vl = v_pages[i][page_table].transpose(1, 0, 2, 3).reshape(
+                H_kv, t_eff, dh)[None]
+            qg = q.reshape(1, H_kv, G, C, dh)
+            s = jnp.einsum("bkgqd,bktd->bkgqt", qg, kl) * scale
+            s = jnp.where(live[None, None, None], s, -1e9)
+            ctx = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, -1), vl)
+            ctx = ctx.reshape(1, H, C, dh).transpose(0, 2, 1, 3).reshape(1, C, D)
+            x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
+        with jax.named_scope("ffn"):
+            x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
 
-    x_last = jax.lax.dynamic_index_in_dim(x[0], last_index, 0, keepdims=False)
-    tok = sample(logits_of(x_last), rng, temperature, top_k, top_p)
+    with jax.named_scope("head"):
+        x_last = jax.lax.dynamic_index_in_dim(x[0], last_index, 0, keepdims=False)
+        logits = logits_of(x_last)
+    with jax.named_scope("sampling"):
+        tok = sample(logits, rng, temperature, top_k, top_p)
     return tok, k_pages, v_pages
 
 
@@ -887,7 +898,8 @@ def paged_decode_step(
     cdt = k_pages.dtype
     p, ln, proj, ffn, logits_of, sample = _paged_ops(params, cfg)
 
-    x = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
+    with jax.named_scope("embed"):
+        x = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
     if rope:
         from paddle_tpu.ops.attention import rope_tables
 
@@ -903,34 +915,42 @@ def paged_decode_step(
                 [yf1 * c - yf2 * s_, yf1 * s_ + yf2 * c], -1
             ).astype(y.dtype)
     else:
-        pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
-        x = x + pe[positions]
+        with jax.named_scope("embed"):
+            pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
+            x = x + pe[positions]
     phys = page_tables[jnp.arange(S), positions // page_size]  # [S]
     off = positions % page_size
     live = _paged_live_mask(positions, t_eff, window)  # [S, T_eff]
 
     for i in range(L):
         pfx = f"layer_{i}/self_attn"
-        q = proj(x, f"{pfx}/q").reshape(S, H, dh)
-        k = proj(x, f"{pfx}/k").reshape(S, H_kv, dh)
-        v = proj(x, f"{pfx}/v").reshape(S, H_kv, dh)
-        if rope:
-            q, k = rot(q), rot(k)
-        k_pages = k_pages.at[i, phys, :, off].set(k.astype(cdt))
-        v_pages = v_pages.at[i, phys, :, off].set(v.astype(cdt))
-        kl = k_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-            S, H_kv, t_eff, dh)
-        vl = v_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-            S, H_kv, t_eff, dh)
-        qg = q.reshape(S, H_kv, G, dh)
-        s = jnp.einsum("skgd,sktd->skgt", qg, kl) * scale
-        s = jnp.where(live[:, None, None], s, -1e9)
-        ctx = jnp.einsum("skgt,sktd->skgd", jax.nn.softmax(s, -1), vl)
-        ctx = ctx.reshape(S, D)
-        x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
+        with jax.named_scope("attention"):
+            q = proj(x, f"{pfx}/q").reshape(S, H, dh)
+            k = proj(x, f"{pfx}/k").reshape(S, H_kv, dh)
+            v = proj(x, f"{pfx}/v").reshape(S, H_kv, dh)
+            if rope:
+                q, k = rot(q), rot(k)
+        with jax.named_scope("page_write"):
+            k_pages = k_pages.at[i, phys, :, off].set(k.astype(cdt))
+            v_pages = v_pages.at[i, phys, :, off].set(v.astype(cdt))
+        with jax.named_scope("attention"):
+            kl = k_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
+                S, H_kv, t_eff, dh)
+            vl = v_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
+                S, H_kv, t_eff, dh)
+            qg = q.reshape(S, H_kv, G, dh)
+            s = jnp.einsum("skgd,sktd->skgt", qg, kl) * scale
+            s = jnp.where(live[:, None, None], s, -1e9)
+            ctx = jnp.einsum("skgt,sktd->skgd", jax.nn.softmax(s, -1), vl)
+            ctx = ctx.reshape(S, D)
+            x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
+        with jax.named_scope("ffn"):
+            x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
 
-    nxt = sample(logits_of(x), rng, temperature, top_k, top_p)
+    with jax.named_scope("head"):
+        logits = logits_of(x)
+    with jax.named_scope("sampling"):
+        nxt = sample(logits, rng, temperature, top_k, top_p)
     return nxt, k_pages, v_pages
 
 
@@ -985,7 +1005,8 @@ def paged_verify_step(
     cdt = k_pages.dtype
     p, ln, proj, ffn, logits_of, _ = _paged_ops(params, cfg)
 
-    x = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
+    with jax.named_scope("embed"):
+        x = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
     pos = positions[:, None] + jnp.arange(K1, dtype=jnp.int32)  # [S, K1]
     if rope:
         from paddle_tpu.ops.attention import rope_tables
@@ -1002,35 +1023,43 @@ def paged_verify_step(
                 [yf1 * c - yf2 * s_, yf1 * s_ + yf2 * c], -1
             ).astype(y.dtype)
     else:
-        pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
-        x = x + pe[pos]
+        with jax.named_scope("embed"):
+            pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
+            x = x + pe[pos]
     phys = page_tables[jnp.arange(S)[:, None], pos // page_size]  # [S, K1]
     off = pos % page_size
     live = _paged_live_mask(pos, t_eff, window)  # [S, K1, T_eff]
 
     for i in range(L):
         pfx = f"layer_{i}/self_attn"
-        q = proj(x, f"{pfx}/q").reshape(S, K1, H, dh)
-        k = proj(x, f"{pfx}/k").reshape(S, K1, H_kv, dh)
-        v = proj(x, f"{pfx}/v").reshape(S, K1, H_kv, dh)
-        if rope:
-            q, k = rot(q), rot(k)
-        k_pages = k_pages.at[i, phys, :, off].set(k.astype(cdt))
-        v_pages = v_pages.at[i, phys, :, off].set(v.astype(cdt))
-        kl = k_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-            S, H_kv, t_eff, dh)
-        vl = v_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-            S, H_kv, t_eff, dh)
-        qg = q.transpose(0, 2, 1, 3).reshape(S, H_kv, G, K1, dh)
-        s = jnp.einsum("skgqd,sktd->skgqt", qg, kl) * scale
-        s = jnp.where(live[:, None, None], s, -1e9)
-        ctx = jnp.einsum("skgqt,sktd->skgqd", jax.nn.softmax(s, -1), vl)
-        ctx = ctx.reshape(S, H, K1, dh).transpose(0, 2, 1, 3).reshape(
-            S, K1, D)
-        x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
+        with jax.named_scope("attention"):
+            q = proj(x, f"{pfx}/q").reshape(S, K1, H, dh)
+            k = proj(x, f"{pfx}/k").reshape(S, K1, H_kv, dh)
+            v = proj(x, f"{pfx}/v").reshape(S, K1, H_kv, dh)
+            if rope:
+                q, k = rot(q), rot(k)
+        with jax.named_scope("page_write"):
+            k_pages = k_pages.at[i, phys, :, off].set(k.astype(cdt))
+            v_pages = v_pages.at[i, phys, :, off].set(v.astype(cdt))
+        with jax.named_scope("attention"):
+            kl = k_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
+                S, H_kv, t_eff, dh)
+            vl = v_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
+                S, H_kv, t_eff, dh)
+            qg = q.transpose(0, 2, 1, 3).reshape(S, H_kv, G, K1, dh)
+            s = jnp.einsum("skgqd,sktd->skgqt", qg, kl) * scale
+            s = jnp.where(live[:, None, None], s, -1e9)
+            ctx = jnp.einsum("skgqt,sktd->skgqd", jax.nn.softmax(s, -1), vl)
+            ctx = ctx.reshape(S, H, K1, dh).transpose(0, 2, 1, 3).reshape(
+                S, K1, D)
+            x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
+        with jax.named_scope("ffn"):
+            x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
 
-    out = jnp.argmax(logits_of(x), -1).astype(jnp.int32)  # [S, K1]
+    with jax.named_scope("head"):
+        logits = logits_of(x)
+    with jax.named_scope("sampling"):
+        out = jnp.argmax(logits, -1).astype(jnp.int32)  # [S, K1]
     return out, k_pages, v_pages
 
 

@@ -417,5 +417,3 @@ def declare_tracing_families(registry: Optional[MetricRegistry] = None) -> None:
             "Latest observed skew ratio per (group, key)")
     r.counter("tracing.spans_evicted",
               "Spans evicted from the bounded in-memory span store")
-    r.counter("profiler.spans_dropped",
-              "Host profiler spans dropped after the span buffer filled")

@@ -306,6 +306,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: in
         )
         out, lse = pl.pallas_call(
             kernel,
+            name="flash_fwd_resident",
             grid=(B * H, T // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -333,6 +334,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: in
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B * H, T // block_q, t_kv // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -542,6 +544,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     len_spec3 = pl.BlockSpec(memory_space=pltpu.SMEM)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(B * h_kv, t_kv // block_k, group * n_qb),
         in_specs=[q_stream, kv_fixed, kv_fixed, q_stream, row_stream, row_stream,
                   len_spec3, len_spec3],
@@ -571,6 +574,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     kv_stream = pl.BlockSpec((1, block_k, d), lambda b, i, j: (kvrow(b), j, 0))
     (dq,) = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(B * H, T // block_q, t_kv // block_k),
         in_specs=[q_fixed, kv_stream, kv_stream, q_fixed, row_fixed, row_fixed,
                   len_spec3, len_spec3],

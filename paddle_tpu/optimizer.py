@@ -152,7 +152,8 @@ class Optimizer:
                     state,
                 ) if new_state else new_state
             info = param_info or model.param_info
-            new_params, new_opt = self.apply_gradients(params, grads, opt_state, info)
+            with jax.named_scope("optimizer_update"):
+                new_params, new_opt = self.apply_gradients(params, grads, opt_state, info)
             finite = None
             from paddle_tpu.core import config as _cfg
 

@@ -670,9 +670,8 @@ class DecodeEngine:
         self._rid_seq = itertools.count()
         self._killed = False
         self._drain_abort = False
-        self._loop_trace: Optional[tracing.SpanContext] = None
-        if tracing.tracing_enabled():
-            self._loop_trace = tracing.SpanContext.new_trace()
+        # the loop thread's spans hang under this one trace
+        self._loop_trace = tracing.SpanContext.new_trace()
 
         if dconf.warmup:
             self._warmup()
@@ -1073,43 +1072,56 @@ class DecodeEngine:
                     pass
             raise
 
+    def _in_flight(self) -> bool:
+        return bool(self._active or self._resume or self._pending_admit
+                    or self._pending_handoff)
+
     def _loop_body(self) -> None:
         dconf = self.decode_config
+        loop = self._loop_trace
         while True:
             if self._killed:
                 return  # abrupt death: kill() resolves the handles
             if self._drain_abort:
                 self._force_drain()
                 break
-            self._sweep_cancel_deadline()
-            self._probe_group()
-            self._admit_handoffs()
-            self._admit()
-            t0 = time.perf_counter()
-            did_promote = self._apply_promotes()
-            did_prefill = self._prefill_some()
-            did_step = self._decode_step()
-            if did_prefill or did_step or did_promote:
-                self.metrics.set_pages(self._kv.pages_in_use,
-                                       self._kv.pages_free)
-                self.metrics.set_active_slots(len(self._active))
-                self.metrics.set_load(self.load())
-                self.metrics.set_queue_depth(self._queue.qsize())
-                self._publish_digest()
-                if self._loop_trace is not None:
-                    tracing.record_span(
-                        "serving.decode.step", t0, time.perf_counter(),
-                        parent=self._loop_trace,
-                        active=len(self._active))
+            # an engine with nothing in flight turns every idle_poll_s:
+            # such a pass cancels its spans (the profiler annotation stays),
+            # or an idle engine would fill the span store with empty passes
+            with tracing.start_span("serving.decode.admit", parent=loop) as sp:
+                self._sweep_cancel_deadline()
+                self._probe_group()
+                self._admit_handoffs()
+                self._admit()
+                if not self._in_flight():
+                    sp.cancel()
+            with tracing.start_span("serving.decode.step", parent=loop) as sp:
+                did_promote = self._apply_promotes()
+                did_prefill = self._prefill_some()
+                did_step = self._decode_step()
+                did = did_prefill or did_step or did_promote
+                if did:
+                    sp.set(active=len(self._active))
+                else:
+                    sp.cancel()
+            if did:
+                with tracing.start_span("serving.decode.publish", parent=loop):
+                    self.metrics.set_pages(self._kv.pages_in_use,
+                                           self._kv.pages_free)
+                    self.metrics.set_active_slots(len(self._active))
+                    self.metrics.set_load(self.load())
+                    self.metrics.set_queue_depth(self._queue.qsize())
+                    self._publish_digest()
                 continue
             # idle: nothing to prefill or step — wait for work or drain out
-            if (self._active or self._resume or self._pending_admit
-                    or self._pending_handoff):
+            if self._in_flight():
                 continue
-            try:
-                req, ok = self._queue.recv(timeout=dconf.idle_poll_s)
-            except TimeoutError:
-                continue
+            with tracing.start_span("serving.decode.idle", parent=loop) as sp:
+                try:
+                    req, ok = self._queue.recv(timeout=dconf.idle_poll_s)
+                except TimeoutError:
+                    sp.cancel()
+                    continue
             if not ok:
                 break  # closed AND drained, nothing in flight
             self._pending_admit.append(req)
@@ -1483,11 +1495,10 @@ class DecodeEngine:
             did = True
             t1 = time.perf_counter()
             self.metrics.record_host_promote(t1 - t0)
-            parent = job_trace if job_trace is not None else self._loop_trace
-            if parent is not None:
-                tracing.record_span(
-                    "serving.host_tier.promote", t0, t1, parent=parent,
-                    engine=self.metrics.engine_label, page=d)
+            tracing.record_span(
+                "serving.host_tier.promote", t0, t1,
+                parent=job_trace if job_trace is not None else self._loop_trace,
+                engine=self.metrics.engine_label, page=d)
             # progress guard: the insert can be trimmed straight back out
             # (size-cap eviction, allocator pressure). Re-enqueue only on
             # real depth growth — otherwise a capped tree and a warm pool
@@ -1608,68 +1619,79 @@ class DecodeEngine:
             chunk_end = (c + 1) * C
             if not self._ensure_pages(req, min(chunk_end, len(req.seq))):
                 continue
-            chunk = np.zeros((C,), np.int32)
-            seg = req.seq[c * C:min((c + 1) * C, len(req.seq))]
-            chunk[:len(seg)] = seg
-            last = len(req.seq) - 1 - c * C
-            t0 = time.perf_counter()
-            try:
-                table_row = jnp.asarray(self._kv.page_tables[req.slot])
-                tok, self._k_pages, self._v_pages = self._prefill(
-                    self._params, jnp.asarray(chunk),
-                    jnp.int32(c * C), jnp.int32(max(last, 0)),
-                    table_row,
-                    self._k_pages, self._v_pages, self._next_key())
-                if self._spec_k:
-                    # the draft's cache must cover the same prefix so its
-                    # proposals attend real context (sampled token unused)
-                    _, self._dk_pages, self._dv_pages = self._draft_prefill(
-                        self._draft_params, jnp.asarray(chunk),
+            last_chunk = (c == n_chunks - 1)
+            # the chunk under the loop's trace: packing, the enqueue, the
+            # last chunk's wait, landing. The request's own tree gets its
+            # copy (enqueue to sync) once the chunk has gone through
+            with tracing.start_span("serving.decode.prefill", chunk=c,
+                                    last_chunk=last_chunk):
+                chunk = np.zeros((C,), np.int32)
+                seg = req.seq[c * C:min((c + 1) * C, len(req.seq))]
+                chunk[:len(seg)] = seg
+                last = len(req.seq) - 1 - c * C
+                t0 = time.perf_counter()
+                try:
+                    table_row = jnp.asarray(self._kv.page_tables[req.slot])
+                    tok, self._k_pages, self._v_pages = self._prefill(
+                        self._params, jnp.asarray(chunk),
                         jnp.int32(c * C), jnp.int32(max(last, 0)),
                         table_row,
-                        self._dk_pages, self._dv_pages, None)
-                last_chunk = (c == n_chunks - 1)
-                tok = int(tok) if last_chunk else 0
-            except Exception as e:
-                self._recover_request(req, e)
-                continue
-            t1 = time.perf_counter()
-            self.metrics.record_prefill_chunk(t1 - t0)
-            self.cost.observe_chunk(t1 - t0)
-            if req.trace is not None:
-                tracing.record_span("serving.decode.prefill", t0, t1,
-                                    parent=req.trace, chunk=c,
-                                    engine=self.metrics.engine_label)
-            req.chunks_done = c + 1
-            self._kv.seq_lens[req.slot] = min(chunk_end, len(req.seq))
-            budget -= 1
-            progressed = True
-            if last_chunk:
-                if self._prefix is not None:
-                    # every fully-written page is immutable from here on
-                    # (decode writes land past len(seq)) — publish them
-                    n_full = len(req.seq) // dconf.page_size
-                    if n_full:
-                        self._prefix.insert(
-                            req.seq, self._kv.slot_pages(req.slot)[:n_full])
-                        # write-through demote: the same immutable pages,
-                        # while the tree holds refs (no recycle race)
-                        self._host_demote(req, n_full)
-                req.phase = "decode"
-                req.cur_len = len(req.seq)
-                # the final chunk's sample IS the next token after the
-                # prefilled sequence — the first (or, after a resume, the
-                # next) generated token
-                self._wf_tokens(req, t1, 1, "prefill")
-                self._append_token(req, tok)
-                # prefill role (serving.disagg): publish instead of
-                # decoding here — unless that one sampled token already
-                # finished the request (it left _active via _finish).
-                # Draft-model engines keep their work local: the payload
-                # carries only the target cache.
-                if (self._handoff_sink is not None and not self._spec_k
-                        and req in self._active):
-                    self._publish_handoff(req)
+                        self._k_pages, self._v_pages, self._next_key())
+                    if self._spec_k:
+                        # the draft's cache must cover the same prefix so its
+                        # proposals attend real context (sampled token unused)
+                        _, self._dk_pages, self._dv_pages = self._draft_prefill(
+                            self._draft_params, jnp.asarray(chunk),
+                            jnp.int32(c * C), jnp.int32(max(last, 0)),
+                            table_row,
+                            self._dk_pages, self._dv_pages, None)
+                    if last_chunk:
+                        # only the last chunk's sample is read: the one
+                        # wait for the device in the prefill path
+                        with tracing.start_span("serving.decode.prefill.wait"):
+                            tok = int(tok)
+                    else:
+                        tok = 0
+                except Exception as e:
+                    self._recover_request(req, e)
+                    continue
+                t1 = time.perf_counter()
+                self.metrics.record_prefill_chunk(t1 - t0)
+                self.cost.observe_chunk(t1 - t0)
+                if req.trace is not None:
+                    tracing.record_span("serving.decode.prefill", t0, t1,
+                                        parent=req.trace, chunk=c,
+                                        engine=self.metrics.engine_label)
+                req.chunks_done = c + 1
+                self._kv.seq_lens[req.slot] = min(chunk_end, len(req.seq))
+                budget -= 1
+                progressed = True
+                if last_chunk:
+                    if self._prefix is not None:
+                        # every fully-written page is immutable from here on
+                        # (decode writes land past len(seq)) — publish them
+                        n_full = len(req.seq) // dconf.page_size
+                        if n_full:
+                            self._prefix.insert(
+                                req.seq, self._kv.slot_pages(req.slot)[:n_full])
+                            # write-through demote: the same immutable pages,
+                            # while the tree holds refs (no recycle race)
+                            self._host_demote(req, n_full)
+                    req.phase = "decode"
+                    req.cur_len = len(req.seq)
+                    # the final chunk's sample IS the next token after the
+                    # prefilled sequence — the first (or, after a resume, the
+                    # next) generated token
+                    self._wf_tokens(req, t1, 1, "prefill")
+                    self._append_token(req, tok)
+                    # prefill role (serving.disagg): publish instead of
+                    # decoding here — unless that one sampled token already
+                    # finished the request (it left _active via _finish).
+                    # Draft-model engines keep their work local: the payload
+                    # carries only the target cache.
+                    if (self._handoff_sink is not None and not self._spec_k
+                            and req in self._active):
+                        self._publish_handoff(req)
         return progressed
 
     def _decode_step(self) -> bool:
@@ -1704,56 +1726,71 @@ class DecodeEngine:
 
         if not decoding:
             return False
-        for req in list(decoding):
-            if req not in self._active:
-                # preempted as the victim of an earlier grow this iteration
-                decoding.remove(req)
-                continue
-            if not self._ensure_pages(req, req.cur_len + 1):
-                decoding.remove(req)
-        # a later grow can also preempt an already-checked request
-        decoding = [r for r in decoding if r in self._active]
-        if not decoding:
-            return False
         S = self.decode_config.max_slots
-        P = self._kv.pages_per_slot
-        tokens = np.zeros((S,), np.int32)
-        positions = np.zeros((S,), np.int32)
-        tables = np.full((S, P), SCRATCH_PAGE, np.int32)
-        for req in decoding:
-            tokens[req.slot] = req.last_tok
-            positions[req.slot] = req.cur_len
-            tables[req.slot] = self._kv.page_tables[req.slot]
-        t0 = time.perf_counter()
-        try:
-            faults.inject(faults.DECODE_STEP,
-                          engine=self.metrics.engine_label)
-            nxt, self._k_pages, self._v_pages = self._step(
-                self._params, jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(tables), self._k_pages, self._v_pages,
-                self._next_key())
-            nxt = np.asarray(nxt)
-        except Exception as e:
-            # a failed step loses this iteration's K/V writes for every
-            # in-flight sequence
-            if self.decode_config.recovery:
-                self._recover_step_fault(e)
+        with tracing.start_span("serving.decode.model_step",
+                                max_slots=S) as step_span:
+            with tracing.start_span("serving.decode.model_step.pack") as pack_span:
+                for req in list(decoding):
+                    if req not in self._active:
+                        # preempted as the victim of an earlier grow this
+                        # iteration
+                        decoding.remove(req)
+                        continue
+                    if not self._ensure_pages(req, req.cur_len + 1):
+                        decoding.remove(req)
+                # a later grow can also preempt an already-checked request
+                decoding = [r for r in decoding if r in self._active]
+                if not decoding:
+                    pack_span.cancel()
+                    step_span.cancel()
+                    return False
+                P = self._kv.pages_per_slot
+                tokens = np.zeros((S,), np.int32)
+                positions = np.zeros((S,), np.int32)
+                tables = np.full((S, P), SCRATCH_PAGE, np.int32)
+                for req in decoding:
+                    tokens[req.slot] = req.last_tok
+                    positions[req.slot] = req.cur_len
+                    tables[req.slot] = self._kv.page_tables[req.slot]
+            t0 = time.perf_counter()
+            try:
+                faults.inject(faults.DECODE_STEP,
+                              engine=self.metrics.engine_label)
+                with tracing.start_span("serving.decode.model_step.dispatch"):
+                    nxt, self._k_pages, self._v_pages = self._step(
+                        self._params, jnp.asarray(tokens),
+                        jnp.asarray(positions), jnp.asarray(tables),
+                        self._k_pages, self._v_pages, self._next_key())
+                with tracing.start_span("serving.decode.model_step.wait"):
+                    nxt = np.asarray(nxt)
+            except Exception as e:
+                # a failed step loses this iteration's K/V writes for every
+                # in-flight sequence
+                if self.decode_config.recovery:
+                    self._recover_step_fault(e)
+                    return True
+                runlog.emit("decode_step_error", error=repr(e),
+                            engine=self.metrics.engine_label)
+                ptlog.error("decode step failed: %r", e)
+                for req in list(self._active):
+                    self._fail(req, e)
                 return True
-            runlog.emit("decode_step_error", error=repr(e),
-                        engine=self.metrics.engine_label)
-            ptlog.error("decode step failed: %r", e)
-            for req in list(self._active):
-                self._fail(req, e)
-            return True
-        t1 = time.perf_counter()
-        self._note_step_ok()
-        self.metrics.record_step(len(decoding), S, t1 - t0, len(decoding))
-        self.cost.observe_step(t1 - t0)
-        for req in list(decoding):
-            req.cur_len += 1
-            self._kv.seq_lens[req.slot] = req.cur_len
-            self._wf_tokens(req, t1, 1, "decode")
-            self._append_token(req, int(nxt[req.slot]))
+            t1 = time.perf_counter()
+            seconds = t1 - t0
+            # the counts at the boundary, and the very float the metrics
+            # get: a reader finds an iteration by it, without a tap
+            step_span.set(active=len(decoding), new_tokens=len(decoding),
+                          seconds=seconds)
+            with tracing.start_span("serving.decode.model_step.land"):
+                self._note_step_ok()
+                self.metrics.record_step(len(decoding), S, seconds,
+                                         len(decoding))
+                self.cost.observe_step(seconds)
+                for req in list(decoding):
+                    req.cur_len += 1
+                    self._kv.seq_lens[req.slot] = req.cur_len
+                    self._wf_tokens(req, t1, 1, "decode")
+                    self._append_token(req, int(nxt[req.slot]))
         return True
 
     def _verify_decode_step(self, spec: List[_DecodeRequest]) -> bool:
@@ -1771,97 +1808,112 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         K = self._spec_k
-        for req in list(spec):
-            if req not in self._active:
-                # preempted as the victim of an earlier grow this iteration
-                spec.remove(req)
-                continue
-            if not self._ensure_pages(req, req.cur_len + K + 1):
-                spec.remove(req)
-        spec = [r for r in spec if r in self._active]
-        if not spec:
-            return False
         S = self.decode_config.max_slots
-        P = self._kv.pages_per_slot
-        tokens = np.zeros((S,), np.int32)
-        positions = np.zeros((S,), np.int32)
-        tables = np.full((S, P), SCRATCH_PAGE, np.int32)
-        for req in spec:
-            tokens[req.slot] = req.last_tok
-            positions[req.slot] = req.cur_len
-            tables[req.slot] = self._kv.page_tables[req.slot]
-        t0 = time.perf_counter()
-        try:
-            faults.inject(faults.DECODE_STEP,
-                          engine=self.metrics.engine_label)
-            tables_j = jnp.asarray(tables)
-            pos = jnp.asarray(positions)
-            cur = jnp.asarray(tokens)
-            cols = []
-            for j in range(K):
-                cur, self._dk_pages, self._dv_pages = self._draft_step(
-                    self._draft_params, cur, pos + j, tables_j,
-                    self._dk_pages, self._dv_pages, None)
-                cols.append(cur)
-            draft_mat = np.stack([np.asarray(c) for c in cols], 1)  # [S, K]
-            block = np.concatenate([tokens[:, None], draft_mat], 1)
-            out, self._k_pages, self._v_pages = self._verify(
-                self._params, jnp.asarray(block), pos, tables_j,
-                self._k_pages, self._v_pages)
-            out = np.asarray(out)
-        except Exception as e:
-            # same contract as the plain step: the iteration's K/V writes
-            # (draft and target) are lost; recovery re-prefills from host
-            if self.decode_config.recovery:
-                self._recover_step_fault(e)
+        with tracing.start_span("serving.decode.verify") as verify_span:
+            with tracing.start_span("serving.decode.verify.pack") as pack_span:
+                for req in list(spec):
+                    if req not in self._active:
+                        # preempted as the victim of an earlier grow this
+                        # iteration
+                        spec.remove(req)
+                        continue
+                    if not self._ensure_pages(req, req.cur_len + K + 1):
+                        spec.remove(req)
+                spec = [r for r in spec if r in self._active]
+                if not spec:
+                    pack_span.cancel()
+                    verify_span.cancel()
+                    return False
+                P = self._kv.pages_per_slot
+                tokens = np.zeros((S,), np.int32)
+                positions = np.zeros((S,), np.int32)
+                tables = np.full((S, P), SCRATCH_PAGE, np.int32)
+                for req in spec:
+                    tokens[req.slot] = req.last_tok
+                    positions[req.slot] = req.cur_len
+                    tables[req.slot] = self._kv.page_tables[req.slot]
+            t0 = time.perf_counter()
+            try:
+                faults.inject(faults.DECODE_STEP,
+                              engine=self.metrics.engine_label)
+                with tracing.start_span("serving.decode.verify.dispatch",
+                                        model="draft"):
+                    tables_j = jnp.asarray(tables)
+                    pos = jnp.asarray(positions)
+                    cur = jnp.asarray(tokens)
+                    cols = []
+                    for j in range(K):
+                        cur, self._dk_pages, self._dv_pages = self._draft_step(
+                            self._draft_params, cur, pos + j, tables_j,
+                            self._dk_pages, self._dv_pages, None)
+                        cols.append(cur)
+                with tracing.start_span("serving.decode.verify.wait",
+                                        model="draft"):
+                    draft_mat = np.stack([np.asarray(c) for c in cols], 1)  # [S, K]
+                with tracing.start_span("serving.decode.verify.dispatch",
+                                        model="target"):
+                    block = np.concatenate([tokens[:, None], draft_mat], 1)
+                    out, self._k_pages, self._v_pages = self._verify(
+                        self._params, jnp.asarray(block), pos, tables_j,
+                        self._k_pages, self._v_pages)
+                with tracing.start_span("serving.decode.verify.wait",
+                                        model="target"):
+                    out = np.asarray(out)
+            except Exception as e:
+                # same contract as the plain step: the iteration's K/V writes
+                # (draft and target) are lost; recovery re-prefills from host
+                if self.decode_config.recovery:
+                    self._recover_step_fault(e)
+                    return True
+                runlog.emit("decode_step_error", error=repr(e),
+                            engine=self.metrics.engine_label)
+                ptlog.error("verify step failed: %r", e)
+                for req in list(self._active):
+                    self._fail(req, e)
                 return True
-            runlog.emit("decode_step_error", error=repr(e),
-                        engine=self.metrics.engine_label)
-            ptlog.error("verify step failed: %r", e)
-            for req in list(self._active):
-                self._fail(req, e)
-            return True
-        t1 = time.perf_counter()
-        self._note_step_ok()
-        new_tokens = 0
-        drafts_accepted = 0
-        eos = self.decode_config.eos_id
-        for req in list(spec):
-            row = out[req.slot]
-            n_acc = 0
-            while (n_acc < K
-                   and int(draft_mat[req.slot, n_acc]) == int(row[n_acc])):
-                n_acc += 1
-            drafts_accepted += n_acc
-            # waterfall booking mirrors _append_token's finish conditions
-            # exactly: the block truncates at eos / budget, and the n
-            # tokens this iteration lands book n TPOT samples of dt/n —
-            # the speculation-aware accounting contract
-            n_land = min(n_acc + 1, req.mnt - len(req.generated))
-            if eos is not None:
-                for j in range(n_land):
-                    if int(row[j]) == eos:
-                        n_land = j + 1
-                        break
-            self._wf_tokens(req, t1, n_land, "verify")
-            for j in range(n_acc + 1):
-                if req not in self._active:
-                    break  # finished (eos / budget) mid-block
-                req.cur_len += 1
-                self._kv.seq_lens[req.slot] = req.cur_len
-                self._append_token(req, int(row[j]))
-                new_tokens += 1
-            if req in self._active:
-                # roll back pages granted for rejected draft positions
-                self._kv.trim(req.slot, req.cur_len)
-        self.metrics.record_verify_step(
-            len(spec), S, t1 - t0, new_tokens,
-            drafts_proposed=len(spec) * K, drafts_accepted=drafts_accepted)
-        self.cost.observe_verify(t1 - t0, new_tokens / len(spec))
-        if self._loop_trace is not None:
-            tracing.record_span(
-                "serving.decode.verify", t0, t1, parent=self._loop_trace,
-                slots=len(spec), accepted=new_tokens)
+            t1 = time.perf_counter()
+            seconds = t1 - t0
+            with tracing.start_span("serving.decode.verify.land"):
+                self._note_step_ok()
+                new_tokens = 0
+                drafts_accepted = 0
+                eos = self.decode_config.eos_id
+                for req in list(spec):
+                    row = out[req.slot]
+                    n_acc = 0
+                    while (n_acc < K
+                           and int(draft_mat[req.slot, n_acc]) == int(row[n_acc])):
+                        n_acc += 1
+                    drafts_accepted += n_acc
+                    # waterfall booking mirrors _append_token's finish
+                    # conditions exactly: the block truncates at eos /
+                    # budget, and the n tokens this iteration lands book n
+                    # TPOT samples of dt/n — the speculation-aware
+                    # accounting contract
+                    n_land = min(n_acc + 1, req.mnt - len(req.generated))
+                    if eos is not None:
+                        for j in range(n_land):
+                            if int(row[j]) == eos:
+                                n_land = j + 1
+                                break
+                    self._wf_tokens(req, t1, n_land, "verify")
+                    for j in range(n_acc + 1):
+                        if req not in self._active:
+                            break  # finished (eos / budget) mid-block
+                        req.cur_len += 1
+                        self._kv.seq_lens[req.slot] = req.cur_len
+                        self._append_token(req, int(row[j]))
+                        new_tokens += 1
+                    if req in self._active:
+                        # roll back pages granted for rejected draft positions
+                        self._kv.trim(req.slot, req.cur_len)
+                self.metrics.record_verify_step(
+                    len(spec), S, seconds, new_tokens,
+                    drafts_proposed=len(spec) * K,
+                    drafts_accepted=drafts_accepted)
+                self.cost.observe_verify(seconds, new_tokens / len(spec))
+            verify_span.set(slots=len(spec), accepted=new_tokens,
+                            seconds=seconds)
         return True
 
     # -- zero-loss recovery (serving.recovery) -----------------------------

@@ -21,8 +21,13 @@ Two timestamp APIs cover the two shapes of instrumentation:
   measured by the batcher thread against a timestamp taken by the
   submitter).
 
-All span times share the profiler's timebase (``time.perf_counter()``
-microseconds) so host spans from both systems line up in one export.
+A scoped span also holds a ``jax.profiler.TraceAnnotation`` of its own name
+for its lifetime, so while ``jax.profiler`` traces, the span lands on the
+host plane of the ``.xplane.pb`` beside the device ops, on the profiler's
+clock. A retroactive ``record_span`` cannot: its ends are already past.
+
+With tracing disabled ``start_span``/``start_trace`` hand out one shared
+no-op scope: no ids, no ``Span``, no annotation, no lock.
 """
 
 from __future__ import annotations
@@ -31,7 +36,9 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional
+
+import jax
 
 from paddle_tpu.core import locks
 from paddle_tpu.core import profiler as prof
@@ -98,13 +105,22 @@ class SpanContext:
         self.parent_id = parent_id
 
     @classmethod
+    def _minted(cls, trace_id: str, span_id: str,
+                parent_id: Optional[str]) -> "SpanContext":
+        # ids this module made itself need no check: a scoped span mints
+        # one on every hot-loop pass
+        ctx = object.__new__(cls)
+        ctx.trace_id, ctx.span_id, ctx.parent_id = trace_id, span_id, parent_id
+        return ctx
+
+    @classmethod
     def new_trace(cls) -> "SpanContext":
         """A fresh root context (no parent)."""
-        return cls(os.urandom(16).hex(), os.urandom(8).hex())
+        return cls._minted(os.urandom(16).hex(), os.urandom(8).hex(), None)
 
     def child(self) -> "SpanContext":
         """A new context in the same trace, parented to this span."""
-        return SpanContext(self.trace_id, os.urandom(8).hex(), self.span_id)
+        return self._minted(self.trace_id, os.urandom(8).hex(), self.span_id)
 
     def to_traceparent(self) -> str:
         """W3C trace-context ``traceparent`` header value
@@ -262,14 +278,17 @@ def _commit(span: Span) -> None:
 
 
 class _SpanScope:
-    """Context manager returned by start_span/start_trace."""
+    """Context manager returned by start_span/start_trace. Holds a profiler
+    annotation of the span's name from enter to exit."""
 
-    __slots__ = ("_span",)
+    __slots__ = ("_span", "_annotation")
 
     def __init__(self, span: Span):
         self._span = span
+        self._annotation = jax.profiler.TraceAnnotation(span.name)
 
     def __enter__(self) -> Span:
+        self._annotation.__enter__()
         _stack().append(self._span)
         _open[id(self._span)] = self._span
         return self._span
@@ -290,21 +309,53 @@ class _SpanScope:
             span.attrs.setdefault("exception", exc_type.__name__)
         if _enabled and not span._cancelled:
             _commit(span)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
-def start_span(name: str, parent=None, **attrs) -> _SpanScope:
+class _NoopSpan:
+    """What the disabled scope yields: takes ``set``/``cancel`` like a
+    Span and keeps nothing."""
+
+    __slots__ = ()
+
+    def set(self, **attrs) -> "_NoopSpan":
+        return self
+
+    def cancel(self) -> None:
+        pass
+
+
+class _NoopScope:
+    __slots__ = ()
+
+    def __enter__(self) -> _NoopSpan:
+        return _NOOP_SPAN
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+_NOOP_SPAN = _NoopSpan()
+_NOOP_SCOPE = _NoopScope()
+
+
+def start_span(name: str, parent=None, **attrs):
     """Open a span as a child of ``parent`` (a Span or SpanContext), or of
     this thread's current span, or as a new root if neither exists. Usable
     as ``with start_span("trainer.h2d") as sp: ...``."""
+    if not _enabled:
+        return _NOOP_SCOPE
     pctx = _resolve_parent(parent)
     ctx = pctx.child() if pctx is not None else SpanContext.new_trace()
     return _SpanScope(Span(name, ctx, time.perf_counter() * 1e6, attrs))
 
 
-def start_trace(name: str, **attrs) -> _SpanScope:
+def start_trace(name: str, **attrs):
     """Open a new ROOT span (fresh trace_id) regardless of any span already
     open on this thread — one trace per training step / per request."""
+    if not _enabled:
+        return _NOOP_SCOPE
     return _SpanScope(Span(name, SpanContext.new_trace(), time.perf_counter() * 1e6, attrs))
 
 

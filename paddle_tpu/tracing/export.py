@@ -6,8 +6,8 @@ converted for chrome://tracing. Here the merge happens directly into Chrome
 Trace Event Format JSON, combining four sources on one timebase
 (``time.perf_counter()`` microseconds):
 
-* tracing spans (``ph:"X"``, with trace_id/span_id/parent_id in ``args``)
-* host profiler spans from ``core.profiler`` (``ph:"X"``, cat ``host``)
+* tracing spans (``ph:"X"``, with trace_id/span_id/parent_id in ``args``;
+  ``core.profiler.record_event`` opens one too, so they are all here)
 * runlog events (``ph:"i"`` instants; epoch timestamps converted via the
   import-time clock offset)
 * device HBM samples (``ph:"C"`` counter tracks per device)
@@ -28,7 +28,6 @@ import json
 import os
 from typing import Dict, List, Optional
 
-from paddle_tpu.core import profiler as prof
 from paddle_tpu.tracing import context as _ctx
 from paddle_tpu.tracing import memory as _mem
 
@@ -46,7 +45,6 @@ _ROOFLINE_RAW_TID = -1
 
 def chrome_trace_doc(
     runlog_path: Optional[str] = None,
-    include_profiler: bool = True,
     include_device: bool = True,
     include_roofline: bool = True,
 ) -> dict:
@@ -79,16 +77,6 @@ def chrome_trace_doc(
             "pid": pid, "tid": chrome_tid(span.tid, span.thread_name),
             "args": args,
         })
-
-    if include_profiler:
-        prof_threads = prof.thread_names()
-        for name, start_us, dur_us, raw_tid in prof.spans():
-            events.append({
-                "name": name, "ph": "X", "cat": "host",
-                "ts": start_us, "dur": dur_us,
-                "pid": pid,
-                "tid": chrome_tid(raw_tid, prof_threads.get(raw_tid, f"thread-{raw_tid}")),
-            })
 
     if runlog_path is None:
         from paddle_tpu.observability import runlog as _runlog
@@ -154,15 +142,12 @@ def chrome_trace_doc(
 def export_chrome_trace(
     path: str,
     runlog_path: Optional[str] = None,
-    include_profiler: bool = True,
     include_device: bool = True,
     include_roofline: bool = True,
 ) -> str:
-    """Write the merged trace atomically (tmp + rename, same contract as
-    ``profiler.export_chrome_trace``) and return ``path``."""
+    """Write the merged trace atomically (tmp + rename) and return ``path``."""
     doc = chrome_trace_doc(
         runlog_path=runlog_path,
-        include_profiler=include_profiler,
         include_device=include_device,
         include_roofline=include_roofline,
     )

@@ -295,22 +295,24 @@ class Trainer:
                             self._elastic.recover(self, probe_err)
                             recovered = True
                             break
-                    t_wait0 = time.perf_counter()
-                    batch = next(batches, None)
-                    t_wait1 = time.perf_counter()
-                    if batch is None:
-                        break
-                    step_id += 1
                     try:
                         with tracing.start_trace(
                             "trainer.step", epoch=epoch_id,
                         ) as step_span:
-                            # the step trace begins where the data wait began
-                            step_span.t0_us = t_wait0 * 1e6
+                            # the step's trace begins with the wait for the
+                            # reader; at the end of the epoch there is no step
+                            with tracing.start_span("trainer.data_wait") as wait_span:
+                                batch = next(batches, None)
+                                if batch is None:
+                                    wait_span.cancel()
+                            if batch is None:
+                                step_span.cancel()
+                                break
+                            step_id += 1
                             step_span.set(step=self.global_step)
-                            tracing.record_span("trainer.data_wait", t_wait0, t_wait1)
                             begin_ev = BeginStepEvent(epoch_id, step_id)
-                            handler(begin_ev)
+                            with tracing.start_span("trainer.begin_event"):
+                                handler(begin_ev)
                             # elastic fault points: a scheduler's advance
                             # preemption notice ("preempt" -> SIGTERM, handled
                             # at the boundary below) and a device vanishing
@@ -333,9 +335,17 @@ class Trainer:
                                     out = self._run_step(batch)
                             else:
                                 out = self._run_step(batch)
-                            bad = (out.finite is not None and not bool(out.finite)) or (
-                                spec is not None and spec.kind == "nan"
-                            )
+                            # the wait for the device: the step was only
+                            # enqueued. Honoring fetch_metrics avoids a host
+                            # sync per step (reference
+                            # BeginStepEvent.fetch_metrics, trainer.py:158)
+                            with tracing.start_span("trainer.fetch"):
+                                bad = (out.finite is not None and not bool(out.finite)) or (
+                                    spec is not None and spec.kind == "nan"
+                                )
+                                metrics = None
+                                if begin_ev.fetch_metrics:
+                                    metrics = float("nan") if bad else float(out.loss)
                             if bad:
                                 step_span.set(status="bad_step")
                                 # charge the wasted step to badput even if the policy
@@ -344,23 +354,25 @@ class Trainer:
                                     time.perf_counter() - t_step, "nan_skip")
                                 # may raise (policy "raise", or rollback gave up)
                                 self._handle_bad_step(epoch_id, step_id)
-                                metrics = float("nan") if begin_ev.fetch_metrics else None
                             else:
-                                self._consec_bad = 0
-                                self._rollbacks_since_good = 0
-                                self.variables, self.opt_state = out.variables, out.opt_state
-                                self.global_step += 1
-                                # honoring fetch_metrics avoids a host sync per step
-                                # (reference BeginStepEvent.fetch_metrics, trainer.py:158)
-                                metrics = float(out.loss) if begin_ev.fetch_metrics else None
-                                self._record_step(
-                                    epoch_id, batch, time.perf_counter() - t_step,
-                                    metrics)
-                            # drop the step's outputs (lm_large: 1 GB of logits)
-                            # before the next step runs: still referenced, they
-                            # sit in HBM beside that step's whole program
-                            out = None
-                            handler(EndStepEvent(epoch_id, step_id, metrics))
+                                # taking the new state frees the old (some 2000
+                                # device arrays of lm_large: milliseconds), and
+                                # so does dropping the step's outputs (1 GB of
+                                # logits): still referenced, they would sit in
+                                # HBM beside the next step's whole program
+                                with tracing.start_span("trainer.commit"):
+                                    self._consec_bad = 0
+                                    self._rollbacks_since_good = 0
+                                    self.variables, self.opt_state = out.variables, out.opt_state
+                                    self.global_step += 1
+                                    out = None
+                                with tracing.start_span("trainer.record_step"):
+                                    self._record_step(
+                                        epoch_id, batch, time.perf_counter() - t_step,
+                                        metrics)
+                            out = None  # a bad step's outputs go too
+                            with tracing.start_span("trainer.end_event"):
+                                handler(EndStepEvent(epoch_id, step_id, metrics))
                             if self._preempt_requested:
                                 with tracing.start_span("trainer.checkpoint",
                                                         reason="preempt"):

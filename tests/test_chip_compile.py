@@ -109,6 +109,28 @@ def test_flash_kernel_compiles(one_chip, as_tpu, backward):
     assert text.count("tpu_custom_call") >= (3 if backward else 1)
 
 
+@pytest.mark.parametrize("kernel, shape, backward", [
+    ("flash_fwd_resident", (4, 16, 2048, 64), False),  # K/V of a head fit VMEM
+    ("flash_fwd", (1, 4, 16384, 128), False),  # 8 MB of K/V a head: streamed
+    ("flash_bwd_dkv", (4, 16, 2048, 64), True),
+    ("flash_bwd_dq", (4, 16, 2048, 64), True),
+])
+def test_flash_kernels_carry_their_names(one_chip, as_tpu, kernel, shape, backward):
+    """Each ``pallas_call`` has a ``name=``: the compiled instruction is
+    called after it, so a device trace tells the kernels apart."""
+    from paddle_tpu.ops.pallas import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    fn = jax.grad(fwd, argnums=(0, 1, 2)) if backward else fwd
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    calls = [line for line in jax.jit(fn).lower(x, x, x).compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    named = [line.split(" = ", 1)[0].strip().rstrip("_.0123456789") for line in calls]
+    assert any(n.endswith(kernel) for n in named), named
+
+
 def test_lm_large_train_step_compiles(one_chip, as_tpu):
     """The Trainer's step (no donation) at batch 2: the flash kernel is in
     it — neither interpret mode nor the fall-through to XLA attention —

@@ -402,38 +402,3 @@ def test_explicit_engine_label_respected():
         eng.close(timeout=30)
 
 
-def _read_trace(path):
-    with open(path) as f:
-        return json.load(f)
-
-
-def test_profiler_reset_clears_spans_and_thread_names(tmp_path):
-    prof.enable_profiler()
-    with prof.record_event("span_a"):
-        pass
-    trace1 = _read_trace(prof.export_chrome_trace(str(tmp_path / "t1.json")))
-    assert any(ev.get("name") == "span_a" for ev in trace1["traceEvents"])
-    # metadata events label host threads for Perfetto
-    meta = [ev for ev in trace1["traceEvents"]
-            if ev.get("ph") == "M" and ev.get("name") == "thread_name"]
-    assert meta and all(ev["args"]["name"] for ev in meta)
-    prof.reset_profiler()
-    # reset must drop spans too: a later export starts from an empty window
-    trace2 = _read_trace(prof.export_chrome_trace(str(tmp_path / "t2.json")))
-    assert all(ev.get("ph") != "X" for ev in trace2["traceEvents"])
-    with prof.record_event("span_b"):
-        pass
-    trace3 = _read_trace(prof.export_chrome_trace(str(tmp_path / "t3.json")))
-    names = [ev.get("name") for ev in trace3["traceEvents"]]
-    assert "span_b" in names and "span_a" not in names  # no stale replay
-    prof.disable_profiler()
-
-
-def test_disable_profiler_clears_spans(tmp_path):
-    prof.enable_profiler()
-    with prof.record_event("window_one"):
-        pass
-    table = prof.disable_profiler()
-    assert "window_one" in table and table["window_one"]["calls"] == 1
-    trace = _read_trace(prof.export_chrome_trace(str(tmp_path / "t.json")))
-    assert all(ev.get("ph") != "X" for ev in trace["traceEvents"])

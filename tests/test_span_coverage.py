@@ -1,0 +1,364 @@
+"""One span system on the profiler's clock.
+
+A scoped ``tracing`` span holds a ``jax.profiler.TraceAnnotation``, so it
+lands on the host plane of the profiler's trace; disabled, the scopes cost
+nothing; the Trainer step and the DecodeEngine iteration are covered from
+inside by named children; ``core.profiler.record_event`` is built on the
+same spans; and the device work carries ``jax.named_scope`` names.
+"""
+
+import functools
+import glob
+import json
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import models, tracing
+from paddle_tpu.core import profiler as prof
+from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+TRAINER_CHILDREN = [
+    "trainer.data_wait", "trainer.begin_event", "trainer.h2d",
+    "trainer.step_compute", "trainer.fetch", "trainer.commit",
+    "trainer.record_step", "trainer.end_event", "trainer.checkpoint",
+]
+
+
+@pytest.fixture(autouse=True)
+def _clean_store():
+    tracing.enable_tracing()
+    tracing.reset_tracing()
+    yield
+    tracing.enable_tracing()
+    tracing.reset_tracing()
+
+
+def _host_event_names(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    return {ev.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+# ---- spans share the profiler's clock --------------------------------------
+
+
+def test_scoped_span_lands_on_the_profilers_host_plane(tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.start_trace("unit.on_the_profilers_clock"):
+            with tracing.start_span("unit.child_of_it"):
+                jnp.ones((8,)).block_until_ready()
+        with prof.record_event("unit.record_event_too"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(str(tmp_path))
+    assert {"unit.on_the_profilers_clock", "unit.child_of_it",
+            "unit.record_event_too"} <= names
+
+
+# ---- off costs nothing -----------------------------------------------------
+
+
+def test_disabled_tracing_hands_out_one_shared_noop_scope():
+    tracing.disable_tracing()
+    scope = tracing.start_span("unit.off")
+    assert scope is tracing.start_trace("unit.off_root")
+    assert scope is tracing.start_span("unit.off", parent=tracing.SpanContext.new_trace(), k=1)
+    with scope as sp:
+        assert sp.set(anything=1) is sp  # the call sites' uses still work
+        sp.cancel()
+        assert tracing.current_context() is None
+    with prof.record_event("unit.off_event"):
+        pass
+    assert tracing.spans() == [] and tracing.active_spans() == []
+
+
+def test_a_trainer_runs_with_tracing_disabled_and_records_nothing():
+    tracing.disable_tracing()
+    losses = _train_tiny(steps=2)
+    assert len(losses) == 2 and all(np.isfinite(losses))
+    assert tracing.spans() == []
+
+
+# ---- the Trainer step, covered from inside ---------------------------------
+
+
+def _train_tiny(steps=3):
+    def net(x, y):
+        pred = pt.layers.fc(x, size=1)
+        return pt.layers.mean((pred - y) ** 2)
+
+    def reader():
+        rng = np.random.RandomState(0)
+        for _ in range(steps):
+            x = rng.randn(8, 4).astype(np.float32)
+            yield x, x.sum(axis=1, keepdims=True)
+
+    losses = []
+
+    def on_event(ev):
+        if isinstance(ev, pt.EndStepEvent):
+            losses.append(ev.metrics)
+
+    trainer = pt.Trainer(lambda: net, lambda: pt.optimizer.SGD(learning_rate=0.1))
+    trainer.train(num_epochs=1, reader=reader, event_handler=on_event)
+    return losses
+
+
+def test_trainer_step_holds_its_children_in_order_without_gaps():
+    _train_tiny(steps=3)
+    roots = [s for s in tracing.spans() if s.name == "trainer.step"]
+    assert len(roots) == 3  # the end-of-epoch wait opened a scope and cancelled it
+    for root in roots:
+        tree = tracing.spans_for_trace(root.context.trace_id)
+        assert tracing.validate_trace(tree) == []
+        children = [s for s in tree if s.context.parent_id == root.context.span_id
+                    and s.name != "executor.compile"]
+        assert [s.name for s in children] == TRAINER_CHILDREN
+        # in order and one after the other: no child starts before the last ended
+        for a, b in zip(children, children[1:]):
+            assert a.t1_us <= b.t0_us
+        assert root.t0_us <= children[0].t0_us and children[-1].t1_us <= root.t1_us
+    assert [r.attrs["step"] for r in roots] == [0, 1, 2]
+    # the compiling call is still found afterwards, inside the step's enqueue
+    compile_span = next(s for s in tracing.spans() if s.name == "executor.compile")
+    by_id = {s.context.span_id: s for s in tracing.spans()}
+    assert by_id[compile_span.context.parent_id].name == "trainer.step_compute"
+
+
+# ---- the engine iteration, covered from inside -----------------------------
+
+
+@pytest.fixture(scope="module")
+def lm():
+    spec = models.get_model("transformer_lm", seq_len=64, vocab=97,
+                            d_model=32, d_inner=64, num_heads=4, n_layers=2)
+    variables = spec.model.init(0, *spec.synth_batch(2, np.random.RandomState(1)))
+    return spec.extra["cfg"], variables
+
+
+def _engine(lm, **kw):
+    cfg, variables = lm
+    conf = dict(max_slots=3, page_size=4, max_context=40, prefill_chunk=8)
+    conf.update(kw)
+    return DecodeEngine(variables, cfg, decode=DecodeConfig(**conf))
+
+
+def _children(spans, parent, prefix=""):
+    return [s for s in sorted(spans, key=lambda s: s.t0_us)
+            if s.context.parent_id == parent.context.span_id and s.name.startswith(prefix)]
+
+
+def test_engine_iteration_spans_and_the_seconds_the_metrics_were_handed(lm):
+    engine = _engine(lm)
+    handed = []
+    record_step = engine.metrics.record_step
+
+    def tapped(active, max_slots, seconds, new_tokens):
+        handed.append((active, max_slots, seconds, new_tokens))
+        return record_step(active, max_slots, seconds, new_tokens)
+
+    engine.metrics.record_step = tapped
+    try:
+        prompts = [np.arange(1, 12, dtype=np.int32), np.arange(3, 8, dtype=np.int32)]
+        outs = [h.result(timeout=300) for h in [engine.submit(p, 6) for p in prompts]]
+        assert all(len(o.tokens) == 6 for o in outs)
+    finally:
+        engine.close()
+    loop = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    names = {s.name for s in loop}
+    assert {"serving.decode.admit", "serving.decode.step", "serving.decode.publish",
+            "serving.decode.prefill", "serving.decode.prefill.wait",
+            "serving.decode.model_step", "serving.decode.idle"} <= names
+    # the passes of the loop hang under the loop's trace, one after the other
+    passes = [s for s in loop if s.context.parent_id == engine._loop_trace.span_id]
+    assert {s.name for s in passes} == {"serving.decode.admit", "serving.decode.step",
+                                        "serving.decode.publish", "serving.decode.idle"}
+    for a, b in zip(passes, passes[1:]):
+        assert a.t1_us <= b.t0_us
+    # a model step holds pack, dispatch, wait and land, in that order, and
+    # carries the counts at the boundary and the very float the metrics got
+    steps = [s for s in loop if s.name == "serving.decode.model_step"]
+    assert [(s.attrs["active"], s.attrs["max_slots"], s.attrs["seconds"], s.attrs["new_tokens"])
+            for s in steps] == handed
+    by_id = {s.context.span_id: s for s in loop}
+    for s in steps:
+        assert by_id[s.context.parent_id].name == "serving.decode.step"
+        assert [c.name for c in _children(loop, s)] == [
+            "serving.decode.model_step." + part for part in ("pack", "dispatch", "wait", "land")]
+    # every prompt's last chunk, and only that one, waited for its token
+    chunks = [s for s in loop if s.name == "serving.decode.prefill"]
+    assert sum(bool(s.attrs["last_chunk"]) for s in chunks) == len(prompts)
+    for s in chunks:
+        assert by_id[s.context.parent_id].name == "serving.decode.step"
+        waits = _children(loop, s, "serving.decode.prefill.wait")
+        assert len(waits) == (1 if s.attrs["last_chunk"] else 0)
+    # a request's own tree keeps its chunks, as README documents it
+    request_chunks = [s for s in tracing.spans() if s.name == "serving.decode.prefill"
+                      and s.context.trace_id != engine._loop_trace.trace_id]
+    assert len(request_chunks) == len(chunks)
+
+
+def test_an_idle_engine_adds_nothing_to_the_store(lm):
+    engine = _engine(lm, idle_poll_s=0.005)
+    try:
+        time.sleep(0.2)  # dozens of empty passes
+        assert tracing.spans_for_trace(engine._loop_trace.trace_id) == []
+    finally:
+        engine.close()
+
+
+def test_verify_step_holds_the_same_four_children(lm):
+    cfg, variables = lm
+    dspec = models.get_model("transformer_lm", seq_len=64, vocab=97,
+                             d_model=16, d_inner=32, num_heads=2, n_layers=1)
+    draft = dspec.model.init(1, *dspec.synth_batch(2, np.random.RandomState(9)))
+    engine = DecodeEngine(
+        variables, cfg,
+        decode=DecodeConfig(max_slots=3, page_size=4, max_context=40,
+                            prefill_chunk=8, spec_tokens=3),
+        draft_variables=draft, draft_cfg=dspec.extra["cfg"])
+    try:
+        engine.infer(np.arange(1, 9, dtype=np.int32), 8)
+    finally:
+        engine.close()
+    loop = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    verifies = [s for s in loop if s.name == "serving.decode.verify"]
+    assert verifies and all(s.attrs["seconds"] > 0 for s in verifies)
+    for s in verifies:
+        assert [c.name.rsplit(".", 1)[1] for c in _children(loop, s)] == [
+            "pack", "dispatch", "wait", "dispatch", "wait", "land"]
+
+
+# ---- one emitter of annotations --------------------------------------------
+
+
+def test_record_event_is_a_tracing_span_and_a_row_of_the_table(tmp_path):
+    prof.enable_profiler()
+    try:
+        with tracing.start_trace("unit.outer") as outer:
+            with prof.record_event("unit.window_one"):
+                pass
+        table = prof.disable_profiler()
+    finally:
+        prof.disable_profiler()
+    assert table["unit.window_one"]["calls"] == 1
+    (span,) = [s for s in tracing.spans() if s.name == "unit.window_one"]
+    assert span.context.parent_id == outer.context.span_id
+    # the one exporter writes it, with a named thread track for Perfetto
+    with open(tracing.export_chrome_trace(str(tmp_path / "t.json"))) as f:
+        doc = json.load(f)
+    tracing.validate_chrome_trace(doc)
+    ev = next(e for e in doc["traceEvents"] if e.get("name") == "unit.window_one")
+    assert ev["ph"] == "X" and ev["cat"] == "tracing"
+    meta = [e for e in doc["traceEvents"] if e["ph"] == "M" and e["tid"] == ev["tid"]]
+    assert meta and meta[0]["args"]["name"]
+
+
+def test_the_table_is_per_window_and_off_between_windows():
+    with prof.record_event("unit.before"):
+        pass
+    prof.enable_profiler()
+    with prof.record_event("unit.kept"):
+        pass
+    prof.reset_profiler()  # drops the window's rows; the spans are tracing's
+    with prof.record_event("unit.after_reset"):
+        pass
+    table = prof.disable_profiler()
+    assert set(table) == {"unit.after_reset"}
+    assert prof.disable_profiler() == {}  # a closed window starts the next one empty
+    assert {s.name for s in tracing.spans()} == {"unit.before", "unit.kept", "unit.after_reset"}
+    tracing.reset_tracing()
+    assert tracing.spans() == []
+
+
+def test_core_profiler_keeps_no_span_list_of_its_own():
+    for gone in ("_spans", "_thread_names", "_MAX_SPANS", "spans", "thread_names",
+                 "export_chrome_trace"):
+        assert not hasattr(prof, gone), gone
+
+
+# ---- device work has names -------------------------------------------------
+
+
+def _scopes_missing(fn, args, scopes):
+    """The scopes no op of the lowered program sits under; under ``grad`` a
+    scope reads ``jvp(name)`` and ``transpose(jvp(name))``."""
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return [s for s in scopes if not re.search(rf'[/(]{s}[/)"]', text)]
+
+
+def test_lm_train_step_carries_its_scope_names():
+    spec = models.get_model("transformer_lm", seq_len=16, vocab=61,
+                            d_model=32, d_inner=64, num_heads=4, n_layers=1)
+    batch = spec.synth_batch(2, np.random.RandomState(0))
+    variables = spec.model.init(0, *batch)
+    opt = spec.optimizer()
+    step = opt.minimize(spec.model)
+    assert _scopes_missing(
+        step, (variables, opt.create_state(variables.params), *batch),
+        ("embed", "attention", "ffn", "head", "loss", "optimizer_update")) == []
+
+
+def test_nmt_forward_carries_its_scope_names():
+    spec = models.get_model("transformer", src_vocab=53, trg_vocab=61, d_model=32,
+                            d_inner=64, num_heads=4, n_layers=1, max_len=16, seq_len=16)
+    batch = spec.synth_batch(2, np.random.RandomState(0))
+    variables = spec.model.init(0, *batch)
+    assert _scopes_missing(lambda v, *b: spec.model.apply(v, *b)[0], (variables, *batch),
+                           ("embed", "attention", "ffn", "head", "loss")) == []
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk", "verify_step"])
+def test_paged_steps_carry_their_scope_names(lm, which):
+    from paddle_tpu.models import transformer_lm as tlm
+
+    cfg, variables = lm
+    slots, page, per_slot = 3, 4, 10
+    pages = jnp.zeros(tlm.paged_cache_shape(cfg, 1 + slots * per_slot, page), jnp.float32)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    if which == "decode_step":
+        fn, args = tlm.paged_decode_step, (i32(slots), i32(slots), i32(slots, per_slot))
+    elif which == "prefill_chunk":
+        fn, args = tlm.paged_prefill_chunk, (i32(8), i32(), i32(), i32(per_slot))
+    else:
+        fn, args = tlm.paged_verify_step, (i32(slots, 4), i32(slots), i32(slots, per_slot))
+    assert _scopes_missing(
+        functools.partial(fn, cfg=cfg, page_size=page), (variables.params, *args, pages, pages),
+        ("embed", "attention", "page_write", "ffn", "head", "sampling")) == []
+
+
+# ---- the tool that shares idle gaps out to the program's spans -------------
+
+
+def test_innermost_gives_every_moment_to_one_span():
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "tools", "span_report.py")
+    spec = importlib.util.spec_from_file_location("span_report", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    events = [("step", 0, 100), ("wait", 10, 20), ("model", 40, 50), ("model.pack", 40, 10),
+              ("model.wait", 55, 30), ("idle", 120, 5)]
+    pieces = sorted(tool.innermost(events), key=lambda e: e[1])
+    assert pieces == [
+        ("step (self)", 0, 10), ("wait", 10, 20), ("step (self)", 30, 10),
+        ("model.pack", 40, 10), ("model (self)", 50, 5), ("model.wait", 55, 30),
+        ("model (self)", 85, 5), ("step (self)", 90, 10), ("idle", 120, 5)]
+    assert sum(d for _, _, d in pieces) == 105  # the union, each moment once
+    table = {r["name"]: r for r in tool.span_table(events, pieces)}
+    assert table["step"]["own_s"] == pytest.approx(30e-9)
+    assert table["model"]["total_s"] == pytest.approx(50e-9)
+    assert table["model"]["own_s"] == pytest.approx(10e-9)

@@ -468,26 +468,6 @@ def test_validate_chrome_trace_rejects_malformed():
         assert frag in msg
 
 
-# ---- profiler satellite ---------------------------------------------------
-
-
-def test_profiler_spans_dropped_counter(monkeypatch):
-    monkeypatch.setattr(prof, "_MAX_SPANS", 1)
-    prof.enable_profiler()
-    try:
-        before = _counter("profiler.spans_dropped")
-        with prof.record_event("unit.kept"):
-            pass
-        with prof.record_event("unit.dropped"):
-            pass
-        with prof.record_event("unit.dropped_too"):
-            pass
-        assert _counter("profiler.spans_dropped") - before == 2
-        assert len(prof.spans()) == 1
-    finally:
-        prof.disable_profiler()
-
-
 # ---- exporter debug endpoints ---------------------------------------------
 
 

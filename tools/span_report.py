@@ -1,0 +1,202 @@
+"""One benchmark run with the program's spans read out of the profiler trace.
+
+    python tools/span_report.py --workload <cell> --seed <n> --seconds <s> \
+        --trace <0|1> [--tracing on|off] [--inside <span>] [--out chiprun_out/span_report]
+
+Runs ``benchmarks/run.py``'s harness in this process. With ``--trace 1`` it
+reads the ``.xplane.pb`` the harness captured, before the harness deletes it:
+
+* the device's idle gaps shared out to the program's spans, through
+  ``trace_reduce.load_xplane(path, host_prefix=...)`` and
+  ``trace_reduce.idle_gaps`` as they are. Nested spans would be counted
+  twice, so each moment goes to the innermost span open on it: a span that
+  holds children keeps only the time no child covers (``<name> (self)``).
+* per span name: count, total and own seconds, and the part of the traced
+  window on the spans' thread that no span covers at all.
+* where the device names landed: the lines of the device plane, and the top
+  operations with their raw event name and stats (``jax.named_scope`` and the
+  ``pallas_call`` names are looked for there, by hand).
+* with ``--inside <span>``: the heaviest host events inside that span, the
+  runtime's own among them (what an enqueue is made of).
+
+``--tracing off`` calls ``tracing.disable_tracing()`` first: the untraced
+run then measures the program without its spans (the cost of tracing is the
+difference; ``PERF.md`` has the runs). No option of the program is involved.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_START = time.perf_counter()
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+PROGRAM_PREFIXES = ("trainer.", "serving.", "executor.")
+# how a ``jax.named_scope`` reads in an HLO op's ``op_name``, forward or under grad
+SCOPE_MARKS = [m % s for s in ("embed", "attention", "ffn", "head", "loss", "optimizer_update",
+                               "page_write", "sampling") for m in ("/%s/", "(%s)/")]
+
+
+def innermost(events):
+    """Disjoint (name, start_ns, duration_ns) pieces: every moment of the
+    nested ``events`` under the innermost one open on it. The part of a span
+    that its children leave is named ``<name> (self)``; a span without
+    children keeps its name."""
+    order = sorted(events, key=lambda e: (e[1], -e[2]))
+    out, stack = [], []  # stack of [name, end, cursor, had_child]
+
+    def close(top):
+        name, end, cursor, had_child = top
+        if end > cursor:
+            out.append((name + " (self)" if had_child else name, cursor, end - cursor))
+
+    for name, start, dur in order:
+        while stack and stack[-1][1] <= start:
+            close(stack.pop())
+        if stack:
+            top = stack[-1]
+            if start > top[2]:
+                out.append((top[0] + " (self)", top[2], start - top[2]))
+            top[2], top[3] = max(top[2], start + dur), True
+        stack.append([name, start + dur, start, False])
+    while stack:
+        close(stack.pop())
+    return out
+
+
+def span_table(events, pieces):
+    total, own, count = {}, {}, {}
+    for name, _, dur in events:
+        total[name] = total.get(name, 0.0) + dur / 1e9
+        count[name] = count.get(name, 0) + 1
+    for name, _, dur in pieces:
+        base = name[:-len(" (self)")] if name.endswith(" (self)") else name
+        own[base] = own.get(base, 0.0) + dur / 1e9
+    return [{"name": n, "count": count[n], "total_s": total[n], "own_s": own.get(n, 0.0)}
+            for n in sorted(total, key=lambda n: -total[n])]
+
+
+def device_names(path, top=12):
+    """What a reader sees on the device planes: line names, and the heaviest
+    operations with their raw names and stats."""
+    from jax.profiler import ProfileData
+
+    from benchmarks import trace_reduce
+
+    # the op events' names and stats (below) carry no op metadata: say whether
+    # the file holds the scope names at all, for whoever parses it further
+    with open(path, "rb") as f:
+        raw = f.read()
+    out = {"scope_marks_in_the_file": {m: raw.count(m.encode()) for m in SCOPE_MARKS}}
+    for plane in ProfileData.from_file(path).planes:
+        if not trace_reduce.DEVICE_PLANE.match(plane.name):
+            continue
+        lines = {}
+        for line in plane.lines:
+            by_name = {}
+            for ev in line.events:
+                rec = by_name.setdefault(ev.name, {"count": 0, "seconds": 0.0, "stats": None})
+                rec["count"] += 1
+                rec["seconds"] += ev.duration_ns / 1e9
+                if rec["stats"] is None:
+                    rec["stats"] = {str(k): str(v)[:300] for k, v in ev.stats}
+            heavy = sorted(by_name.items(), key=lambda kv: -kv[1]["seconds"])[:top]
+            # an HLO instruction's metadata (op_name: the named scopes) is at its end
+            lines[line.name] = [{"name": n[:300], "tail": n[-400:] if len(n) > 300 else "", **rec}
+                                for n, rec in heavy]
+        out[plane.name] = lines
+    return out
+
+
+def inside(host, span_name, top=15):
+    """The heaviest host events of any writer (the runtime's own among them)
+    that lie inside an instance of ``span_name``: what that span's time is
+    made of. Threads are not told apart: ``load_xplane`` merges them."""
+    spans = [(s, s + d) for n, s, d in host if n == span_name]
+    by_name = {}
+    for n, s, d in host:
+        if n != span_name and any(a <= s and s + d <= b for a, b in spans):
+            rec = by_name.setdefault(n, [0, 0.0])
+            rec[0] += 1
+            rec[1] += d / 1e9
+    heavy = sorted(by_name.items(), key=lambda kv: -kv[1][1])[:top]
+    return {"instances": len(spans), "events": [[n[:120], c, sec] for n, (c, sec) in heavy]}
+
+
+def report(path: str, cell: str, out_dir: str, load_xplane, look_inside=()) -> None:
+    from benchmarks import trace_reduce
+
+    host = []
+    for prefix in PROGRAM_PREFIXES:
+        host += load_xplane(path, host_prefix=prefix)["host"]
+    trace = load_xplane(path)  # the devices, and the benchmark's own annotations
+    pieces = innermost(host)
+    devs = trace["devices"]
+    first = devs[sorted(devs)[0]] if devs else []
+    doc = {
+        "cell": cell,
+        "window_s": trace_reduce.window_ns(trace) / 1e9,
+        "busy_s": trace_reduce.busy_ns(first) / 1e9,
+        "idle_gaps_by_program_span": trace_reduce.idle_gaps(first, pieces, top=40),
+        "idle_gaps_by_bench_annotation": trace_reduce.idle_gaps(first, trace["host"], top=10),
+        "spans": span_table(host, pieces),
+        "device_names": device_names(path),
+    }
+    if look_inside:
+        everything = load_xplane(path, host_prefix="")["host"]
+        doc["inside"] = {name: inside(everything, name) for name in look_inside}
+    if host:
+        t0 = min(s for _, s, _ in host)
+        t1 = max(s + d for _, s, d in host)
+        doc["span_thread_s"] = (t1 - t0) / 1e9
+        doc["outside_any_span_s"] = (t1 - t0 - trace_reduce.busy_ns(host)) / 1e9
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, cell + ".json"), "w") as f:
+        json.dump(doc, f, indent=1)
+    print("span report:", json.dumps({k: doc[k] for k in doc if k != "device_names"}), flush=True)
+
+
+def main(argv) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", required=True)
+    ap.add_argument("--seconds", required=True)
+    ap.add_argument("--trace", choices=("0", "1"), default="1")
+    ap.add_argument("--tracing", choices=("on", "off"), default="on")
+    ap.add_argument("--inside", action="append", default=[],
+                    help="a span whose inner host events to list (may repeat)")
+    ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out", "span_report"))
+    args = ap.parse_args(argv)
+
+    from benchmarks import harness, trace_reduce
+    from paddle_tpu import tracing
+
+    if args.tracing == "off":
+        tracing.disable_tracing()
+    load = trace_reduce.load_xplane
+
+    def load_and_report(path, *a, **kw):
+        # the harness reads the trace once, then deletes it: read it here too
+        try:
+            report(path, args.workload, args.out, load, args.inside)
+        except Exception as e:  # the run's own result must still come out
+            print(f"span report failed: {type(e).__name__}: {e}", file=sys.stderr, flush=True)
+        return load(path, *a, **kw)
+
+    trace_reduce.load_xplane = load_and_report
+    try:
+        return harness.main(["--workload", args.workload, "--seed", args.seed,
+                             "--seconds", args.seconds, "--trace", args.trace], T_START)
+    finally:
+        trace_reduce.load_xplane = load
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
