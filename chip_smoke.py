@@ -29,8 +29,10 @@ SEED = 0
 TRAIN_BATCH = 4
 TRAIN_STEPS = 5
 DP_STEPS = 3
-# 16 slots x 2048 positions of f32 pages (2 x 1.5 GB) fit one chip's 15.75 GB
-# beside the undonated decode step (11.8 GB in all); 32 slots do not
+# 16 slots x 2048 positions of f32 pages (2 x 1.7 GB as the chip lays them
+# out) fit one chip's 15.75 GB: the decode step, its pages donated, needs
+# 14.0 GB in all, 9.7 GB of it temp for converting K and V to the page
+# write's layout and back; 32 slots do not (22.1 GB, refused by the compiler)
 DECODE = dict(max_slots=16, page_size=16, max_context=2048)
 N_REQUESTS = 8
 PROMPT_LEN = (64, 1024)

@@ -86,6 +86,6 @@ def gather_kv_page(pages, page_id):
 
 def scatter_kv_page(pages, page_id, page):
     """Implant one page payload at ``page_id`` in a paged KV array
-    (device-side; the functional update donates into the engine's
-    running page arrays)."""
+    (device-side; the engine jits this with ``pages`` donated, so the
+    update lands in its running page array in place)."""
     return pages.at[:, page_id].set(page)

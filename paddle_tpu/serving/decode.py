@@ -455,8 +455,6 @@ class DecodeEngine:
         self._cache_dtype = cdt or jnp.float32
         if group is None:
             self._params = jax.device_put(params)
-            self._k_pages = jnp.zeros(pshape, self._cache_dtype)
-            self._v_pages = jnp.zeros(pshape, self._cache_dtype)
             kvs = rep = None
         else:
             if dconf.lint_layout:
@@ -474,11 +472,19 @@ class DecodeEngine:
             self._params = self._layout.shard_params(group, params)
             kvs = self._layout.kv_page_sharding(group, pshape)
             rep = self._layout.replicated(group)
-            self._k_pages = jax.device_put(
-                jnp.zeros(pshape, self._cache_dtype), kvs)
-            self._v_pages = jax.device_put(
-                jnp.zeros(pshape, self._cache_dtype), kvs)
-        jit_kw = {} if group is None else {"out_shardings": (rep, kvs, kvs)}
+        # The engine is the sole owner of its page arrays: every jit that
+        # returns a new version of one takes the old one donated, so a
+        # page write updates the array in place instead of copying it.
+        # Every call site rebinds the result and nothing else may hold a
+        # page array across a loop pass. Group mode keeps the alias per
+        # shard: the page outputs are pinned to the inputs' sharding.
+        self._k_pages = self._zero_pages(pshape, kvs)
+        self._v_pages = self._zero_pages(pshape, kvs)
+        jit_kw = {"donate_argnames": ("k_pages", "v_pages")}
+        page_kw = {"donate_argnames": ("pages",)}
+        if group is not None:
+            jit_kw["out_shardings"] = (rep, kvs, kvs)
+            page_kw["out_shardings"] = kvs
         sample_kw = dict(temperature=dconf.temperature, top_k=dconf.top_k,
                          top_p=dconf.top_p)
         # roofline-instrumented: these jits bypass Executor.prepare(), so
@@ -500,9 +506,7 @@ class DecodeEngine:
         self._gather_page = jax.jit(
             collective.gather_kv_page,
             **({} if group is None else {"out_shardings": rep}))
-        self._implant_page = jax.jit(
-            collective.scatter_kv_page,
-            **({} if group is None else {"out_shardings": kvs}))
+        self._implant_page = jax.jit(collective.scatter_kv_page, **page_kw)
         self._rng = (jax.random.PRNGKey(dconf.rng_seed)
                      if dconf.temperature > 0.0 else None)
 
@@ -529,19 +533,16 @@ class DecodeEngine:
             # bookkeeping (grow/preempt/trim) covers both caches at once
             dshape = paged_cache_shape(self.draft_cfg, num_pages,
                                        dconf.page_size)
+            djit_kw = {"donate_argnames": ("k_pages", "v_pages")}
             if group is None:
                 self._draft_params = jax.device_put(dp)
-                self._dk_pages = jnp.zeros(dshape, self._cache_dtype)
-                self._dv_pages = jnp.zeros(dshape, self._cache_dtype)
-                djit_kw = {}
+                dkvs = None
             else:
                 self._draft_params = self._layout.shard_params(group, dp)
                 dkvs = self._layout.kv_page_sharding(group, dshape)
-                self._dk_pages = jax.device_put(
-                    jnp.zeros(dshape, self._cache_dtype), dkvs)
-                self._dv_pages = jax.device_put(
-                    jnp.zeros(dshape, self._cache_dtype), dkvs)
-                djit_kw = {"out_shardings": (rep, dkvs, dkvs)}
+                djit_kw["out_shardings"] = (rep, dkvs, dkvs)
+            self._dk_pages = self._zero_pages(dshape, dkvs)
+            self._dv_pages = self._zero_pages(dshape, dkvs)
             self._draft_step = roofline.instrument(
                 "serving.decode.draft_step", jax.jit(functools.partial(
                     paged_decode_step, cfg=self.draft_cfg,
@@ -567,11 +568,11 @@ class DecodeEngine:
             # may shard differently, hence two jits) so the cache arrays
             # never drift placement between iterations.
             _copy = lambda pages, src, dst: pages.at[:, dst].set(pages[:, src])
-            self._copy_page = jax.jit(
-                _copy, **({} if group is None else {"out_shardings": kvs}))
+            self._copy_page = jax.jit(_copy, **page_kw)
             self._copy_page_d = (self._copy_page if group is None
                                  or not self._spec_k else jax.jit(
-                                     _copy, out_shardings=dkvs))
+                                     _copy, donate_argnames=("pages",),
+                                     out_shardings=dkvs))
 
         # -- hierarchical KV host tier (serving.host_tier) ----------------
         # a pool passed in is SHARED (fleet-wide prefix sharing + crash
@@ -682,56 +683,84 @@ class DecodeEngine:
 
     # -- startup -----------------------------------------------------------
 
+    def _zero_pages(self, shape, sharding):
+        """One zeroed page array (``sharding`` None = single device)."""
+        import jax.numpy as jnp
+
+        pages = jnp.zeros(shape, self._cache_dtype)
+        return pages if sharding is None else jax.device_put(pages, sharding)
+
     def _warmup(self) -> None:
-        """Compile the prefill-chunk and decode-step executables before
-        traffic arrives. Warmup writes land on the scratch page (zero
-        tables), so no reset is needed afterwards."""
+        """Compile every executable that writes the page arrays before
+        traffic arrives, and publish whether each consumed the arrays it
+        was handed (``serving.decode.pages_donated``). Warmup writes land
+        on the scratch page (zero tables), so no reset is needed
+        afterwards."""
         import jax.numpy as jnp
 
         dconf = self.decode_config
         S, P = dconf.max_slots, self._kv.pages_per_slot
         table0 = jnp.zeros((P,), jnp.int32)
-        key = None
-        if self._rng is not None:
-            self._rng, key = jax.random.split(self._rng)
+        chunk0 = jnp.zeros((dconf.prefill_chunk,), jnp.int32)
+        slots0 = jnp.zeros((S,), jnp.int32)
+        tables0 = jnp.zeros((S, P), jnp.int32)
+        z = jnp.int32(SCRATCH_PAGE)  # page 0, and the chunk's position 0
+        kept: List[str] = []  # write-jits that left a page array alive
+
+        def consumed(name, *pages):
+            if not all(p.is_deleted() for p in pages):
+                kept.append(name)
+
+        k, v = self._k_pages, self._v_pages
         _, self._k_pages, self._v_pages = self._prefill(
-            self._params, jnp.zeros((dconf.prefill_chunk,), jnp.int32),
-            jnp.int32(0), jnp.int32(0), table0,
-            self._k_pages, self._v_pages, key)
-        if self._rng is not None:
-            self._rng, key = jax.random.split(self._rng)
+            self._params, chunk0, z, z, table0, k, v, self._next_key())
+        consumed("prefill", k, v)
+        k, v = self._k_pages, self._v_pages
         out, self._k_pages, self._v_pages = self._step(
-            self._params, jnp.zeros((S,), jnp.int32),
-            jnp.zeros((S,), jnp.int32),
-            jnp.zeros((S, P), jnp.int32),
-            self._k_pages, self._v_pages, key)
+            self._params, slots0, slots0, tables0, k, v, self._next_key())
+        consumed("step", k, v)
         jax.block_until_ready(out)
         if self._spec_k:
+            k, v = self._dk_pages, self._dv_pages
             _, self._dk_pages, self._dv_pages = self._draft_prefill(
-                self._draft_params,
-                jnp.zeros((dconf.prefill_chunk,), jnp.int32),
-                jnp.int32(0), jnp.int32(0), table0,
-                self._dk_pages, self._dv_pages, None)
+                self._draft_params, chunk0, z, z, table0, k, v, None)
+            consumed("draft_prefill", k, v)
+            k, v = self._dk_pages, self._dv_pages
             _, self._dk_pages, self._dv_pages = self._draft_step(
-                self._draft_params, jnp.zeros((S,), jnp.int32),
-                jnp.zeros((S,), jnp.int32),
-                jnp.zeros((S, P), jnp.int32),
-                self._dk_pages, self._dv_pages, None)
+                self._draft_params, slots0, slots0, tables0, k, v, None)
+            consumed("draft_step", k, v)
+            k, v = self._k_pages, self._v_pages
             vout, self._k_pages, self._v_pages = self._verify(
-                self._params,
-                jnp.zeros((S, self._spec_k + 1), jnp.int32),
-                jnp.zeros((S,), jnp.int32),
-                jnp.zeros((S, P), jnp.int32),
-                self._k_pages, self._v_pages)
+                self._params, jnp.zeros((S, self._spec_k + 1), jnp.int32),
+                slots0, tables0, k, v)
+            consumed("verify", k, v)
             jax.block_until_ready(vout)
+        # scratch -> scratch: harmless. The implant serves handoff adoption
+        # and host-tier promotes, the copy the prefix cache's copy-on-write
+        page0 = jnp.zeros(
+            self._k_pages.shape[:1] + self._k_pages.shape[2:],
+            self._cache_dtype)
+        k, v = self._k_pages, self._v_pages
+        self._k_pages = self._implant_page(k, z, page0)
+        self._v_pages = self._implant_page(v, z, page0)
+        consumed("implant_page", k, v)
         if self._prefix is not None:
-            # scratch -> scratch: harmless, compiles the CoW copy
-            z = jnp.int32(SCRATCH_PAGE)
-            self._k_pages = self._copy_page(self._k_pages, z, z)
-            self._v_pages = self._copy_page(self._v_pages, z, z)
+            k, v = self._k_pages, self._v_pages
+            self._k_pages = self._copy_page(k, z, z)
+            self._v_pages = self._copy_page(v, z, z)
+            consumed("copy_page", k, v)
             if self._spec_k:
-                self._dk_pages = self._copy_page_d(self._dk_pages, z, z)
-                self._dv_pages = self._copy_page_d(self._dv_pages, z, z)
+                k, v = self._dk_pages, self._dv_pages
+                self._dk_pages = self._copy_page_d(k, z, z)
+                self._dv_pages = self._copy_page_d(v, z, z)
+                consumed("copy_page_d", k, v)
+        for name in kept:
+            ptlog.warn_once(
+                ("decode.pages_not_donated", name),
+                "DecodeEngine: %s left a page array it was handed alive: "
+                "the donation did not engage and every page write copies "
+                "the whole array", name)
+        self.metrics.set_pages_donated(not kept)
         # persist the compiled keys so a restarted engine can prewarm
         from paddle_tpu.tune import warmup as tune_warmup
 
@@ -1766,14 +1795,7 @@ class DecodeEngine:
             except Exception as e:
                 # a failed step loses this iteration's K/V writes for every
                 # in-flight sequence
-                if self.decode_config.recovery:
-                    self._recover_step_fault(e)
-                    return True
-                runlog.emit("decode_step_error", error=repr(e),
-                            engine=self.metrics.engine_label)
-                ptlog.error("decode step failed: %r", e)
-                for req in list(self._active):
-                    self._fail(req, e)
+                self._step_faulted(e, "decode")
                 return True
             t1 = time.perf_counter()
             seconds = t1 - t0
@@ -1862,14 +1884,7 @@ class DecodeEngine:
             except Exception as e:
                 # same contract as the plain step: the iteration's K/V writes
                 # (draft and target) are lost; recovery re-prefills from host
-                if self.decode_config.recovery:
-                    self._recover_step_fault(e)
-                    return True
-                runlog.emit("decode_step_error", error=repr(e),
-                            engine=self.metrics.engine_label)
-                ptlog.error("verify step failed: %r", e)
-                for req in list(self._active):
-                    self._fail(req, e)
+                self._step_faulted(e, "verify")
                 return True
             t1 = time.perf_counter()
             seconds = t1 - t0
@@ -1992,14 +2007,57 @@ class DecodeEngine:
                         group=self._group.name, shard=flagged,
                         skew=round(skew, 3))
 
+    def _restore_lost_pages(self) -> bool:
+        """A write-jit that fails AFTER consuming its donated inputs
+        leaves the engine holding deleted arrays, and every later call
+        would raise "Array has been deleted". Replace each lost array with
+        zeros of the same shape, dtype and sharding and drop what indexed
+        into the lost contents: the radix tree's device pages (the host
+        tier repopulates it as it does after a restart). Live slots are
+        the caller's to quarantine: their KV went with the arrays. Returns
+        whether anything was lost."""
+        names = ["_k_pages", "_v_pages"]
+        if self._spec_k:
+            names += ["_dk_pages", "_dv_pages"]
+        lost = [n for n in names if getattr(self, n).is_deleted()]
+        for n in lost:
+            old = getattr(self, n)
+            setattr(self, n, self._zero_pages(
+                old.shape, None if self._group is None else old.sharding))
+        if lost:
+            if self._prefix is not None:
+                self._prefix.clear()
+            runlog.emit("decode_pages_rebuilt", arrays=len(lost),
+                        engine=self.metrics.engine_label)
+            ptlog.warning("engine %s lost %d page array(s) to a failed "
+                          "call; rebuilt zeroed", self.metrics.engine_label,
+                          len(lost))
+        return bool(lost)
+
+    def _step_faulted(self, exc: BaseException, what: str) -> None:
+        """A decode or verify step raised: recover every live request, or
+        with recovery off fail them all."""
+        if self.decode_config.recovery:
+            self._recover_step_fault(exc)
+            return
+        self._restore_lost_pages()
+        runlog.emit("decode_step_error", error=repr(exc),
+                    engine=self.metrics.engine_label)
+        ptlog.error("%s step failed: %r", what, exc)
+        for req in list(self._active):
+            self._fail(req, exc)
+
     def _recover_step_fault(self, exc: BaseException) -> None:
         """A jitted decode step failed: only that iteration's KV writes
         are lost, and every live request is reconstructible from host
         state. Ladder: quarantine + re-admit (per-request budget) →
         after ``unhealthy_after`` consecutive faults, migrate everything
         to a healthy engine via the fleet's rescue sink. A fault inside
-        recovery itself (DECODE_RECOVER) escalates one rung."""
+        recovery itself (DECODE_RECOVER) escalates one rung. Where the
+        failed call had already consumed the page arrays, they are
+        rebuilt first: the contract is the same, more was lost."""
         dconf = self.decode_config
+        self._restore_lost_pages()
         self.metrics.record_step_fault()
         self._consec_faults += 1
         self.metrics.set_consecutive_faults(self._consec_faults)
@@ -2082,7 +2140,13 @@ class DecodeEngine:
         its slot's pages): quarantine just that request through the
         resume path, on the same lifetime budget. Does not count toward
         engine-level consecutive faults — a single poison prompt must
-        exhaust its own budget, not condemn the engine."""
+        exhaust its own budget, not condemn the engine. A chunk that
+        failed after it consumed the page arrays took every slot's KV with
+        it: that is the engine's fault, not the prompt's, and goes down
+        the step-fault ladder."""
+        if self._restore_lost_pages():
+            self._step_faulted(exc, "prefill")
+            return
         if not self.decode_config.recovery:
             self._fail(req, exc)
             return

@@ -20,6 +20,13 @@ Attention gathers a sequence's pages through its table row and masks
 positions ``> seq_len``; writes scatter one token's K/V into
 ``table[pos // page_size]`` at offset ``pos % page_size``.
 
+The page arrays have one owner, the engine: every jit that returns a new
+version of one takes the old one donated (each write consumes its input
+and updates it in place; undonated, a one-token write copied the whole
+array), the engine rebinds the result, and nothing holds a page array
+across a pass of its loop. This module is host-side bookkeeping only and
+never sees them.
+
 Page 0 is reserved scratch: inactive slots point their whole table at it
 and their (garbage) writes land there harmlessly, so the step needs no
 per-slot branching. The allocator hands out pages ``1..num_pages-1``

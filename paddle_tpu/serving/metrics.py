@@ -775,6 +775,13 @@ class DecodeMetrics:
     def set_active_slots(self, n: int) -> None:
         prof.set_gauge("serving.decode.active_slots", n, labels=self._labels)
 
+    def set_pages_donated(self, ok: bool) -> None:
+        """1 when every jit that writes the page arrays consumed the
+        arrays it was handed at warm-up (the writes update in place), 0
+        when one left them alive (every write copies the whole array)."""
+        prof.set_gauge("serving.decode.pages_donated", int(ok),
+                       labels=self._labels)
+
     def snapshot(self) -> Dict[str, float]:
         with self._lock:
             return {

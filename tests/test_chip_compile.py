@@ -146,10 +146,16 @@ def test_lm_large_train_step_compiles(one_chip, as_tpu):
 
 
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
-def test_paged_serving_steps_compile(one_chip, as_tpu, which):
-    """chip_smoke.py's phase-2 engine: 16 slots x 2048 positions of f32
-    pages. The pages are not donated (ROADMAP A3), so the step holds two
-    copies of the cache; it must still fit."""
+def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which):
+    """The engine of chip_smoke.py's phase 2 and of lm_big.serve_closed16:
+    16 slots x 2048 positions of f32 pages, 1.6 GB each for K and V. The
+    engine donates them, so the compiled step must alias both to its
+    outputs (undonated, PR 22 read alias 0) and no longer re-lay-out a
+    whole array around each of its 24 page writes (XLA's
+    ``remat_(un)compressed`` copies under memory pressure). It still
+    converts K and V to the scatter's layout on entry and back on exit,
+    which is what its 9.7 GB of temp holds: 14.0 GB in all (PERF.md,
+    PR 26), so the bound is the chip's memory."""
     from paddle_tpu.models.transformer_lm import (
         paged_cache_shape, paged_decode_step, paged_prefill_chunk,
     )
@@ -168,8 +174,13 @@ def test_paged_serving_steps_compile(one_chip, as_tpu, which):
         fn, args = paged_decode_step, (i32(SLOTS), i32(SLOTS), i32(SLOTS, per_slot))
     else:
         fn, args = paged_prefill_chunk, (i32(CHUNK), i32(), i32(), i32(per_slot))
-    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=PAGE)).lower(
-        _shapes(params, one_chip), *args, pages, pages, None).compile()
+    compiled = jax.jit(
+        functools.partial(fn, cfg=cfg, page_size=PAGE),
+        donate_argnames=("k_pages", "v_pages"),  # as DecodeEngine.__init__
+    ).lower(_shapes(params, one_chip), *args, pages, pages, None).compile()
+    page_bytes = int(np.prod(pages.shape)) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * page_bytes
+    assert "remat_" not in compiled.as_text()
     assert _program_bytes(compiled) < HBM_BYTES
 
 

@@ -8,18 +8,22 @@ size stays flat as requests of different prompt lengths and budgets
 enter and leave.  Also covered: preempt/resume continuation under a
 starved page pool, cancel mid-generation, eos stopping, the bf16
 ``cache_dtype`` plumbing, and per-token deadline prediction feeding the
-admission controller (satellite of PR 8's latency histograms).
+admission controller (satellite of PR 8's latency histograms), and the
+ownership rule of ISSUE 26: every jit that writes the page arrays
+consumes the arrays it is handed.
 """
 
 import time
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from paddle_tpu import models
 from paddle_tpu.models.transformer_lm import generate
+from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.serving import (
     AdmissionRejected,
     DecodeConfig,
@@ -323,3 +327,103 @@ def test_cost_model_speculative_math():
     assert snap["accepted_per_step"] == pytest.approx(2.0)
     # the non-speculative estimate path is untouched when verify_s is cold
     assert cm2.snapshot()["step_s"] is None
+
+
+# ---- the engine owns its KV pages (ISSUE 26) --------------------------------
+
+# every jit that returns a new version of a page array, by the engine that
+# reaches it: the plain engine the one-token step, the self-draft engine the
+# draft and verify steps; both the chunked prefill and the prefix cache's copy
+_WRITE_JITS = [("plain", "_step"), ("plain", "_prefill"),
+               ("plain", "_implant_page"), ("plain", "_copy_page"),
+               ("spec", "_draft_step"), ("spec", "_draft_prefill"),
+               ("spec", "_verify"), ("spec", "_copy_page_d")]
+
+
+class _ConsumedSpy:
+    """Stands in for one write-jit: runs the real call, then counts the page
+    arrays ([L, pages, H_kv, page_size, dh]: the only 5-d arguments) it was
+    handed that are still alive. Runs on the loop thread, so it only counts;
+    the test asserts."""
+
+    def __init__(self, fn):
+        self.fn, self.calls, self.kept = fn, 0, 0
+
+    def __call__(self, *args):
+        out = self.fn(*args)
+        pages = [a for a in args if getattr(a, "ndim", 0) == 5]
+        self.calls += bool(pages)  # a call that found none proves nothing
+        self.kept += sum(not p.is_deleted() for p in pages)
+        return out
+
+    def __getattr__(self, name):  # _cache_size
+        return getattr(self.fn, name)
+
+
+@pytest.fixture(scope="module", params=["single", "group2"])
+def owned(request, lm):
+    """For one placement (one device, or a two-device tp group): a plain and
+    a self-draft engine, prefix cache on, a spy on each write-jit, after
+    traffic that reaches them all: mixed lengths at once, then two prompts
+    sharing a 14-token prefix one after the other (3 pages hit, not
+    chunk-aligned: the continuation chunk copies on write)."""
+    group = None
+    if request.param == "group2":
+        from paddle_tpu.serving.shardgroup import make_groups
+
+        if jax.device_count() < 2:
+            pytest.skip("a tp group needs two devices")
+        group = make_groups(2)[0]
+    rng = np.random.RandomState(26)
+    shared = rng.randint(1, VOCAB, size=(14,)).astype(np.int32)
+    cases = list(lm.cases[:3])
+    for _ in range(2):
+        prompt = np.concatenate(
+            [shared, rng.randint(1, VOCAB, size=(4,)).astype(np.int32)])
+        cases.append((prompt, 8, np.asarray(generate(
+            lm.variables, jnp.asarray(prompt[None]), 8, lm.cfg))[0]))
+    engines, diverged = {}, []
+    for kind in ("plain", "spec"):
+        draft = (dict(draft_variables=lm.variables, draft_cfg=lm.cfg)
+                 if kind == "spec" else {})
+        eng = engines[kind] = DecodeEngine(
+            lm.variables, lm.cfg, group=group, decode=DecodeConfig(
+                max_slots=3, page_size=4, max_context=40, prefill_chunk=8,
+                num_pages=14, prefix_cache=True,
+                spec_tokens=3 if kind == "spec" else 0), **draft)
+        for k, name in _WRITE_JITS:
+            if k == kind:
+                setattr(eng, name, _ConsumedSpy(getattr(eng, name)))
+        outs = [h.result(timeout=300)
+                for h in [eng.submit(p, n) for p, n, _ in cases[:3]]]
+        outs += [eng.infer(p, n) for p, n, _ in cases[3:]]
+        diverged += [(kind, len(p)) for (p, _, ref), out in zip(cases, outs)
+                     if not np.array_equal(out.tokens, ref)]
+    # no traffic implants without a second engine or a host tier: by hand,
+    # into the scratch page, while the loop thread idles
+    eng = engines["plain"]
+    old = eng._k_pages
+    eng._k_pages = eng._implant_page(
+        old, jnp.int32(0), jnp.zeros(old.shape[:1] + old.shape[2:], old.dtype))
+    yield types.SimpleNamespace(engines=engines, diverged=diverged)
+    for eng in engines.values():
+        eng.close()
+        eng.kv.assert_no_leaks()
+
+
+@pytest.mark.parametrize("kind,jit", _WRITE_JITS,
+                         ids=[name.strip("_") for _, name in _WRITE_JITS])
+def test_write_jit_consumes_the_pages_it_is_handed(owned, kind, jit):
+    """Donation engages on every write path: each call consumed its page
+    arrays, warm-up published that, the step still compiled once and the
+    served tokens are generate()'s."""
+    eng = owned.engines[kind]
+    spy = getattr(eng, jit)
+    assert spy.calls >= 1, f"{jit} never ran"
+    assert spy.kept == 0, f"{jit} left {spy.kept} page array(s) alive"
+    assert obs_metrics.default_registry().get(
+        "serving.decode.pages_donated", {"engine": eng.metrics.engine_label},
+        default=None) == 1.0
+    assert eng.decode_step_cache_size() == 1
+    assert eng.prefill_cache_size() == 1
+    assert not owned.diverged

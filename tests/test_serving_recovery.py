@@ -164,6 +164,72 @@ def test_prefill_fault_recovers_single_request(lm):
         eng.close(timeout=30)
 
 
+@pytest.mark.parametrize("where,tp", [("step", 1), ("prefill", 1), ("step", 2)],
+                         ids=["step", "prefill", "step-group2"])
+def test_fault_after_the_call_consumed_its_pages(lm, where, tp):
+    """The write-jits take the page arrays donated, so a call that fails
+    AFTER it ran leaves the engine holding deleted arrays (DECODE_STEP
+    injects before the call and never reaches this). The engine rebuilds
+    them, drops the radix tree that indexed the lost contents, and every
+    live request still finishes token-exact inside its recovery budget;
+    a prompt cached before the fault is served exact again after it,
+    which adopting one of its old (now zeroed) pages would break. In a
+    tp group the rebuilt arrays keep the group's sharding."""
+    group = None
+    if tp > 1:
+        from paddle_tpu.serving.shardgroup import make_groups
+
+        if __import__("jax").device_count() < tp:
+            pytest.skip("a tp group needs two devices")
+        group = make_groups(tp)[0]
+    eng = DecodeEngine(lm.variables, lm.cfg, group=group,
+                       decode=DecodeConfig(**DC, prefix_cache=True))
+    sharding = eng._k_pages.sharding
+    first, n_first, ref_first = lm.cases[0]
+    assert np.array_equal(eng.infer(first, n_first).tokens, ref_first)
+    assert eng.prefix.num_pages >= 1  # the tree indexes pre-fault pages
+    real = getattr(eng, "_" + where)
+    calls = {"n": 0}
+
+    def consume_then_raise(*a, **kw):
+        out = real(*a, **kw)  # the inputs are gone from here on
+        calls["n"] += 1
+        if calls["n"] in (2, 4):
+            raise OSError(f"injected fault after the {where} ran")
+        return out
+
+    restored = []
+    real_restore = eng._restore_lost_pages
+
+    def restore_spy():
+        lost = real_restore()
+        if lost:
+            restored.append(eng.prefix.num_pages)
+        return lost
+
+    setattr(eng, "_" + where, consume_then_raise)
+    eng._restore_lost_pages = restore_spy
+    try:
+        handles = [eng.submit(p, n) for p, n, _ in lm.cases[1:]]
+        outs = [h.result(timeout=120) for h in handles]
+        for (_, _, ref), out in zip(lm.cases[1:], outs):
+            assert np.array_equal(out.tokens, ref)
+        # both faults found the arrays deleted, and left an empty tree
+        assert restored == [0, 0], restored
+        for a in (eng._k_pages, eng._v_pages):
+            assert not a.is_deleted() and a.sharding == sharding
+        snap = eng.metrics.snapshot()
+        assert snap["errors_total"] == 0, snap
+        assert snap["retries_exhausted_total"] == 0, snap
+        assert snap["recovered_total"] >= 1, snap
+        assert np.array_equal(eng.infer(first, n_first).tokens, ref_first)
+        assert real._cache_size() == 1  # recovered through the same jit
+    finally:
+        setattr(eng, "_" + where, real)
+        eng.close(timeout=30)
+    eng.kv.assert_no_leaks()
+
+
 # ---- (b) cross-engine migration -------------------------------------------
 
 
