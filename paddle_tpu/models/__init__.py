@@ -19,7 +19,7 @@ import numpy as np
 
 from paddle_tpu.framework import Model
 
-__all__ = ["ModelSpec", "get_model", "MODELS"]
+__all__ = ["ModelSpec", "ServingPrograms", "get_model", "serving_programs", "MODELS"]
 
 
 @dataclasses.dataclass
@@ -35,6 +35,44 @@ class ModelSpec:
     # elements counted per batch row for throughput (e.g. tokens per sentence)
     examples_per_row: int = 1
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingPrograms:
+    """What a language model brings to ``serving.DecodeEngine``: the arrays
+    its cache lives in and the two jittable programs that write them. The
+    engine owns the arrays and hands them to every call donated; a program
+    returns the new version of each after its first result.
+
+    ``prefill_chunk(params, tokens [C], pos0, last_index, slot_ref, *cache,
+    rng, cfg=, ...) -> (next_token, *cache)`` and ``decode_step(params,
+    tokens [S], positions [S], slot_refs, *cache, rng, cfg=, ...) ->
+    (next_tokens [S], *cache)``. With ``cache`` ``"pages"`` the arrays are
+    KV pages, ``slot_ref`` is a slot's page-table row and the programs also
+    take ``page_size=``; with ``"state"`` they are recurrent states indexed
+    by slot, ``slot_ref`` is the slot's index, and ``slot_refs`` marks the
+    slots that decode."""
+
+    cache: str                      # "pages" | "state"
+    cache_args: Tuple[str, ...]     # the programs' names for the arrays
+    # cache_specs(cfg, *, max_slots, num_pages, page_size, dtype)
+    #   -> one jax.ShapeDtypeStruct per array
+    cache_specs: Callable[..., Tuple[Any, ...]]
+    prefill_chunk: Callable
+    decode_step: Callable
+    verify_step: Optional[Callable]  # scores a draft block; None = cannot
+    mechanism: str                   # named when the engine refuses a feature
+
+
+def serving_programs(cfg: dict) -> ServingPrograms:
+    """The serving programs of the model ``cfg`` describes: ``cfg["family"]``
+    names the model module, ``transformer_lm`` where it is absent."""
+    import importlib
+
+    family = cfg.get("family", "transformer_lm")
+    if family not in MODELS:
+        raise KeyError(f"unknown model family {family!r} in a serving cfg")
+    return importlib.import_module(f"paddle_tpu.models.{family}").serving_programs()
 
 
 def get_model(name: str, **cfg) -> ModelSpec:
@@ -86,6 +124,12 @@ def _machine_translation(**cfg):
     return machine_translation.get_model(**cfg)
 
 
+def _retention_lm(**cfg):
+    from paddle_tpu.models import retention_lm
+
+    return retention_lm.get_model(**cfg)
+
+
 def _transformer_lm(**cfg):
     from paddle_tpu.models import transformer_lm
 
@@ -99,6 +143,7 @@ MODELS: Dict[str, Callable[..., ModelSpec]] = {
     "vgg": _vgg,
     "transformer": _transformer,
     "transformer_lm": _transformer_lm,
+    "retention_lm": _retention_lm,
     "stacked_dynamic_lstm": _stacked_dynamic_lstm,
     "machine_translation": _machine_translation,
 }

@@ -683,6 +683,28 @@ def paged_cache_shape(cfg: dict, num_pages: int, page_size: int):
     return (cfg["n_layers"], num_pages, H_kv, page_size, dh)
 
 
+def sample_logits(logits, key, temperature, top_k, top_p):
+    """Greedy argmax at temperature 0, else temperature / top-k / top-p
+    sampling with ``key``: the one sampler of every serving program."""
+    if temperature == 0.0:
+        return jnp.argmax(logits, -1).astype(jnp.int32)
+    logits = logits / temperature
+    if top_k is not None:
+        kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+        logits = jnp.where(logits < kth, -jnp.inf, logits)
+    if top_p is not None:
+        sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
+        probs = jax.nn.softmax(sorted_logits, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep_sorted = cum - probs < top_p
+        cutoff = jnp.min(
+            jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1,
+            keepdims=True,
+        )
+        logits = jnp.where(logits < cutoff, -jnp.inf, logits)
+    return jax.random.categorical(key, logits).astype(jnp.int32)
+
+
 def _paged_ops(params, cfg):
     """The p/ln/proj/ffn/logits/sample closures shared by the paged prefill
     and decode-step entry points — the same math as :func:`generate`'s
@@ -708,26 +730,7 @@ def _paged_ops(params, cfg):
     def logits_of(x_last):
         return ln(x_last, "layer_norm") @ p("project/logits/w")
 
-    def sample(logits, key, temperature, top_k, top_p):
-        if temperature == 0.0:
-            return jnp.argmax(logits, -1).astype(jnp.int32)
-        logits = logits / temperature
-        if top_k is not None:
-            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-            logits = jnp.where(logits < kth, -jnp.inf, logits)
-        if top_p is not None:
-            sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-            probs = jax.nn.softmax(sorted_logits, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            keep_sorted = cum - probs < top_p
-            cutoff = jnp.min(
-                jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1,
-                keepdims=True,
-            )
-            logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-        return jax.random.categorical(key, logits).astype(jnp.int32)
-
-    return p, ln, proj, ffn, logits_of, sample
+    return p, ln, proj, ffn, logits_of, sample_logits
 
 
 def _paged_live_mask(q_pos, t_eff: int, window):
@@ -1090,6 +1093,22 @@ BASE_CFG = dict(
     moe_capacity_factor=1.25,
     moe_aux_weight=0.01,
 )
+
+
+def paged_cache_specs(cfg: dict, *, num_pages: int, page_size: int, dtype, **_):
+    """The two page arrays (K and V) the engine allocates for ``cfg``."""
+    shape = paged_cache_shape(cfg, num_pages, page_size)
+    return (jax.ShapeDtypeStruct(shape, dtype),) * 2
+
+
+def serving_programs():
+    from paddle_tpu.models import ServingPrograms
+
+    return ServingPrograms(
+        cache="pages", cache_args=("k_pages", "v_pages"),
+        cache_specs=paged_cache_specs, prefill_chunk=paged_prefill_chunk,
+        decode_step=paged_decode_step, verify_step=paged_verify_step,
+        mechanism="softmax attention over a paged KV cache")
 
 
 def get_model(

@@ -27,6 +27,11 @@ once at warmup and admission/eviction/preemption never recompile
 test pins it). Prefill runs as fixed-size chunks through the same pages,
 at most ``prefill_chunks_per_iter`` per iteration, so a long prompt is
 absorbed a chunk at a time between decode steps instead of stalling them.
+An iteration enqueues its step first and its chunks behind it, and only
+then waits for the step's tokens; a prompt's first token, the last chunk's
+sample, is read one iteration on with the next step already queued. The
+device so goes from step to chunk to step while the host lands tokens,
+admits and packs.
 
 When the page pool is exhausted mid-growth the engine preempts the most
 recently admitted other request (LIFO — oldest work finishes first):
@@ -67,12 +72,7 @@ from paddle_tpu.core import logging as ptlog
 from paddle_tpu.core import profiler as prof
 from paddle_tpu.core import retry as retry_mod
 from paddle_tpu.core.enforce import enforce
-from paddle_tpu.models.transformer_lm import (
-    paged_cache_shape,
-    paged_decode_step,
-    paged_prefill_chunk,
-    paged_verify_step,
-)
+from paddle_tpu.models import serving_programs
 from paddle_tpu.observability import roofline, runlog
 from paddle_tpu.parallel import collective
 from paddle_tpu.tracing import waterfall
@@ -88,7 +88,7 @@ from paddle_tpu.serving.engine import (
     ServingConfig,
 )
 from paddle_tpu.serving.host_tier import HostPageCorrupt, HostPagePool
-from paddle_tpu.serving.kv_cache import SCRATCH_PAGE, PagedKVCache
+from paddle_tpu.serving.kv_cache import SCRATCH_PAGE, PagedKVCache, SlotStates
 from paddle_tpu.serving.metrics import DecodeMetrics
 from paddle_tpu.serving.prefix_cache import RadixPrefixCache
 from paddle_tpu.serving.shardgroup import (
@@ -258,7 +258,7 @@ class _DecodeRequest:
                  "t_submit", "handle", "generated", "slot", "phase", "seq",
                  "chunks_done", "cur_len", "last_tok", "cancelled",
                  "n_preemptions", "trace", "t_enqueue_pc", "t_admit_pc",
-                 "rid", "recoveries")
+                 "rid", "recoveries", "first_tok")
 
     def __init__(self, prompt: np.ndarray, mnt: int, n_chunks: int,
                  deadline: Optional[float], t_submit: float,
@@ -276,7 +276,7 @@ class _DecodeRequest:
         self.handle = DecodeHandle(self)
         self.generated: List[int] = []
         self.slot: Optional[int] = None
-        self.phase = "queued"          # queued | prefill | decode
+        self.phase = "queued"  # queued | prefill | first_token | decode
         self.seq: Optional[np.ndarray] = None  # tokens being prefilled
         self.chunks_done = 0
         self.cur_len = 0               # K/V positions written so far
@@ -288,6 +288,9 @@ class _DecodeRequest:
         self.t_admit_pc: Optional[float] = None
         self.rid: Optional[str] = None   # journal/migration identity
         self.recoveries = 0              # quarantine cycles survived
+        # phase "first_token": the last chunk's sample, still on the
+        # device, with the chunk's enqueue time and index
+        self.first_tok = None
 
 
 class DecodeCostModel:
@@ -371,8 +374,13 @@ class DecodeCostModel:
 
 class DecodeEngine:
     """Iteration-level batched autoregressive serving over a trained
-    transformer LM (params as created by
-    :func:`~paddle_tpu.models.transformer_lm.lm_forward`).
+    language model (params as created by its ``lm_forward``). The model
+    brings its own programs (:func:`~paddle_tpu.models.serving_programs`):
+    ``transformer_lm`` a paged KV cache, ``retention_lm`` one fixed
+    recurrent state per slot. The engine owns the cache arrays either way;
+    what needs pages that can be shared, copied or rolled back (prefix
+    cache, host tier, handoff, a draft model, a replica group) is refused
+    at construction for a model that keeps a state.
 
     ::
 
@@ -424,12 +432,23 @@ class DecodeEngine:
         enforce(dconf.max_context % dconf.prefill_chunk == 0,
                 f"max_context ({dconf.max_context}) must be a multiple of "
                 f"prefill_chunk ({dconf.prefill_chunk})")
+        self._programs = progs = serving_programs(self.model_cfg)
+        self._paged = progs.cache == "pages"
+        for feature, asked in (
+                ("the prefix cache", dconf.prefix_cache),
+                ("the host tier", host_tier is not None or dconf.host_tier_bytes),
+                ("a draft model", draft_variables is not None),
+                ("a replica group", group is not None)):
+            if asked:
+                self._refuse_unless_paged(feature)
         pages_per_slot = dconf.max_context // dconf.page_size
         num_pages = (dconf.num_pages if dconf.num_pages is not None
                      else 1 + dconf.max_slots * pages_per_slot)
-        self._kv = PagedKVCache(
+        self._kv = (PagedKVCache(
             max_slots=dconf.max_slots, page_size=dconf.page_size,
             num_pages=num_pages, pages_per_slot=pages_per_slot)
+            if self._paged else SlotStates(
+                max_slots=dconf.max_slots, max_context=dconf.max_context))
         self.metrics = DecodeMetrics(engine_label=self.config.engine_label)
         observability.setup()
         self.cost = DecodeCostModel()
@@ -449,10 +468,13 @@ class DecodeEngine:
         self._last_probe = 0.0
         cdt = (dconf.cache_dtype if dconf.cache_dtype is not None
                else self.config.cache_dtype)
-        pshape = paged_cache_shape(self.model_cfg, num_pages, dconf.page_size)
         import jax.numpy as jnp
 
         self._cache_dtype = cdt or jnp.float32
+        specs = progs.cache_specs(
+            self.model_cfg, max_slots=dconf.max_slots, num_pages=num_pages,
+            page_size=dconf.page_size, dtype=self._cache_dtype)
+        pshape = specs[0].shape
         if group is None:
             self._params = jax.device_put(params)
             kvs = rep = None
@@ -472,32 +494,36 @@ class DecodeEngine:
             self._params = self._layout.shard_params(group, params)
             kvs = self._layout.kv_page_sharding(group, pshape)
             rep = self._layout.replicated(group)
-        # The engine is the sole owner of its page arrays: every jit that
-        # returns a new version of one takes the old one donated, so a
-        # page write updates the array in place instead of copying it.
-        # Every call site rebinds the result and nothing else may hold a
-        # page array across a loop pass. Group mode keeps the alias per
-        # shard: the page outputs are pinned to the inputs' sharding.
-        self._k_pages = self._zero_pages(pshape, kvs)
-        self._v_pages = self._zero_pages(pshape, kvs)
-        jit_kw = {"donate_argnames": ("k_pages", "v_pages")}
+        # The engine is the sole owner of its cache arrays (the model's K
+        # and V pages, or its per-slot states): every jit that returns a
+        # new version of one takes the old one donated, so a write updates
+        # the array in place instead of copying it. Every call site
+        # rebinds the result and nothing else may hold a cache array
+        # across a loop pass. Group mode keeps the alias per shard: the
+        # page outputs are pinned to the inputs' sharding. A paged model's
+        # list is [K pages, V pages]: the page paths index it so.
+        self._cache = [self._zero_pages(sp.shape, kvs, sp.dtype) for sp in specs]
+        jit_kw = {"donate_argnames": progs.cache_args}
         page_kw = {"donate_argnames": ("pages",)}
         if group is not None:
             jit_kw["out_shardings"] = (rep, kvs, kvs)
             page_kw["out_shardings"] = kvs
         sample_kw = dict(temperature=dconf.temperature, top_k=dconf.top_k,
                          top_p=dconf.top_p)
+        model_kw = dict(sample_kw, cfg=self.model_cfg)
+        if self._paged:
+            model_kw["page_size"] = dconf.page_size
+        else:
+            self.metrics.set_state_bytes(sum(c.nbytes for c in self._cache))
         # roofline-instrumented: these jits bypass Executor.prepare(), so
         # they feed the cost ledger through their own wrapper (compiles
         # capture cost/memory analysis, later calls book wall seconds)
         self._step = roofline.instrument(
             "serving.decode.step", jax.jit(functools.partial(
-                paged_decode_step, cfg=self.model_cfg,
-                page_size=dconf.page_size, **sample_kw), **jit_kw))
+                progs.decode_step, **model_kw), **jit_kw))
         self._prefill = roofline.instrument(
             "serving.decode.prefill", jax.jit(functools.partial(
-                paged_prefill_chunk, cfg=self.model_cfg,
-                page_size=dconf.page_size, **sample_kw), **jit_kw))
+                progs.prefill_chunk, **model_kw), **jit_kw))
         # disagg KV handoff (serving.disagg): one page is the fixed-shape
         # [L, H_kv, page_size, dh] slice, so gather/implant compile once.
         # In group mode the gather's output is pinned replicated — the
@@ -521,6 +547,8 @@ class DecodeEngine:
                     "speculative decoding is greedy-only: acceptance "
                     "compares argmaxes, so temperature must be 0.0")
             self.draft_cfg = dict(draft_cfg) if draft_cfg else self.model_cfg
+            dprogs = serving_programs(self.draft_cfg)
+            self._refuse_unless_paged("a draft model", dprogs)
             enforce(self.draft_cfg.get("vocab") == self.model_cfg.get("vocab"),
                     "draft and target models must share a vocabulary "
                     f"({self.draft_cfg.get('vocab')} vs "
@@ -531,8 +559,10 @@ class DecodeEngine:
             # the draft reads/writes THROUGH the same page tables: its own
             # page arrays, same (num_pages, page_size) geometry, so slot
             # bookkeeping (grow/preempt/trim) covers both caches at once
-            dshape = paged_cache_shape(self.draft_cfg, num_pages,
-                                       dconf.page_size)
+            dshape = dprogs.cache_specs(
+                self.draft_cfg, max_slots=dconf.max_slots,
+                num_pages=num_pages, page_size=dconf.page_size,
+                dtype=self._cache_dtype)[0].shape
             djit_kw = {"donate_argnames": ("k_pages", "v_pages")}
             if group is None:
                 self._draft_params = jax.device_put(dp)
@@ -545,15 +575,15 @@ class DecodeEngine:
             self._dv_pages = self._zero_pages(dshape, dkvs)
             self._draft_step = roofline.instrument(
                 "serving.decode.draft_step", jax.jit(functools.partial(
-                    paged_decode_step, cfg=self.draft_cfg,
+                    dprogs.decode_step, cfg=self.draft_cfg,
                     page_size=dconf.page_size, temperature=0.0), **djit_kw))
             self._draft_prefill = roofline.instrument(
                 "serving.decode.draft_prefill", jax.jit(functools.partial(
-                    paged_prefill_chunk, cfg=self.draft_cfg,
+                    dprogs.prefill_chunk, cfg=self.draft_cfg,
                     page_size=dconf.page_size, temperature=0.0), **djit_kw))
             self._verify = roofline.instrument(
                 "serving.decode.verify", jax.jit(functools.partial(
-                    paged_verify_step, cfg=self.model_cfg,
+                    progs.verify_step, cfg=self.model_cfg,
                     page_size=dconf.page_size), **jit_kw))
 
         # -- radix prefix cache -------------------------------------------
@@ -683,84 +713,92 @@ class DecodeEngine:
 
     # -- startup -----------------------------------------------------------
 
-    def _zero_pages(self, shape, sharding):
-        """One zeroed page array (``sharding`` None = single device)."""
+    def _refuse_unless_paged(self, feature: str, programs=None) -> None:
+        """The one error for everything that needs KV pages."""
+        programs = programs or self._programs
+        enforce(programs.cache == "pages",
+                f"DecodeEngine: {feature} cannot be used with a model that "
+                f"keeps {programs.mechanism}. It shares, copies, ships or "
+                "rolls back KV pages; a recurrent state would need "
+                "snapshots, which the engine does not have")
+
+    def _zero_pages(self, shape, sharding, dtype=None):
+        """One zeroed cache array (``sharding`` None = single device)."""
         import jax.numpy as jnp
 
-        pages = jnp.zeros(shape, self._cache_dtype)
+        pages = jnp.zeros(shape, dtype or self._cache_dtype)
         return pages if sharding is None else jax.device_put(pages, sharding)
 
+    def _slot_ref(self, slot: int):
+        """What a prefill chunk finds its slot's cache by: the page-table
+        row, or the slot's index into the state arrays."""
+        import jax.numpy as jnp
+
+        return (jnp.asarray(self._kv.page_tables[slot]) if self._paged
+                else jnp.int32(slot))
+
+    def _slot_refs(self, decoding) -> np.ndarray:
+        """The same for a decode step over ``decoding``: every other slot
+        gets a scratch table row, or a 0 that leaves its state alone."""
+        S = self.decode_config.max_slots
+        if not self._paged:
+            refs = np.zeros((S,), np.int32)
+            refs[[r.slot for r in decoding]] = 1
+            return refs
+        refs = np.full((S, self._kv.pages_per_slot), SCRATCH_PAGE, np.int32)
+        for req in decoding:
+            refs[req.slot] = self._kv.page_tables[req.slot]
+        return refs
+
+    def _publish_cache(self) -> None:
+        if self._paged:
+            self.metrics.set_pages(self._kv.pages_in_use, self._kv.pages_free)
+        else:
+            self.metrics.set_state_slots_in_use(len(self._kv.active_slots()))
+
     def _warmup(self) -> None:
-        """Compile every executable that writes the page arrays before
+        """Compile every executable that writes the cache arrays before
         traffic arrives, and publish whether each consumed the arrays it
-        was handed (``serving.decode.pages_donated``). Warmup writes land
-        on the scratch page (zero tables), so no reset is needed
-        afterwards."""
+        was handed (``serving.decode.pages_donated`` / ``state_donated``).
+        Warmup writes land on the scratch page (zero tables), or in slot
+        0's state, which the first chunk of an admission starts over, so
+        no reset is needed afterwards."""
         import jax.numpy as jnp
 
         dconf = self.decode_config
-        S, P = dconf.max_slots, self._kv.pages_per_slot
-        table0 = jnp.zeros((P,), jnp.int32)
+        S, P = dconf.max_slots, dconf.max_context // dconf.page_size
         chunk0 = jnp.zeros((dconf.prefill_chunk,), jnp.int32)
         slots0 = jnp.zeros((S,), jnp.int32)
-        tables0 = jnp.zeros((S, P), jnp.int32)
         z = jnp.int32(SCRATCH_PAGE)  # page 0, and the chunk's position 0
-        kept: List[str] = []  # write-jits that left a page array alive
+        kept: List[str] = []  # write-jits that left a cache array alive
 
         def consumed(name, *pages):
             if not all(p.is_deleted() for p in pages):
                 kept.append(name)
 
-        k, v = self._k_pages, self._v_pages
-        _, self._k_pages, self._v_pages = self._prefill(
-            self._params, chunk0, z, z, table0, k, v, self._next_key())
-        consumed("prefill", k, v)
-        k, v = self._k_pages, self._v_pages
-        out, self._k_pages, self._v_pages = self._step(
-            self._params, slots0, slots0, tables0, k, v, self._next_key())
-        consumed("step", k, v)
+        old = list(self._cache)
+        _, *self._cache = self._prefill(
+            self._params, chunk0, z, z, self._slot_ref(0), *old,
+            self._next_key())
+        consumed("prefill", *old)
+        old = list(self._cache)
+        out, *self._cache = self._step(
+            self._params, slots0, slots0, jnp.asarray(self._slot_refs([])),
+            *old, self._next_key())
+        consumed("step", *old)
         jax.block_until_ready(out)
-        if self._spec_k:
-            k, v = self._dk_pages, self._dv_pages
-            _, self._dk_pages, self._dv_pages = self._draft_prefill(
-                self._draft_params, chunk0, z, z, table0, k, v, None)
-            consumed("draft_prefill", k, v)
-            k, v = self._dk_pages, self._dv_pages
-            _, self._dk_pages, self._dv_pages = self._draft_step(
-                self._draft_params, slots0, slots0, tables0, k, v, None)
-            consumed("draft_step", k, v)
-            k, v = self._k_pages, self._v_pages
-            vout, self._k_pages, self._v_pages = self._verify(
-                self._params, jnp.zeros((S, self._spec_k + 1), jnp.int32),
-                slots0, tables0, k, v)
-            consumed("verify", k, v)
-            jax.block_until_ready(vout)
-        # scratch -> scratch: harmless. The implant serves handoff adoption
-        # and host-tier promotes, the copy the prefix cache's copy-on-write
-        page0 = jnp.zeros(
-            self._k_pages.shape[:1] + self._k_pages.shape[2:],
-            self._cache_dtype)
-        k, v = self._k_pages, self._v_pages
-        self._k_pages = self._implant_page(k, z, page0)
-        self._v_pages = self._implant_page(v, z, page0)
-        consumed("implant_page", k, v)
-        if self._prefix is not None:
-            k, v = self._k_pages, self._v_pages
-            self._k_pages = self._copy_page(k, z, z)
-            self._v_pages = self._copy_page(v, z, z)
-            consumed("copy_page", k, v)
-            if self._spec_k:
-                k, v = self._dk_pages, self._dv_pages
-                self._dk_pages = self._copy_page_d(k, z, z)
-                self._dv_pages = self._copy_page_d(v, z, z)
-                consumed("copy_page_d", k, v)
+        if self._paged:
+            self._warmup_page_jits(consumed, chunk0, slots0, z)
         for name in kept:
             ptlog.warn_once(
                 ("decode.pages_not_donated", name),
-                "DecodeEngine: %s left a page array it was handed alive: "
-                "the donation did not engage and every page write copies "
-                "the whole array", name)
-        self.metrics.set_pages_donated(not kept)
+                "DecodeEngine: %s left a cache array it was handed alive: "
+                "the donation did not engage and every write copies the "
+                "whole array", name)
+        if self._paged:
+            self.metrics.set_pages_donated(not kept)
+        else:
+            self.metrics.set_state_donated(not kept)
         # persist the compiled keys so a restarted engine can prewarm
         from paddle_tpu.tune import warmup as tune_warmup
 
@@ -785,6 +823,48 @@ class DecodeEngine:
             except Exception as e:
                 ptlog.warning("warmup manifest save failed: %s", e)
 
+    def _warmup_page_jits(self, consumed, chunk0, slots0, z) -> None:
+        """The write-jits only a paged model has: the draft's and the verify
+        step, the page implant and the copy-on-write."""
+        import jax.numpy as jnp
+
+        S, P = self.decode_config.max_slots, self._kv.pages_per_slot
+        tables0 = jnp.zeros((S, P), jnp.int32)
+        table0 = jnp.zeros((P,), jnp.int32)
+        if self._spec_k:
+            k, v = self._dk_pages, self._dv_pages
+            _, self._dk_pages, self._dv_pages = self._draft_prefill(
+                self._draft_params, chunk0, z, z, table0, k, v, None)
+            consumed("draft_prefill", k, v)
+            k, v = self._dk_pages, self._dv_pages
+            _, self._dk_pages, self._dv_pages = self._draft_step(
+                self._draft_params, slots0, slots0, tables0, k, v, None)
+            consumed("draft_step", k, v)
+            k, v = self._cache
+            vout, *self._cache = self._verify(
+                self._params, jnp.zeros((S, self._spec_k + 1), jnp.int32),
+                slots0, tables0, k, v)
+            consumed("verify", k, v)
+            jax.block_until_ready(vout)
+        # scratch -> scratch: harmless. The implant serves handoff adoption
+        # and host-tier promotes, the copy the prefix cache's copy-on-write
+        page0 = jnp.zeros(
+            self._cache[0].shape[:1] + self._cache[0].shape[2:],
+            self._cache_dtype)
+        k, v = self._cache
+        self._cache = [self._implant_page(k, z, page0),
+                       self._implant_page(v, z, page0)]
+        consumed("implant_page", k, v)
+        if self._prefix is not None:
+            k, v = self._cache
+            self._cache = [self._copy_page(k, z, z), self._copy_page(v, z, z)]
+            consumed("copy_page", k, v)
+            if self._spec_k:
+                k, v = self._dk_pages, self._dv_pages
+                self._dk_pages = self._copy_page_d(k, z, z)
+                self._dv_pages = self._copy_page_d(v, z, z)
+                consumed("copy_page_d", k, v)
+
     def _manifest_name(self) -> str:
         """Manifest identity for this engine: model dims + the static
         decode-shape knobs (a config change must not replay stale keys)."""
@@ -793,6 +873,8 @@ class DecodeEngine:
         name = ("decode_L{l}_D{dm}_S{s}_P{p}_C{c}".format(
             l=mc.get("n_layers", 0), dm=mc.get("d_model", 0),
             s=d.max_slots, p=d.page_size, c=d.prefill_chunk))
+        if "family" in mc:
+            name = f"{mc['family']}_{name}"
         if self._group is not None:
             # a group program is a different executable than the
             # single-device one — never replay the other's keys
@@ -845,7 +927,7 @@ class DecodeEngine:
                 if hasattr(self._verify, "_cache_size") else -1)
 
     @property
-    def kv(self) -> PagedKVCache:
+    def kv(self):
         return self._kv
 
     @property
@@ -1126,17 +1208,14 @@ class DecodeEngine:
                     sp.cancel()
             with tracing.start_span("serving.decode.step", parent=loop) as sp:
                 did_promote = self._apply_promotes()
-                did_prefill = self._prefill_some()
-                did_step = self._decode_step()
-                did = did_prefill or did_step or did_promote
+                did = self._decode_step() or did_promote
                 if did:
                     sp.set(active=len(self._active))
                 else:
                     sp.cancel()
             if did:
                 with tracing.start_span("serving.decode.publish", parent=loop):
-                    self.metrics.set_pages(self._kv.pages_in_use,
-                                           self._kv.pages_free)
+                    self._publish_cache()
                     self.metrics.set_active_slots(len(self._active))
                     self.metrics.set_load(self.load())
                     self.metrics.set_queue_depth(self._queue.qsize())
@@ -1160,7 +1239,7 @@ class DecodeEngine:
         self._promote_keys.clear()
         self._publish_digest()  # tree gone: publish the empty digest
         self.metrics.set_active_slots(0)
-        self.metrics.set_pages(self._kv.pages_in_use, self._kv.pages_free)
+        self._publish_cache()
 
     def _sweep_cancel_deadline(self) -> None:
         now = time.monotonic()
@@ -1246,10 +1325,12 @@ class DecodeEngine:
         mismatch, page-pool pressure, implant error) degrades to the
         proven resume path, which re-prefills ``prompt + generated``
         token-exactly — a bad transfer costs latency, never a request."""
+        if not self._pending_handoff:
+            return
         import jax.numpy as jnp
 
         dconf = self.decode_config
-        page_shape = (self._k_pages.shape[:1] + self._k_pages.shape[2:])
+        page_shape = (self._cache[0].shape[:1] + self._cache[0].shape[2:])
         while (self._pending_handoff
                and len(self._active) < dconf.max_slots):
             req, payload = self._pending_handoff.popleft()
@@ -1284,12 +1365,12 @@ class DecodeEngine:
                         table = self._kv.page_tables[req.slot]
                         for li in range(n_pages):
                             pid = jnp.int32(table[li])
-                            self._k_pages = self._implant_page(
-                                self._k_pages, pid,
+                            self._cache[0] = self._implant_page(
+                                self._cache[0], pid,
                                 jnp.asarray(payload.k_pages[li],
                                             self._cache_dtype))
-                            self._v_pages = self._implant_page(
-                                self._v_pages, pid,
+                            self._cache[1] = self._implant_page(
+                                self._cache[1], pid,
                                 jnp.asarray(payload.v_pages[li],
                                             self._cache_dtype))
                         ok = True
@@ -1375,8 +1456,8 @@ class DecodeEngine:
             for li in range((c0 * C) // ps, m):
                 src, dst = self._kv.private_copy(req.slot, li)
                 s, d = jnp.int32(src), jnp.int32(dst)
-                self._k_pages = self._copy_page(self._k_pages, s, d)
-                self._v_pages = self._copy_page(self._v_pages, s, d)
+                self._cache[0] = self._copy_page(self._cache[0], s, d)
+                self._cache[1] = self._copy_page(self._cache[1], s, d)
                 if self._spec_k:
                     self._dk_pages = self._copy_page_d(self._dk_pages, s, d)
                     self._dv_pages = self._copy_page_d(self._dv_pages, s, d)
@@ -1412,9 +1493,9 @@ class DecodeEngine:
             for i, p in enumerate(pages):
                 if self._host_tier.contains(req.seq, i + 1):
                     continue  # shared prefix already demoted — dedup
-                k = np.asarray(self._gather_page(self._k_pages,
+                k = np.asarray(self._gather_page(self._cache[0],
                                                  jnp.int32(p)))
-                v = np.asarray(self._gather_page(self._v_pages,
+                v = np.asarray(self._gather_page(self._cache[1],
                                                  jnp.int32(p)))
                 res = self._host_tier.put(
                     req.seq, i, k, v, engine=self.metrics.engine_label)
@@ -1514,10 +1595,10 @@ class DecodeEngine:
                 continue
             page = alloced[0]
             p = jnp.int32(page)
-            self._k_pages = self._implant_page(
-                self._k_pages, p, jnp.asarray(got[0], self._cache_dtype))
-            self._v_pages = self._implant_page(
-                self._v_pages, p, jnp.asarray(got[1], self._cache_dtype))
+            self._cache[0] = self._implant_page(
+                self._cache[0], p, jnp.asarray(got[0], self._cache_dtype))
+            self._cache[1] = self._implant_page(
+                self._cache[1], p, jnp.asarray(got[1], self._cache_dtype))
             self._prefix.insert(toks[:(d + 1) * ps], tree_pages + [page])
             self._kv.allocator.free([page])  # hand ownership to the tree
             budget -= 1
@@ -1660,12 +1741,11 @@ class DecodeEngine:
                 last = len(req.seq) - 1 - c * C
                 t0 = time.perf_counter()
                 try:
-                    table_row = jnp.asarray(self._kv.page_tables[req.slot])
-                    tok, self._k_pages, self._v_pages = self._prefill(
+                    table_row = self._slot_ref(req.slot)
+                    tok, *self._cache = self._prefill(
                         self._params, jnp.asarray(chunk),
                         jnp.int32(c * C), jnp.int32(max(last, 0)),
-                        table_row,
-                        self._k_pages, self._v_pages, self._next_key())
+                        table_row, *self._cache, self._next_key())
                     if self._spec_k:
                         # the draft's cache must cover the same prefix so its
                         # proposals attend real context (sampled token unused)
@@ -1674,65 +1754,117 @@ class DecodeEngine:
                             jnp.int32(c * C), jnp.int32(max(last, 0)),
                             table_row,
                             self._dk_pages, self._dv_pages, None)
-                    if last_chunk:
-                        # only the last chunk's sample is read: the one
-                        # wait for the device in the prefill path
-                        with tracing.start_span("serving.decode.prefill.wait"):
-                            tok = int(tok)
-                    else:
-                        tok = 0
                 except Exception as e:
                     self._recover_request(req, e)
                     continue
                 t1 = time.perf_counter()
                 self.metrics.record_prefill_chunk(t1 - t0)
-                self.cost.observe_chunk(t1 - t0)
-                if req.trace is not None:
-                    tracing.record_span("serving.decode.prefill", t0, t1,
-                                        parent=req.trace, chunk=c,
-                                        engine=self.metrics.engine_label)
                 req.chunks_done = c + 1
                 self._kv.seq_lens[req.slot] = min(chunk_end, len(req.seq))
                 budget -= 1
                 progressed = True
                 if last_chunk:
-                    if self._prefix is not None:
-                        # every fully-written page is immutable from here on
-                        # (decode writes land past len(seq)) — publish them
-                        n_full = len(req.seq) // dconf.page_size
-                        if n_full:
-                            self._prefix.insert(
-                                req.seq, self._kv.slot_pages(req.slot)[:n_full])
-                            # write-through demote: the same immutable pages,
-                            # while the tree holds refs (no recycle race)
-                            self._host_demote(req, n_full)
-                    req.phase = "decode"
-                    req.cur_len = len(req.seq)
-                    # the final chunk's sample IS the next token after the
-                    # prefilled sequence — the first (or, after a resume, the
-                    # next) generated token
-                    self._wf_tokens(req, t1, 1, "prefill")
-                    self._append_token(req, tok)
-                    # prefill role (serving.disagg): publish instead of
-                    # decoding here — unless that one sampled token already
-                    # finished the request (it left _active via _finish).
-                    # Draft-model engines keep their work local: the payload
-                    # carries only the target cache.
-                    if (self._handoff_sink is not None and not self._spec_k
-                            and req in self._active):
-                        self._publish_handoff(req)
+                    # its sample is the one value the prefill path reads
+                    # back, and not here: the device would stand idle from
+                    # the chunk's end until the next call reaches it.
+                    # _land_first_tokens reads it once more work is queued
+                    req.phase = "first_token"
+                    req.first_tok = (tok, t0, c)
+                    continue
+                self.cost.observe_chunk(t1 - t0)
+                if req.trace is not None:
+                    tracing.record_span("serving.decode.prefill", t0, t1,
+                                        parent=req.trace, chunk=c,
+                                        engine=self.metrics.engine_label)
         return progressed
 
+    def _land_first_token(self, req: _DecodeRequest, in_step: bool) -> None:
+        """Read the sample of ``req``'s last prefill chunk (the one wait
+        for the device in the prefill path) and move the request on to
+        decoding: it joins the next step that is packed. A chunk that
+        fails here is its own request's fault, unless a step is enqueued
+        behind it (``in_step``): that step is lost too, and the error is
+        its caller's to take down the step-fault ladder."""
+        tok, t0, c = req.first_tok
+        req.first_tok = None
+        try:
+            with tracing.start_span("serving.decode.prefill.wait"):
+                tok = int(tok)
+        except Exception as e:
+            if in_step:
+                raise
+            self._recover_request(req, e)
+            return
+        t1 = time.perf_counter()
+        self.cost.observe_chunk(t1 - t0)
+        if req.trace is not None:
+            tracing.record_span("serving.decode.prefill", t0, t1,
+                                parent=req.trace, chunk=c,
+                                engine=self.metrics.engine_label)
+        if self._prefix is not None:
+            # every fully-written page is immutable from here on
+            # (decode writes land past len(seq)) — publish them
+            n_full = len(req.seq) // self.decode_config.page_size
+            if n_full:
+                self._prefix.insert(
+                    req.seq, self._kv.slot_pages(req.slot)[:n_full])
+                # write-through demote: the same immutable pages,
+                # while the tree holds refs (no recycle race)
+                self._host_demote(req, n_full)
+        req.phase = "decode"
+        req.cur_len = len(req.seq)
+        # the final chunk's sample IS the next token after the
+        # prefilled sequence — the first (or, after a resume, the
+        # next) generated token
+        self._wf_tokens(req, t1, 1, "prefill")
+        self._append_token(req, tok)
+        # prefill role (serving.disagg): publish instead of
+        # decoding here — unless that one sampled token already
+        # finished the request (it left _active via _finish).
+        # Draft-model engines keep their work local: the payload
+        # carries only the target cache.
+        if (self._handoff_sink is not None and not self._spec_k
+                and req in self._active):
+            self._publish_handoff(req)
+
+    def _first_tokens_due(self) -> List[_DecodeRequest]:
+        return [r for r in self._active if r.phase == "first_token"]
+
+    def _land_first_tokens(self, due: List[_DecodeRequest],
+                           in_step: bool = False) -> bool:
+        due = [r for r in due if r.phase == "first_token"]
+        for req in due:
+            self._land_first_token(req, in_step)
+        return bool(due)
+
     def _decode_step(self) -> bool:
-        """One decode iteration: with a draft model configured, slots with
+        """One iteration's device work: the step over the decoding slots,
+        and up to ``prefill_chunks_per_iter`` prefill chunks enqueued
+        BEHIND it, before the loop waits for the step's tokens. The device
+        then runs chunk and step back to back while the host lands tokens,
+        admits and packs: it never waits for the host in an iteration that
+        carries a chunk. A last chunk's sampled token is read one
+        iteration on, once the next step is queued behind that chunk, or
+        at the end of an iteration that queued no step.
+
+        With a draft model configured the chunk goes first and its token
+        is read at once (a draft-and-verify iteration is several calls
+        with waits between them: nothing to queue work behind); slots with
         headroom for a full ``spec_tokens + 1`` block go through the
         draft-and-verify path; the rest (within ``spec_tokens`` positions
         of ``max_context``) fall back to the plain one-token step, which
         is always exact. Both substeps keep the scratch-page discipline:
         uninvolved slots get scratch table rows and position 0."""
+        chunks: List[bool] = []  # one entry once the chunks went out
+
+        def chunks_behind() -> None:
+            chunks.append(self._prefill_some())
+
         did = False
         handled: set = set()
         if self._spec_k:
+            chunks_behind()
+            self._land_first_tokens(self._first_tokens_due())
             limit = self.decode_config.max_context - self._spec_k - 1
             spec = [r for r in self._active
                     if r.phase == "decode" and r.cur_len <= limit]
@@ -1742,15 +1874,26 @@ class DecodeEngine:
         rest = [r for r in self._active
                 if r.phase == "decode" and id(r) not in handled]
         if rest:
-            did = self._plain_decode_step(rest) or did
-        return did
+            did = self._plain_decode_step(
+                rest, None if chunks else chunks_behind) or did
+        if not chunks:
+            # no step went out: nothing to queue the chunks behind, and
+            # nothing to read their first tokens behind either
+            chunks_behind()
+            did = self._land_first_tokens(self._first_tokens_due()) or did
+        return did or chunks[0]
 
-    def _plain_decode_step(self, decoding: List[_DecodeRequest]) -> bool:
+    def _plain_decode_step(self, decoding: List[_DecodeRequest],
+                           chunks_behind=None) -> bool:
         """One jitted iteration over the given decode-phase slots. Slots
         that are inactive or mid-prefill get a scratch table row and
-        position 0, so their garbage writes land on the scratch page and
-        their outputs are ignored — no per-slot branching inside the
-        step."""
+        position 0, so their garbage writes land on the scratch page (a
+        state model is told to leave their states alone) and their
+        outputs are ignored — no per-slot branching inside the step.
+        ``chunks_behind`` is called once the step is enqueued, or not at
+        all where no step goes out: it enqueues this iteration's prefill
+        chunks. Its seconds are not the step's: the chunks book their
+        own."""
         import jax.numpy as jnp
 
         if not decoding:
@@ -1773,23 +1916,31 @@ class DecodeEngine:
                     pack_span.cancel()
                     step_span.cancel()
                     return False
-                P = self._kv.pages_per_slot
                 tokens = np.zeros((S,), np.int32)
                 positions = np.zeros((S,), np.int32)
-                tables = np.full((S, P), SCRATCH_PAGE, np.int32)
                 for req in decoding:
                     tokens[req.slot] = req.last_tok
                     positions[req.slot] = req.cur_len
-                    tables[req.slot] = self._kv.page_tables[req.slot]
+                refs = self._slot_refs(decoding)
             t0 = time.perf_counter()
             try:
                 faults.inject(faults.DECODE_STEP,
                               engine=self.metrics.engine_label)
                 with tracing.start_span("serving.decode.model_step.dispatch"):
-                    nxt, self._k_pages, self._v_pages = self._step(
+                    nxt, *self._cache = self._step(
                         self._params, jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(tables),
-                        self._k_pages, self._v_pages, self._next_key())
+                        jnp.asarray(positions), jnp.asarray(refs),
+                        *self._cache, self._next_key())
+                # the step is queued behind the last iteration's chunks: a
+                # prompt whose last chunk was among them gets its first
+                # token now, with this iteration's chunks queued too. A
+                # chunk that fails here took the step with it
+                due = self._first_tokens_due()
+                behind = time.perf_counter()
+                if chunks_behind is not None:
+                    chunks_behind()
+                behind = time.perf_counter() - behind
+                self._land_first_tokens(due, in_step=True)
                 with tracing.start_span("serving.decode.model_step.wait"):
                     nxt = np.asarray(nxt)
             except Exception as e:
@@ -1798,7 +1949,11 @@ class DecodeEngine:
                 self._step_faulted(e, "decode")
                 return True
             t1 = time.perf_counter()
-            seconds = t1 - t0
+            seconds = t1 - t0 - behind
+            # a chunk enqueued behind the step may have preempted one of
+            # its slots, or lost the arrays and sent every request back to
+            # the queue: their tokens are made again after the re-prefill
+            decoding = [r for r in decoding if r in self._active]
             # the counts at the boundary, and the very float the metrics
             # get: a reader finds an iteration by it, without a tap
             step_span.set(active=len(decoding), new_tokens=len(decoding),
@@ -1875,9 +2030,9 @@ class DecodeEngine:
                 with tracing.start_span("serving.decode.verify.dispatch",
                                         model="target"):
                     block = np.concatenate([tokens[:, None], draft_mat], 1)
-                    out, self._k_pages, self._v_pages = self._verify(
+                    out, *self._cache = self._verify(
                         self._params, jnp.asarray(block), pos, tables_j,
-                        self._k_pages, self._v_pages)
+                        *self._cache)
                 with tracing.start_span("serving.decode.verify.wait",
                                         model="target"):
                     out = np.asarray(out)
@@ -2016,14 +2171,19 @@ class DecodeEngine:
         tier repopulates it as it does after a restart). Live slots are
         the caller's to quarantine: their KV went with the arrays. Returns
         whether anything was lost."""
-        names = ["_k_pages", "_v_pages"]
+        def rebuilt(old):
+            return self._zero_pages(
+                old.shape, None if self._group is None else old.sharding,
+                old.dtype)
+
+        lost = [i for i, c in enumerate(self._cache) if c.is_deleted()]
+        for i in lost:
+            self._cache[i] = rebuilt(self._cache[i])
         if self._spec_k:
-            names += ["_dk_pages", "_dv_pages"]
-        lost = [n for n in names if getattr(self, n).is_deleted()]
-        for n in lost:
-            old = getattr(self, n)
-            setattr(self, n, self._zero_pages(
-                old.shape, None if self._group is None else old.sharding))
+            for n in ("_dk_pages", "_dv_pages"):
+                if getattr(self, n).is_deleted():
+                    setattr(self, n, rebuilt(getattr(self, n)))
+                    lost.append(n)
         if lost:
             if self._prefix is not None:
                 self._prefix.clear()
@@ -2312,10 +2472,10 @@ class DecodeEngine:
         n_pages = -(-req.cur_len // dconf.page_size)
         # gather BEFORE _release: freed pages can be rewritten immediately
         pages = self._kv.slot_pages(req.slot)[:n_pages]
-        k_pages = [np.asarray(self._gather_page(self._k_pages,
+        k_pages = [np.asarray(self._gather_page(self._cache[0],
                                                 jnp.int32(p)))
                    for p in pages]
-        v_pages = [np.asarray(self._gather_page(self._v_pages,
+        v_pages = [np.asarray(self._gather_page(self._cache[1],
                                                 jnp.int32(p)))
                    for p in pages]
         payload = HandoffPayload(
@@ -2358,6 +2518,7 @@ class DecodeEngine:
         ``cur_len`` without re-prefilling. The client's original handle
         is repointed here, mirroring :meth:`adopt_rescue`. Thread-safe;
         returns the (possibly fresh) handle."""
+        self._refuse_unless_paged("disaggregated handoff")
         if self._closed:
             raise EngineClosedError("engine is closed")
         prompt = np.asarray(payload.prompt, np.int32).reshape(-1)

@@ -42,7 +42,7 @@ import numpy as np
 
 from paddle_tpu.core.enforce import enforce
 
-__all__ = ["PageAllocator", "PagedKVCache", "SCRATCH_PAGE"]
+__all__ = ["PageAllocator", "PagedKVCache", "SCRATCH_PAGE", "SlotStates"]
 
 # physical page 0: never allocated; inactive slots write/read it
 SCRATCH_PAGE = 0
@@ -343,3 +343,59 @@ class PagedKVCache:
         enforce(sum(len(p) for p in self._slot_pages) == 0,
                 "slot page lists non-empty after drain")
         self.allocator.assert_empty()
+
+
+class SlotStates:
+    """Host-side bookkeeping for a model whose cache is one fixed recurrent
+    state per slot (``models/retention_lm.py``): the slot-lifecycle half of
+    :class:`PagedKVCache` and nothing else. A state does not grow with the
+    sequence, so there are no pages to grant, run out of, preempt for or
+    leak; ``seq_lens`` is kept for the engine's bookkeeping only. The
+    device array is the engine's, as the pages are. A slot's state is
+    started over by the first prefill chunk of whoever is admitted to it,
+    so releasing a slot touches nothing on the device."""
+
+    def __init__(self, *, max_slots: int, max_context: int):
+        enforce(max_slots >= 1, f"max_slots must be >= 1, got {max_slots}")
+        self.max_slots = int(max_slots)
+        self.max_context = int(max_context)
+        self.seq_lens = np.zeros((max_slots,), dtype=np.int32)
+        self._active = [False] * max_slots
+
+    def acquire_slot(self) -> Optional[int]:
+        """Claim a free slot (None when all are occupied)."""
+        for s in range(self.max_slots):
+            if not self._active[s]:
+                self._active[s] = True
+                self.seq_lens[s] = 0
+                return s
+        return None
+
+    def release_slot(self, slot: int) -> int:
+        enforce(self._active[slot], f"release_slot: slot {slot} not active")
+        self._active[slot] = False
+        self.seq_lens[slot] = 0
+        return 0
+
+    def release_all(self) -> int:
+        slots = self.active_slots()
+        for s in slots:
+            self.release_slot(s)
+        return len(slots)
+
+    def ensure_capacity(self, slot: int, n_positions: int) -> bool:
+        """Always granted: the state holds any length up to the positions
+        the engine was built for."""
+        enforce(self._active[slot], f"ensure_capacity: slot {slot} not active")
+        enforce(n_positions <= self.max_context,
+                f"sequence needs {n_positions} positions but max_context is "
+                f"{self.max_context}")
+        return True
+
+    def active_slots(self) -> List[int]:
+        return [s for s in range(self.max_slots) if self._active[s]]
+
+    def assert_no_leaks(self) -> None:
+        """Drain invariant: no slot, and so no state, is still held."""
+        enforce(not any(self._active),
+                f"active slots after drain: {self.active_slots()}")

@@ -782,6 +782,19 @@ class DecodeMetrics:
         prof.set_gauge("serving.decode.pages_donated", int(ok),
                        labels=self._labels)
 
+    # a model that keeps a recurrent state per slot instead of KV pages
+    def set_state_bytes(self, n: int) -> None:
+        prof.set_gauge("serving.decode.state_bytes", n, labels=self._labels)
+
+    def set_state_slots_in_use(self, n: int) -> None:
+        prof.set_gauge("serving.decode.state_slots_in_use", n,
+                       labels=self._labels)
+
+    def set_state_donated(self, ok: bool) -> None:
+        """The twin of ``pages_donated`` for the state arrays."""
+        prof.set_gauge("serving.decode.state_donated", int(ok),
+                       labels=self._labels)
+
     def snapshot(self) -> Dict[str, float]:
         with self._lock:
             return {

@@ -3,7 +3,8 @@
 The TPU compiler is installed beside the CPU backend and compiles for a
 chip that is described, not attached. These are the programs
 ``chip_smoke.py`` runs — the flash kernel, the ``lm_large`` train step, the
-paged serving steps and the four-chip data-parallel step — so what the
+paged serving steps and the four-chip data-parallel step — and the two
+serving programs of the benchmark's ``brumby_14b`` cell, so what the
 chip's compiler would refuse (a kernel that cannot be partitioned, a
 program that does not fit HBM) fails here, at no chip time. Nothing runs:
 a passing compile says nothing about results or speed.
@@ -182,6 +183,54 @@ def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which):
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * page_bytes
     assert "remat_" not in compiled.as_text()
     assert _program_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_brumby_serving_steps_fit_the_chip_and_alias_their_state(one_chip, as_tpu, which):
+    """The cell brumby_14b.serve_docs16 at its own shapes: 8 layers at the
+    published widths in bfloat16 (8.4 GB) beside 16 slots of recurrent state
+    (5.1 GB, float32). The engine donates the state, so each program must
+    alias it to its output, must not copy it whole anywhere (an undonated or
+    sliced state would), must hold the ``retention_step`` kernel once a layer
+    in the step, and must fit the chip beside weights and state."""
+    import json
+
+    from benchmarks.families import retention_lm as family
+    from paddle_tpu.models import retention_lm
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(here, "configs", "brumby_14b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "traffic", "serve_docs16.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = dict(retention_lm.BASE_CFG, **family.model_cfg(config))
+    assert cfg["max_len"] == engine["max_context"]
+    progs = models.serving_programs(cfg)
+    bf16 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = {k: bf16(shape) for k, shape in retention_lm.param_shapes(cfg).items()}
+    (spec,) = progs.cache_specs(cfg, max_slots=engine["max_slots"])
+    state = jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    slots = engine["max_slots"]
+    if which == "decode_step":
+        fn, args = progs.decode_step, (i32(slots), i32(slots), i32(slots))
+    else:
+        fn, args = progs.prefill_chunk, (i32(engine["prefill_chunk"]), i32(), i32(), i32())
+    compiled = jax.jit(functools.partial(fn, cfg=cfg), donate_argnames=progs.cache_args,
+                       ).lower(params, *args, state, None).compile()
+    text = compiled.as_text()
+    state_bytes = int(np.prod(state.shape)) * 4
+    weight_bytes = 2 * sum(int(np.prod(p.shape)) for p in params.values())
+    assert 5.0e9 < state_bytes < 5.2e9 and 8.3e9 < weight_bytes < 8.5e9
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+    dims = ",".join(str(d) for d in state.shape)
+    whole_state_copies = [l for l in text.splitlines()
+                          if f"f32[{dims}]" in l.split("=")[0] and " copy(" in l]
+    assert not whole_state_copies, whole_state_copies[:2]
+    assert text.count("tpu_custom_call") == (cfg["n_layers"] if which == "decode_step" else 0)
+    assert ("retention_step" in text) == (which == "decode_step")
+    assert _program_bytes(compiled) < HBM_BYTES
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
 def test_data_parallel_step_compiles_on_four_chips(topo, as_tpu):

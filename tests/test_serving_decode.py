@@ -138,6 +138,61 @@ def test_eos_stops_early(lm):
     engine.kv.assert_no_leaks()
 
 
+def test_chunks_go_out_behind_the_step_and_first_tokens_behind_the_next(lm):
+    """While others decode, an iteration enqueues its step first and its
+    prefill chunk behind it, before it waits for the step's tokens; a last
+    chunk's token is read one iteration on, with the next step (and chunk)
+    already queued behind that chunk. So no iteration that carries a chunk
+    leaves the device waiting for the host. The request decodes from the
+    step after, or ends there on a budget of one; every output stays
+    generate()'s."""
+    engine = DecodeEngine(lm.variables, lm.cfg, decode=DecodeConfig(
+        max_slots=2, page_size=8, max_context=64, prefill_chunk=8))
+    order = []
+    step, chunk, land = engine._step, engine._prefill, engine._land_first_token
+
+    def spy(name, fn):
+        def call(*args):
+            order.append(name)
+            return fn(*args)
+        return call
+
+    def spy_land(req, in_step):
+        order.append("first token in step" if in_step else "first token")
+        return land(req, in_step)
+
+    engine._step, engine._prefill = spy("step", step), spy("chunk", chunk)
+    engine._land_first_token = spy_land
+    (p0, n0, r0), (p1, _, r1) = lm.cases[:2]
+    try:
+        h0 = engine.submit(p0, n0)
+        deadline = time.monotonic() + 60
+        while len(h0._req.generated) < 2:
+            assert time.monotonic() < deadline, "no tokens generated"
+            time.sleep(0.002)
+        h1 = engine.submit(p1, 1)  # ends on its first token, inside h0's step
+        h2 = engine.submit(p1, 5)  # takes the slot h1 leaves
+        outs = [h.result(timeout=300) for h in (h0, h1, h2)]
+    finally:
+        engine.close()
+    engine.kv.assert_no_leaks()
+    for out, ref in zip(outs, (r0, r1[:1], r1[:5])):
+        assert np.array_equal(out.tokens, ref)
+    first = order.index("step")
+    # h0 had no step to hide behind: its chunks, then its token at once
+    assert set(order[:first]) == {"chunk", "first token"} and order[first - 1] == "first token"
+    rest = order[first:]
+    assert rest.count("first token in step") == 2 and "first token" not in rest
+    for i, what in enumerate(rest):
+        if what != "step":  # behind a step: the chunks, then an earlier chunk's token
+            assert rest[i - 1] in ("step", "chunk")
+            assert what == "chunk" or rest[i + 1:i + 2] != ["chunk"]
+    # the token is of a chunk that went out behind an EARLIER step
+    for i in [i for i, what in enumerate(rest) if what == "first token in step"]:
+        this_step = max(j for j in range(i) if rest[j] == "step")
+        assert "chunk" in rest[:this_step]
+
+
 def test_cache_dtype_bf16(lm):
     """Satellite: cache_dtype flows ServingConfig -> engine, and the
     DecodeConfig override wins; decode still runs end to end on a bf16
@@ -148,8 +203,8 @@ def test_cache_dtype_bf16(lm):
         decode=DecodeConfig(max_slots=2, page_size=8, max_context=64,
                             prefill_chunk=8, cache_dtype=jnp.bfloat16))
     try:
-        assert engine._k_pages.dtype == jnp.bfloat16
-        assert engine._v_pages.dtype == jnp.bfloat16
+        assert engine._cache[0].dtype == jnp.bfloat16
+        assert engine._cache[1].dtype == jnp.bfloat16
         out = engine.infer(lm.cases[1][0], 8)
         assert out.finish_reason == "length" and len(out.tokens) == 8
     finally:
@@ -402,8 +457,8 @@ def owned(request, lm):
     # no traffic implants without a second engine or a host tier: by hand,
     # into the scratch page, while the loop thread idles
     eng = engines["plain"]
-    old = eng._k_pages
-    eng._k_pages = eng._implant_page(
+    old = eng._cache[0]
+    eng._cache[0] = eng._implant_page(
         old, jnp.int32(0), jnp.zeros(old.shape[:1] + old.shape[2:], old.dtype))
     yield types.SimpleNamespace(engines=engines, diverged=diverged)
     for eng in engines.values():

@@ -184,7 +184,7 @@ def test_fault_after_the_call_consumed_its_pages(lm, where, tp):
         group = make_groups(tp)[0]
     eng = DecodeEngine(lm.variables, lm.cfg, group=group,
                        decode=DecodeConfig(**DC, prefix_cache=True))
-    sharding = eng._k_pages.sharding
+    sharding = eng._cache[0].sharding
     first, n_first, ref_first = lm.cases[0]
     assert np.array_equal(eng.infer(first, n_first).tokens, ref_first)
     assert eng.prefix.num_pages >= 1  # the tree indexes pre-fault pages
@@ -216,7 +216,7 @@ def test_fault_after_the_call_consumed_its_pages(lm, where, tp):
             assert np.array_equal(out.tokens, ref)
         # both faults found the arrays deleted, and left an empty tree
         assert restored == [0, 0], restored
-        for a in (eng._k_pages, eng._v_pages):
+        for a in (eng._cache[0], eng._cache[1]):
             assert not a.is_deleted() and a.sharding == sharding
         snap = eng.metrics.snapshot()
         assert snap["errors_total"] == 0, snap

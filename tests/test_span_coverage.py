@@ -188,22 +188,41 @@ def test_engine_iteration_spans_and_the_seconds_the_metrics_were_handed(lm):
     for a, b in zip(passes, passes[1:]):
         assert a.t1_us <= b.t0_us
     # a model step holds pack, dispatch, wait and land, in that order, and
-    # carries the counts at the boundary and the very float the metrics got
+    # carries the counts at the boundary and the very float the metrics got.
+    # Between dispatch and wait, with the step enqueued, it enqueues the
+    # iteration's chunk behind the step and then reads the first token of a
+    # last chunk that an earlier iteration enqueued
     steps = [s for s in loop if s.name == "serving.decode.model_step"]
     assert [(s.attrs["active"], s.attrs["max_slots"], s.attrs["seconds"], s.attrs["new_tokens"])
             for s in steps] == handed
     by_id = {s.context.span_id: s for s in loop}
+    chunk, first_token = "serving.decode.prefill", "serving.decode.prefill.wait"
+    part = "serving.decode.model_step.%s"
     for s in steps:
         assert by_id[s.context.parent_id].name == "serving.decode.step"
-        assert [c.name for c in _children(loop, s)] == [
-            "serving.decode.model_step." + part for part in ("pack", "dispatch", "wait", "land")]
-    # every prompt's last chunk, and only that one, waited for its token
-    chunks = [s for s in loop if s.name == "serving.decode.prefill"]
+        names = [c.name for c in _children(loop, s)]
+        assert names[:2] == [part % "pack", part % "dispatch"]
+        assert names[-2:] == [part % "wait", part % "land"]
+        behind = names[2:-2]
+        assert set(behind) <= {chunk, first_token}
+        assert behind == sorted(behind, key=[chunk, first_token].index)
+    # a chunk is enqueued behind its iteration's step, or under the pass where
+    # no step went out; it never waits. Every prompt's last chunk, and only
+    # that one, has its token waited for: once, in a later step, or at the end
+    # of a pass that enqueued no step
+    chunks = [s for s in loop if s.name == chunk]
     assert sum(bool(s.attrs["last_chunk"]) for s in chunks) == len(prompts)
-    for s in chunks:
-        assert by_id[s.context.parent_id].name == "serving.decode.step"
-        waits = _children(loop, s, "serving.decode.prefill.wait")
-        assert len(waits) == (1 if s.attrs["last_chunk"] else 0)
+    waits = [s for s in loop if s.name == first_token]
+    assert len(waits) == len(prompts)
+    for s in chunks + waits:
+        assert by_id[s.context.parent_id].name in ("serving.decode.model_step", "serving.decode.step")
+    assert not any(w.context.parent_id == s.context.span_id for w in waits for s in chunks)
+    for w in waits:
+        over = by_id[w.context.parent_id]
+        last = max((s for s in chunks if s.attrs["last_chunk"] and s.t1_us <= w.t0_us),
+                   key=lambda s: s.t1_us)
+        # under a step, the chunk is an earlier step's or pass's; under the pass, its own
+        assert (last.context.parent_id == over.context.span_id) == (over.name == "serving.decode.step")
     # a request's own tree keeps its chunks, as README documents it
     request_chunks = [s for s in tracing.spans() if s.name == "serving.decode.prefill"
                       and s.context.trace_id != engine._loop_trace.trace_id]
