@@ -73,7 +73,12 @@ class _InstrumentedCompiled:
 
             # parents under the caller's active span (a trainer step, a
             # serving warmup), so compiles show up inside the step trace
-            tracing.record_span("executor.compile", t0, t1, target=self._label)
+            # the flash kernels this compile traced, with the blocks and the
+            # form each resolved to ("512x1024 table resident")
+            from paddle_tpu.ops.pallas.flash_attention import take_resolved
+
+            tracing.record_span("executor.compile", t0, t1, target=self._label,
+                                **take_resolved())
             if ledger_on:
                 try:
                     roofline.capture_costs(

@@ -72,6 +72,14 @@ def _flash_block(t: int):
     return None
 
 
+def _flash_block_arg(t: int):
+    """The block to hand the kernel for a length ``_flash_block`` fits:
+    128-divisible lengths defer to the kernel's own per-kernel table or rule
+    (None); shorter ones pin their largest divisor."""
+    b = _flash_block(t)
+    return None if b == 128 else b
+
+
 def _flash_per_shard(q, k, v, kv_len, **kw):
     """The flash kernel under the ambient mesh (``jax.set_mesh`` —
     ``DataParallel.step`` runs under one). A Mosaic kernel cannot be
@@ -181,12 +189,10 @@ def scaled_dot_product_attention(
 
             out_dtype = q.dtype
             q, k, v = mxu_operands(q, k, v)  # bf16 halves K/V HBM traffic
-            # 128-divisible lengths defer to the kernel's chip-measured
-            # tuned_blocks table; shorter sequences pin the largest divisor
             return _flash_per_shard(
                 q, k, v, kv_len, causal=causal, sm_scale=scale,
-                block_q=None if bq == 128 else bq,
-                block_k=None if bk == 128 else bk,
+                block_q=_flash_block_arg(q.shape[-2]),
+                block_k=_flash_block_arg(k.shape[-2]),
                 window=window,
             ).astype(out_dtype)
     if kv_len is not None:

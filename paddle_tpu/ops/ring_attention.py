@@ -144,7 +144,7 @@ def _ring_flash_fwd(
     blocks are block-skipped inside the kernel and come back with
     lse ≈ NEG_INF, which the merge weights to zero — sliding-window cost
     stays O(T·W) through the FLASH path."""
-    from paddle_tpu.ops.attention import _flash_block
+    from paddle_tpu.ops.attention import _flash_block_arg
     from paddle_tpu.ops.pallas import flash_attention_with_lse
 
     n_dev = jax.lax.psum(1, axis)
@@ -153,11 +153,11 @@ def _ring_flash_fwd(
     dtype = q.dtype
     # q in f32 (merge accumulates in its dtype); k/v keep the input dtype —
     # they rotate the ring, and bf16 halves the per-step ICI bytes (the
-    # kernel upcasts tiles internally anyway)
+    # kernel widens them to q's dtype as it loads a tile)
     q32 = q.astype(jnp.float32)
     perm = [(j, (j + 1) % n_dev) for j in range(n_dev)]
-    bq = _flash_block(t_local)
-    bk = _flash_block(k.shape[-2])
+    bq = _flash_block_arg(t_local)
+    bk = _flash_block_arg(k.shape[-2])
     q_off = rank * t_local
 
     o, lse = flash_attention_with_lse(
@@ -201,15 +201,15 @@ def _ring_flash_bwd_ring(q, k, v, out, lse, g, axis: str, causal: bool,
     cycle (n-1 scan steps + one final shift) each block's gradient arrives
     back at its home device. Nothing [T_local, T_local]-shaped ever hits
     HBM in the backward either."""
-    from paddle_tpu.ops.attention import _flash_block
+    from paddle_tpu.ops.attention import _flash_block_arg
     from paddle_tpu.ops.pallas import flash_attention_bwd_block
 
     n_dev = jax.lax.psum(1, axis)
     rank = jax.lax.axis_index(axis)
     t_local = q.shape[-2]
     perm = [(j, (j + 1) % n_dev) for j in range(n_dev)]
-    bq = _flash_block(t_local)
-    bk = _flash_block(k.shape[-2])
+    bq = _flash_block_arg(t_local)
+    bk = _flash_block_arg(k.shape[-2])
     q32 = q.astype(jnp.float32)
     g32 = g.astype(jnp.float32)
     out32 = out.astype(jnp.float32)

@@ -78,10 +78,11 @@ def flash_fingerprint() -> str:
         inspect.getsource(f)
         for f in (
             fa._flash_fwd_kernel,
-            fa._flash_fwd_kernel_resident,
             fa._flash_bwd_dkv_kernel,
             fa._flash_bwd_dq_kernel,
-            fa._flash_fwd,
+            fa._fwd_call,
+            fa._dkv_call,
+            fa._dq_call,
             fa._flash_bwd,
         )
     ]

@@ -96,29 +96,13 @@ def _abstract_state(spec, batch):
     return opt, v, jax.eval_shape(opt.create_state, v.params)
 
 
-@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-def test_flash_kernel_compiles(one_chip, as_tpu, backward):
-    from paddle_tpu.ops.pallas import flash_attention
-
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
-
-    fn = jax.grad(fwd, argnums=(0, 1, 2)) if backward else fwd
-    x = jax.ShapeDtypeStruct((4, 16, 2048, 64), jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(fn).lower(x, x, x).compile().as_text()
-    # forward only: one kernel; with the fused backward: fwd + dq + dkv
-    assert text.count("tpu_custom_call") >= (3 if backward else 1)
+# lm_big.train_2k's call, whose heads fit VMEM (resident forms, the table's
+# blocks), and 8 MB of K/V a head, which do not (streamed forms, ruled blocks)
+CELL_SHAPE, LONG_SHAPE = (4, 16, 2048, 64), (1, 4, 16384, 128)
 
 
-@pytest.mark.parametrize("kernel, shape, backward", [
-    ("flash_fwd_resident", (4, 16, 2048, 64), False),  # K/V of a head fit VMEM
-    ("flash_fwd", (1, 4, 16384, 128), False),  # 8 MB of K/V a head: streamed
-    ("flash_bwd_dkv", (4, 16, 2048, 64), True),
-    ("flash_bwd_dq", (4, 16, 2048, 64), True),
-])
-def test_flash_kernels_carry_their_names(one_chip, as_tpu, kernel, shape, backward):
-    """Each ``pallas_call`` has a ``name=``: the compiled instruction is
-    called after it, so a device trace tells the kernels apart."""
+def _flash_calls(shape, backward, one_chip):
+    """The compiled program's Mosaic calls, by instruction name."""
     from paddle_tpu.ops.pallas import flash_attention
 
     def fwd(q, k, v):
@@ -128,8 +112,65 @@ def test_flash_kernels_carry_their_names(one_chip, as_tpu, kernel, shape, backwa
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     calls = [line for line in jax.jit(fn).lower(x, x, x).compile().as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    named = [line.split(" = ", 1)[0].strip().rstrip("_.0123456789") for line in calls]
+    return [line.split(" = ", 1)[0].strip().rstrip("_.0123456789") for line in calls]
+
+
+@pytest.mark.parametrize("shape, form", [(CELL_SHAPE, "resident"), (LONG_SHAPE, "streamed")],
+                         ids=["cell_2k", "long_16k"])
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_kernel_compiles(one_chip, as_tpu, backward, shape, form):
+    """Forward, and forward with the fused backward, compile for the chip
+    with the blocks the code resolves and inside the ``vmem_limit_bytes`` it
+    sets: the cell's shape from the table in the resident forms, a head
+    that does not fit VMEM by the rule in the streamed forms."""
+    import importlib
+
+    from paddle_tpu.core import profiler as prof
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    fa.take_resolved()
+    before = prof.counters()
+    named = _flash_calls(shape, backward, one_chip)
+    # forward only: one kernel; with the fused backward: fwd + dkv + dq
+    assert len(named) >= (3 if backward else 1), named
+    resolved = fa.take_resolved()
+    assert len(resolved) == (3 if backward else 1), resolved
+    source = "table" if shape == CELL_SHAPE else "rule"
+    assert all(v.endswith(f" {source} {form}") for v in resolved.values()), resolved
+    grew = prof.counters().get(f"flash.form.{form}", 0) - before.get(f"flash.form.{form}", 0)
+    assert grew == len(resolved)
+
+
+@pytest.mark.parametrize("kernel, shape, backward", [
+    ("flash_fwd_resident", CELL_SHAPE, False),  # K/V of a head fit VMEM
+    ("flash_bwd_dkv_resident", CELL_SHAPE, True),  # and so do Q and dO
+    ("flash_bwd_dq_resident", CELL_SHAPE, True),
+    ("flash_fwd", LONG_SHAPE, False),  # 8 MB of K/V a head: streamed
+    ("flash_bwd_dkv", LONG_SHAPE, True),
+    ("flash_bwd_dq", LONG_SHAPE, True),
+])
+def test_flash_kernels_carry_their_names(one_chip, as_tpu, kernel, shape, backward):
+    """Each ``pallas_call`` has a ``name=``: the compiled instruction is
+    called after it, so a device trace tells the kernels, and the resident
+    forms from the streamed ones, apart."""
+    named = _flash_calls(shape, backward, one_chip)
     assert any(n.endswith(kernel) for n in named), named
+
+
+def test_flash_kv_len_fits_smem_at_128_pairs(one_chip, as_tpu):
+    """``kv_len`` reaches the kernels as one 1-D scalar-prefetch array of B
+    lengths: at 128 pairs x 16 heads the [B*H, 1] form it had overflowed
+    SMEM in the backward (PERF.md, PR 24)."""
+    from paddle_tpu.ops.pallas import flash_attention
+
+    def loss(q, k, v, kv_len):
+        return flash_attention(q, k, v, kv_len=kv_len, block_q=64, block_k=64
+                               ).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((128, 16, 64, 64), jnp.bfloat16, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x, n).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
 
 
 def test_lm_large_train_step_compiles(one_chip, as_tpu):

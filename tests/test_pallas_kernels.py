@@ -390,22 +390,127 @@ def test_flash_attention_gqa_with_window(rng):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
 
 
-def test_tuned_blocks_resolution():
-    """tuned_blocks: empty table -> 128/128; a populated row applies only
-    when its blocks divide the sequence lengths (no silent misconfig)."""
+def test_tuned_blocks_resolution(monkeypatch):
+    """tuned_blocks: a shape the table lacks takes the rule (the largest
+    fitted blocks under the VMEM budget); a row applies per kernel, and only
+    to the self-attention shape, head size and itemsize it was measured at."""
     import importlib
 
     # the package re-exports the flash_attention FUNCTION under the same
     # name, so plain `import ... as fa` resolves to it — load the module
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
-    assert fa.tuned_blocks(1024, 1024) == (128, 128)
-    old = fa._TUNED_BLOCKS
-    fa._TUNED_BLOCKS = [(0, 128, 128), (2048, 512, 256)]
-    try:
-        assert fa.tuned_blocks(4096, 4096) == (512, 256)
-        # 4096 q but kv=1920 (not 256-divisible): the tuned row must NOT apply
-        assert fa.tuned_blocks(4096, 1920) == (128, 128)
-        assert fa.tuned_blocks(1024, 1024) == (128, 128)  # below min_T
-    finally:
-        fa._TUNED_BLOCKS = old
+    monkeypatch.setattr(fa, "_TUNED_BLOCKS", {})
+    assert fa.tuned_blocks(1024, 1024) == fa.rule_blocks(1024, 1024)
+    bq, bk = fa.rule_blocks(1024, 1024, d=64, itemsize=2, kernel="dkv")
+    assert 1024 % bq == 0 and 1024 % bk == 0 and bq >= 128 and bk >= 128
+    assert fa.working_set_bytes("dkv", bq, bk, 64, 2, 1024) <= fa._RULE_VMEM_BYTES
+    monkeypatch.setattr(fa, "_TUNED_BLOCKS", {
+        (4096, 128, 2): {"fwd": (512, 256), "dkv": (256, 512), "dq": (128, 1024)}})
+    assert fa.tuned_blocks(4096, 4096) == (512, 256)
+    assert fa.tuned_blocks(4096, 4096, kernel="dkv") == (256, 512)
+    assert fa.tuned_blocks(4096, 4096, kernel="dq") == (128, 1024)
+    # another kv length, head size or itemsize: the row must NOT apply
+    assert fa.tuned_blocks(4096, 1920) == fa.rule_blocks(4096, 1920)
+    assert fa.tuned_blocks(4096, 4096, d=64) == fa.rule_blocks(4096, 4096, d=64)
+    assert fa.tuned_blocks(4096, 4096, itemsize=4) == fa.rule_blocks(4096, 4096, itemsize=4)
+    assert fa.tuned_blocks(1024, 1024) == fa.rule_blocks(1024, 1024)
+
+
+# ---- the three kernels over their degrees of freedom ------------------------
+
+# name -> (B, H, h_kv, T, d), call keywords, (q_off, k_off), table row or None
+_KERNEL_CASES = {
+    "blocks_differ": ((2, 2, 2, 64, 16), dict(causal=True, block_q=32, block_k=16), (0, 0), None),
+    "per_kernel_blocks": ((2, 2, 2, 64, 16), dict(causal=True), (0, 0),
+                          {"fwd": (32, 16), "dkv": (16, 32), "dq": (64, 16)}),
+    "full_square": ((1, 2, 2, 64, 16), dict(causal=False, block_q=16, block_k=32), (0, 0), None),
+    "gqa4": ((2, 4, 1, 64, 16), dict(causal=True, block_q=16, block_k=16), (0, 0), None),
+    "window": ((1, 4, 2, 64, 16), dict(causal=True, window=24, block_q=16, block_k=16), (0, 0), None),
+    "kvlen_padded_tail": ((2, 2, 2, 64, 16), dict(causal=False, kv_len=(20, 64), block_q=16, block_k=16),
+                          (0, 0), None),
+    "kvlen_causal": ((2, 2, 1, 64, 16), dict(causal=True, kv_len=(33, 7), block_q=16, block_k=16),
+                     (0, 0), None),
+    "ring_offsets": ((1, 2, 2, 32, 16), dict(causal=True, block_q=8, block_k=8), (32, 16), None),
+    "ring_offsets_window": ((1, 2, 2, 32, 16), dict(causal=True, window=20, block_q=16, block_k=8),
+                            (32, 16), None),
+    "t192_fitted": ((1, 2, 2, 192, 16), dict(causal=True, block_q=128, block_k=128), (0, 0), None),
+}
+# largest error over the largest reference value: float32 operands leave
+# rounding of the sums only; bfloat16 rounds the inputs' products' operands
+# P and dS to 8 bits (2^-8 = 3.9e-3 each) and the outputs once more
+_KERNEL_TOL = {"float32": 2e-5, "bfloat16": 2.5e-2}
+
+
+def _global_reference(q, k, v, causal, window, kv_len, q_off, k_off):
+    """softmax(QK^T / sqrt(d)) V and its row logsumexp with every mask at
+    GLOBAL positions, float32 throughout; GQA by repeating kv heads."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    rep = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(q.shape[-1])
+    q_pos = q_off + jnp.arange(q.shape[2])[:, None]
+    k_pos = k_off + jnp.arange(k.shape[2])[None, :]
+    keep = jnp.ones(s.shape[-2:], bool)
+    if causal:
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = keep & (q_pos - k_pos < window)
+    keep = jnp.broadcast_to(keep, s.shape)
+    if kv_len is not None:
+        keep = keep & (k_pos[None, None] < kv_len[:, None, None, None])
+    s = jnp.where(keep, s, -1e9)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse), v), lse
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("form", ["resident", "streamed"])
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_flash_kernels_against_reference(rng, monkeypatch, case, form, dtype):
+    """Forward (out, lse) and the fused backward (dq, dk, dv) of the block
+    API ring attention calls, against the float32 reference with global
+    masks: resident and streamed forms on the same inputs (the bound is
+    forced), block_q != block_k, per-kernel blocks from the table, both
+    operand dtypes, GQA, window, kv_len with a fully padded tail block,
+    non-zero offsets handed over TRACED as ring attention does, T 192."""
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    (B, H, h_kv, T, d), kw, (q_off, k_off), row = _KERNEL_CASES[case]
+    kw = dict(kw)
+    kv_len = kw.pop("kv_len", None)
+    kv_len = None if kv_len is None else jnp.asarray(kv_len, jnp.int32)
+    if form == "streamed":
+        monkeypatch.setattr(fa, "_VMEM_RESIDENT_BYTES", 0)
+    if row is not None:
+        monkeypatch.setattr(fa, "_TUNED_BLOCKS", {(T, d, jnp.dtype(dtype).itemsize): row})
+    q, g = (jnp.asarray(rng.randn(B, H, T, d), dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(B, h_kv, T, d), dtype) for _ in range(2))
+
+    @jax.jit
+    def run(q, k, v, g, q_off, k_off):
+        out, lse = fa.flash_attention_with_lse(
+            q, k, v, kv_len=kv_len, q_off=q_off, k_off=k_off, interpret=True, **kw)
+        return (out, lse) + fa.flash_attention_bwd_block(
+            q, k, v, out, lse, g, kv_len=kv_len, q_off=q_off, k_off=k_off,
+            interpret=True, **kw)
+
+    fa.take_resolved()  # what earlier tests of this process traced
+    got = run(q, k, v, g, jnp.int32(q_off), jnp.int32(k_off))
+    resolved = fa.take_resolved()
+    suffix = "_resident" if form == "resident" else ""
+    assert set(resolved) == {f"flash_fwd{suffix}", f"flash_bwd_dkv{suffix}", f"flash_bwd_dq{suffix}"}
+    if row is not None:
+        assert resolved[f"flash_bwd_dkv{suffix}"] == f"16x32 table {form}", resolved
+
+    ref_fn = lambda a, b, c: _global_reference(
+        a, b, c, kw["causal"], kw.get("window"), kv_len, q_off, k_off)
+    (ref_out, ref_lse), vjp = jax.vjp(ref_fn, q, k, v)
+    ref = (ref_out, ref_lse) + vjp((g.astype(jnp.float32), jnp.zeros_like(ref_lse)))
+    assert got[0].dtype == q.dtype and got[2].dtype == q.dtype
+    assert got[3].dtype == k.dtype and got[3].shape == k.shape
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, ref):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= _KERNEL_TOL[dtype] * np.abs(b).max(), (
+            name, np.abs(a - b).max(), np.abs(b).max())

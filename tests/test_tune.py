@@ -84,6 +84,42 @@ def test_candidate_blocks_always_valid_never_empty():
                for bq, bk in tune_search.candidate_blocks(1024, 1024, 64))
 
 
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+def test_candidates_follow_each_kernels_working_set(kernel):
+    """Candidates reach 1024, are sized by the kernel's own arithmetic (not a
+    copy of the forward's), and a head size that makes the large tiles too
+    big drops them for that kernel only."""
+    assert max(tune_search.CANDIDATE_SIZES) == 1024
+    cands = tune_search.candidate_blocks(2048, 2048, 64, kernel)
+    assert (1024, 1024) in cands and (128, 128) in cands
+    for bq, bk in cands:
+        assert tune_search._tile_bytes(bq, bk, 64, kernel) == fa.working_set_bytes(
+            kernel, bq, bk, 64, 2) <= tune_search._VMEM_BUDGET_BYTES
+    # dQ holds four score-sized float32 tiles to the forward's three
+    assert (tune_search._tile_bytes(512, 512, 64, "dq")
+            > tune_search._tile_bytes(512, 512, 64, "fwd"))
+
+
+def test_sweep_prints_the_table_row(capsys):
+    """``python -m paddle_tpu.tune.search --shape ...``: each kernel timed
+    alone over its candidates, fastest first, and the row for
+    ``_TUNED_BLOCKS`` with the runners-up, parseable as the table's entry."""
+    rc = tune_search.main(["--shape", "1,2,256,32", "--dtype", "float32",
+                           "--iters", "1", "--reps", "1"])
+    assert rc == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    rows = [json.loads(l) for l in lines[:-1]]
+    assert {r["kernel"] for r in rows} == {"fwd", "dkv", "dq"}
+    assert all(r["ms"] > 0 and 256 % r["block_q"] == 0 for r in rows)
+    key, entry = lines[-1].split("#")[0].strip().rstrip(",").split(": ", 1)
+    assert eval(key) == (256, 32, 4)
+    table = eval(entry)
+    assert set(table) == {"fwd", "dkv", "dq"}
+    for kernel, blocks in table.items():
+        best = min((r for r in rows if r["kernel"] == kernel), key=lambda r: r["ms"])
+        assert blocks == (best["block_q"], best["block_k"])
+
+
 def test_shape_bucket_and_variant_tag():
     assert tune_search.shape_bucket(1024) == "q1024"
     assert tune_search.shape_bucket(1000) == "q1024"
