@@ -13,11 +13,20 @@ sharding take over beyond single-chip VMEM), and matmuls run bf16 under
 Sharding: reuses the Megatron-style column/row-parallel projections of
 ``models/transformer.py`` (q/k/v/fc1 column, out/fc2 row over the model
 axis).
+
+Two halves. Training is ``lm_forward`` over ``lm_block`` (the framework's
+layers: parameters created in the frame, dropout, MoE, ring / Ulysses /
+flash cores, remat, scan, pipeline). Decoding is one ``decode_block`` over
+the trained parameters with three caches behind its ``attend``:
+``generate``'s static cache, ``generate_beam``'s, and the serving engine's
+KV pages (``paged_prefill_chunk`` / ``paged_decode_step`` /
+``paged_verify_step`` over one ``_paged_attend``).
 """
 
 from __future__ import annotations
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +34,7 @@ import numpy as np
 
 import paddle_tpu as pt
 from paddle_tpu import layers
+from paddle_tpu.core.enforce import enforce
 from paddle_tpu.framework import name_scope
 from paddle_tpu.models import ModelSpec
 from paddle_tpu.models.transformer import (
@@ -33,7 +43,9 @@ from paddle_tpu.models.transformer import (
     multi_head_attention,
     positionwise_ffn,
     prepare_embedding,
+    sinusoid_position_encoding,
 )
+from paddle_tpu.ops.attention import apply_rope, rope_tables, scaled_dot_product_attention
 
 __all__ = ["get_model", "lm_forward", "generate", "generate_beam",
            "stack_decode_params", "BASE_CFG",
@@ -66,8 +78,6 @@ def _rope_core(cfg):
     """Attention core applying rotary position embeddings to q/k before the
     (flash-routed) fused attention; positions are absolute so scores are
     relative-position functions."""
-    from paddle_tpu.ops.attention import apply_rope, rope_tables, scaled_dot_product_attention
-
     def core(qh, kh, vh, kv_len=None):
         cos, sin = rope_tables(qh.shape[-1], qh.shape[-2])
         return scaled_dot_product_attention(
@@ -78,35 +88,10 @@ def _rope_core(cfg):
     return core
 
 
-def _decode_ffn_fn(proj, swiglu: bool):
-    """FFN for the cached decoders, pinned to ``positionwise_ffn``:
-    relu(fc1) or fc1 * silu(gate). One copy shared by generate and
-    generate_beam so train/decode FFN parity has a single edit point."""
-    def ffn(x, i):
-        if swiglu:
-            h = proj(x, f"layer_{i}/ffn/fc1") * jax.nn.silu(proj(x, f"layer_{i}/ffn/gate"))
-        else:
-            h = jax.nn.relu(proj(x, f"layer_{i}/ffn/fc1"))
-        return proj(h, f"layer_{i}/ffn/fc2")
-
-    return ffn
-
-
-def _live_mask(t_max: int, t, window):
-    """[t_max] bool mask of cache positions a token at position ``t`` may
-    attend: <= t, and within the last ``window`` positions when sliding."""
-    live = jnp.arange(t_max) <= t
-    if window is not None:
-        live &= jnp.arange(t_max) > t - window
-    return live
-
-
 def _with_rope(core):
     """Wrap a sequence-parallel attention core with RoPE: the rotation is
     per-position (applied on the GLOBAL [B, H, T, d] arrays before the core
     shards them), so rope composes exactly with ring/ulysses."""
-    from paddle_tpu.ops.attention import apply_rope, rope_tables
-
     def rotated(qh, kh, vh, kv_len=None):
         cos, sin = rope_tables(qh.shape[-1], qh.shape[-2])
         q_r, k_r = apply_rope(qh, cos, sin), apply_rope(kh, cos, sin)
@@ -362,16 +347,217 @@ def lm_forward(ids, labels, seq_lens=None, *, cfg):
     return jnp.mean(nll) + aux_term, n_tok, logits
 
 
+# ---- the decode side: one block, three caches behind ``attend`` -----------
+#
+# Decoding runs the trained parameters (names as ``lm_forward`` created them)
+# as plain jittable functions over a lookup ``p(name)``. It is deliberately
+# NOT built on ``lm_block``: a scan-stepped cache cannot use
+# ``multi_head_attention``'s shape-growing concatenate cache, and re-entering
+# ``name_scope``s inside a scan body would re-uniquify parameter names
+# (ROADMAP C1 names the seam that would join the two). Everything a layer
+# does is ``decode_block``; the five entry points differ only in where K and
+# V live, which is the ``attend`` each hands the block: generate()'s static
+# cache, generate_beam()'s (layer axis behind the beam axis), or the engine's
+# pages. ``test_transformer_lm_generate_matches_naive_decode`` pins the block
+# to ``lm_forward``, and the serving tests pin the paged path to generate()
+# token for token.
+
+
+def _decode_ffn_fn(proj, swiglu: bool):
+    """FFN of the cached decoders (and of ``retention_lm``), pinned to
+    ``positionwise_ffn``: relu(fc1) or fc1 * silu(gate)."""
+    def ffn(x, i):
+        if swiglu:
+            h = proj(x, f"layer_{i}/ffn/fc1") * jax.nn.silu(proj(x, f"layer_{i}/ffn/gate"))
+        else:
+            h = jax.nn.relu(proj(x, f"layer_{i}/ffn/fc1"))
+        return proj(h, f"layer_{i}/ffn/fc2")
+
+    return ffn
+
+
+def _decode_ops(p, cfg):
+    """LayerNorm, projection, FFN and output logits over the lookup
+    ``p(name)``: the one set of parameter ops of every cached decoder."""
+    def ln(x, pfx):
+        mu = jnp.mean(x, -1, keepdims=True)
+        var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * p(f"{pfx}/scale") + p(f"{pfx}/bias")
+
+    def proj(x, pfx, bias=True):
+        out = x @ p(f"{pfx}/w")
+        return out + p(f"{pfx}/b") if bias else out
+
+    def logits_of(x_last):  # [..., D] -> [..., vocab]
+        return ln(x_last, "layer_norm") @ p("project/logits/w")
+
+    ffn = _decode_ffn_fn(proj, cfg.get("ffn_activation", "relu") == "swiglu")
+    return types.SimpleNamespace(p=p, cfg=cfg, ln=ln, proj=proj, ffn=ffn, logits_of=logits_of)
+
+
+def decode_block(ops, x, i, rotate, attend):
+    """Layer ``i`` (an int, or ``"SCAN"`` under the layer scan) on ``x``
+    [N, T, D], or [N, D] for one token a sequence: q/k/v projections split
+    into heads [N, n, T, dh] (or [N, n, dh]), ``rotate`` on q and k (RoPE at
+    the caller's positions, or identity), ``attend(i, q, k, v)`` -> the
+    context, shaped as q, against whatever cache the caller keeps (it stores
+    k and v there), out-projection, post-LN, FFN, post-LN."""
+    dh = x.shape[-1] // ops.cfg["num_heads"]
+    pfx = f"layer_{i}/self_attn"
+    heads = lambda y: jnp.moveaxis(y.reshape(*x.shape[:-1], -1, dh), -2, 1)
+    with jax.named_scope("attention"):
+        q, k, v = (heads(ops.proj(x, f"{pfx}/{w}")) for w in "qkv")
+        ctx = jnp.moveaxis(attend(i, rotate(q), rotate(k), v), 1, -2).reshape(x.shape)
+        x = ops.ln(x + ops.proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
+    with jax.named_scope("ffn"):
+        return ops.ln(x + ops.ffn(x, i), f"layer_{i}/layer_norm_1")
+
+
+def _embed(ops, ids, rows, n_pos: int):
+    """ids [N, T] or [N] -> x [..., D]: word embedding x sqrt(D), plus the
+    sinusoid table's rows at the tokens' positions unless RoPE puts position
+    into the attention rotation instead. ``rows(table)`` picks those rows of
+    a [n_pos, .] table: [N, T, .] or [N, .], N being 1 where the sequences
+    share their positions."""
+    D = ops.cfg["d_model"]
+    with jax.named_scope("embed"):
+        e = jnp.take(ops.p("emb/embedding/word_emb"), ids, axis=0) * (D ** 0.5)
+        if ops.cfg.get("pos_encoding", "sinusoid") == "rope":
+            return e
+        return e + rows(sinusoid_position_encoding(n_pos, D))
+
+
+def _rotation(ops, rows, n_pos: int):
+    """``rotate(x)`` for q and k [N, n, T, dh] or [N, n, dh]: RoPE at the
+    tokens' absolute positions (``rows`` as for :func:`_embed`), the identity
+    without RoPE. Cached K is stored PRE-rotated: the rotation depends only
+    on the key's own position, and scores only on relative offsets."""
+    cfg = ops.cfg
+    if cfg.get("pos_encoding", "sinusoid") != "rope":
+        return lambda x: x
+    cos, sin = (jnp.expand_dims(rows(t), 1)  # the heads' axis
+                for t in rope_tables(cfg["d_model"] // cfg["num_heads"], n_pos))
+    return lambda x: apply_rope(x, cos, sin)
+
+
+def _live_mask(q_pos, t_max: int, window):
+    """[..., t_max] bool: cache position t is visible from a query at
+    position ``q_pos`` ([...] int32) — causal, and within the last ``window``
+    positions when sliding. Positions not written yet are > q_pos."""
+    t = jnp.arange(t_max)
+    q_pos = jnp.asarray(q_pos)[..., None]
+    live = t <= q_pos
+    if window is not None:
+        live &= t > q_pos - window
+    return live
+
+
+def _attend_cached(q, kl, vl, live):
+    """Grouped-query softmax attention of q [N, H, Q, dh] over a cache's
+    rows kl, vl [N, H_kv, T, dh] under ``live`` (broadcastable to
+    [N, 1, 1, Q, T]) -> [N, H, Q, dh]."""
+    N, H, Q, dh = q.shape
+    H_kv = kl.shape[1]
+    qg = q.reshape(N, H_kv, H // H_kv, Q, dh)
+    s = jnp.einsum("bkgqd,bktd->bkgqt", qg, kl) * (1.0 / np.sqrt(dh))
+    s = jnp.where(live, s, -1e9)
+    ctx = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, -1), vl)
+    return ctx.reshape(N, H, Q, dh)
+
+
+def run_layer_scan(ops, x, rotate, attend, cache: list, scan_view: dict, stacked: dict):
+    """The layer loop as one ``lax.scan`` over ``stacked`` ({suffix: [L, ...]},
+    see :func:`stack_decode_params`) — compile cost O(1) in depth, the
+    decode-side analogue of ``framework.scan_layer_stack``. Each slice
+    repopulates ``scan_view``, which the ops' lookup reads under the reserved
+    ``layer_SCAN/`` prefix; ``cache`` (the list ``attend`` writes) rides the
+    carry, and ``attend`` is handed the traced layer index."""
+    def body(carry, sl):
+        y, *cache[:] = carry
+        scan_view.clear()
+        scan_view.update(sl["p"])
+        y = decode_block(ops, y, "SCAN", rotate,
+                         lambda _, q, k, v: attend(sl["i"], q, k, v))
+        return (y, *cache), None
+
+    (x, *cache[:]), _ = jax.lax.scan(
+        body, (x, *cache), {"p": stacked, "i": jnp.arange(ops.cfg["n_layers"])})
+    return x
+
+
+def _params_of(variables_or_params):
+    return getattr(variables_or_params, "params", variables_or_params)
+
+
 def stack_decode_params(variables_or_params, cfg: dict) -> dict:
     """Stack the per-layer parameter arrays for ``scan_layers`` decode:
     {suffix: [L, ...]}. Call ONCE outside the jitted decode (or let jit
     close over the result) so the stack is not re-copied per call; pass to
     :func:`generate` as ``stacked_params``."""
-    params = (variables_or_params.params
-              if hasattr(variables_or_params, "params") else variables_or_params)
     return pt.framework.stack_layer_params(
-        params, cfg["n_layers"], lambda i: f"layer_{i}"
+        _params_of(variables_or_params), cfg["n_layers"], lambda i: f"layer_{i}"
     )
+
+
+def _static_decoder(variables, cfg, batch: int, t_max: int, layer_axis: int,
+                    cache_dtype, stacked_params):
+    """What generate() and generate_beam() share: a static k/v cache of
+    ``t_max`` positions, [L, B, H_kv, T, dh] or (``layer_axis`` 1: beam
+    tiling stays on dim 0) [B, L, H_kv, T, dh], and ``run(ids, t, prefill)``
+    that takes ids [B, T] at positions [t, t + T) through every layer.
+    Returns ``(ops, cache, run)``; ``cache`` is the list [k, v] that ``run``
+    reads and rebinds, so a scan step puts its carry there first.
+
+    With ``cfg['scan_layers']`` the layer loop is :func:`run_layer_scan`;
+    prefer a caller-prestacked tree (``stacked_params``, built once OUTSIDE
+    jit) — stacking here copies the full parameter set on every jitted call."""
+    params = _params_of(variables)
+    H, L = cfg["num_heads"], cfg["n_layers"]
+    window = cfg.get("attention_window")
+    n_pos = max(cfg["max_len"], t_max)
+    scan_view: dict = {}
+    stacked = None
+    if cfg.get("scan_layers"):
+        stacked = (stacked_params if stacked_params is not None
+                   else stack_decode_params(params, cfg))
+
+    def p(name):
+        if name.startswith("layer_SCAN/"):
+            return scan_view[name[len("layer_SCAN/"):]]
+        return params[name]
+
+    ops = _decode_ops(p, cfg)
+    shape = [batch, cfg.get("num_kv_heads") or H, t_max, cfg["d_model"] // H]
+    shape.insert(layer_axis, L)  # GQA: the cache holds H_kv heads
+    cache = [jnp.zeros(shape, cache_dtype or jnp.float32)] * 2
+
+    def run(ids, t, prefill=False):
+        rows = lambda table: jax.lax.dynamic_slice_in_dim(table, t, ids.shape[1], axis=0)[None]
+
+        def attend(li, q, k, v):
+            start = [0, 0, 0, t, 0]
+            start[layer_axis] = li
+            for j, new in enumerate((k, v)):
+                cache[j] = jax.lax.dynamic_update_slice(
+                    cache[j], jnp.expand_dims(new, layer_axis).astype(cache[j].dtype), start)
+            if prefill:
+                # sdpa routes long prompts through the flash kernel when the
+                # flag is on (no [T, T] materialization) and composes the
+                # identical causal+window einsum math otherwise — the
+                # training forward's path, so decode-vs-forward stays exact
+                return scaled_dot_product_attention(q, k, v, causal=True, window=window)
+            kl, vl = (jax.lax.dynamic_index_in_dim(c, li, layer_axis, keepdims=False)
+                      for c in cache)
+            return _attend_cached(q, kl, vl, _live_mask(t, t_max, window))
+
+        x, rotate = _embed(ops, ids, rows, n_pos), _rotation(ops, rows, n_pos)
+        if stacked is not None:
+            return run_layer_scan(ops, x, rotate, attend, cache, scan_view, stacked)
+        for i in range(L):
+            x = decode_block(ops, x, i, rotate, attend)
+        return x
+
+    return ops, cache, run
 
 
 def generate(
@@ -390,16 +576,10 @@ def generate(
     prompt, then one ``lax.scan`` step per new token (single compile, no
     shape growth; the TPU-idiomatic replacement for the reference's
     per-step re-run of a decode program). Returns [B, max_new_tokens] int32.
-
-    Implemented directly over the trained params dict (names as created by
-    :func:`lm_forward`) so the decode loop is a plain jittable function —
-    greedy at ``temperature=0``, else softmax sampling with ``rng``
-    (required then). Deliberately NOT built on ``lm_block``: a scan-stepped
-    static cache can't use ``multi_head_attention``'s shape-growing
-    concatenate cache, and re-entering ``name_scope``s inside a scan body
-    would re-uniquify parameter names. The decode math is pinned to
-    ``lm_forward`` by ``test_transformer_lm_generate_matches_naive_decode``
-    — change one, and that exact-match test catches the drift.
+    Greedy at ``temperature=0``, else :func:`sample_logits` with ``rng``
+    (required then). ``cfg['scan_layers']`` runs the layer loop, prefill and
+    per token, as a ``lax.scan`` over stacked params (``stacked_params``
+    from :func:`stack_decode_params` avoids re-stacking per jitted call).
 
     ``cache_dtype`` (default f32): the k/v cache dtype. ``jnp.bfloat16``
     halves decode HBM traffic — the decode-throughput lever on TPU, where
@@ -407,16 +587,7 @@ def generate(
     values (scores still accumulate f32; confident predictions are
     unaffected, see the memorized-decode test).
     """
-    from paddle_tpu.core.enforce import enforce
-    from paddle_tpu.models.transformer import sinusoid_position_encoding
-
-    params = variables.params if hasattr(variables, "params") else variables
     B, Tp = prompt.shape
-    T_max = Tp + max_new_tokens
-    D, H, L = cfg["d_model"], cfg["num_heads"], cfg["n_layers"]
-    dh = D // H
-    H_kv = cfg.get("num_kv_heads") or H  # GQA: cache holds H_kv heads
-    G = H // H_kv
     enforce(max_new_tokens >= 1, f"max_new_tokens must be >= 1, got {max_new_tokens}")
     enforce(
         temperature == 0.0 or rng is not None,
@@ -428,235 +599,110 @@ def generate(
         "generate: MoE FFNs are not supported in the cached decoders yet — "
         "decode with lm_forward teacher-forcing, or use a dense-FFN config",
     )
-    rope = cfg.get("pos_encoding", "sinusoid") == "rope"
-    swiglu = cfg.get("ffn_activation", "relu") == "swiglu"
-    window = cfg.get("attention_window")
-    pe = sinusoid_position_encoding(max(cfg["max_len"], T_max), D)
-    if rope:
-        from paddle_tpu.ops.attention import apply_rope, rope_tables
-
-        rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], T_max))
-    scale = 1.0 / np.sqrt(dh)
-
-    # scan-over-layers decode (cfg['scan_layers']): layer params stack to
-    # [L, ...] by suffix and the per-token layer loop runs as a lax.scan;
-    # inside the scan body the block's name-based lookups resolve through
-    # ``scan_view`` via the reserved 'layer_SCAN/' prefix (the decode-side
-    # analogue of framework.scan_layer_stack — compile cost O(1) in depth)
-    scan_layers = bool(cfg.get("scan_layers"))
-    scan_view: dict = {}
-    if scan_layers:
-        # prefer a caller-prestacked tree (stack_decode_params, built once
-        # OUTSIDE jit / closed over by it) — stacking here would copy the
-        # full parameter set on every jitted decode call
-        stacked = (stacked_params if stacked_params is not None
-                   else stack_decode_params(params, cfg))
-
-    def p(name):
-        if name.startswith("layer_SCAN/"):
-            return scan_view[name[len("layer_SCAN/"):]]
-        return params[name]
-
-    def ln(x, pfx):
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * p(f"{pfx}/scale") + p(f"{pfx}/bias")
-
-    def proj(x, pfx, bias=True):
-        out = x @ p(f"{pfx}/w")
-        return out + p(f"{pfx}/b") if bias else out
-
-    ffn = _decode_ffn_fn(proj, swiglu)
-
-    def heads(x, n=None):  # [B, T, n*dh] -> [B, n, T, dh]
-        n = n or H
-        return x.reshape(x.shape[0], x.shape[1], n, dh).transpose(0, 2, 1, 3)
-
-    def grouped(q):  # [B, H, T, dh] -> [B, H_kv, G, T, dh]
-        return q.reshape(q.shape[0], H_kv, G, q.shape[2], dh)
-
-    def ungrouped(o):  # [B, H_kv, G, T, dh] -> [B, H, T, dh]
-        return o.reshape(o.shape[0], H, o.shape[3], dh)
-
-    def embed(ids, pos0):
-        e = jnp.take(p("emb/embedding/word_emb"), ids, axis=0) * (D ** 0.5)
-        if rope:  # position enters at the attention rotation instead
-            return e
-        t = ids.shape[1]
-        return e + jax.lax.dynamic_slice_in_dim(pe, pos0, t, axis=0)
-
-    def rotate(x, pos0):
-        """RoPE at absolute positions [pos0, pos0+T): cached K is stored
-        PRE-rotated (rotation depends only on the key's own position, and
-        scores depend only on relative offsets)."""
-        t = x.shape[2]
-        cos = jax.lax.dynamic_slice_in_dim(rope_cos, pos0, t, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(rope_sin, pos0, t, axis=0)
-        return apply_rope(x, cos, sin)
-
-    def block(x, i, attend, pos0=0):
-        pfx = f"layer_{i}/self_attn"
-        q = heads(proj(x, f"{pfx}/q"))
-        k = heads(proj(x, f"{pfx}/k"), H_kv)
-        v = heads(proj(x, f"{pfx}/v"), H_kv)
-        if rope:
-            q = rotate(q, pos0)
-            k = rotate(k, pos0)
-        ctx = attend(q, k, v, i)  # [B, H, Tq, dh]
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], D)
-        x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        return ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
-
-    def logits_of(x_last):  # [B, D] -> [B, vocab]
-        return ln(x_last, "layer_norm") @ p("project/logits/w")
-
-    def sample(logits, key):
-        if temperature == 0.0:
-            return jnp.argmax(logits, -1).astype(jnp.int32)
-        logits = logits / temperature
-        if top_k is not None:
-            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-            logits = jnp.where(logits < kth, -jnp.inf, logits)
-        if top_p is not None:
-            # nucleus: keep the smallest prefix of sorted probs with
-            # cumulative mass >= top_p (the top token always survives)
-            sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-            probs = jax.nn.softmax(sorted_logits, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            keep_sorted = cum - probs < top_p
-            cutoff = jnp.min(
-                jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True
-            )
-            logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-        return jax.random.categorical(key, logits).astype(jnp.int32)
+    ops, cache, run = _static_decoder(
+        variables, cfg, B, Tp + max_new_tokens, 0, cache_dtype, stacked_params)
+    sample = functools.partial(sample_logits, temperature=temperature,
+                               top_k=top_k, top_p=top_p)
 
     # ---- prefill: full causal pass over the prompt fills caches [0, Tp)
-    cdt = cache_dtype or jnp.float32
-    kc0 = jnp.zeros((L, B, H_kv, T_max, dh), cdt)
-    vc0 = jnp.zeros((L, B, H_kv, T_max, dh), cdt)
-    caches = {"k": kc0, "v": vc0}
-
-    # sdpa routes long prompts through the flash kernel when the flag is
-    # on (no [Tp, Tp] materialization) and composes the identical
-    # causal+window einsum math otherwise — same path as the training
-    # forward, so decode-vs-forward stays exact
-    from paddle_tpu.ops.attention import scaled_dot_product_attention
-
-    def run_layer_scan(x0, kc, vc, pos0, make_attend):
-        """The shared layer-scan body for scan_layers prefill AND decode:
-        repopulate the scan_view overlay from the stacked slice, run the
-        block with an attend built for this layer index, carry caches."""
-        def body(carry, sl):
-            y, kc, vc = carry
-            scan_view.clear()
-            scan_view.update(sl["p"])
-            li = sl["i"]
-
-            def attend(q, k, v, _i):
-                nonlocal kc, vc
-                ctx, kc, vc = make_attend(q, k, v, li, kc, vc)
-                return ctx
-
-            y = block(y, "SCAN", attend, pos0=pos0)
-            return (y, kc, vc), None
-
-        return jax.lax.scan(
-            body, (x0, kc, vc), {"p": stacked, "i": jnp.arange(L)}
-        )[0]
-
-    if scan_layers:
-        def prefill_write(q, k, v, li, kc, vc):
-            kc = kc.at[li, :, :, :Tp].set(k.astype(cdt))
-            vc = vc.at[li, :, :, :Tp].set(v.astype(cdt))
-            ctx = scaled_dot_product_attention(
-                q, k, v, causal=True, window=window
-            )
-            return ctx, kc, vc
-
-        x, kc_f, vc_f = run_layer_scan(
-            embed(prompt, 0), kc0, vc0, 0, prefill_write
-        )
-        caches = {"k": kc_f, "v": vc_f}
-    else:
-        def prefill_attend(q, k, v, i):
-            caches["k"] = caches["k"].at[i, :, :, :Tp].set(k.astype(cdt))
-            caches["v"] = caches["v"].at[i, :, :, :Tp].set(v.astype(cdt))
-            return scaled_dot_product_attention(
-                q, k, v, causal=True, window=window
-            )
-
-        x = embed(prompt, 0)
-        for i in range(L):
-            x = block(x, i, prefill_attend, pos0=0)
+    x = run(prompt, 0, prefill=True)
     first_key, scan_rng = (
         jax.random.split(rng) if rng is not None else (None, None)
     )
-    first_tok = sample(logits_of(x[:, -1]), first_key)
+    first_tok = sample(ops.logits_of(x[:, -1]), first_key)
 
     # ---- decode: one token per scan step against the cache
     def step(carry, s):
-        tok, kc, vc, key = carry
-        t = Tp + s  # position of this token
-        xt = embed(tok[:, None], t)  # [B, 1, D] — pos0 is traced; ok for slice
-
-        def cached_attend(q, k, v, li, kc, vc):
-            """One token's attention against layer ``li``'s cache rows
-            (li may be traced under the layer scan); returns the updated
-            caches alongside the context."""
-            kc = jax.lax.dynamic_update_slice(kc, k[None].astype(cdt), (li, 0, 0, t, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v[None].astype(cdt), (li, 0, 0, t, 0))
-            kci = jax.lax.dynamic_index_in_dim(kc, li, 0, keepdims=False)
-            vci = jax.lax.dynamic_index_in_dim(vc, li, 0, keepdims=False)
-            s_ = jnp.einsum("bkgqd,bktd->bkgqt", grouped(q), kci) * scale
-            live = _live_mask(T_max, t, window)
-            s_ = jnp.where(live[None, None, None, None, :], s_, -1e9)
-            ctx = ungrouped(
-                jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s_, -1), vci)
-            )
-            return ctx, kc, vc
-
-        if scan_layers:
-            y, kc, vc = run_layer_scan(xt, kc, vc, t, cached_attend)
-        else:
-            def attend_i(q, k, v, i):
-                nonlocal kc, vc
-                ctx, kc, vc = cached_attend(q, k, v, i, kc, vc)
-                return ctx
-
-            y = xt
-            for i in range(L):
-                y = block(y, i, attend_i, pos0=t)
+        tok, *cache[:], key = carry
+        y = run(tok[:, None], Tp + s)  # [B, 1, D]
         if key is not None:
             key, sub = jax.random.split(key)
         else:
             sub = None
-        nxt = sample(logits_of(y[:, -1]), sub)
-        return (nxt, kc, vc, key), tok
+        nxt = sample(ops.logits_of(y[:, -1]), sub)
+        return (nxt, *cache, key), tok
 
     if max_new_tokens == 1:
         return first_tok[:, None]
-    carry = (first_tok, caches["k"], caches["v"], scan_rng)
-    (last_tok, _, _, _), toks = jax.lax.scan(
-        step, carry, jnp.arange(max_new_tokens - 1)
+    (last_tok, *_), toks = jax.lax.scan(
+        step, (first_tok, *cache, scan_rng), jnp.arange(max_new_tokens - 1)
     )
     return jnp.concatenate([toks.transpose(1, 0), last_tok[:, None]], axis=1)
+
+
+def generate_beam(
+    variables,
+    prompt: jax.Array,
+    max_new_tokens: int,
+    cfg: dict,
+    beam_size: int = 4,
+    eos_id: int = 1,
+    length_penalty_alpha: float = 0.0,
+    cache_dtype=None,
+    stacked_params: dict | None = None,
+):
+    """Beam-search continuation of ``prompt``: returns
+    ``(sequences [B, beam, max_new_tokens], scores [B, beam])`` best-first.
+
+    Built on the generic :func:`paddle_tpu.ops.control_flow.beam_search`
+    (the reference's beam_search/beam_search_decode op pair — beam search is
+    a first-class path there, ``operators/beam_search_op.cc``) over
+    :func:`generate`'s decoder with the cache's layer axis at dim 1, because
+    beam_search tiles dim 0 of every carry leaf: the prompt minus its last
+    token is prefilled into the cache, each row's last prompt token seeds
+    its beams, and every scan step attends against cache[0..t].
+    ``cfg['scan_layers']``, ``stacked_params`` and ``cache_dtype`` as in
+    :func:`generate` (deep-model beam decode then pays O(1) compile cost,
+    VERDICT r4 #6).
+    """
+    from paddle_tpu.ops import control_flow as ocf
+
+    B, Tp = prompt.shape
+    enforce(Tp >= 1, "generate_beam needs a non-empty prompt")
+    enforce(
+        not cfg.get("moe_experts"),
+        "generate_beam: MoE FFNs are not supported in the cached decoders "
+        "yet — use a dense-FFN config",
+    )
+    ops, cache, run = _static_decoder(
+        variables, cfg, B, Tp + max_new_tokens, 1, cache_dtype, stacked_params)
+    # --- prefill positions [0, Tp-1): full causal pass over the prompt head
+    Thead = Tp - 1
+    if Thead > 0:
+        run(prompt[:, :Thead], 0, prefill=True)
+
+    # --- beam decode: carry leaves are [B, ...] (beam_search tiles dim 0)
+    def step_fn(carry, tokens):
+        cache[:] = carry["k"], carry["v"]
+        y = run(tokens[:, None], carry["t"][0])
+        logp = jax.nn.log_softmax(ops.logits_of(y[:, -1]).astype(jnp.float32), -1)
+        return {"k": cache[0], "v": cache[1], "t": carry["t"] + 1}, logp
+
+    return ocf.beam_search(
+        step_fn,
+        {"k": cache[0], "v": cache[1], "t": jnp.full((B,), Thead, jnp.int32)},
+        batch_size=B,
+        beam_size=beam_size,
+        vocab_size=cfg["vocab"],
+        max_len=max_new_tokens,
+        bos_id=prompt[:, -1],
+        eos_id=eos_id,
+        length_penalty_alpha=length_penalty_alpha,
+    )
 
 
 # ---- paged decode (serving.kv_cache / serving.decode) ---------------------
 #
 # The paged variant of generate()'s cache read/write: K/V live in fixed-size
-# pages ([L, num_pages, H_kv, page_size, dh]) and each sequence maps logical
-# positions to physical pages through an int32 page-table row. Every array
-# shape below is a function of static config (slot count, table width, page
-# size) — never of which requests are in flight — so the serving decode step
-# compiles once and continuous batching (admit/evict between steps) never
-# pays XLA again. Same parameter names and attention math as generate();
-# the exactness test pins the two against each other.
+# pages and each sequence maps logical positions to physical pages through an
+# int32 page-table row. Every array shape below is a function of static
+# config (slot count, table width, page size) — never of which requests are
+# in flight — so the serving programs compile once and continuous batching
+# (admit/evict between steps) never pays XLA again. How a page array is
+# indexed is spelled twice: ``paged_cache_shape`` and ``_paged_attend``.
 
 
 def _paged_enforce(cfg, temperature, rng):
-    from paddle_tpu.core.enforce import enforce
-
     enforce(
         not cfg.get("scan_layers"),
         "paged decode: scan_layers is not supported in the paged path yet "
@@ -685,7 +731,7 @@ def paged_cache_shape(cfg: dict, num_pages: int, page_size: int):
 
 def sample_logits(logits, key, temperature, top_k, top_p):
     """Greedy argmax at temperature 0, else temperature / top-k / top-p
-    sampling with ``key``: the one sampler of every serving program."""
+    sampling with ``key``: the one sampler of every decoder."""
     if temperature == 0.0:
         return jnp.argmax(logits, -1).astype(jnp.int32)
     logits = logits / temperature
@@ -693,6 +739,8 @@ def sample_logits(logits, key, temperature, top_k, top_p):
         kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
         logits = jnp.where(logits < kth, -jnp.inf, logits)
     if top_p is not None:
+        # nucleus: keep the smallest prefix of sorted probs with
+        # cumulative mass >= top_p (the top token always survives)
         sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
         probs = jax.nn.softmax(sorted_logits, axis=-1)
         cum = jnp.cumsum(probs, axis=-1)
@@ -705,44 +753,57 @@ def sample_logits(logits, key, temperature, top_k, top_p):
     return jax.random.categorical(key, logits).astype(jnp.int32)
 
 
-def _paged_ops(params, cfg):
-    """The p/ln/proj/ffn/logits/sample closures shared by the paged prefill
-    and decode-step entry points — the same math as :func:`generate`'s
-    inline copies (parameter names as created by :func:`lm_forward`)."""
-    D, H = cfg["d_model"], cfg["num_heads"]
-    dh = D // H
-    swiglu = cfg.get("ffn_activation", "relu") == "swiglu"
+def _paged_attend(pages: list, page_tables, pos, page_size: int, window):
+    """``attend(i, q, k, v)`` of queries at absolute positions ``pos``: [C]
+    of the one sequence whose table ``page_tables`` [P] is, or [S] or [S, Q]
+    with a table row a slot [S, P]. It writes the queries' K and V
+    (pre-rotated K, exactly as generate() stores it) into their pages,
+    gathers each sequence's whole logical context [0, P * page_size) back
+    through its table (the rows just written included) and attends under the
+    live mask. ``pages`` is the list [k_pages, v_pages], each
+    [L, page, H_kv, offset, dh]; it is read and rebound layer by layer.
 
-    def p(name):
-        return params[name]
+    The gather materializes each sequence's [H_kv, T_eff, dh] context per
+    layer — the straightforward XLA lowering. ROADMAP A1b (the pages' layout)
+    and A5 (a Pallas kernel that streams pages from HBM without the copy)
+    edit this one body."""
+    P = page_tables.shape[-1]
+    B, t_eff = page_tables.size // P, P * page_size
+    page, off = pos // page_size, pos % page_size
+    if page_tables.ndim == 1:  # no slot axis to index: the chunk's program stays as it compiled
+        phys = page_tables[page]
+    else:
+        slot = jnp.arange(B).reshape((B,) + (1,) * (pos.ndim - 1))
+        phys = page_tables[slot, page]
+    live = _live_mask(pos, t_eff, window).reshape(B, 1, 1, -1, t_eff)
 
-    def ln(x, pfx):
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * p(f"{pfx}/scale") + p(f"{pfx}/bias")
+    def attend(i, q, k, v):  # [B, n, Q, dh], or [S, n, dh]
+        H, dh = q.shape[1], q.shape[-1]
+        with jax.named_scope("page_write"):
+            for j, new in enumerate((k, v)):
+                new = jnp.moveaxis(new, 1, -2).reshape(pos.shape + (-1, dh))
+                pages[j] = pages[j].at[i, phys, :, off].set(new.astype(pages[j].dtype))
+        kl, vl = (jnp.moveaxis(pg[i][page_tables], -3, -4).reshape(B, -1, t_eff, dh)
+                  for pg in pages)
+        return _attend_cached(q.reshape(B, H, -1, dh), kl, vl, live).reshape(q.shape)
 
-    def proj(x, pfx, bias=True):
-        out = x @ p(f"{pfx}/w")
-        return out + p(f"{pfx}/b") if bias else out
-
-    ffn = _decode_ffn_fn(proj, swiglu)
-
-    def logits_of(x_last):
-        return ln(x_last, "layer_norm") @ p("project/logits/w")
-
-    return p, ln, proj, ffn, logits_of, sample_logits
+    return attend
 
 
-def _paged_live_mask(q_pos, t_eff: int, window):
-    """[..., T_eff] bool: key position t visible from query position
-    ``q_pos`` ([...] int32) — causal, and within the sliding window when
-    configured. The gathered pages cover logical positions [0, T_eff); any
-    slot beyond the sequence's written length is > q_pos and masks out."""
-    t = jnp.arange(t_eff)
-    live = t <= q_pos[..., None]
-    if window is not None:
-        live &= t > q_pos[..., None] - window
-    return live
+def _paged_hidden(params, tokens, rows, page_tables, pos, k_pages, v_pages, *,
+                  cfg, page_size):
+    """``tokens`` (``rows(table)`` picks their positions' rows of a position
+    table) through every layer against the paged cache, ``page_tables`` and
+    ``pos`` as :func:`_paged_attend` takes them. Returns
+    ``(ops, x [*tokens.shape, D], k_pages, v_pages)``."""
+    ops = _decode_ops(_params_of(params).__getitem__, cfg)
+    n_pos = max(cfg["max_len"], page_tables.shape[-1] * page_size)
+    x, rotate = _embed(ops, tokens, rows, n_pos), _rotation(ops, rows, n_pos)
+    pages = [k_pages, v_pages]
+    attend = _paged_attend(pages, page_tables, pos, page_size, cfg.get("attention_window"))
+    for i in range(cfg["n_layers"]):
+        x = decode_block(ops, x, i, rotate, attend)
+    return ops, x, *pages
 
 
 def paged_prefill_chunk(
@@ -775,80 +836,18 @@ def paged_prefill_chunk(
     end) write K/V that decode overwrites position-by-position before ever
     attending to them, and their own outputs are discarded.
     """
-    from paddle_tpu.models.transformer import sinusoid_position_encoding
-
-    params = params.params if hasattr(params, "params") else params
     _paged_enforce(cfg, temperature, rng)
     (C,) = tokens.shape
-    P = page_table.shape[0]
-    t_eff = P * page_size
-    D, H = cfg["d_model"], cfg["num_heads"]
-    dh = D // H
-    H_kv = cfg.get("num_kv_heads") or H
-    G = H // H_kv
-    L = cfg["n_layers"]
-    rope = cfg.get("pos_encoding", "sinusoid") == "rope"
-    window = cfg.get("attention_window")
-    scale = 1.0 / np.sqrt(dh)
-    cdt = k_pages.dtype
-    p, ln, proj, ffn, logits_of, sample = _paged_ops(params, cfg)
-
-    with jax.named_scope("embed"):
-        e = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
-        if rope:
-            from paddle_tpu.ops.attention import apply_rope, rope_tables
-
-            rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], t_eff))
-        else:
-            pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
-            e = e + jax.lax.dynamic_slice_in_dim(pe, pos0, C, axis=0)
-        x = e[None]  # [1, C, D]
-    pos = pos0 + jnp.arange(C, dtype=jnp.int32)
-    phys = page_table[pos // page_size]  # [C] physical page per position
-    off = pos % page_size
-    live = _paged_live_mask(pos, t_eff, window)  # [C, T_eff]
-
-    def heads(y, n):  # [1, C, n*dh] -> [1, n, C, dh]
-        return y.reshape(1, C, n, dh).transpose(0, 2, 1, 3)
-
-    for i in range(L):
-        pfx = f"layer_{i}/self_attn"
-        with jax.named_scope("attention"):
-            q = heads(proj(x, f"{pfx}/q"), H)
-            k = heads(proj(x, f"{pfx}/k"), H_kv)
-            v = heads(proj(x, f"{pfx}/v"), H_kv)
-            if rope:
-                cos = jax.lax.dynamic_slice_in_dim(rope_cos, pos0, C, axis=0)
-                sin = jax.lax.dynamic_slice_in_dim(rope_sin, pos0, C, axis=0)
-                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        # scatter the chunk's K/V into this sequence's pages (pre-rotated
-        # K, exactly as generate() stores it)
-        with jax.named_scope("page_write"):
-            k_pages = k_pages.at[i, phys, :, off].set(
-                k[0].transpose(1, 0, 2).astype(cdt))
-            v_pages = v_pages.at[i, phys, :, off].set(
-                v[0].transpose(1, 0, 2).astype(cdt))
-        # gather the sequence's whole logical context back through the
-        # table (includes the chunk just written) and mask by position
-        with jax.named_scope("attention"):
-            kl = k_pages[i][page_table].transpose(1, 0, 2, 3).reshape(
-                H_kv, t_eff, dh)[None]
-            vl = v_pages[i][page_table].transpose(1, 0, 2, 3).reshape(
-                H_kv, t_eff, dh)[None]
-            qg = q.reshape(1, H_kv, G, C, dh)
-            s = jnp.einsum("bkgqd,bktd->bkgqt", qg, kl) * scale
-            s = jnp.where(live[None, None, None], s, -1e9)
-            ctx = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, -1), vl)
-            ctx = ctx.reshape(1, H, C, dh).transpose(0, 2, 1, 3).reshape(1, C, D)
-            x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        with jax.named_scope("ffn"):
-            x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
-
+    ops, x, k_pages, v_pages = _paged_hidden(
+        params, tokens[None],
+        lambda table: jax.lax.dynamic_slice_in_dim(table, pos0, C, axis=0)[None],
+        page_table, pos0 + jnp.arange(C, dtype=jnp.int32), k_pages, v_pages,
+        cfg=cfg, page_size=page_size)
     with jax.named_scope("head"):
         x_last = jax.lax.dynamic_index_in_dim(x[0], last_index, 0, keepdims=False)
-        logits = logits_of(x_last)
+        logits = ops.logits_of(x_last)
     with jax.named_scope("sampling"):
-        tok = sample(logits, rng, temperature, top_k, top_p)
+        tok = sample_logits(logits, rng, temperature, top_k, top_p)
     return tok, k_pages, v_pages
 
 
@@ -877,83 +876,15 @@ def paged_decode_step(
     continuous-batching contract: slots change occupants between calls
     without recompiling. Inactive slots point at the scratch page; their
     writes and outputs are garbage the engine ignores.
-
-    The gather materializes each slot's ``[H_kv, T_eff, dh]`` context per
-    layer — the straightforward XLA lowering. A Pallas paged-attention
-    kernel that streams pages from HBM without the copy is the known TPU
-    follow-up; the interface (pages + tables) is already shaped for it.
     """
-    from paddle_tpu.models.transformer import sinusoid_position_encoding
-
-    params = params.params if hasattr(params, "params") else params
     _paged_enforce(cfg, temperature, rng)
-    (S,) = tokens.shape
-    P = page_tables.shape[1]
-    t_eff = P * page_size
-    D, H = cfg["d_model"], cfg["num_heads"]
-    dh = D // H
-    H_kv = cfg.get("num_kv_heads") or H
-    G = H // H_kv
-    L = cfg["n_layers"]
-    rope = cfg.get("pos_encoding", "sinusoid") == "rope"
-    window = cfg.get("attention_window")
-    scale = 1.0 / np.sqrt(dh)
-    cdt = k_pages.dtype
-    p, ln, proj, ffn, logits_of, sample = _paged_ops(params, cfg)
-
-    with jax.named_scope("embed"):
-        x = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
-    if rope:
-        from paddle_tpu.ops.attention import rope_tables
-
-        rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], t_eff))
-        cos, sin = rope_cos[positions], rope_sin[positions]  # [S, dh//2]
-
-        def rot(y):  # [S, n, dh] rotated at each slot's own position
-            half = dh // 2
-            y1, y2 = y[..., :half], y[..., half:]
-            c, s_ = cos[:, None, :], sin[:, None, :]
-            yf1, yf2 = y1.astype(jnp.float32), y2.astype(jnp.float32)
-            return jnp.concatenate(
-                [yf1 * c - yf2 * s_, yf1 * s_ + yf2 * c], -1
-            ).astype(y.dtype)
-    else:
-        with jax.named_scope("embed"):
-            pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
-            x = x + pe[positions]
-    phys = page_tables[jnp.arange(S), positions // page_size]  # [S]
-    off = positions % page_size
-    live = _paged_live_mask(positions, t_eff, window)  # [S, T_eff]
-
-    for i in range(L):
-        pfx = f"layer_{i}/self_attn"
-        with jax.named_scope("attention"):
-            q = proj(x, f"{pfx}/q").reshape(S, H, dh)
-            k = proj(x, f"{pfx}/k").reshape(S, H_kv, dh)
-            v = proj(x, f"{pfx}/v").reshape(S, H_kv, dh)
-            if rope:
-                q, k = rot(q), rot(k)
-        with jax.named_scope("page_write"):
-            k_pages = k_pages.at[i, phys, :, off].set(k.astype(cdt))
-            v_pages = v_pages.at[i, phys, :, off].set(v.astype(cdt))
-        with jax.named_scope("attention"):
-            kl = k_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-                S, H_kv, t_eff, dh)
-            vl = v_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-                S, H_kv, t_eff, dh)
-            qg = q.reshape(S, H_kv, G, dh)
-            s = jnp.einsum("skgd,sktd->skgt", qg, kl) * scale
-            s = jnp.where(live[:, None, None], s, -1e9)
-            ctx = jnp.einsum("skgt,sktd->skgd", jax.nn.softmax(s, -1), vl)
-            ctx = ctx.reshape(S, D)
-            x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        with jax.named_scope("ffn"):
-            x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
-
+    ops, x, k_pages, v_pages = _paged_hidden(
+        params, tokens, lambda table: table[positions], page_tables, positions,
+        k_pages, v_pages, cfg=cfg, page_size=page_size)
     with jax.named_scope("head"):
-        logits = logits_of(x)
+        logits = ops.logits_of(x)
     with jax.named_scope("sampling"):
-        nxt = sample(logits, rng, temperature, top_k, top_p)
+        nxt = sample_logits(logits, rng, temperature, top_k, top_p)
     return nxt, k_pages, v_pages
 
 
@@ -990,79 +921,15 @@ def paged_verify_step(
     the accepted frontier, masked (``t > q_pos``) until the next block
     overwrites them.
     """
-    from paddle_tpu.models.transformer import sinusoid_position_encoding
-
-    params = params.params if hasattr(params, "params") else params
     _paged_enforce(cfg, 0.0, None)
-    S, K1 = tokens.shape
-    P = page_tables.shape[1]
-    t_eff = P * page_size
-    D, H = cfg["d_model"], cfg["num_heads"]
-    dh = D // H
-    H_kv = cfg.get("num_kv_heads") or H
-    G = H // H_kv
-    L = cfg["n_layers"]
-    rope = cfg.get("pos_encoding", "sinusoid") == "rope"
-    window = cfg.get("attention_window")
-    scale = 1.0 / np.sqrt(dh)
-    cdt = k_pages.dtype
-    p, ln, proj, ffn, logits_of, _ = _paged_ops(params, cfg)
-
-    with jax.named_scope("embed"):
-        x = jnp.take(p("emb/embedding/word_emb"), tokens, axis=0) * (D ** 0.5)
-    pos = positions[:, None] + jnp.arange(K1, dtype=jnp.int32)  # [S, K1]
-    if rope:
-        from paddle_tpu.ops.attention import rope_tables
-
-        rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], t_eff))
-        cos, sin = rope_cos[pos], rope_sin[pos]  # [S, K1, dh//2]
-
-        def rot(y):  # [S, K1, n, dh] rotated at each token's own position
-            half = dh // 2
-            y1, y2 = y[..., :half], y[..., half:]
-            c, s_ = cos[:, :, None, :], sin[:, :, None, :]
-            yf1, yf2 = y1.astype(jnp.float32), y2.astype(jnp.float32)
-            return jnp.concatenate(
-                [yf1 * c - yf2 * s_, yf1 * s_ + yf2 * c], -1
-            ).astype(y.dtype)
-    else:
-        with jax.named_scope("embed"):
-            pe = sinusoid_position_encoding(max(cfg["max_len"], t_eff), D)
-            x = x + pe[pos]
-    phys = page_tables[jnp.arange(S)[:, None], pos // page_size]  # [S, K1]
-    off = pos % page_size
-    live = _paged_live_mask(pos, t_eff, window)  # [S, K1, T_eff]
-
-    for i in range(L):
-        pfx = f"layer_{i}/self_attn"
-        with jax.named_scope("attention"):
-            q = proj(x, f"{pfx}/q").reshape(S, K1, H, dh)
-            k = proj(x, f"{pfx}/k").reshape(S, K1, H_kv, dh)
-            v = proj(x, f"{pfx}/v").reshape(S, K1, H_kv, dh)
-            if rope:
-                q, k = rot(q), rot(k)
-        with jax.named_scope("page_write"):
-            k_pages = k_pages.at[i, phys, :, off].set(k.astype(cdt))
-            v_pages = v_pages.at[i, phys, :, off].set(v.astype(cdt))
-        with jax.named_scope("attention"):
-            kl = k_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-                S, H_kv, t_eff, dh)
-            vl = v_pages[i][page_tables].transpose(0, 2, 1, 3, 4).reshape(
-                S, H_kv, t_eff, dh)
-            qg = q.transpose(0, 2, 1, 3).reshape(S, H_kv, G, K1, dh)
-            s = jnp.einsum("skgqd,sktd->skgqt", qg, kl) * scale
-            s = jnp.where(live[:, None, None], s, -1e9)
-            ctx = jnp.einsum("skgqt,sktd->skgqd", jax.nn.softmax(s, -1), vl)
-            ctx = ctx.reshape(S, H, K1, dh).transpose(0, 2, 1, 3).reshape(
-                S, K1, D)
-            x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        with jax.named_scope("ffn"):
-            x = ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
-
+    pos = positions[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)  # [S, K+1]
+    ops, x, k_pages, v_pages = _paged_hidden(
+        params, tokens, lambda table: table[pos], page_tables, pos,
+        k_pages, v_pages, cfg=cfg, page_size=page_size)
     with jax.named_scope("head"):
-        logits = logits_of(x)
+        logits = ops.logits_of(x)
     with jax.named_scope("sampling"):
-        out = jnp.argmax(logits, -1).astype(jnp.int32)  # [S, K1]
+        out = sample_logits(logits, None, 0.0, None, None)  # [S, K+1]
     return out, k_pages, v_pages
 
 
@@ -1146,227 +1013,4 @@ def get_model(
         unit="tokens/sec",
         examples_per_row=seq_len,
         extra={"cfg": cfg, "seq_len": seq_len},
-    )
-
-
-def generate_beam(
-    variables,
-    prompt: jax.Array,
-    max_new_tokens: int,
-    cfg: dict,
-    beam_size: int = 4,
-    eos_id: int = 1,
-    length_penalty_alpha: float = 0.0,
-    cache_dtype=None,
-    stacked_params: dict | None = None,
-):
-    """Beam-search continuation of ``prompt``: returns
-    ``(sequences [B, beam, max_new_tokens], scores [B, beam])`` best-first.
-
-    Built on the generic :func:`paddle_tpu.ops.control_flow.beam_search`
-    (the reference's beam_search/beam_search_decode op pair — beam search is
-    a first-class path there, ``operators/beam_search_op.cc``) over the same
-    static k/v cache layout as :func:`generate`: the prompt minus its last
-    token is prefilled into the cache, each row's last prompt token seeds
-    its beams, and every scan step attends against cache[0..t]. Same decode
-    math as ``generate`` (same param names/ops); GQA cache layout included.
-
-    ``cfg['scan_layers']`` runs the per-token (and prefill) layer loop as a
-    ``lax.scan`` over stacked params, exactly as in :func:`generate` — one
-    traced layer body regardless of depth, so deep-model beam decode pays
-    O(1) compile cost (VERDICT r4 #6). Beam caches keep the layer axis at
-    dim 1 (beam tiling stays on dim 0); the scan indexes it dynamically.
-    Pass ``stacked_params`` (from :func:`stack_decode_params`) to avoid
-    re-stacking per jitted call.
-    """
-    from paddle_tpu.core.enforce import enforce
-    from paddle_tpu.models.transformer import sinusoid_position_encoding
-    from paddle_tpu.ops import control_flow as ocf
-
-    params = variables.params if hasattr(variables, "params") else variables
-    B, Tp = prompt.shape
-    enforce(Tp >= 1, "generate_beam needs a non-empty prompt")
-    enforce(
-        not cfg.get("moe_experts"),
-        "generate_beam: MoE FFNs are not supported in the cached decoders "
-        "yet — use a dense-FFN config",
-    )
-    T_max = Tp + max_new_tokens
-    D, H, L = cfg["d_model"], cfg["num_heads"], cfg["n_layers"]
-    dh = D // H
-    H_kv = cfg.get("num_kv_heads") or H
-    G = H // H_kv
-    rope = cfg.get("pos_encoding", "sinusoid") == "rope"
-    swiglu = cfg.get("ffn_activation", "relu") == "swiglu"
-    window = cfg.get("attention_window")
-    pe = sinusoid_position_encoding(max(cfg["max_len"], T_max), D)
-    if rope:
-        from paddle_tpu.ops.attention import apply_rope, rope_tables
-
-        rope_cos, rope_sin = rope_tables(dh, max(cfg["max_len"], T_max))
-    scale = 1.0 / np.sqrt(dh)
-
-    scan_layers = bool(cfg.get("scan_layers"))
-    scan_view: dict = {}
-    if scan_layers:
-        stacked = (stacked_params if stacked_params is not None
-                   else stack_decode_params(params, cfg))
-
-    def p(name):
-        if name.startswith("layer_SCAN/"):
-            return scan_view[name[len("layer_SCAN/"):]]
-        return params[name]
-
-    def ln(x, pfx):
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * p(f"{pfx}/scale") + p(f"{pfx}/bias")
-
-    def proj(x, pfx, bias=True):
-        out = x @ p(f"{pfx}/w")
-        return out + p(f"{pfx}/b") if bias else out
-
-    ffn = _decode_ffn_fn(proj, swiglu)
-
-    def heads(x, n):
-        return x.reshape(x.shape[0], x.shape[1], n, dh).transpose(0, 2, 1, 3)
-
-    def embed(ids, pos0):
-        e = jnp.take(p("emb/embedding/word_emb"), ids, axis=0) * (D ** 0.5)
-        if rope:
-            return e
-        return e + jax.lax.dynamic_slice_in_dim(pe, pos0, ids.shape[1], axis=0)
-
-    def rotate(x, pos0):  # pre-rotated K cache (see generate())
-        t = x.shape[2]
-        cos = jax.lax.dynamic_slice_in_dim(rope_cos, pos0, t, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(rope_sin, pos0, t, axis=0)
-        return apply_rope(x, cos, sin)
-
-    def attn_vs_cache(q, kc_l, vc_l, t):
-        # q [N, H, 1, dh]; kc_l/vc_l [N, H_kv, T_max, dh]; attend over [0, t]
-        n = q.shape[0]
-        qg = q.reshape(n, H_kv, G, 1, dh)
-        s = jnp.einsum("bkgqd,bktd->bkgqt", qg, kc_l) * scale
-        live = _live_mask(T_max, t, window)
-        s = jnp.where(live[None, None, None, None, :], s, -1e9)
-        o = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, -1), vc_l)
-        return o.reshape(n, H, 1, dh)
-
-    def block(x, i, attend, pos0=0):
-        pfx = f"layer_{i}/self_attn"
-        q = heads(proj(x, f"{pfx}/q"), H)
-        k = heads(proj(x, f"{pfx}/k"), H_kv)
-        v = heads(proj(x, f"{pfx}/v"), H_kv)
-        if rope:
-            q = rotate(q, pos0)
-            k = rotate(k, pos0)
-        ctx = attend(q, k, v, i)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], D)
-        x = ln(x + proj(ctx, f"{pfx}/out"), f"layer_{i}/layer_norm")
-        return ln(x + ffn(x, i), f"layer_{i}/layer_norm_1")
-
-    def logits_of(x_last):
-        return ln(x_last, "layer_norm") @ p("project/logits/w")
-
-    def run_layer_scan(x0, kc, vc, pos0, make_attend):
-        """generate()'s scanned layer loop, beam cache layout (layer axis at
-        dim 1): repopulate the scan_view overlay per slice, carry caches."""
-        def body(carry, sl):
-            y, kc, vc = carry
-            scan_view.clear()
-            scan_view.update(sl["p"])
-            li = sl["i"]
-
-            def attend(q, k, v, _i):
-                nonlocal kc, vc
-                ctx, kc, vc = make_attend(q, k, v, li, kc, vc)
-                return ctx
-
-            y = block(y, "SCAN", attend, pos0=pos0)
-            return (y, kc, vc), None
-
-        return jax.lax.scan(
-            body, (x0, kc, vc), {"p": stacked, "i": jnp.arange(L)}
-        )[0]
-
-    # --- prefill positions [0, Tp-1): full causal pass over the prompt head
-    from paddle_tpu.ops.attention import scaled_dot_product_attention
-
-    cdt = cache_dtype or jnp.float32  # bf16 halves decode HBM traffic
-    kc0 = jnp.zeros((B, L, H_kv, T_max, dh), cdt)
-    vc0 = jnp.zeros((B, L, H_kv, T_max, dh), cdt)
-    caches = {"k": kc0, "v": vc0}
-    Thead = Tp - 1
-    if Thead > 0 and scan_layers:
-        def prefill_write(q, k, v, li, kc, vc):
-            kc = jax.lax.dynamic_update_slice(
-                kc, k[:, None].astype(cdt), (0, li, 0, 0, 0)
-            )
-            vc = jax.lax.dynamic_update_slice(
-                vc, v[:, None].astype(cdt), (0, li, 0, 0, 0)
-            )
-            ctx = scaled_dot_product_attention(q, k, v, causal=True, window=window)
-            return ctx, kc, vc
-
-        x, kc_f, vc_f = run_layer_scan(
-            embed(prompt[:, :Thead], 0), kc0, vc0, 0, prefill_write
-        )
-        caches = {"k": kc_f, "v": vc_f}
-    elif Thead > 0:
-        def prefill_attend(q, k, v, i):
-            caches["k"] = caches["k"].at[:, i, :, :Thead].set(k.astype(cdt))
-            caches["v"] = caches["v"].at[:, i, :, :Thead].set(v.astype(cdt))
-            # flash-capable prefill, exactly as in generate()
-            return scaled_dot_product_attention(q, k, v, causal=True, window=window)
-
-        x = embed(prompt[:, :Thead], 0)
-        for i in range(L):
-            x = block(x, i, prefill_attend, pos0=0)
-
-    # --- beam decode: carry leaves are [B, ...] (beam_search tiles dim 0)
-    init_carry = {"k": caches["k"], "v": caches["v"],
-                  "t": jnp.full((B,), Thead, jnp.int32)}
-
-    def step_fn(carry, tokens):
-        t = carry["t"][0]
-        xt = embed(tokens[:, None], t)
-        kc, vc = carry["k"], carry["v"]
-
-        if scan_layers:
-            def cached_attend(q, k, v, li, kc, vc):
-                kc = jax.lax.dynamic_update_slice(
-                    kc, k[:, None].astype(kc.dtype), (0, li, 0, t, 0)
-                )
-                vc = jax.lax.dynamic_update_slice(
-                    vc, v[:, None].astype(vc.dtype), (0, li, 0, t, 0)
-                )
-                kci = jax.lax.dynamic_index_in_dim(kc, li, 1, keepdims=False)
-                vci = jax.lax.dynamic_index_in_dim(vc, li, 1, keepdims=False)
-                return attn_vs_cache(q, kci, vci, t), kc, vc
-
-            y, kc, vc = run_layer_scan(xt, kc, vc, t, cached_attend)
-        else:
-            def attend(q, k, v, i):
-                nonlocal kc, vc
-                kc = jax.lax.dynamic_update_slice(kc, k[:, None].astype(kc.dtype), (0, i, 0, t, 0))
-                vc = jax.lax.dynamic_update_slice(vc, v[:, None].astype(vc.dtype), (0, i, 0, t, 0))
-                return attn_vs_cache(q, kc[:, i], vc[:, i], t)
-
-            y = xt
-            for i in range(L):
-                y = block(y, i, attend, pos0=t)
-        logp = jax.nn.log_softmax(logits_of(y[:, -1]).astype(jnp.float32), -1)
-        return {"k": kc, "v": vc, "t": carry["t"] + 1}, logp
-
-    return ocf.beam_search(
-        step_fn,
-        init_carry,
-        batch_size=B,
-        beam_size=beam_size,
-        vocab_size=cfg["vocab"],
-        max_len=max_new_tokens,
-        bos_id=prompt[:, -1],
-        eos_id=eos_id,
-        length_penalty_alpha=length_penalty_alpha,
     )

@@ -633,3 +633,56 @@ def test_transformer_lm_generate_flash_prefill_matches_composed():
         pt.core.config.set_flags(use_flash_attention=False)
     np.testing.assert_array_equal(np.asarray(out_composed), np.asarray(out_flash))
     np.testing.assert_array_equal(np.asarray(beam_composed), np.asarray(beam_flash))
+
+
+@pytest.mark.parametrize("entry", [
+    "generate", "generate_scan", "generate_beam",
+    "paged_prefill_chunk", "paged_decode_step", "paged_verify_step"])
+def test_transformer_lm_decoders_share_one_block(monkeypatch, entry):
+    """Every decode-side entry point runs its layers through the module's
+    one ``decode_block`` — n_layers calls a pass over the layers (the static
+    cache's two decoders make two passes: the prompt, then the token step
+    their scan traces once), a single call a pass under the layer scan — and
+    the decode-side parameter prefix is spelled in that one place."""
+    import functools
+    import inspect
+
+    from paddle_tpu.models import transformer_lm as tlm
+
+    n_layers = 3
+    spec = models.get_model("transformer_lm", seq_len=16, vocab=32, d_model=16,
+                            d_inner=32, num_heads=2, n_layers=n_layers,
+                            scan_layers=entry == "generate_scan")
+    cfg = spec.extra["cfg"]
+    variables = jax.eval_shape(
+        lambda: spec.model.init(0, *spec.synth_batch(1, np.random.RandomState(0))))
+    calls = []
+    block = tlm.decode_block
+    monkeypatch.setattr(
+        tlm, "decode_block", lambda *a, **k: (calls.append(a[2]), block(*a, **k))[1])
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    slots, page, per_slot = 2, 4, 4
+    pages = jax.ShapeDtypeStruct(
+        tlm.paged_cache_shape(cfg, 1 + slots * per_slot, page), jnp.float32)
+    paged = dict(cfg=cfg, page_size=page)
+    fn, args, passes = {
+        "generate": (functools.partial(tlm.generate, max_new_tokens=3, cfg=cfg),
+                     (i32(2, 5),), 2),
+        "generate_scan": (functools.partial(tlm.generate, max_new_tokens=3, cfg=cfg),
+                          (i32(2, 5),), 2),
+        "generate_beam": (functools.partial(tlm.generate_beam, max_new_tokens=3,
+                                            cfg=cfg, beam_size=2), (i32(2, 5),), 2),
+        "paged_prefill_chunk": (functools.partial(tlm.paged_prefill_chunk, **paged),
+                                (i32(8), i32(), i32(), i32(per_slot), pages, pages), 1),
+        "paged_decode_step": (functools.partial(tlm.paged_decode_step, **paged),
+                              (i32(slots), i32(slots), i32(slots, per_slot), pages, pages), 1),
+        "paged_verify_step": (functools.partial(tlm.paged_verify_step, **paged),
+                              (i32(slots, 3), i32(slots), i32(slots, per_slot), pages, pages), 1),
+    }[entry]
+    jax.eval_shape(fn, variables.params, *args)
+    if entry == "generate_scan":
+        assert calls == ["SCAN"] * passes
+    else:
+        assert calls == list(range(n_layers)) * passes
+    assert inspect.getsource(tlm).count('/self_attn"') == 1

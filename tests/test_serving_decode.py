@@ -320,6 +320,7 @@ def test_speculative_divergent_draft_still_exact(lm):
     engine.kv.assert_no_leaks()
 
 
+@pytest.mark.parametrize("path", ["verify", "step"])
 @pytest.mark.parametrize("variant", [
     {},
     {"pos_encoding": "rope"},
@@ -328,11 +329,13 @@ def test_speculative_divergent_draft_still_exact(lm):
     {"num_kv_heads": 2, "pos_encoding": "rope", "ffn_activation": "swiglu",
      "attention_window": 4},
 ], ids=["sinusoid", "rope", "gqa", "window", "modern"])
-def test_verify_step_exact_across_model_configs(variant):
-    """paged_verify_step must reproduce generate() under every cache
-    layout it special-cases: additive sinusoid PE, per-position RoPE,
-    the H_kv-head GQA cache, sliding-window masking, and all of them
-    at once."""
+def test_verify_step_exact_across_model_configs(variant, path):
+    """The paged programs must reproduce generate() under every cache
+    layout decode_block and the paged attend serve: additive sinusoid PE,
+    per-position RoPE, the H_kv-head GQA cache, sliding-window masking,
+    and all of them at once — paged_verify_step behind a self-draft
+    (``verify``), and paged_prefill_chunk + paged_decode_step alone
+    (``step``)."""
     spec = models.get_model("transformer_lm", seq_len=48, vocab=VOCAB,
                             d_model=32, d_inner=64, num_heads=4, n_layers=2,
                             **variant)
@@ -345,20 +348,24 @@ def test_verify_step_exact_across_model_configs(variant):
         ref = np.asarray(generate(variables, jnp.asarray(prompt[None]),
                                   10, cfg))[0]
         cases.append((prompt, ref))
+    speculative = path == "verify"
     engine = DecodeEngine(
         variables, cfg,
         decode=DecodeConfig(max_slots=2, page_size=4, max_context=32,
                             prefill_chunk=8, num_pages=12, spec_tokens=3),
-        draft_variables=variables, draft_cfg=cfg)
+        **({"draft_variables": variables, "draft_cfg": cfg} if speculative else {}))
     try:
         handles = [engine.submit(p, 10) for p, _ in cases]
         outs = [h.result(timeout=300) for h in handles]
         for (prompt, ref), out in zip(cases, outs):
             assert np.array_equal(out.tokens, ref), (
-                f"verify step diverged for variant={variant} "
+                f"{path} diverged for variant={variant} "
                 f"Tp={len(prompt)}")
-        assert engine.metrics.snapshot()["verify_steps_total"] >= 1
-        assert engine.verify_step_cache_size() == 1
+        if speculative:
+            assert engine.metrics.snapshot()["verify_steps_total"] >= 1
+            assert engine.verify_step_cache_size() == 1
+        else:
+            assert engine.decode_step_cache_size() == 1
     finally:
         engine.close()
     engine.kv.assert_no_leaks()
