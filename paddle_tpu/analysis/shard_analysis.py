@@ -74,11 +74,12 @@ __all__ = [
     "tp_comm_report",
 ]
 
-# KV page arrays are [L, num_pages, H_kv, page_size, dh]; page ids are
-# global across a replica group, so only the head dim may shard
+# KV page arrays are [L, num_pages, page_size, H_kv * dh]; page ids are
+# global across a replica group, so only the last dim, whose major part is
+# the heads, may shard, and by whole heads
 KV_PAGES_DIM = 1
-KV_HEAD_DIM = 2
-KV_OFFSET_DIM = 3
+KV_OFFSET_DIM = 2
+KV_HEAD_DIM = 3
 
 AxisSizes = Mapping[str, int]
 # a layout: a GroupLayout-like object (``.rules`` + ``.optional``) or a
@@ -285,6 +286,12 @@ def _analyze_kv_pages(
             ))
     from jax.sharding import PartitionSpec as P
 
+    # as GroupLayout.kv_page_spec: the default rule asks divisibility of the
+    # head count (the geometry's ``kv_heads``), not of the width H_kv * dh
+    heads = (geometry or {}).get("kv_heads")
+    if (heads is not None and getattr(layout, "kv_rule", None) is None
+            and len(shape) > KV_HEAD_DIM):
+        shape = shape[:KV_HEAD_DIM] + (heads,) + shape[KV_HEAD_DIM + 1:]
     for dim, axis, reason in degraded_dims(axis_sizes, P(*dims), shape):
         if reason == NON_DIVISIBLE and dim == KV_HEAD_DIM:
             diags.append(Diagnostic(
@@ -454,10 +461,11 @@ def analyze_model(
     kv_shape = None
     kv_geometry = None
     if model == "transformer_lm":
-        from paddle_tpu.models.transformer_lm import paged_cache_shape
+        from paddle_tpu.models.transformer_lm import kv_heads, paged_cache_shape
 
         kv_shape = tuple(paged_cache_shape(model_cfg, num_pages, page_size))
-        kv_geometry = {"num_pages": num_pages, "page_size": page_size}
+        kv_geometry = {"num_pages": num_pages, "page_size": page_size,
+                       "kv_heads": kv_heads(model_cfg)}
     diags = analyze_layout(
         shapes, layout, axis_sizes, kv_page_shape=kv_shape,
         kv_geometry=kv_geometry, where=f"{model}@tp={tp}")

@@ -62,6 +62,9 @@ class ServingPrograms:
     decode_step: Callable
     verify_step: Optional[Callable]  # scores a draft block; None = cannot
     mechanism: str                   # named when the engine refuses a feature
+    # kv_heads(cfg) -> the heads a page's row holds: a replica group shards
+    # pages by whole heads. None for a model without pages
+    kv_heads: Optional[Callable[[dict], int]] = None
 
 
 def serving_programs(cfg: dict) -> ServingPrograms:

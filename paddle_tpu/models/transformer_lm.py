@@ -49,7 +49,7 @@ from paddle_tpu.ops.attention import apply_rope, rope_tables, scaled_dot_product
 
 __all__ = ["get_model", "lm_forward", "generate", "generate_beam",
            "stack_decode_params", "BASE_CFG",
-           "paged_cache_shape", "paged_prefill_chunk", "paged_decode_step",
+           "kv_heads", "paged_cache_shape", "paged_prefill_chunk", "paged_decode_step",
            "paged_verify_step"]
 
 
@@ -700,6 +700,10 @@ def generate_beam(
 # in flight — so the serving programs compile once and continuous batching
 # (admit/evict between steps) never pays XLA again. How a page array is
 # indexed is spelled twice: ``paged_cache_shape`` and ``_paged_attend``.
+# Its form, ``[L, num_pages, page_size, H_kv * dh]``, is the one the page
+# write takes: a row of 128 lanes or a multiple of them is held by the chip
+# as spelled, so no program converts the array on entry or on exit (with
+# heads an axis of their own and ``dh`` 64 it did, eight times an iteration).
 
 
 def _paged_enforce(cfg, temperature, rng):
@@ -720,13 +724,18 @@ def _paged_enforce(cfg, temperature, rng):
     )
 
 
+def kv_heads(cfg: dict) -> int:
+    """``H_kv``: the heads a cached K or V row holds, fewer than the query
+    heads under grouped-query attention."""
+    return cfg.get("num_kv_heads") or cfg["num_heads"]
+
+
 def paged_cache_shape(cfg: dict, num_pages: int, page_size: int):
     """Shape of ``k_pages``/``v_pages`` for ``cfg``:
-    ``[L, num_pages, H_kv, page_size, dh]``."""
-    H = cfg["num_heads"]
-    H_kv = cfg.get("num_kv_heads") or H
-    dh = cfg["d_model"] // H
-    return (cfg["n_layers"], num_pages, H_kv, page_size, dh)
+    ``[L, num_pages, page_size, H_kv * dh]``: a position's K (or V) is one
+    contiguous row of all its heads, the form the page write takes."""
+    dh = cfg["d_model"] // cfg["num_heads"]
+    return (cfg["n_layers"], num_pages, page_size, kv_heads(cfg) * dh)
 
 
 def sample_logits(logits, key, temperature, top_k, top_p):
@@ -761,12 +770,11 @@ def _paged_attend(pages: list, page_tables, pos, page_size: int, window):
     gathers each sequence's whole logical context [0, P * page_size) back
     through its table (the rows just written included) and attends under the
     live mask. ``pages`` is the list [k_pages, v_pages], each
-    [L, page, H_kv, offset, dh]; it is read and rebound layer by layer.
+    [L, page, offset, H_kv * dh]; it is read and rebound layer by layer.
 
-    The gather materializes each sequence's [H_kv, T_eff, dh] context per
-    layer — the straightforward XLA lowering. ROADMAP A1b (the pages' layout)
-    and A5 (a Pallas kernel that streams pages from HBM without the copy)
-    edit this one body."""
+    The gather materializes each sequence's [T_eff, H_kv, dh] context per
+    layer — the straightforward XLA lowering. ROADMAP A5 (a Pallas kernel
+    that streams live pages from HBM without the copy) edits this one body."""
     P = page_tables.shape[-1]
     B, t_eff = page_tables.size // P, P * page_size
     page, off = pos // page_size, pos % page_size
@@ -781,11 +789,19 @@ def _paged_attend(pages: list, page_tables, pos, page_size: int, window):
         H, dh = q.shape[1], q.shape[-1]
         with jax.named_scope("page_write"):
             for j, new in enumerate((k, v)):
-                new = jnp.moveaxis(new, 1, -2).reshape(pos.shape + (-1, dh))
-                pages[j] = pages[j].at[i, phys, :, off].set(new.astype(pages[j].dtype))
-        kl, vl = (jnp.moveaxis(pg[i][page_tables], -3, -4).reshape(B, -1, t_eff, dh)
-                  for pg in pages)
-        return _attend_cached(q.reshape(B, H, -1, dh), kl, vl, live).reshape(q.shape)
+                row = jnp.moveaxis(new, 1, -2).reshape(pos.shape + (-1,))
+                pages[j] = pages[j].at[i, phys, off].set(row.astype(pages[j].dtype))
+
+        def context(pg):  # [B, H_kv, t_eff, dh]
+            # layer and page are one index into [L * num_pages, offset,
+            # H_kv * dh], a bitcast of the array: no layer's slice is
+            # materialised first
+            rows = jnp.take(pg.reshape((-1,) + pg.shape[2:]),
+                            i * pg.shape[1] + page_tables, axis=0, mode="clip")
+            return jnp.moveaxis(rows.reshape(B, t_eff, -1, dh), 1, 2)
+
+        return _attend_cached(
+            q.reshape(B, H, -1, dh), *map(context, pages), live).reshape(q.shape)
 
     return attend
 
@@ -975,7 +991,7 @@ def serving_programs():
         cache="pages", cache_args=("k_pages", "v_pages"),
         cache_specs=paged_cache_specs, prefill_chunk=paged_prefill_chunk,
         decode_step=paged_decode_step, verify_step=paged_verify_step,
-        mechanism="softmax attention over a paged KV cache")
+        mechanism="softmax attention over a paged KV cache", kv_heads=kv_heads)
 
 
 def get_model(

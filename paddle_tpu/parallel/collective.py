@@ -75,9 +75,10 @@ def axis_size(axis_name: str):
 # live in one process these run jitted on-device (a gather/scatter per
 # page — no host round-trip); across processes the gathered pages are
 # serialized with per-page CRCs (serving.disagg.HandoffPayload). Page
-# arrays are ``[L, num_pages, H_kv, page_size, dh]``; one page is the
-# fixed-shape ``[L, H_kv, page_size, dh]`` slice, so both ops compile
-# exactly once per engine geometry.
+# arrays are ``[L, num_pages, page_size, H_kv * dh]``; one page is the
+# fixed-shape ``[L, page_size, H_kv * dh]`` slice, so both ops compile
+# exactly once per engine geometry. Neither spells the axes after the
+# page's: ``pages[:, id]`` holds for any form.
 
 def gather_kv_page(pages, page_id):
     """Extract one physical page from a paged KV array (device-side)."""

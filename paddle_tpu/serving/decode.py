@@ -487,12 +487,14 @@ class DecodeEngine:
                 )
 
                 lint_group_layout_or_raise(
-                    params, self._layout, group.mesh,
-                    kv_page_shape=pshape, kv_geometry=self._kv.geometry(),
+                    params, self._layout, group.mesh, kv_page_shape=pshape,
+                    kv_geometry=dict(self._kv.geometry(),
+                                     kv_heads=progs.kv_heads(self.model_cfg)),
                     where=f"DecodeEngine[{group.name}]",
                 )
             self._params = self._layout.shard_params(group, params)
-            kvs = self._layout.kv_page_sharding(group, pshape)
+            kvs = self._layout.kv_page_sharding(
+                group, pshape, progs.kv_heads(self.model_cfg))
             rep = self._layout.replicated(group)
         # The engine is the sole owner of its cache arrays (the model's K
         # and V pages, or its per-slot states): every jit that returns a
@@ -525,7 +527,7 @@ class DecodeEngine:
             "serving.decode.prefill", jax.jit(functools.partial(
                 progs.prefill_chunk, **model_kw), **jit_kw))
         # disagg KV handoff (serving.disagg): one page is the fixed-shape
-        # [L, H_kv, page_size, dh] slice, so gather/implant compile once.
+        # [L, page_size, H_kv * dh] slice, so gather/implant compile once.
         # In group mode the gather's output is pinned replicated — the
         # wire image is always the FULL logical page regardless of tp —
         # and the implant re-scatters it back over the group's heads.
@@ -569,7 +571,8 @@ class DecodeEngine:
                 dkvs = None
             else:
                 self._draft_params = self._layout.shard_params(group, dp)
-                dkvs = self._layout.kv_page_sharding(group, dshape)
+                dkvs = self._layout.kv_page_sharding(
+                    group, dshape, dprogs.kv_heads(self.draft_cfg))
                 djit_kw["out_shardings"] = (rep, dkvs, dkvs)
             self._dk_pages = self._zero_pages(dshape, dkvs)
             self._dv_pages = self._zero_pages(dshape, dkvs)
@@ -759,7 +762,9 @@ class DecodeEngine:
     def _warmup(self) -> None:
         """Compile every executable that writes the cache arrays before
         traffic arrives, and publish whether each consumed the arrays it
-        was handed (``serving.decode.pages_donated`` / ``state_donated``).
+        was handed (``serving.decode.pages_donated`` / ``state_donated``)
+        and whether the device holds the page arrays as the model spells
+        them (``serving.decode.pages_row_major``).
         Warmup writes land on the scratch page (zero tables), or in slot
         0's state, which the first chunk of an admission starts over, so
         no reset is needed afterwards."""
@@ -797,6 +802,12 @@ class DecodeEngine:
                 "whole array", name)
         if self._paged:
             self.metrics.set_pages_donated(not kept)
+            pages = list(self._cache)
+            if self._spec_k:
+                pages += [self._dk_pages, self._dv_pages]
+            self.metrics.set_pages_row_major(all(
+                list(p.format.layout.major_to_minor) == list(range(p.ndim))
+                for p in pages))
         else:
             self.metrics.set_state_donated(not kept)
         # persist the compiled keys so a restarted engine can prewarm

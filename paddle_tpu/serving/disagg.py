@@ -18,7 +18,9 @@ role split, made elastic — so prefill load cannot move decode latency:
   :mod:`paddle_tpu.parallel.collective` gather/scatter; across processes
   they travel as a :class:`HandoffPayload` wire blob with a CRC per page
   — a receiver rejects torn transfers (:class:`HandoffCorrupt`) instead
-  of adopting garbage KV state.
+  of adopting garbage KV state. A page is ``[L, page_size, H_kv * dh]``;
+  an adopter whose own page shape differs (another model, or an engine
+  that still held heads as an axis) re-prefills instead.
 
 **Durability.** The handoff window is the only new place a request could
 be lost, so it is journaled like everything else: a ``hof`` record

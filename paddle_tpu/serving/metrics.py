@@ -782,6 +782,14 @@ class DecodeMetrics:
         prof.set_gauge("serving.decode.pages_donated", int(ok),
                        labels=self._labels)
 
+    def set_pages_row_major(self, ok: bool) -> None:
+        """1 when the device holds every page array in the order the model
+        spells it (``Array.format.layout.major_to_minor`` ascending), so a
+        program takes it as it is; 0 when the device reordered one and every
+        program converts it whole on entry and on exit. Always 1 on CPU."""
+        prof.set_gauge("serving.decode.pages_row_major", int(ok),
+                       labels=self._labels)
+
     # a model that keeps a recurrent state per slot instead of KV pages
     def set_state_bytes(self, n: int) -> None:
         prof.set_gauge("serving.decode.state_bytes", n, labels=self._labels)

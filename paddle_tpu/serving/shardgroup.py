@@ -16,8 +16,9 @@ Layout (Megatron-style, heads over ``tp``):
 - ffn fc1/gate column-parallel, fc2 row-parallel;
 - embeddings, logits projection and layernorms replicated (tiny, and the
   test vocab is deliberately not divisible by tp);
-- paged KV arrays ``[L, num_pages, H_kv, page_size, dh]`` sharded on the
-  head dim ``P(None, None, "tp", None, None)``.
+- paged KV arrays ``[L, num_pages, page_size, H_kv * dh]`` sharded on the
+  last dim, whose major part is the heads: ``P(None, None, None, "tp")``
+  when ``H_kv`` divides by tp, so a shard holds whole heads.
 
 Every per-shard ``PageAllocator`` geometry is identical — page ids are
 global and only heads are split — so refcounts, the radix prefix cache,
@@ -53,8 +54,9 @@ __all__ = [
     "probe_members",
 ]
 
-# Head dim of the paged KV arrays [L, num_pages, H_kv, page_size, dh]
-KV_HEAD_DIM = 2
+# The dim of the paged KV arrays [L, num_pages, page_size, H_kv * dh] that
+# holds the heads (its major part; dh is the minor)
+KV_HEAD_DIM = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,7 +130,7 @@ class GroupLayout:
     rules are not dead on a relu model. Any other zero-match rule is a
     ``shard-dead-rule`` finding in ``analysis.shard_analysis`` (stale
     after a param rename, or a layout for the wrong model family).
-    ``kv_rule`` overrides the default head-dim KV-page spec; the static
+    ``kv_rule`` overrides the default heads KV-page spec; the static
     analyzer checks it against ``PagedKVCache.geometry()`` — page-id and
     page-offset dims must stay global across the group."""
 
@@ -146,21 +148,24 @@ class GroupLayout:
     ) -> NamedSharding:
         return NamedSharding(group.mesh, self.param_spec(name, shape, group.mesh))
 
-    def kv_page_spec(self, shape: Tuple[int, ...], mesh: Mesh) -> P:
-        """KV pages sharded along heads; degrades to replicated when the
-        kv-head count doesn't divide tp (the same model still serves, just
-        without the memory win)."""
+    def kv_page_spec(self, shape: Tuple[int, ...], mesh: Mesh, kv_heads: int) -> P:
+        """KV pages sharded along heads, the major part of their last dim;
+        degrades to replicated when ``kv_heads`` doesn't divide tp (the same
+        model still serves, just without the memory win). The count decides,
+        not the width: ``H_kv * dh`` may divide where ``H_kv`` does not, and
+        a shard must hold whole heads."""
         if self.kv_rule is not None:
             return degrade_spec(mesh, self.kv_rule, shape, name="kv_pages")
         dims = [None] * len(shape)
         if len(shape) > KV_HEAD_DIM:
             dims[KV_HEAD_DIM] = self.tp_axis
+            shape = shape[:KV_HEAD_DIM] + (kv_heads,) + shape[KV_HEAD_DIM + 1:]
         return degrade_spec(mesh, P(*dims), shape, name="kv_pages")
 
     def kv_page_sharding(
-        self, group: ReplicaGroup, shape: Tuple[int, ...]
+        self, group: ReplicaGroup, shape: Tuple[int, ...], kv_heads: int
     ) -> NamedSharding:
-        return NamedSharding(group.mesh, self.kv_page_spec(shape, group.mesh))
+        return NamedSharding(group.mesh, self.kv_page_spec(shape, group.mesh, kv_heads))
 
     def replicated(self, group: ReplicaGroup) -> NamedSharding:
         return NamedSharding(group.mesh, P())

@@ -187,17 +187,24 @@ def test_lm_large_train_step_compiles(one_chip, as_tpu):
     assert _program_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("slots", [SLOTS, 32])
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
-def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which):
+def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, slots):
     """The engine of chip_smoke.py's phase 2 and of lm_big.serve_closed16:
-    16 slots x 2048 positions of f32 pages, 1.6 GB each for K and V. The
-    engine donates them, so the compiled step must alias both to its
-    outputs (undonated, PR 22 read alias 0) and no longer re-lay-out a
-    whole array around each of its 24 page writes (XLA's
-    ``remat_(un)compressed`` copies under memory pressure). It still
-    converts K and V to the scatter's layout on entry and back on exit,
-    which is what its 9.7 GB of temp holds: 14.0 GB in all (PERF.md,
-    PR 26), so the bound is the chip's memory."""
+    16 slots x 2048 positions of f32 pages, 1.6 GB each for K and V; and
+    one of 32 slots, which the chip refused (22.1 GB) while a program
+    converted its pages. The engine donates them, so the compiled program
+    must alias both to its outputs (undonated, PR 22 read alias 0), and it
+    must take them as the model spells them,
+    ``[L, num_pages, page_size, H_kv * dh]`` with the row of all heads
+    minor-most: no whole-array copy on entry or exit (with heads an axis of
+    their own and ``dh`` 64 the chip kept the page axis minor-most and each
+    program converted K and V both ways: 9.7 GB of temp, 14.0 GB in all,
+    PERF.md PR 26 and 30), no layer's slice of one before the gather (the
+    gather indexes layer and page together), nor XLA's
+    ``remat_(un)compressed`` re-lay-outs around the 24 page writes."""
+    import re
+
     from paddle_tpu.models.transformer_lm import (
         paged_cache_shape, paged_decode_step, paged_prefill_chunk,
     )
@@ -209,21 +216,38 @@ def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which):
     ).params
     per_slot = CONTEXT // PAGE
     pages = jax.ShapeDtypeStruct(
-        paged_cache_shape(cfg, 1 + SLOTS * per_slot, PAGE), jnp.float32,
+        paged_cache_shape(cfg, 1 + slots * per_slot, PAGE), jnp.float32,
         sharding=one_chip)
+    assert pages.shape == (12, 1 + slots * per_slot, PAGE, 1024)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     if which == "decode_step":
-        fn, args = paged_decode_step, (i32(SLOTS), i32(SLOTS), i32(SLOTS, per_slot))
+        fn, args = paged_decode_step, (i32(slots), i32(slots), i32(slots, per_slot))
     else:
         fn, args = paged_prefill_chunk, (i32(CHUNK), i32(), i32(), i32(per_slot))
     compiled = jax.jit(
         functools.partial(fn, cfg=cfg, page_size=PAGE),
         donate_argnames=("k_pages", "v_pages"),  # as DecodeEngine.__init__
     ).lower(_shapes(params, one_chip), *args, pages, pages, None).compile()
+    text = compiled.as_text()
     page_bytes = int(np.prod(pages.shape)) * 4
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * page_bytes
-    assert "remat_" not in compiled.as_text()
-    assert _program_bytes(compiled) < HBM_BYTES
+    assert "remat_" not in text
+    whole, layer = ("f32[" + ",".join(str(d) for d in shape) + "]"
+                    for shape in (pages.shape, pages.shape[1:]))
+    page_array_copies = [l.strip()[:120] for l in text.splitlines()
+                         if whole in l.split("=")[0] and " copy(" in l]
+    assert not page_array_copies, page_array_copies[:2]
+    layer_slices = [l.strip()[:120] for l in text.splitlines() if layer in l.split("=")[0]]
+    assert not layer_slices, layer_slices[:2]
+    # both page parameters enter (and leave) in the order they are spelled
+    entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
+    layouts = re.findall(re.escape(whole) + r"\{([\d,]+)", entry)
+    assert len(layouts) == 4 and set(layouts) == {"3,2,1,0"}, layouts
+    # 16 slots read 0.69 / 0.03 GB of temp in 4.78 / 4.12 GB; 32 slots
+    # 1.36 / 0.03 GB in 8.67 / 7.34 GB
+    temp_limit, program_limit = (1.0e9, 6.0e9) if slots == SLOTS else (1.5e9, 9.0e9)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+    assert _program_bytes(compiled) < program_limit
 
 
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])

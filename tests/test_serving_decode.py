@@ -404,16 +404,16 @@ _WRITE_JITS = [("plain", "_step"), ("plain", "_prefill"),
 
 class _ConsumedSpy:
     """Stands in for one write-jit: runs the real call, then counts the page
-    arrays ([L, pages, H_kv, page_size, dh]: the only 5-d arguments) it was
-    handed that are still alive. Runs on the loop thread, so it only counts;
-    the test asserts."""
+    arrays ([L, pages, page_size, H_kv * dh], or a state model's 5-d states:
+    the only arguments of 4 axes or more) it was handed that are still
+    alive. Runs on the loop thread, so it only counts; the test asserts."""
 
     def __init__(self, fn):
         self.fn, self.calls, self.kept = fn, 0, 0
 
     def __call__(self, *args):
         out = self.fn(*args)
-        pages = [a for a in args if getattr(a, "ndim", 0) == 5]
+        pages = [a for a in args if getattr(a, "ndim", 0) >= 4]
         self.calls += bool(pages)  # a call that found none proves nothing
         self.kept += sum(not p.is_deleted() for p in pages)
         return out
@@ -489,3 +489,22 @@ def test_write_jit_consumes_the_pages_it_is_handed(owned, kind, jit):
     assert eng.decode_step_cache_size() == 1
     assert eng.prefill_cache_size() == 1
     assert not owned.diverged
+
+
+@pytest.mark.parametrize("kind", ["plain", "spec"])
+def test_warmup_publishes_whether_the_device_holds_pages_as_spelled(owned, kind):
+    """``serving.decode.pages_row_major`` is set once at warm-up beside
+    ``pages_donated``: 1 when every page array (the draft's too) is held in
+    the order the model spells it, which a CPU always does; 0 when the device
+    reordered one (a v5e did, with heads an axis of their own and dh 64)."""
+    eng = owned.engines[kind]
+    read = lambda: obs_metrics.default_registry().get(
+        "serving.decode.pages_row_major", {"engine": eng.metrics.engine_label},
+        default=None)
+    assert read() == 1.0
+    arrays = list(eng._cache) + ([eng._dk_pages, eng._dv_pages] if kind == "spec" else [])
+    assert all(a.ndim == 4 and a.format.layout.major_to_minor == (0, 1, 2, 3)
+               for a in arrays)
+    eng.metrics.set_pages_row_major(False)
+    assert read() == 0.0
+    eng.metrics.set_pages_row_major(True)

@@ -80,7 +80,7 @@ def _engine(lm, **over):
 
 def _payload():
     rng = np.random.RandomState(7)
-    pages = [rng.randn(2, 4, 4, 8).astype(np.float32) for _ in range(2)]
+    pages = [rng.randn(2, 4, 4 * 8).astype(np.float32) for _ in range(2)]  # [L, page_size, H_kv * dh]
     return HandoffPayload(
         rid="r-1", prompt=np.array([3, 5, 8], np.int32),
         generated=[11, 13], mnt=16, cur_len=5, last_tok=13, page_size=4,
@@ -258,6 +258,67 @@ def test_disagg_faulted_transfer_reprefills_token_exact(lm):
         assert np.array_equal(out.tokens, ref)
         assert router.handoff_rejects_total == 1
         assert router.handoff_reprefills_total == 1
+    finally:
+        router.close(30)
+    pre.kv.assert_no_leaks()
+    dec.kv.assert_no_leaks()
+
+
+def test_gathered_page_implants_and_reads_back_bit_exact(lm):
+    """The handoff's two page ops spell no axis after the page's
+    (``pages[:, id]``), so they hold for the form
+    ``[L, num_pages, page_size, H_kv * dh]`` as they did with heads an axis
+    of their own: a page gathered from one engine and implanted in another
+    reads back bit for bit, and no other page of the target is touched."""
+    from paddle_tpu.models.transformer_lm import paged_cache_shape
+
+    src, dst = _engine(lm), _engine(lm)
+    try:
+        shape = src._cache[0].shape
+        assert shape == paged_cache_shape(lm.cfg, DC["num_pages"], DC["page_size"])
+        assert shape == (2, 14, 4, 4 * 8)
+        page = np.random.RandomState(3).randn(2, 4, 32).astype(np.float32)
+        # by hand, while the loop threads idle: the engines own their arrays,
+        # so each write rebinds the result
+        src._cache[0] = src._implant_page(src._cache[0], jnp.int32(9), jnp.asarray(page))
+        wire = np.asarray(src._gather_page(src._cache[0], jnp.int32(9)))
+        assert wire.shape == (2, 4, 32) and wire.tobytes() == page.tobytes()
+        dst._cache[1] = dst._implant_page(dst._cache[1], jnp.int32(5), jnp.asarray(wire))
+        back = np.asarray(dst._cache[1])
+        assert back[:, 5].tobytes() == page.tobytes()
+        assert not back[:, :5].any() and not back[:, 6:].any()
+    finally:
+        src.close(30)
+        dst.close(30)
+
+
+def test_handoff_of_the_old_page_form_is_refused_and_reprefilled(lm):
+    """A payload gathered by an engine that still held heads as an axis
+    carries pages ``[L, H_kv, page_size, dh]``: the same values, but not this
+    engine's page shape. Adoption is refused and the decode worker
+    re-prefills, token-exact, as for a payload of another tp degree."""
+    pre, dec = _engine(lm), _engine(lm)
+    router = DisaggRouter([pre, dec], [PREFILL, DECODE], transport="serialized")
+    adopt, seen = dec.adopt_handoff, []
+
+    def adopt_old_form(payload, **kw):
+        old = lambda p: np.ascontiguousarray(
+            np.moveaxis(p.reshape(p.shape[0], p.shape[1], 4, 8), 2, 1))
+        payload.k_pages = [old(p) for p in payload.k_pages]
+        payload.v_pages = [old(p) for p in payload.v_pages]
+        seen.extend(p.shape for p in payload.k_pages)
+        return adopt(payload, **kw)
+
+    dec.adopt_handoff = adopt_old_form
+    try:
+        handles = [router.submit(p, n) for p, n, _ in lm.cases]
+        outs = [h.result(timeout=120) for h in handles]
+        for (_, _, ref), out in zip(lm.cases, outs):
+            assert np.array_equal(out.tokens, ref)
+        assert seen and set(seen) == {(2, 4, 4, 8)}
+        snap = dec.metrics.snapshot()
+        assert snap["handoffs_in_total"] == 0, snap
+        assert snap["recovered_total"] == len(lm.cases), snap
     finally:
         router.close(30)
     pre.kv.assert_no_leaks()

@@ -21,7 +21,7 @@ from paddle_tpu.serving.host_tier import HostPagePool
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks import check  # noqa: E402
-from test_serving_decode import _ConsumedSpy  # noqa: E402  (counts 5-d arguments left alive)
+from test_serving_decode import _ConsumedSpy  # noqa: E402  (counts cache arrays left alive)
 from benchmarks.references import common as refc  # noqa: E402
 from benchmarks.references import retention_lm as ref  # noqa: E402
 

@@ -198,13 +198,39 @@ def test_layout_shards_params_and_kv_across_members():
     assert ow.sharding.spec == P(TP_AXIS, None)
     logits = sharded["project/logits/w"]  # 32x97: vocab not divisible
     assert logits.sharding.spec in (P(), P(None), P(None, None))
-    # KV pages [L, num_pages, H_kv, page_size, dh] shard on the head dim
-    pshape = (2, 14, 4, 4, 8)
-    kv_spec = layout.kv_page_spec(pshape, group.mesh)
+    # KV pages [L, num_pages, page_size, H_kv * dh] shard on the heads,
+    # the major part of the last dim
+    kv_spec = layout.kv_page_spec((2, 14, 4, 4 * 8), group.mesh, 4)
+    assert kv_spec == P(None, None, None, TP_AXIS)
     assert kv_spec[KV_HEAD_DIM] == TP_AXIS
     # GQA with H_kv=1 < tp: degrade to replicated, never a crash
-    assert layout.kv_page_spec((2, 14, 1, 4, 8), group.mesh) == P(
-        *([None] * 5))
+    assert layout.kv_page_spec((2, 14, 4, 1 * 8), group.mesh, 1) == P(
+        *([None] * 4))
+
+
+@pytest.mark.parametrize("kv_heads,dh,tp,sharded", [
+    (4, 8, 4, True),    # a head a shard
+    (8, 8, 4, True),    # two heads a shard
+    (2, 8, 4, False),   # the width 16 divides by 4, the 2 heads do not
+    (3, 8, 2, False),   # the width 24 divides by 2, the 3 heads do not
+    (1, 64, 2, False),  # multi-query: one head cannot be split
+], ids=["4x8_tp4", "8x8_tp4", "2x8_tp4", "3x8_tp2", "1x64_tp2"])
+def test_kv_page_spec_shards_whole_heads_or_replicates(kv_heads, dh, tp, sharded):
+    """The merged last axis of a page array shards only when the head COUNT
+    divides tp, so a shard always holds whole heads: the width ``H_kv * dh``
+    may divide where the count does not, and a split there would cut a head
+    in two. The placed array's shards are then ``H_kv / tp`` heads wide."""
+    if jax.device_count() < tp:
+        pytest.skip(f"needs {tp} devices")
+    group = make_groups(tp)[0]
+    shape = (2, 14, 4, kv_heads * dh)
+    spec = default_layout().kv_page_spec(shape, group.mesh, kv_heads)
+    assert spec == (P(None, None, None, TP_AXIS) if sharded else P(*([None] * 4)))
+    pages = jax.device_put(
+        np.zeros(shape, np.float32),
+        default_layout().kv_page_sharding(group, shape, kv_heads))
+    widths = {s.data.shape[-1] for s in pages.addressable_shards}
+    assert widths == {kv_heads * dh // tp if sharded else kv_heads * dh}
 
 
 # ---- tentpole acceptance: tp=2 token-exact vs generate() -------------------

@@ -143,8 +143,9 @@ def test_identical_effective_specs_do_not_conflict():
 # ---- KV-page geometry ----------------------------------------------------
 
 
-KV_SHAPE = (2, 14, 4, 4, 8)  # [L, num_pages, H_kv, page_size, dh]
-KV_GEO = {"num_pages": 14, "page_size": 4, "max_slots": 3, "pages_per_slot": 10}
+KV_SHAPE = (2, 14, 4, 4 * 8)  # [L, num_pages, page_size, H_kv * dh]
+KV_GEO = {"num_pages": 14, "page_size": 4, "max_slots": 3, "pages_per_slot": 10,
+          "kv_heads": 4}
 
 
 def test_default_kv_rule_passes_geometry():
@@ -155,16 +156,25 @@ def test_default_kv_rule_passes_geometry():
 
 def test_kv_rule_sharding_page_ids_is_an_error():
     layout = GroupLayout(rules=(), optional=(),
-                         kv_rule=P(None, "tp", None, None, None))
+                         kv_rule=P(None, "tp", None, None))
     diags = analyze_layout({}, layout, {"tp": 2},
                            kv_page_shape=KV_SHAPE, kv_geometry=KV_GEO)
     assert _codes(diags) == ["shard-kv-geometry"]
     assert "page ids" in diags[0].message
 
 
+def test_kv_rule_sharding_page_offsets_is_an_error():
+    layout = GroupLayout(rules=(), optional=(),
+                         kv_rule=P(None, None, "tp", None))
+    diags = analyze_layout({}, layout, {"tp": 2},
+                           kv_page_shape=KV_SHAPE, kv_geometry=KV_GEO)
+    assert _codes(diags) == ["shard-kv-geometry"]
+    assert "page offsets" in diags[0].message
+
+
 def test_kv_shape_disagreeing_with_geometry_is_an_error():
     diags = analyze_layout({}, GroupLayout(rules=(), optional=()), {"tp": 2},
-                           kv_page_shape=(2, 99, 4, 4, 8), kv_geometry=KV_GEO)
+                           kv_page_shape=(2, 99, 4, 32), kv_geometry=KV_GEO)
     assert _codes(diags) == ["shard-kv-geometry"]
     assert "num_pages" in diags[0].message
 
@@ -174,6 +184,33 @@ def test_kv_head_non_divisible_warns_about_lost_memory_win():
                            kv_page_shape=KV_SHAPE, kv_geometry=KV_GEO)
     assert _codes(diags) == ["shard-silent-degrade"]
     assert diags[0].severity == "warning"
+
+
+@pytest.mark.parametrize("kv_heads,tp,degrades", [
+    (4, 4, False), (2, 4, True), (1, 2, True), (None, 4, False)],
+    ids=["4_heads_tp4", "2_heads_tp4", "1_head_tp2", "heads_unknown_tp4"])
+def test_kv_degrade_is_decided_by_the_head_count_not_the_width(kv_heads, tp, degrades):
+    """As ``GroupLayout.kv_page_spec``: the merged axis (32 wide here) divides
+    by 2 and by 4 whatever the heads, but a shard must hold whole heads, so 2
+    heads over tp 4 replicate and the analyzer says so. A geometry that does
+    not say how many heads the row holds is judged by the width alone."""
+    geo = {k: v for k, v in KV_GEO.items() if k != "kv_heads"}
+    if kv_heads is not None:
+        geo["kv_heads"] = kv_heads
+    diags = analyze_layout({}, GroupLayout(rules=(), optional=()), {"tp": tp},
+                           kv_page_shape=KV_SHAPE, kv_geometry=geo)
+    assert _codes(diags) == (["shard-silent-degrade"] if degrades else [])
+    if degrades:
+        assert f"KV head count {kv_heads} " in diags[0].message
+
+
+def test_analyze_model_hands_the_head_count_to_the_kv_check():
+    """The one-call analysis of a GQA model whose 2 KV heads of 8 make a row
+    of 16: over tp 4 the row divides and the heads do not."""
+    diags, _ = analyze_model("transformer_lm", tp=4, vocab=64, d_model=32,
+                             d_inner=64, num_heads=4, num_kv_heads=2, n_layers=1)
+    kv = [d for d in diags if d.where == "kv_pages"]
+    assert _codes(kv) == ["shard-silent-degrade"] and "head count 2" in kv[0].message
 
 
 # ---- tp comm report ------------------------------------------------------

@@ -88,9 +88,9 @@ def leg_seeded_violations_caught() -> None:
            f"got {[d.code for d in conf]}")
 
     kv_bad = GroupLayout(rules=(), optional=(),
-                         kv_rule=P(None, "tp", None, None, None))
+                         kv_rule=P(None, "tp", None, None))
     kv = analyze_layout(
-        {}, kv_bad, {"tp": 2}, kv_page_shape=(2, 14, 4, 4, 8),
+        {}, kv_bad, {"tp": 2}, kv_page_shape=(2, 14, 4, 32),
         kv_geometry={"num_pages": 14, "page_size": 4})
     _check([d.code for d in kv] == ["shard-kv-geometry"],
            "sharded KV page ids rejected",
