@@ -74,11 +74,13 @@ class _InstrumentedCompiled:
             # parents under the caller's active span (a trainer step, a
             # serving warmup), so compiles show up inside the step trace
             # the flash kernels this compile traced, with the blocks and the
-            # form each resolved to ("512x1024 table resident")
+            # form each resolved to ("512x1024 table resident"), and the
+            # expert kernel's calls with their column block ("512 table")
+            from paddle_tpu.ops.pallas import moe as moe_kernel
             from paddle_tpu.ops.pallas.flash_attention import take_resolved
 
             tracing.record_span("executor.compile", t0, t1, target=self._label,
-                                **take_resolved())
+                                **take_resolved(), **moe_kernel.take_resolved())
             if ledger_on:
                 try:
                     roofline.capture_costs(

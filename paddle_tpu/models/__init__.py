@@ -48,10 +48,16 @@ class ServingPrograms:
     rng, cfg=, ...) -> (next_token, *cache)`` and ``decode_step(params,
     tokens [S], positions [S], slot_refs, *cache, rng, cfg=, ...) ->
     (next_tokens [S], *cache)``. With ``cache`` ``"pages"`` the arrays are
-    KV pages, ``slot_ref`` is a slot's page-table row and the programs also
-    take ``page_size=``; with ``"state"`` they are recurrent states indexed
-    by slot, ``slot_ref`` is the slot's index, and ``slot_refs`` marks the
-    slots that decode."""
+    pages ``[L, num_pages, page_size, row]`` (a K and a V array, or one
+    array of latent rows), ``slot_ref`` is a slot's page-table row and the
+    programs also take ``page_size=``; with ``"state"`` they are recurrent
+    states indexed by slot, ``slot_ref`` is the slot's index, and
+    ``slot_refs`` marks the slots that decode.
+
+    Both programs may return small arrays after the cache, one per name in
+    ``extras`` (an expert layer's tokens per expert); the engine reads them
+    in the turn in which it reads the tokens and puts
+    ``span_attrs(cfg, *extras)`` on the call's span."""
 
     cache: str                      # "pages" | "state"
     cache_args: Tuple[str, ...]     # the programs' names for the arrays
@@ -63,8 +69,14 @@ class ServingPrograms:
     verify_step: Optional[Callable]  # scores a draft block; None = cannot
     mechanism: str                   # named when the engine refuses a feature
     # kv_heads(cfg) -> the heads a page's row holds: a replica group shards
-    # pages by whole heads. None for a model without pages
+    # pages by whole heads. None for a model without pages, or whose rows
+    # are not heads side by side
     kv_heads: Optional[Callable[[dict], int]] = None
+    extras: Tuple[str, ...] = ()     # names of the small outputs after the cache
+    # span_attrs(cfg, *extras as numpy) -> {attribute: number}
+    span_attrs: Optional[Callable[..., Dict[str, Any]]] = None
+    # gauges(cfg) -> {name: number}, published once as serving.decode.<name>
+    gauges: Optional[Callable[[dict], Dict[str, float]]] = None
 
 
 def serving_programs(cfg: dict) -> ServingPrograms:
@@ -133,6 +145,12 @@ def _retention_lm(**cfg):
     return retention_lm.get_model(**cfg)
 
 
+def _latent_moe_lm(**cfg):
+    from paddle_tpu.models import latent_moe_lm
+
+    return latent_moe_lm.get_model(**cfg)
+
+
 def _transformer_lm(**cfg):
     from paddle_tpu.models import transformer_lm
 
@@ -147,6 +165,7 @@ MODELS: Dict[str, Callable[..., ModelSpec]] = {
     "transformer": _transformer,
     "transformer_lm": _transformer_lm,
     "retention_lm": _retention_lm,
+    "latent_moe_lm": _latent_moe_lm,
     "stacked_dynamic_lstm": _stacked_dynamic_lstm,
     "machine_translation": _machine_translation,
 }

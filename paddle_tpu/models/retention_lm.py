@@ -292,10 +292,15 @@ def block(p, x, i: int, cfg: dict, rope, retain):
         return x + ffn(norm(x, f"layer_{i}/ffn_norm"), i)
 
 
+def _embed(p, ids):
+    """Token ids -> the float32 residual stream; the embedding is not scaled."""
+    with jax.named_scope("embed"):
+        return jnp.take(p("emb/word_emb"), ids, axis=0).astype(jnp.float32)
+
+
 def _hidden(p, ids, cfg, rope, retain):
     """[N, T] token ids -> [N, T, d_model] after the last block."""
-    with jax.named_scope("embed"):
-        x = jnp.take(p("emb/word_emb"), ids, axis=0).astype(jnp.float32)
+    x = _embed(p, ids)
     for i in range(cfg["n_layers"]):
         x = block(p, x, i, cfg, rope, retain)
     return x
@@ -329,19 +334,21 @@ def param_shapes(cfg: dict) -> dict:
     return out
 
 
-def _frame_params(cfg):
+def _frame_params(cfg, shapes=None, own=None):
     """``p(name)`` inside a ``pt.build`` frame: created at init, fetched at
-    apply, by the full name."""
+    apply, by the full name. ``shapes`` {name: shape}: this model's own
+    where not given. ``own`` {name: initializer} for the leaves that take
+    neither their kind's nor the framework's."""
     from paddle_tpu import initializer as init
 
-    shapes = param_shapes(cfg)
+    shapes = shapes or param_shapes(cfg)
     rules = {"scale": init.Constant(1.0),
              "word_emb": init.Normal(0.0, cfg["d_model"] ** -0.5)}
 
     def p(name):
         return pt.framework.create_parameter(
             shapes[name], cfg["param_dtype"], name=name,
-            default_initializer=rules.get(name.rsplit("/", 1)[-1]))
+            default_initializer=(own or {}).get(name) or rules.get(name.rsplit("/", 1)[-1]))
 
     return p
 
@@ -353,16 +360,21 @@ def _dict_params(params):
 
 # -- training ---------------------------------------------------------------
 
+def _next_token_loss(logits, labels):
+    """``(mean nll, token count, logits)``: what a training forward returns."""
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    return jnp.mean(nll), float(np.prod(labels.shape)), logits
+
+
 def lm_forward(ids, labels, *, cfg):
     """Next-token training forward through the chunked form, differentiated
     by XLA: ``(loss, token count, logits)`` like ``transformer_lm``'s."""
     p = _frame_params(cfg)
     rope = rope_tables(cfg["head_dim"], ids.shape[1], cfg["rope_theta"])
-    logits = _logits(p, _hidden(p, ids, cfg, rope, _retain_train(cfg)), cfg)
-    with jax.named_scope("loss"):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll), float(np.prod(labels.shape)), logits
+    return _next_token_loss(
+        _logits(p, _hidden(p, ids, cfg, rope, _retain_train(cfg)), cfg), labels)
 
 
 # -- serving: the engine's three programs ----------------------------------
@@ -373,9 +385,9 @@ def state_cache_specs(cfg: dict, *, max_slots: int, **_):
     return (jax.ShapeDtypeStruct(state_shape(cfg, max_slots), jnp.float32),)
 
 
-def _enforce_sampling(temperature, rng):
+def _enforce_sampling(temperature, rng, what="retention decode"):
     enforce(temperature == 0.0 or rng is not None,
-            "retention decode: sampling (temperature > 0) needs an explicit rng key")
+            f"{what}: sampling (temperature > 0) needs an explicit rng key")
 
 
 def state_prefill_chunk(params, tokens, pos0, last_index, slot, state, rng=None,

@@ -715,8 +715,9 @@ def _paged_enforce(cfg, temperature, rng):
     )
     enforce(
         not cfg.get("moe_experts"),
-        "paged decode: MoE FFNs are not supported in the cached decoders — "
-        "use a dense-FFN config",
+        "paged decode: transformer_lm's capacity-dropping MoE FFN has no "
+        "paged path; an expert layer is served by the latent_moe_lm family "
+        "(ops/moe.py: a top-k router that drops nothing)",
     )
     enforce(
         temperature == 0.0 or rng is not None,
@@ -762,19 +763,42 @@ def sample_logits(logits, key, temperature, top_k, top_p):
     return jax.random.categorical(key, logits).astype(jnp.int32)
 
 
-def _paged_attend(pages: list, page_tables, pos, page_size: int, window):
-    """``attend(i, q, k, v)`` of queries at absolute positions ``pos``: [C]
-    of the one sequence whose table ``page_tables`` [P] is, or [S] or [S, Q]
-    with a table row a slot [S, P]. It writes the queries' K and V
-    (pre-rotated K, exactly as generate() stores it) into their pages,
-    gathers each sequence's whole logical context [0, P * page_size) back
-    through its table (the rows just written included) and attends under the
-    live mask. ``pages`` is the list [k_pages, v_pages], each
-    [L, page, offset, H_kv * dh]; it is read and rebound layer by layer.
+def _kv_core(q, gather, live):
+    """Grouped-query softmax attention over the K and V page arrays: a
+    gathered row is the position's heads side by side."""
+    B, H, dh = q.shape[0], q.shape[1], q.shape[-1]
 
-    The gather materializes each sequence's [T_eff, H_kv, dh] context per
-    layer — the straightforward XLA lowering. ROADMAP A5 (a Pallas kernel
-    that streams live pages from HBM without the copy) edits this one body."""
+    def context(j):  # [B, H_kv, t_eff, dh]
+        return jnp.moveaxis(gather(j).reshape(B, live.shape[-1], -1, dh), 1, 2)
+
+    return _attend_cached(
+        q.reshape(B, H, -1, dh), context(0), context(1), live).reshape(q.shape)
+
+
+def _heads_last(new):  # [B, n, Q, dh] or [S, n, dh]: a position's heads side by side
+    return jnp.moveaxis(new, 1, -2)
+
+
+def _paged_attend(pages: list, page_tables, pos, page_size: int, window,
+                  core=_kv_core, to_row=_heads_last):
+    """``attend(i, q, *new)`` of queries at absolute positions ``pos``: [C]
+    of the one sequence whose table ``page_tables`` [P] is, or [S] or [S, Q]
+    with a table row a slot [S, P]. It writes the queries' ``new`` rows, one
+    per array of ``pages`` (K and V, the pre-rotated K exactly as generate()
+    stores it; or the one latent row of ``models/latent_moe_lm.py``), into
+    their pages, gathers each sequence's whole logical context
+    [0, P * page_size) back through its table (the rows just written
+    included) and attends under the live mask. ``pages`` is the list of page
+    arrays, each [L, page, offset, row]; it is read and rebound layer by
+    layer. ``to_row`` brings a ``new`` into the order of its rows (it is
+    then reshaped to ``pos.shape + (row,)``); ``core(q, gather, live)`` is
+    the attention itself, ``gather(j)`` the context of array ``j`` as the
+    table gathers it, ``page_tables.shape + (page_size, row)``, and ``live``
+    the mask [B, 1, 1, Q, t_eff].
+
+    The gather materializes each sequence's [T_eff, row] context per layer —
+    the straightforward XLA lowering. ROADMAP A5 (a Pallas kernel that
+    streams live pages from HBM without the copy) edits this one body."""
     P = page_tables.shape[-1]
     B, t_eff = page_tables.size // P, P * page_size
     page, off = pos // page_size, pos % page_size
@@ -785,23 +809,21 @@ def _paged_attend(pages: list, page_tables, pos, page_size: int, window):
         phys = page_tables[slot, page]
     live = _live_mask(pos, t_eff, window).reshape(B, 1, 1, -1, t_eff)
 
-    def attend(i, q, k, v):  # [B, n, Q, dh], or [S, n, dh]
-        H, dh = q.shape[1], q.shape[-1]
+    def attend(i, q, *new):  # q [B, n, Q, dh], or [S, n, dh]
         with jax.named_scope("page_write"):
-            for j, new in enumerate((k, v)):
-                row = jnp.moveaxis(new, 1, -2).reshape(pos.shape + (-1,))
+            for j, rows in enumerate(new):
+                row = to_row(rows).reshape(pos.shape + (-1,))
                 pages[j] = pages[j].at[i, phys, off].set(row.astype(pages[j].dtype))
 
-        def context(pg):  # [B, H_kv, t_eff, dh]
+        def gather(j):
             # layer and page are one index into [L * num_pages, offset,
-            # H_kv * dh], a bitcast of the array: no layer's slice is
+            # row], a bitcast of the array: no layer's slice is
             # materialised first
-            rows = jnp.take(pg.reshape((-1,) + pg.shape[2:]),
+            pg = pages[j]
+            return jnp.take(pg.reshape((-1,) + pg.shape[2:]),
                             i * pg.shape[1] + page_tables, axis=0, mode="clip")
-            return jnp.moveaxis(rows.reshape(B, t_eff, -1, dh), 1, 2)
 
-        return _attend_cached(
-            q.reshape(B, H, -1, dh), *map(context, pages), live).reshape(q.shape)
+        return core(q, gather, live)
 
     return attend
 

@@ -26,6 +26,7 @@ from paddle_tpu.ops import (  # noqa: F401
     losses,
     detection,
     quant,
+    moe,
 )
 
 from paddle_tpu.ops import math as _math
@@ -53,5 +54,6 @@ __all__ = (
         "losses",
         "detection",
         "quant",
+        "moe",
     ]
 )

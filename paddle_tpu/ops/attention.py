@@ -15,23 +15,71 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from paddle_tpu.core.enforce import enforce
 
 __all__ = [
     "scaled_dot_product_attention", "split_heads", "combine_heads",
-    "causal_mask", "rope_tables", "apply_rope",
+    "causal_mask", "rope_tables", "apply_rope", "yarn_inv_freq", "yarn_mscale",
 ]
 
 
-def rope_tables(dim: int, t: int, base: float = 10000.0, pos0: int = 0):
+def rope_tables(dim: int, t: int, base: float = 10000.0, pos0: int = 0,
+                scaling: dict | None = None):
     """Rotary position embedding cos/sin tables: [t, dim//2] each.
     No reference counterpart (the reference era used additive sinusoid PE,
     ``models/transformer.py`` position_encoding_init); RoPE is the modern
     long-context scheme — relative-position attention scores, exact under
-    sequence sharding since tables index GLOBAL positions via ``pos0``."""
+    sequence sharding since tables index GLOBAL positions via ``pos0``.
+
+    ``scaling`` (a published config's ``rope_scaling`` group, or None):
+    :func:`yarn_inv_freq` in place of the plain inverse frequencies."""
     half = dim // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(dim, base, scaling))
     angles = (pos0 + jnp.arange(t, dtype=jnp.float32))[:, None] * freqs[None, :]
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_inv_freq(dim: int, base: float, scaling: dict) -> np.ndarray:
+    """[dim // 2] float32 inverse frequencies under YaRN as DeepSeek-V2
+    spells it (``rope_scaling.type`` ``deepseek_yarn`` or ``yarn``): a
+    frequency that turns more than ``beta_fast`` times within the original
+    context keeps its value, one that turns fewer than ``beta_slow`` times
+    is divided by ``factor``, and a linear ramp over the dimensions between
+    the two blends them. The tables' own magnitude factor is
+    ``mscale / mscale_all_dim`` in that spelling; only 1 is supported (the
+    softmax scale carries the rest: :func:`yarn_mscale`)."""
+    kind = scaling.get("type", scaling.get("rope_type"))
+    enforce(kind in ("deepseek_yarn", "yarn"),
+            f"rope_tables: no rope_scaling of type {kind!r} (deepseek_yarn only)")
+    enforce(scaling.get("mscale", 1.0) == scaling.get("mscale_all_dim", 1.0),
+            "rope_tables: mscale != mscale_all_dim would scale the tables; "
+            "not supported")
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    half = dim // 2
+
+    def correction_dim(rotations: float) -> float:
+        return dim * np.log(orig / (rotations * 2 * np.pi)) / (2 * np.log(base))
+
+    low = max(np.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(np.ceil(correction_dim(scaling.get("beta_slow", 1))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low), 0, 1)
+    extra = base ** (-np.arange(half, dtype=np.float32) / half)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``; the softmax scale of a latent
+    attention under YaRN is ``head_dim ** -0.5 * yarn_mscale(factor,
+    mscale_all_dim) ** 2``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -434,13 +434,14 @@ class DecodeEngine:
                 f"prefill_chunk ({dconf.prefill_chunk})")
         self._programs = progs = serving_programs(self.model_cfg)
         self._paged = progs.cache == "pages"
+        if dconf.prefix_cache:
+            self._refuse_unless_paged("the prefix cache")
         for feature, asked in (
-                ("the prefix cache", dconf.prefix_cache),
                 ("the host tier", host_tier is not None or dconf.host_tier_bytes),
                 ("a draft model", draft_variables is not None),
                 ("a replica group", group is not None)):
             if asked:
-                self._refuse_unless_paged(feature)
+                self._refuse_unless_kv_pair(feature)
         pages_per_slot = dconf.max_context // dconf.page_size
         num_pages = (dconf.num_pages if dconf.num_pages is not None
                      else 1 + dconf.max_slots * pages_per_slot)
@@ -503,7 +504,10 @@ class DecodeEngine:
         # rebinds the result and nothing else may hold a cache array
         # across a loop pass. Group mode keeps the alias per shard: the
         # page outputs are pinned to the inputs' sharding. A paged model's
-        # list is [K pages, V pages]: the page paths index it so.
+        # list is [K pages, V pages] or one array of latent rows: what
+        # touches a page by its id loops over the list, and the paths that
+        # name [0] and [1] (host tier, handoff) are reached only through
+        # features _refuse_unless_kv_pair let through.
         self._cache = [self._zero_pages(sp.shape, kvs, sp.dtype) for sp in specs]
         jit_kw = {"donate_argnames": progs.cache_args}
         page_kw = {"donate_argnames": ("pages",)}
@@ -515,8 +519,16 @@ class DecodeEngine:
         model_kw = dict(sample_kw, cfg=self.model_cfg)
         if self._paged:
             model_kw["page_size"] = dconf.page_size
+            self.metrics.set_cache_bytes_per_token(sum(
+                sp.shape[0] * sp.shape[3] * np.dtype(sp.dtype).itemsize for sp in specs))
         else:
             self.metrics.set_state_bytes(sum(c.nbytes for c in self._cache))
+        if progs.gauges is not None:
+            self.metrics.set_program_gauges(progs.gauges(self.model_cfg))
+        # (chunk number, span, extras) of the chunks whose extras are not
+        # read yet: a later turn reads them, once they have run
+        self._chunk_extras: Deque = deque()
+        self._chunk_seq = 0
         # roofline-instrumented: these jits bypass Executor.prepare(), so
         # they feed the cost ledger through their own wrapper (compiles
         # capture cost/memory analysis, later calls book wall seconds)
@@ -550,7 +562,7 @@ class DecodeEngine:
                     "compares argmaxes, so temperature must be 0.0")
             self.draft_cfg = dict(draft_cfg) if draft_cfg else self.model_cfg
             dprogs = serving_programs(self.draft_cfg)
-            self._refuse_unless_paged("a draft model", dprogs)
+            self._refuse_unless_kv_pair("a draft model", dprogs)
             enforce(self.draft_cfg.get("vocab") == self.model_cfg.get("vocab"),
                     "draft and target models must share a vocabulary "
                     f"({self.draft_cfg.get('vocab')} vs "
@@ -717,13 +729,54 @@ class DecodeEngine:
     # -- startup -----------------------------------------------------------
 
     def _refuse_unless_paged(self, feature: str, programs=None) -> None:
-        """The one error for everything that needs KV pages."""
+        """The one error for everything that needs pages of any row."""
         programs = programs or self._programs
         enforce(programs.cache == "pages",
                 f"DecodeEngine: {feature} cannot be used with a model that "
                 f"keeps {programs.mechanism}. It shares, copies, ships or "
                 "rolls back KV pages; a recurrent state would need "
                 "snapshots, which the engine does not have")
+
+    def _refuse_unless_kv_pair(self, feature: str, programs=None) -> None:
+        """The one error for everything that names a K and a V page array
+        of whole heads: the host tier's and the handoff's page images and
+        checksums, a replica group's sharding of pages by heads, a draft
+        model's verify step."""
+        programs = programs or self._programs
+        self._refuse_unless_paged(feature, programs)
+        enforce(len(programs.cache_args) == 2 and programs.kv_heads is not None,
+                f"DecodeEngine: {feature} cannot be used with a model that "
+                f"keeps {programs.mechanism}. It ships, checksums or shards "
+                "a K and a V page of whole heads; a one-array cache needs "
+                "its own page geometry there (ROADMAP M4)")
+
+    def _take(self, out):
+        """A program's results: rebinds the cache arrays it returned (the
+        ones it was handed are donated) and returns ``(first result,
+        extras)``."""
+        n = len(self._cache)
+        self._cache = list(out[1:1 + n])
+        return out[0], tuple(out[1 + n:])
+
+    def _land_extras(self, span, extras) -> None:
+        """Read a call's extras (small arrays of a call that has run) and
+        put what they say on its span and under the counters."""
+        attrs = self._programs.span_attrs(
+            self.model_cfg, *(np.asarray(e) for e in extras))
+        span.set(**attrs)
+        self.metrics.record_call_attrs(attrs)
+
+    def _land_chunk_extras(self, before: int) -> None:
+        """The same for the chunks enqueued before chunk number ``before``:
+        they have run once anything enqueued behind them was read."""
+        while self._chunk_extras and self._chunk_extras[0][0] < before:
+            _, span, extras = self._chunk_extras.popleft()
+            try:
+                self._land_extras(span, extras)
+            except Exception as e:
+                # reading a failed chunk's outputs raises its error again;
+                # the request's own path has failed it already
+                ptlog.warning("prefill chunk's extras not read: %r", e)
 
     def _zero_pages(self, shape, sharding, dtype=None):
         """One zeroed cache array (``sharding`` None = single device)."""
@@ -782,14 +835,14 @@ class DecodeEngine:
                 kept.append(name)
 
         old = list(self._cache)
-        _, *self._cache = self._prefill(
+        self._take(self._prefill(
             self._params, chunk0, z, z, self._slot_ref(0), *old,
-            self._next_key())
+            self._next_key()))
         consumed("prefill", *old)
         old = list(self._cache)
-        out, *self._cache = self._step(
+        out, _ = self._take(self._step(
             self._params, slots0, slots0, jnp.asarray(self._slot_refs([])),
-            *old, self._next_key())
+            *old, self._next_key()))
         consumed("step", *old)
         jax.block_until_ready(out)
         if self._paged:
@@ -859,17 +912,14 @@ class DecodeEngine:
             jax.block_until_ready(vout)
         # scratch -> scratch: harmless. The implant serves handoff adoption
         # and host-tier promotes, the copy the prefix cache's copy-on-write
-        page0 = jnp.zeros(
-            self._cache[0].shape[:1] + self._cache[0].shape[2:],
-            self._cache_dtype)
-        k, v = self._cache
-        self._cache = [self._implant_page(k, z, page0),
-                       self._implant_page(v, z, page0)]
-        consumed("implant_page", k, v)
+        old = list(self._cache)
+        self._cache = [self._implant_page(
+            c, z, jnp.zeros(c.shape[:1] + c.shape[2:], c.dtype)) for c in old]
+        consumed("implant_page", *old)
         if self._prefix is not None:
-            k, v = self._cache
-            self._cache = [self._copy_page(k, z, z), self._copy_page(v, z, z)]
-            consumed("copy_page", k, v)
+            old = list(self._cache)
+            self._cache = [self._copy_page(c, z, z) for c in old]
+            consumed("copy_page", *old)
             if self._spec_k:
                 k, v = self._dk_pages, self._dv_pages
                 self._dk_pages = self._copy_page_d(k, z, z)
@@ -1467,8 +1517,7 @@ class DecodeEngine:
             for li in range((c0 * C) // ps, m):
                 src, dst = self._kv.private_copy(req.slot, li)
                 s, d = jnp.int32(src), jnp.int32(dst)
-                self._cache[0] = self._copy_page(self._cache[0], s, d)
-                self._cache[1] = self._copy_page(self._cache[1], s, d)
+                self._cache = [self._copy_page(c, s, d) for c in self._cache]
                 if self._spec_k:
                     self._dk_pages = self._copy_page_d(self._dk_pages, s, d)
                     self._dv_pages = self._copy_page_d(self._dv_pages, s, d)
@@ -1745,7 +1794,7 @@ class DecodeEngine:
             # last chunk's wait, landing. The request's own tree gets its
             # copy (enqueue to sync) once the chunk has gone through
             with tracing.start_span("serving.decode.prefill", chunk=c,
-                                    last_chunk=last_chunk):
+                                    last_chunk=last_chunk) as chunk_span:
                 chunk = np.zeros((C,), np.int32)
                 seg = req.seq[c * C:min((c + 1) * C, len(req.seq))]
                 chunk[:len(seg)] = seg
@@ -1753,10 +1802,13 @@ class DecodeEngine:
                 t0 = time.perf_counter()
                 try:
                     table_row = self._slot_ref(req.slot)
-                    tok, *self._cache = self._prefill(
+                    tok, extras = self._take(self._prefill(
                         self._params, jnp.asarray(chunk),
                         jnp.int32(c * C), jnp.int32(max(last, 0)),
-                        table_row, *self._cache, self._next_key())
+                        table_row, *self._cache, self._next_key()))
+                    if extras:
+                        self._chunk_extras.append((self._chunk_seq, chunk_span, extras))
+                    self._chunk_seq += 1
                     if self._spec_k:
                         # the draft's cache must cover the same prefix so its
                         # proposals attend real context (sampled token unused)
@@ -1890,8 +1942,10 @@ class DecodeEngine:
         if not chunks:
             # no step went out: nothing to queue the chunks behind, and
             # nothing to read their first tokens behind either
+            ran_before = self._chunk_seq
             chunks_behind()
             did = self._land_first_tokens(self._first_tokens_due()) or did
+            self._land_chunk_extras(ran_before)
         return did or chunks[0]
 
     def _plain_decode_step(self, decoding: List[_DecodeRequest],
@@ -1938,10 +1992,11 @@ class DecodeEngine:
                 faults.inject(faults.DECODE_STEP,
                               engine=self.metrics.engine_label)
                 with tracing.start_span("serving.decode.model_step.dispatch"):
-                    nxt, *self._cache = self._step(
+                    nxt, extras = self._take(self._step(
                         self._params, jnp.asarray(tokens),
                         jnp.asarray(positions), jnp.asarray(refs),
-                        *self._cache, self._next_key())
+                        *self._cache, self._next_key()))
+                ran_before = self._chunk_seq  # the chunks queued ahead of this step
                 # the step is queued behind the last iteration's chunks: a
                 # prompt whose last chunk was among them gets its first
                 # token now, with this iteration's chunks queued too. A
@@ -1954,6 +2009,8 @@ class DecodeEngine:
                 self._land_first_tokens(due, in_step=True)
                 with tracing.start_span("serving.decode.model_step.wait"):
                     nxt = np.asarray(nxt)
+                    if extras:
+                        self._land_extras(step_span, extras)
             except Exception as e:
                 # a failed step loses this iteration's K/V writes for every
                 # in-flight sequence
@@ -1970,6 +2027,7 @@ class DecodeEngine:
             step_span.set(active=len(decoding), new_tokens=len(decoding),
                           seconds=seconds)
             with tracing.start_span("serving.decode.model_step.land"):
+                self._land_chunk_extras(ran_before)
                 self._note_step_ok()
                 self.metrics.record_step(len(decoding), S, seconds,
                                          len(decoding))
@@ -2529,7 +2587,7 @@ class DecodeEngine:
         ``cur_len`` without re-prefilling. The client's original handle
         is repointed here, mirroring :meth:`adopt_rescue`. Thread-safe;
         returns the (possibly fresh) handle."""
-        self._refuse_unless_paged("disaggregated handoff")
+        self._refuse_unless_kv_pair("disaggregated handoff")
         if self._closed:
             raise EngineClosedError("engine is closed")
         prompt = np.asarray(payload.prompt, np.int32).reshape(-1)

@@ -316,7 +316,7 @@ class DisaggRouter(DecodeFleet):
 
     def _wire(self, eng, role: str) -> None:
         """Attach one engine to the router's plumbing for its role."""
-        eng._refuse_unless_paged("disaggregated handoff")  # it ships KV pages
+        eng._refuse_unless_kv_pair("disaggregated handoff")  # it ships K and V pages
         eng._rescue_sink = self._rescue
         if self._journal is not None and eng._journal is None:
             eng._journal = self._journal

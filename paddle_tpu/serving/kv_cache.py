@@ -12,9 +12,11 @@ in-flight sequences changes every iteration.
 Here HBM is carved into ``num_pages`` fixed ``page_size``-token pages
 (``k_pages``/``v_pages``: ``[L, num_pages, page_size, H_kv * dh]``, a
 position's K or V one contiguous row of all its heads: the form the page
-write takes, which the chip holds as spelled), and each of ``max_slots``
-sequence slots holds a page *table* — an int32 row of physical page ids,
-one per logical page. The jitted decode step takes
+write takes, which the chip holds as spelled; a latent-attention model
+brings one array instead, ``latent_pages``, whose row is a token's latent
+and shared rotary key: this class never sees the arrays, only page ids),
+and each of ``max_slots`` sequence slots holds a page *table* — an int32
+row of physical page ids, one per logical page. The jitted decode step takes
 ``(tokens [S], positions [S], page_tables [S, P], k_pages, v_pages)``:
 every shape is a function of static config only, so XLA compiles the
 step ONCE and admission/eviction between steps never recompiles.

@@ -790,6 +790,26 @@ class DecodeMetrics:
         prof.set_gauge("serving.decode.pages_row_major", int(ok),
                        labels=self._labels)
 
+    def set_cache_bytes_per_token(self, n: int) -> None:
+        """Bytes a cached position takes over all layers and page arrays."""
+        prof.set_gauge("serving.decode.cache_bytes_per_token", n,
+                       labels=self._labels)
+
+    def set_program_gauges(self, gauges: Dict[str, float]) -> None:
+        """What the model's programs say of themselves once
+        (``ServingPrograms.gauges``): ``serving.decode.<name>``, for an
+        expert layer ``moe.experts_held`` and ``moe.router_width``."""
+        for name, value in gauges.items():
+            prof.set_gauge(f"serving.decode.{name}", value, labels=self._labels)
+
+    def record_call_attrs(self, attrs: Dict[str, float]) -> None:
+        """The attributes a call's extras gave its span
+        (``ServingPrograms.span_attrs``): an expert layer's pairs are also
+        counted, ``serving.decode.moe.pairs_total``."""
+        if "moe_pairs" in attrs:
+            prof.inc_counter("serving.decode.moe.pairs_total", attrs["moe_pairs"],
+                             labels=self._labels)
+
     # a model that keeps a recurrent state per slot instead of KV pages
     def set_state_bytes(self, n: int) -> None:
         prof.set_gauge("serving.decode.state_bytes", n, labels=self._labels)

@@ -250,6 +250,102 @@ def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, slots):
     assert _program_bytes(compiled) < program_limit
 
 
+# sha256 of the StableHLO text ``lm_big``'s two serving programs lower to (16
+# slots x 2048, chunk 32, float32 pages), taken on PR 30's tree: PR 31 gave
+# ``_paged_attend`` a core and a row form as arguments for the latent cache,
+# and the K and V path had to stay the program it was, byte for byte. A PR
+# that means to change these programs replaces the digests and says so.
+LM_BIG_TEXT = {
+    "decode_step": "01706439647abc12d06cbccc558c445d323c31acd94e5d34704e7f7b341d2953",
+    "prefill_chunk": "ca1ddf2ca818330a02eed971dcf8a0e582ef0363fb13f091f7996b57c2bfe9ef",
+}
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_lm_big_serving_programs_lower_to_the_text_they_had(which):
+    import hashlib
+
+    from paddle_tpu.models.transformer_lm import (
+        paged_cache_shape, paged_decode_step, paged_prefill_chunk,
+    )
+
+    spec = _lm_large()
+    cfg = dict(spec.extra["cfg"], scan_layers=False)
+    params = jax.eval_shape(
+        lambda: spec.model.init(0, *spec.synth_batch(1, np.random.RandomState(0)))
+    ).params
+    per_slot = CONTEXT // PAGE
+    pages = jax.ShapeDtypeStruct(paged_cache_shape(cfg, 1 + SLOTS * per_slot, PAGE), jnp.float32)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if which == "decode_step":
+        fn, args = paged_decode_step, (i32(SLOTS), i32(SLOTS), i32(SLOTS, per_slot))
+    else:
+        fn, args = paged_prefill_chunk, (i32(CHUNK), i32(), i32(), i32(per_slot))
+    text = jax.jit(functools.partial(fn, cfg=cfg, page_size=PAGE),
+                   donate_argnames=("k_pages", "v_pages"),
+                   ).lower(params, *args, pages, pages, None).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == LM_BIG_TEXT[which]
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_sarvam_serving_steps_fit_the_chip_and_alias_their_latent_pages(one_chip, as_tpu, which):
+    """The cell sarvam_105b.serve_docs32 at its own shapes: the dense layer
+    and four expert layers of 32 held experts at the published widths in
+    bfloat16 (9.07 GB) beside 32 slots x 16384 positions of latent rows
+    (3.36 GB: a row of 576 in 640 lanes). The engine donates the one page
+    array, so each program must alias it to its output, must take it as the
+    model spells it (a row of 576 was kept page-minor by the chip and
+    converted whole at every layer: 4.7 GB of temp), must hold the
+    ``moe_gmm`` kernel three times an expert layer, and must fit the chip."""
+    import json
+    import re
+
+    from benchmarks.families import latent_moe_lm as family
+    from paddle_tpu.models import latent_moe_lm
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(here, "configs", "sarvam_105b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "traffic", "serve_docs32.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = dict(latent_moe_lm.BASE_CFG, **family.model_cfg(config))
+    assert cfg["max_len"] == engine["max_context"] and cfg["experts_held"] == (0, 32)
+    progs = models.serving_programs(cfg)
+    bf16 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = {k: bf16(shape) for k, shape in latent_moe_lm.param_shapes(cfg).items()}
+    slots, page = engine["max_slots"], engine["page_size"]
+    per_slot = engine["max_context"] // page
+    (spec,) = progs.cache_specs(cfg, max_slots=slots, num_pages=1 + slots * per_slot,
+                                page_size=page, dtype=jnp.dtype(engine["cache_dtype"]))
+    assert spec.shape == (5, 1 + 32 * 1024, 16, 640) and spec.dtype == jnp.bfloat16
+    pages = jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    if which == "decode_step":
+        fn, args = progs.decode_step, (i32(slots), i32(slots), i32(slots, per_slot))
+    else:
+        fn, args = progs.prefill_chunk, (i32(engine["prefill_chunk"]), i32(), i32(),
+                                         i32(per_slot))
+    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
+                       donate_argnames=progs.cache_args,
+                       ).lower(params, *args, pages, None).compile()
+    text = compiled.as_text()
+    page_bytes = 2 * int(np.prod(pages.shape))
+    weight_bytes = 2 * sum(int(np.prod(p.shape)) for p in params.values())
+    assert 3.3e9 < page_bytes < 3.4e9 and 9.0e9 < weight_bytes < 9.1e9
+    assert compiled.memory_analysis().alias_size_in_bytes >= page_bytes
+    whole = "bf16[" + ",".join(str(d) for d in pages.shape) + "]"
+    page_array_copies = [l.strip()[:120] for l in text.splitlines()
+                         if whole in l.split("=")[0] and " copy(" in l]
+    assert not page_array_copies and "remat_" not in text, page_array_copies[:2]
+    entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
+    layouts = re.findall(re.escape(whole) + r"\{([\d,]+)", entry)
+    assert len(layouts) == 2 and set(layouts) == {"3,2,1,0"}, layouts
+    assert text.count("tpu_custom_call") == 3 * 4 and "moe_gmm" in text
+    # the step reads 0.84 GB of temp beside 12.43 GB of arguments, the chunk 0.71
+    assert _program_bytes(compiled) < HBM_BYTES - 2.0e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
 def test_brumby_serving_steps_fit_the_chip_and_alias_their_state(one_chip, as_tpu, which):
     """The cell brumby_14b.serve_docs16 at its own shapes: 8 layers at the
