@@ -79,15 +79,19 @@ class _InstrumentedCompiled:
             from paddle_tpu.ops.pallas import moe as moe_kernel
             from paddle_tpu.ops.pallas.flash_attention import take_resolved
 
-            tracing.record_span("executor.compile", t0, t1, target=self._label,
-                                **take_resolved(), **moe_kernel.take_resolved())
+            # the ledger's capture compiles ahead of time where the backend
+            # reports memory; what the program aliases (a donated state's
+            # bytes) goes on the span beside the kernels
+            costs = {}
             if ledger_on:
                 try:
-                    roofline.capture_costs(
+                    costs = roofline.capture_costs(
                         self._fn, roofline.call_key(self._label, args, kwargs),
-                        args, kwargs)
+                        args, kwargs) or {}
                 except Exception:
                     pass
+            tracing.record_span("executor.compile", t0, t1, target=self._label,
+                                **take_resolved(), **moe_kernel.take_resolved(), **costs)
         elif ledger_on:
             try:
                 roofline.observe_call(

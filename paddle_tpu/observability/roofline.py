@@ -397,7 +397,7 @@ def _arg_nbytes(args: tuple, kwargs: dict) -> int:
         return 0
 
 
-def capture_costs(jitted, key: str, args: tuple, kwargs: dict) -> None:
+def capture_costs(jitted, key: str, args: tuple, kwargs: dict) -> Optional[dict]:
     """Capture static costs for the executable a jit call just compiled:
     re-lower for ``cost_analysis()`` (a trace, no compile) and — when
     :func:`memory_capture_enabled` — AOT-compile for ``memory_analysis()``
@@ -406,14 +406,17 @@ def capture_costs(jitted, key: str, args: tuple, kwargs: dict) -> None:
     duplicate compile is the price of the peak number — which is why the
     ``auto`` policy skips it on CPU, where there is no real peak to buy.
     Failures and absent analyses degrade to a cost-only entry, never an
-    error."""
+    error. Returns ``{"alias_bytes": n}`` where the compile was made: the
+    bytes of outputs that take a donated input's buffer (``args`` may be
+    arrays the call consumed: lowering reads their shapes only)."""
     try:
         lowered = jitted.lower(*args, **kwargs)
     except Exception:
-        return
+        return None
     totals = mfu.cost_analysis_totals(lowered)
     peak_hbm = None
     out_bytes = 0
+    span_attrs = None
     if memory_capture_enabled():
         try:
             compiled = lowered.compile()
@@ -427,6 +430,7 @@ def capture_costs(jitted, key: str, args: tuple, kwargs: dict) -> None:
                         return 0
 
                 out_bytes = _get("output_size_in_bytes")
+                span_attrs = {"alias_bytes": _get("alias_size_in_bytes")}
                 peak_hbm = _get("peak_memory_in_bytes")
                 if not peak_hbm:
                     # backends reporting no peak: reconstruct like
@@ -444,6 +448,7 @@ def capture_costs(jitted, key: str, args: tuple, kwargs: dict) -> None:
         arg_bytes=_arg_nbytes(args, kwargs),
         out_bytes=out_bytes,
     )
+    return span_attrs
 
 
 class InstrumentedJit:

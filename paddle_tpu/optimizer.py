@@ -161,6 +161,14 @@ class Optimizer:
                 finite = jnp.isfinite(loss)
                 for g in jax.tree_util.tree_leaves(grads):
                     finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
+                # a non-finite step returns the state it was handed: a
+                # caller that donated that state (Trainer, DataParallel) has
+                # no older copy to fall back on, so what the step returns is
+                # always what is carried. The select fuses into the update,
+                # which reads the old leaf anyway.
+                new_params, new_state, new_opt = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(finite, new, old.astype(new.dtype)),
+                    (new_params, new_state, new_opt), (params, state, opt_state))
             return StepOutput(
                 Variables(new_params, new_state), new_opt, loss, outputs, finite
             )

@@ -83,6 +83,14 @@ class Trainer:
     ``train_func`` builds and returns the model (a :class:`Model` or a plain
     layer-calling function, which is wrapped); its forward must return the
     loss first. ``optimizer_func`` returns an :class:`Optimizer`.
+
+    The Trainer owns ``variables`` and ``opt_state``: each step consumes the
+    arrays it is handed (they are donated to the compiled step, which writes
+    the new state into their buffers) and the Trainer holds what the step
+    returned. An array read from ``trainer.variables`` or
+    ``trainer.opt_state`` is therefore valid until the next step; a caller
+    who keeps one across a step copies it first:
+    ``jax.tree_util.tree_map(jnp.array, trainer.variables)``.
     """
 
     def __init__(
@@ -120,6 +128,7 @@ class Trainer:
         self.trainer_id = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
         self._dp = None
         self._step_fn = None
+        self._donation_noted = False  # gauge trainer.state_donated is set
         self.variables: Optional[Variables] = None
         self.opt_state: Optional[OptState] = None
         self.epoch = 0
@@ -228,7 +237,11 @@ class Trainer:
     def _compiled_step(self):
         if self._step_fn is None:
             raw = self.optimizer.minimize(self.model)
-            self._step_fn = self.exe.prepare(raw, key=("trainer_step", id(self)))
+            # the step consumes the state it is handed (the Trainer is its
+            # only holder between steps), so every state output takes its
+            # input's buffer and the enqueue allocates only what is new
+            self._step_fn = self.exe.prepare(
+                raw, donate_argnums=(0, 1), key=("trainer_step", id(self)))
         return self._step_fn
 
     # -- train loop ---------------------------------------------------------
@@ -330,7 +343,13 @@ class Trainer:
                                 faults.TRAINER_STEP, epoch=epoch_id, step=step_id
                             )
                             t_step = time.perf_counter()
-                            if self._watchdog is not None:
+                            # an injected "nan" is known before the step: the
+                            # step is not run, so the state stays as it is
+                            # (no second program, no operand of the one)
+                            bad = spec is not None and spec.kind == "nan"
+                            if bad:
+                                out = None
+                            elif self._watchdog is not None:
                                 with self._watchdog.watch(f"epoch {epoch_id} step {step_id}"):
                                     out = self._run_step(batch)
                             else:
@@ -340,12 +359,23 @@ class Trainer:
                             # sync per step (reference
                             # BeginStepEvent.fetch_metrics, trainer.py:158)
                             with tracing.start_span("trainer.fetch"):
-                                bad = (out.finite is not None and not bool(out.finite)) or (
-                                    spec is not None and spec.kind == "nan"
-                                )
+                                bad = bad or (
+                                    out.finite is not None and not bool(out.finite))
                                 metrics = None
                                 if begin_ev.fetch_metrics:
                                     metrics = float("nan") if bad else float(out.loss)
+                            if out is not None:
+                                # the step consumed the state it was handed, so
+                                # what it returned is the state to carry, on a
+                                # bad step too (where the program computed
+                                # `finite` it kept the old values). The old
+                                # arrays' buffers went into the new ones;
+                                # dropping the step's outputs frees 1 GB of
+                                # logits that would sit in HBM beside the
+                                # next step's whole program
+                                with tracing.start_span("trainer.commit"):
+                                    self.variables, self.opt_state = out.variables, out.opt_state
+                                    out = None
                             if bad:
                                 step_span.set(status="bad_step")
                                 # charge the wasted step to badput even if the policy
@@ -355,22 +385,13 @@ class Trainer:
                                 # may raise (policy "raise", or rollback gave up)
                                 self._handle_bad_step(epoch_id, step_id)
                             else:
-                                # taking the new state frees the old (some 2000
-                                # device arrays of lm_large: milliseconds), and
-                                # so does dropping the step's outputs (1 GB of
-                                # logits): still referenced, they would sit in
-                                # HBM beside the next step's whole program
-                                with tracing.start_span("trainer.commit"):
-                                    self._consec_bad = 0
-                                    self._rollbacks_since_good = 0
-                                    self.variables, self.opt_state = out.variables, out.opt_state
-                                    self.global_step += 1
-                                    out = None
+                                self._consec_bad = 0
+                                self._rollbacks_since_good = 0
+                                self.global_step += 1
                                 with tracing.start_span("trainer.record_step"):
                                     self._record_step(
                                         epoch_id, batch, time.perf_counter() - t_step,
                                         metrics)
-                            out = None  # a bad step's outputs go too
                             with tracing.start_span("trainer.end_event"):
                                 handler(EndStepEvent(epoch_id, step_id, metrics))
                             if self._preempt_requested:
@@ -691,8 +712,30 @@ class Trainer:
         step_fn = self._compiled_step()
         with tracing.start_span("trainer.h2d"):
             args = [jax.numpy.asarray(b) for b in batch]
+        # the first step says whether the state was consumed
+        handed = None
+        if not self._donation_noted:
+            handed = jax.tree_util.tree_leaves_with_path((self.variables, self.opt_state))
         with tracing.start_span("trainer.step_compute"):
-            return step_fn(self.variables, self.opt_state, *args)
+            out = step_fn(self.variables, self.opt_state, *args)
+        if handed is not None:
+            self._note_donation(handed)
+        return out
+
+    def _note_donation(self, handed) -> None:
+        """Gauge ``trainer.state_donated``, set once: 1 when the first step
+        consumed every leaf of the state it was handed, 0 (with a warning
+        naming the first leaf it did not) when one was copied to the
+        device instead: a host array someone put into the state."""
+        self._donation_noted = True
+        kept = [path for path, leaf in handed
+                if not (isinstance(leaf, jax.Array) and leaf.is_deleted())]
+        prof.set_gauge("trainer.state_donated", 0.0 if kept else 1.0)
+        if kept:
+            ptlog.warning(
+                "trainer step did not consume %d of %d state leaves (first: %s): "
+                "the step allocates those outputs anew",
+                len(kept), len(handed), jax.tree_util.keystr(kept[0]))
 
     def _maybe_checkpoint(self, epoch_id: int, step: bool):
         cfg = self.checkpoint_cfg

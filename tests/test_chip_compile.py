@@ -419,3 +419,39 @@ def test_data_parallel_step_compiles_on_four_chips(topo, as_tpu):
     assert "tpu_custom_call" in text and "all-reduce" in text
     assert batch_sh[0].spec[0] == "data"  # one sequence per chip
     assert _program_bytes(compiled) < HBM_BYTES
+
+
+def test_trainer_step_aliases_its_state_at_lm_big_train_2k(topo, one_chip, as_tpu):
+    """``lm_big.train_2k``'s step (4 x 2048 tokens, Adam) as
+    ``Trainer._compiled_step`` builds it, on an Executor whose place is the
+    described chip. Every state output must take its input's buffer:
+    parameters and Adam's two slots, 12 bytes a parameter. Undonated the
+    step allocated an output a leaf at every call (593 allocations of 43 us
+    with the device idle, PERF.md PR 25) and held both copies of the state."""
+    import paddle_tpu as pt
+    from paddle_tpu.core.config import TPUPlace
+
+    class DescribedChip(TPUPlace):
+        def device(self):
+            return topo.devices[0]
+
+    spec = _lm_large()
+    batch = spec.synth_batch(4, np.random.RandomState(0))
+    opt, v, o = _abstract_state(spec, batch)
+    n_params = sum(int(np.prod(p.shape)) for p in v.params.values())
+    assert n_params == 216_692_736  # the cell's 216.7 M
+    args = (_shapes(v, one_chip), _shapes(o, one_chip), *_shapes(batch, one_chip))
+    trainer = pt.Trainer(lambda: spec.model, lambda: opt, place=DescribedChip())
+    compiled = trainer._compiled_step().lower(*args).compile()
+    undonated = jax.jit(opt.minimize(spec.model)).lower(*args).compile()
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert aliased >= 12 * n_params
+    assert undonated.memory_analysis().alias_size_in_bytes == 0
+    saved = _program_bytes(undonated) - _program_bytes(compiled)
+    # 1.99 of the 2.60 GB: donated, the outputs cannot serve as scratch while
+    # their inputs live, and the program takes 0.61 GB more of temp
+    assert 0.7 * aliased < saved <= aliased
+    text = compiled.as_text()
+    for kernel in ("flash_fwd_resident", "flash_bwd_dkv_resident", "flash_bwd_dq_resident"):
+        assert kernel in text
+    assert _program_bytes(compiled) < HBM_BYTES

@@ -256,7 +256,9 @@ def test_trainer_loss_and_gradients_are_the_references_and_the_bias_stays():
                                        refc.mm_f32) / labels.size
     want_loss, want_grad = jax.value_and_grad(mean_loss)(params)
     trainer = pt.Trainer(lambda: spec.model, lambda: pt.optimizer.SGD(learning_rate=1.0))
-    trainer.variables = trainer.exe.put(pt.framework.Variables(dict(params), {}))
+    # the step consumes the state it is handed: the Trainer gets a copy
+    trainer.variables = trainer.exe.put(pt.framework.Variables(
+        {k: jnp.array(v) for k, v in params.items()}, {}))
     trainer.opt_state = trainer.exe.put(trainer.optimizer.create_state(trainer.variables.params))
     losses = []
     trainer.train(num_epochs=1, reader=lambda: iter([(ids, labels)]),

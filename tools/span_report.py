@@ -163,6 +163,21 @@ def report(path: str, cell: str, out_dir: str, load_xplane, look_inside=()) -> N
     print("span report:", json.dumps({k: doc[k] for k in doc if k != "device_names"}), flush=True)
 
 
+def print_set_once() -> None:
+    """What the program says once about its own programs: the gauges that
+    tell whether a donation engaged, and each compile span's attributes (the
+    kernels' blocks, the bytes a program aliases)."""
+    from paddle_tpu import tracing
+    from paddle_tpu.core import profiler as prof
+
+    said = {k: v for k, v in prof.gauges().items()
+            if k.endswith(("_donated", "_row_major"))}
+    print("program gauges:", json.dumps(said), flush=True)
+    for span in tracing.spans():
+        if span.name == "executor.compile":
+            print("executor.compile:", json.dumps(span.attrs, default=str), flush=True)
+
+
 def main(argv) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -196,6 +211,7 @@ def main(argv) -> int:
                              "--seconds", args.seconds, "--trace", args.trace], T_START)
     finally:
         trace_reduce.load_xplane = load
+        print_set_once()
 
 
 if __name__ == "__main__":
