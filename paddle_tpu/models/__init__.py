@@ -48,9 +48,10 @@ class ServingPrograms:
     rng, cfg=, ...) -> (next_token, *cache)`` and ``decode_step(params,
     tokens [S], positions [S], slot_refs, *cache, rng, cfg=, ...) ->
     (next_tokens [S], *cache)``. With ``cache`` ``"pages"`` the arrays are
-    pages ``[L, num_pages, page_size, row]`` (a K and a V array, or one
-    array of latent rows), ``slot_ref`` is a slot's page-table row and the
-    programs also take ``page_size=``; with ``"state"`` they are recurrent
+    pages ``[planes, num_pages, page_size, row]``, planes being the layers
+    or, where a stack runs several passes, passes x layers (a K and a V
+    array, or one array of latent rows), ``slot_ref`` is a slot's
+    page-table row and the programs also take ``page_size=``; with ``"state"`` they are recurrent
     states indexed by slot, ``slot_ref`` is the slot's index, and
     ``slot_refs`` marks the slots that decode.
 
@@ -151,6 +152,12 @@ def _latent_moe_lm(**cfg):
     return latent_moe_lm.get_model(**cfg)
 
 
+def _looped_lm(**cfg):
+    from paddle_tpu.models import looped_lm
+
+    return looped_lm.get_model(**cfg)
+
+
 def _transformer_lm(**cfg):
     from paddle_tpu.models import transformer_lm
 
@@ -166,6 +173,7 @@ MODELS: Dict[str, Callable[..., ModelSpec]] = {
     "transformer_lm": _transformer_lm,
     "retention_lm": _retention_lm,
     "latent_moe_lm": _latent_moe_lm,
+    "looped_lm": _looped_lm,
     "stacked_dynamic_lstm": _stacked_dynamic_lstm,
     "machine_translation": _machine_translation,
 }

@@ -700,8 +700,10 @@ def generate_beam(
 # in flight — so the serving programs compile once and continuous batching
 # (admit/evict between steps) never pays XLA again. How a page array is
 # indexed is spelled twice: ``paged_cache_shape`` and ``_paged_attend``.
-# Its form, ``[L, num_pages, page_size, H_kv * dh]``, is the one the page
-# write takes: a row of 128 lanes or a multiple of them is held by the chip
+# Its form, ``[planes, num_pages, page_size, H_kv * dh]`` (planes being the
+# layers or, where a stack runs several passes, passes x layers:
+# ``models/looped_lm.py`` hands ``_paged_attend`` the plane ``r * L + i``
+# where this file hands it the layer ``i``), is the one the page write takes: a row of 128 lanes or a multiple of them is held by the chip
 # as spelled, so no program converts the array on entry or on exit (with
 # heads an axis of their own and ``dh`` 64 it did, eight times an iteration).
 

@@ -128,7 +128,7 @@ class DecodeConfig:
     # tokens per KV page; pages are the HBM allocation granularity
     page_size: int = 16
     # per-sequence position capacity (prompt + generation); must be a
-    # multiple of both page_size and prefill_chunk
+    # multiple of page_size (and, for a state cache, of prefill_chunk)
     max_context: int = 256
     # physical page pool; None = every slot fully grown + scratch
     num_pages: Optional[int] = None
@@ -427,13 +427,21 @@ class DecodeEngine:
         enforce(dconf.max_context % dconf.page_size == 0,
                 f"max_context ({dconf.max_context}) must be a multiple of "
                 f"page_size ({dconf.page_size})")
-        # padded prompt chunks must stay inside the slot's table span —
-        # a chunk running past it would clamp-scatter into the last page
-        enforce(dconf.max_context % dconf.prefill_chunk == 0,
-                f"max_context ({dconf.max_context}) must be a multiple of "
-                f"prefill_chunk ({dconf.prefill_chunk})")
         self._programs = progs = serving_programs(self.model_cfg)
         self._paged = progs.cache == "pages"
+        # padded prompt chunks must stay inside the table they are handed —
+        # a chunk running past it would clamp-scatter into the last page.
+        # Where max_context is no multiple of prefill_chunk, a paged
+        # chunk's table row is lengthened by scratch entries to a whole
+        # number of chunks (_slot_ref): the overhang lands on the scratch
+        # page, as an idle slot's step does
+        enforce(self._paged or dconf.max_context % dconf.prefill_chunk == 0,
+                f"max_context ({dconf.max_context}) must be a multiple of "
+                f"prefill_chunk ({dconf.prefill_chunk}) for a model that "
+                f"keeps {progs.mechanism}")
+        chunked = -(-dconf.max_context // dconf.prefill_chunk) * dconf.prefill_chunk
+        self._chunk_table_pad = (-(-chunked // dconf.page_size)
+                                 - dconf.max_context // dconf.page_size)
         if dconf.prefix_cache:
             self._refuse_unless_paged("the prefix cache")
         for feature, asked in (
@@ -442,6 +450,8 @@ class DecodeEngine:
                 ("a replica group", group is not None)):
             if asked:
                 self._refuse_unless_kv_pair(feature)
+        if draft_variables is not None:
+            self._refuse_unless_verified()
         pages_per_slot = dconf.max_context // dconf.page_size
         num_pages = (dconf.num_pages if dconf.num_pages is not None
                      else 1 + dconf.max_slots * pages_per_slot)
@@ -512,7 +522,8 @@ class DecodeEngine:
         jit_kw = {"donate_argnames": progs.cache_args}
         page_kw = {"donate_argnames": ("pages",)}
         if group is not None:
-            jit_kw["out_shardings"] = (rep, kvs, kvs)
+            # tokens, the K and the V pages, then the programs' small extras
+            jit_kw["out_shardings"] = (rep, kvs, kvs) + (rep,) * len(progs.extras)
             page_kw["out_shardings"] = kvs
         sample_kw = dict(temperature=dconf.temperature, top_k=dconf.top_k,
                          top_p=dconf.top_p)
@@ -563,6 +574,7 @@ class DecodeEngine:
             self.draft_cfg = dict(draft_cfg) if draft_cfg else self.model_cfg
             dprogs = serving_programs(self.draft_cfg)
             self._refuse_unless_kv_pair("a draft model", dprogs)
+            self._refuse_unless_verified(dprogs)
             enforce(self.draft_cfg.get("vocab") == self.model_cfg.get("vocab"),
                     "draft and target models must share a vocabulary "
                     f"({self.draft_cfg.get('vocab')} vs "
@@ -750,6 +762,17 @@ class DecodeEngine:
                 "a K and a V page of whole heads; a one-array cache needs "
                 "its own page geometry there (ROADMAP M4)")
 
+    def _refuse_unless_verified(self, programs=None) -> None:
+        """The one error for a draft model beside programs that bring no
+        verify step (as target: nothing scores the draft's block; as draft:
+        the engine's draft calls take three results and no extras)."""
+        programs = programs or self._programs
+        enforce(programs.verify_step is not None,
+                f"DecodeEngine: a draft model cannot be used with a model that "
+                f"keeps {programs.mechanism}. Speculative decoding scores a "
+                "block of draft tokens in one verify step over a K and a V "
+                "page a layer, which these programs do not have")
+
     def _take(self, out):
         """A program's results: rebinds the cache arrays it returned (the
         ones it was handed are donated) and returns ``(first result,
@@ -790,8 +813,13 @@ class DecodeEngine:
         row, or the slot's index into the state arrays."""
         import jax.numpy as jnp
 
-        return (jnp.asarray(self._kv.page_tables[slot]) if self._paged
-                else jnp.int32(slot))
+        if not self._paged:
+            return jnp.int32(slot)
+        row = self._kv.page_tables[slot]
+        if self._chunk_table_pad:  # the last chunk's overhang: scratch
+            row = np.concatenate(
+                [row, np.full((self._chunk_table_pad,), SCRATCH_PAGE, row.dtype)])
+        return jnp.asarray(row)
 
     def _slot_refs(self, decoding) -> np.ndarray:
         """The same for a decode step over ``decoding``: every other slot
@@ -894,7 +922,7 @@ class DecodeEngine:
 
         S, P = self.decode_config.max_slots, self._kv.pages_per_slot
         tables0 = jnp.zeros((S, P), jnp.int32)
-        table0 = jnp.zeros((P,), jnp.int32)
+        table0 = jnp.zeros((P + self._chunk_table_pad,), jnp.int32)
         if self._spec_k:
             k, v = self._dk_pages, self._dv_pages
             _, self._dk_pages, self._dv_pages = self._draft_prefill(
@@ -928,11 +956,14 @@ class DecodeEngine:
 
     def _manifest_name(self) -> str:
         """Manifest identity for this engine: model dims + the static
-        decode-shape knobs (a config change must not replay stale keys)."""
+        decode-shape knobs (a config change must not replay stale keys).
+        The depth it names is the cache's: the planes of its first array,
+        which are the layers or, where a stack runs several passes, passes
+        x layers; two engines that differ only in passes never share keys."""
         d = self.decode_config
         mc = self.model_cfg
         name = ("decode_L{l}_D{dm}_S{s}_P{p}_C{c}".format(
-            l=mc.get("n_layers", 0), dm=mc.get("d_model", 0),
+            l=self._cache[0].shape[0], dm=mc.get("d_model", 0),
             s=d.max_slots, p=d.page_size, c=d.prefill_chunk))
         if "family" in mc:
             name = f"{mc['family']}_{name}"

@@ -4,7 +4,8 @@ The TPU compiler is installed beside the CPU backend and compiles for a
 chip that is described, not attached. These are the programs
 ``chip_smoke.py`` runs — the flash kernel, the ``lm_large`` train step, the
 paged serving steps and the four-chip data-parallel step — and the two
-serving programs of the benchmark's ``brumby_14b`` cell, so what the
+serving programs of the benchmark's ``brumby_14b``, ``sarvam_105b`` and
+``ouro_2_6b`` cells, so what the
 chip's compiler would refuse (a kernel that cannot be partitioned, a
 program that does not fit HBM) fails here, at no chip time. Nothing runs:
 a passing compile says nothing about results or speed.
@@ -344,6 +345,71 @@ def test_sarvam_serving_steps_fit_the_chip_and_alias_their_latent_pages(one_chip
     # the step reads 0.84 GB of temp beside 12.43 GB of arguments, the chunk 0.71
     assert _program_bytes(compiled) < HBM_BYTES - 2.0e9
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_ouro_serving_steps_fit_the_chip_and_keep_their_pages_in_place(one_chip, as_tpu, which):
+    """The cell ouro_2_6b.serve_reason8 at its own shapes, nothing cut: 48
+    layers at the published widths in bfloat16 (5.34 GB) run four passes,
+    beside 8 slots x 640 positions of K and V pages in 192 planes (2 x 4.04
+    GB). The page arrays ride the carry of the passes' loop and of the
+    layers' loop inside it, and are both written and gathered in its body:
+    they must stay in place (no whole-array copy, aliased to the outputs,
+    held as the model spells them), so the temporaries stay under 1.5 GB and
+    arguments plus temporaries fit the chip. The chunk of 192 does not
+    divide the context of 640: its table row is the engine's, lengthened to
+    768 positions."""
+    import json
+    import re
+
+    from benchmarks.families import looped_lm as family
+    from paddle_tpu.models import looped_lm
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(here, "configs", "ouro_2_6b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "traffic", "serve_reason8.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = dict(looped_lm.BASE_CFG, **family.model_cfg(config))
+    assert cfg["max_len"] == engine["max_context"] and looped_lm.planes(cfg) == 192
+    progs = models.serving_programs(cfg)
+    bf16 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = {k: bf16(shape) for k, shape in looped_lm.param_shapes(cfg).items()}
+    assert params["layers/ffn/fc1/w"].shape == (48, 2048, 5632)
+    slots, page = engine["max_slots"], engine["page_size"]
+    per_slot = engine["max_context"] // page
+    k_spec, v_spec = progs.cache_specs(cfg, max_slots=slots, num_pages=1 + slots * per_slot,
+                                       page_size=page, dtype=jnp.dtype(engine["cache_dtype"]))
+    assert k_spec.shape == v_spec.shape == (192, 321, 16, 2048) and k_spec.dtype == jnp.bfloat16
+    pages = jax.ShapeDtypeStruct(k_spec.shape, k_spec.dtype, sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    if which == "decode_step":
+        fn, args = progs.decode_step, (i32(slots), i32(slots), i32(slots, per_slot))
+    else:
+        chunk = engine["prefill_chunk"]
+        assert (chunk, -(-engine["max_context"] // chunk) * chunk // page) == (192, 48)
+        fn, args = progs.prefill_chunk, (i32(chunk), i32(), i32(), i32(48))
+    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
+                       donate_argnames=progs.cache_args,
+                       ).lower(params, *args, pages, pages, None).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    page_bytes = 2 * int(np.prod(pages.shape))
+    weight_bytes = 2 * sum(int(np.prod(p.shape)) for p in params.values())
+    assert page_bytes == 4_039_114_752 and 5.33e9 < weight_bytes < 5.34e9
+    assert mem.alias_size_in_bytes >= 2 * page_bytes
+    whole = "bf16[" + ",".join(str(d) for d in pages.shape) + "]"
+    page_array_copies = [l.strip()[:120] for l in text.splitlines()
+                         if whole in l.split("=")[0] and " copy(" in l]
+    assert not page_array_copies, page_array_copies[:2]
+    entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
+    layouts = re.findall(re.escape(whole) + r"\{([\d,]+)", entry)
+    assert len(layouts) == 4 and set(layouts) == {"3,2,1,0"}, layouts
+    print(which, "temp", mem.temp_size_in_bytes, "arguments", mem.argument_size_in_bytes)
+    # the step reads 0.81 GB of temp beside 13.41 GB of arguments
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    # the passes are one traced body and the layers another: two loops, whatever the passes
+    assert text.count(" while(") == 2
 
 
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
