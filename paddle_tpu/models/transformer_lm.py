@@ -699,7 +699,10 @@ def generate_beam(
 # config (slot count, table width, page size) — never of which requests are
 # in flight — so the serving programs compile once and continuous batching
 # (admit/evict between steps) never pays XLA again. How a page array is
-# indexed is spelled twice: ``paged_cache_shape`` and ``_paged_attend``.
+# indexed is spelled three times: ``paged_cache_shape``, ``_paged_attend``
+# (the page write and the gather) and the copies of
+# ``ops/pallas/paged_attention.py``, which a decode step on a TPU attends
+# through in the gather's place.
 # Its form, ``[planes, num_pages, page_size, H_kv * dh]`` (planes being the
 # layers or, where a stack runs several passes, passes x layers:
 # ``models/looped_lm.py`` hands ``_paged_attend`` the plane ``r * L + i``
@@ -777,6 +780,21 @@ def _kv_core(q, gather, live):
         q.reshape(B, H, -1, dh), context(0), context(1), live).reshape(q.shape)
 
 
+def step_attends_in_kernel(pages, page_size: int, head_dim: int, window) -> bool:
+    """Whether a decode step over the K and V page arrays shaped as ``pages``
+    attends through ``ops/pallas/paged_attention.py``'s kernel and not the
+    gather: on a TPU (``ops/moe.py``'s rule for ``moe_gmm``), with no sliding
+    window, pages that lie in whole tiles, and no mesh of several devices
+    around the trace (a Mosaic kernel cannot be partitioned automatically;
+    the engine traces a replica group's programs under the group's mesh)."""
+    from paddle_tpu.ops.pallas.paged_attention import step_fits
+
+    mesh = jax.sharding.get_abstract_mesh()
+    return (jax.default_backend() == "tpu" and window is None
+            and step_fits(pages.shape, pages.dtype, page_size, head_dim)
+            and all(n == 1 for n in mesh.shape.values()))
+
+
 def _heads_last(new):  # [B, n, Q, dh] or [S, n, dh]: a position's heads side by side
     return jnp.moveaxis(new, 1, -2)
 
@@ -798,12 +816,17 @@ def _paged_attend(pages: list, page_tables, pos, page_size: int, window,
     table gathers it, ``page_tables.shape + (page_size, row)``, and ``live``
     the mask [B, 1, 1, Q, t_eff].
 
-    The gather materializes each sequence's [T_eff, row] context per layer —
-    the straightforward XLA lowering. ROADMAP A5 (a Pallas kernel that
-    streams live pages from HBM without the copy) edits this one body."""
+    The gather materializes each sequence's [T_eff, row] context per layer,
+    the straightforward XLA lowering, whatever is live. A decode step over K
+    and V pages (one query a slot, a table row a slot) on a TPU attends
+    through the ``paged_attend_step`` kernel instead, which reads the slot's
+    live pages where they lie (:func:`step_attends_in_kernel`); a chunk, a
+    verify block, a latent core and every program lowered for a CPU keep
+    the gather (ROADMAP A5 has what is left)."""
     P = page_tables.shape[-1]
     B, t_eff = page_tables.size // P, P * page_size
     page, off = pos // page_size, pos % page_size
+    one_query_a_slot = core is _kv_core and page_tables.ndim == 2 and pos.ndim == 1
     if page_tables.ndim == 1:  # no slot axis to index: the chunk's program stays as it compiled
         phys = page_tables[page]
     else:
@@ -816,6 +839,15 @@ def _paged_attend(pages: list, page_tables, pos, page_size: int, window,
             for j, rows in enumerate(new):
                 row = to_row(rows).reshape(pos.shape + (-1,))
                 pages[j] = pages[j].at[i, phys, off].set(row.astype(pages[j].dtype))
+
+        if one_query_a_slot and step_attends_in_kernel(
+                pages[0], page_size, q.shape[-1], window):
+            from paddle_tpu.ops.pallas.paged_attention import paged_attend_step
+
+            with jax.named_scope("paged_attend"):
+                ctx = paged_attend_step(q.reshape(B, -1, q.shape[-1]), *pages, i,
+                                        page_tables, pos)
+            return ctx.reshape(q.shape)
 
         def gather(j):
             # layer and page are one index into [L * num_pages, offset,

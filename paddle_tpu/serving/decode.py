@@ -73,6 +73,7 @@ from paddle_tpu.core import profiler as prof
 from paddle_tpu.core import retry as retry_mod
 from paddle_tpu.core.enforce import enforce
 from paddle_tpu.models import serving_programs
+from paddle_tpu.models.transformer_lm import step_attends_in_kernel
 from paddle_tpu.observability import roofline, runlog
 from paddle_tpu.parallel import collective
 from paddle_tpu.tracing import waterfall
@@ -291,6 +292,22 @@ class _DecodeRequest:
         # phase "first_token": the last chunk's sample, still on the
         # device, with the chunk's enqueue time and index
         self.first_tok = None
+
+
+def _under_mesh(group, fn):
+    """``fn`` as a replica group's engine traces it: under the group's mesh,
+    so that code which cannot run as one program over several chips (a
+    Mosaic kernel is not partitioned automatically) sees it is not alone.
+    With no group, ``fn`` itself."""
+    if group is None:
+        return fn
+
+    @functools.wraps(fn)  # jit finds the arguments it donates by their names
+    def traced(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(group.mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return traced
 
 
 class DecodeCostModel:
@@ -544,11 +561,19 @@ class DecodeEngine:
         # they feed the cost ledger through their own wrapper (compiles
         # capture cost/memory analysis, later calls book wall seconds)
         self._step = roofline.instrument(
-            "serving.decode.step", jax.jit(functools.partial(
-                progs.decode_step, **model_kw), **jit_kw))
+            "serving.decode.step", jax.jit(_under_mesh(group, functools.partial(
+                progs.decode_step, **model_kw)), **jit_kw))
         self._prefill = roofline.instrument(
             "serving.decode.prefill", jax.jit(functools.partial(
                 progs.prefill_chunk, **model_kw), **jit_kw))
+        # whether the step attends through the paged_attend_step kernel: the
+        # model's own rule, asked where the step is traced
+        self._attend_kernel = int(
+            self._paged and progs.kv_heads is not None and _under_mesh(
+                group, step_attends_in_kernel)(
+                    specs[0], dconf.page_size,
+                    pshape[3] // progs.kv_heads(self.model_cfg),
+                    self.model_cfg.get("attention_window")))
         # disagg KV handoff (serving.disagg): one page is the fixed-shape
         # [L, page_size, H_kv * dh] slice, so gather/implant compile once.
         # In group mode the gather's output is pinned replicated — the
@@ -601,9 +626,9 @@ class DecodeEngine:
             self._dk_pages = self._zero_pages(dshape, dkvs)
             self._dv_pages = self._zero_pages(dshape, dkvs)
             self._draft_step = roofline.instrument(
-                "serving.decode.draft_step", jax.jit(functools.partial(
+                "serving.decode.draft_step", jax.jit(_under_mesh(group, functools.partial(
                     dprogs.decode_step, cfg=self.draft_cfg,
-                    page_size=dconf.page_size, temperature=0.0), **djit_kw))
+                    page_size=dconf.page_size, temperature=0.0)), **djit_kw))
             self._draft_prefill = roofline.instrument(
                 "serving.decode.draft_prefill", jax.jit(functools.partial(
                     dprogs.prefill_chunk, cfg=self.draft_cfg,
@@ -2018,6 +2043,17 @@ class DecodeEngine:
                     tokens[req.slot] = req.last_tok
                     positions[req.slot] = req.cur_len
                 refs = self._slot_refs(decoding)
+                if self._paged:
+                    # the pages the decoding slots hold rows in, of the
+                    # table the gather reads whole
+                    attend = {
+                        "attend_live_pages": int(sum(
+                            r.cur_len // self.decode_config.page_size + 1
+                            for r in decoding)),
+                        "attend_table_pages": S * self._kv.pages_per_slot,
+                        "attend_kernel": self._attend_kernel}
+                    step_span.set(**attend)
+                    self.metrics.record_call_attrs(attend)
             t0 = time.perf_counter()
             try:
                 faults.inject(faults.DECODE_STEP,

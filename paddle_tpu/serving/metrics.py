@@ -804,11 +804,19 @@ class DecodeMetrics:
 
     def record_call_attrs(self, attrs: Dict[str, float]) -> None:
         """The attributes a call's extras gave its span
-        (``ServingPrograms.span_attrs``): an expert layer's pairs are also
-        counted, ``serving.decode.moe.pairs_total``."""
+        (``ServingPrograms.span_attrs``), or the engine gave a paged step's:
+        an expert layer's pairs are also counted,
+        ``serving.decode.moe.pairs_total``, and so are the pages a step's
+        slots hold rows in and the pages of the tables it was handed,
+        ``serving.decode.attend.{live,table}_pages_total`` (their ratio is
+        the share of a gathered context that is live)."""
         if "moe_pairs" in attrs:
             prof.inc_counter("serving.decode.moe.pairs_total", attrs["moe_pairs"],
                              labels=self._labels)
+        if "attend_live_pages" in attrs:
+            for which in ("live", "table"):
+                prof.inc_counter(f"serving.decode.attend.{which}_pages_total",
+                                 attrs[f"attend_{which}_pages"], labels=self._labels)
 
     # a model that keeps a recurrent state per slot instead of KV pages
     def set_state_bytes(self, n: int) -> None:

@@ -229,6 +229,36 @@ def test_engine_iteration_spans_and_the_seconds_the_metrics_were_handed(lm):
     assert len(request_chunks) == len(chunks)
 
 
+def test_a_paged_step_carries_the_pages_its_slots_hold_and_the_table_it_was_handed(lm):
+    """What the gather reads and what is live, on the step's span: the sum
+    over the decoding slots of ``pos // page_size + 1``, the ``S * P`` pages
+    of the tables, and whether the compiled step attends through the
+    ``paged_attend_step`` kernel (never on a CPU)."""
+    engine = _engine(lm)
+    handed, step = [], engine._step
+
+    def tapped(params, tokens, positions, *rest):
+        handed.append(np.asarray(positions))
+        return step(params, tokens, positions, *rest)
+
+    engine._step = tapped
+    try:
+        prompts = [np.arange(1, 12, dtype=np.int32), np.arange(3, 8, dtype=np.int32)]
+        for h in [engine.submit(p, 6) for p in prompts]:
+            h.result(timeout=300)
+    finally:
+        engine.close()
+    steps = [s for s in tracing.spans_for_trace(engine._loop_trace.trace_id)
+             if s.name == "serving.decode.model_step"]
+    assert len(steps) == len(handed) >= 6
+    # a decoding slot's position is past its prompt, an idle slot's is 0
+    assert [s.attrs["attend_live_pages"] for s in steps] == [
+        int((pos[pos > 0] // 4 + 1).sum()) for pos in handed]
+    assert max(s.attrs["attend_live_pages"] for s in steps) >= 2 + 4
+    assert {s.attrs["attend_table_pages"] for s in steps} == {3 * 10}
+    assert {s.attrs["attend_kernel"] for s in steps} == {0}
+
+
 def test_an_idle_engine_adds_nothing_to_the_store(lm):
     engine = _engine(lm, idle_poll_s=0.005)
     try:
@@ -361,7 +391,7 @@ def test_paged_steps_carry_their_scope_names(lm, which):
 # ---- the tool that shares idle gaps out to the program's spans -------------
 
 
-def test_innermost_gives_every_moment_to_one_span():
+def _span_report():
     import importlib.util
 
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -369,6 +399,48 @@ def test_innermost_gives_every_moment_to_one_span():
     spec = importlib.util.spec_from_file_location("span_report", path)
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
+    return tool
+
+
+def test_loop_turns_reads_a_turn_and_its_host_part_from_the_spans(lm):
+    """The tool's untraced twin of ``loop_iteration_ms`` and ``loop_host_ms``:
+    a turn that held a model step, from the end of the turn before, and that
+    less the ``.wait`` spans inside it."""
+    engine = _engine(lm)
+    try:
+        engine.submit(np.arange(1, 12, dtype=np.int32), 8).result(timeout=300)
+    finally:
+        engine.close()
+    spans = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    steps = [s for s in spans if s.name == "serving.decode.model_step"]
+    loop = _span_report().loop_turns(spans)
+    assert len(steps) - 1 <= loop["turns"] <= len(steps)  # the first has no turn before it
+    assert 0 < loop["host_ms_p50"] < loop["turn_ms_p50"] <= loop["turn_ms_p95"]
+    assert _span_report().loop_turns([s for s in spans if s.name != "serving.decode.step"]) == {}
+
+
+def test_the_tools_idle_gaps_are_the_harness_s():
+    """``tools/span_report.py::idle_gaps`` against ``trace_reduce.idle_gaps``
+    on device operations with gaps of every size and host events that nest,
+    overlap a gap's edge, span several gaps or touch none."""
+    from benchmarks import trace_reduce
+
+    rng = np.random.RandomState(3)
+    at, device = 0, []
+    for i in range(400):
+        at += int(rng.choice([0, 1, 3, 50, 4000]))
+        device.append((f"op{i % 7}", at, int(rng.randint(1, 300))))
+        at += device[-1][2]
+    host = [(f"h{i % 5}", int(rng.randint(0, at)), int(rng.choice([1, 40, 900, 60000])))
+            for i in range(300)]
+    want = trace_reduce.idle_gaps(device, host, top=10)
+    got = _span_report().idle_gaps(device, host, top=10)
+    assert [n for n, _ in got] == [n for n, _ in want] and len(want) == 6
+    assert [s for _, s in got] == pytest.approx([s for _, s in want], rel=1e-12)
+
+
+def test_innermost_gives_every_moment_to_one_span():
+    tool = _span_report()
     events = [("step", 0, 100), ("wait", 10, 20), ("model", 40, 50), ("model.pack", 40, 10),
               ("model.wait", 55, 30), ("idle", 120, 5)]
     pieces = sorted(tool.innermost(events), key=lambda e: e[1])

@@ -7,8 +7,9 @@ Runs ``benchmarks/run.py``'s harness in this process. With ``--trace 1`` it
 reads the ``.xplane.pb`` the harness captured, before the harness deletes it:
 
 * the device's idle gaps shared out to the program's spans, through
-  ``trace_reduce.load_xplane(path, host_prefix=...)`` and
-  ``trace_reduce.idle_gaps`` as they are. Nested spans would be counted
+  ``trace_reduce.load_xplane(path, host_prefix=...)`` as it is and
+  ``trace_reduce.idle_gaps``'s numbers by a shorter way (:func:`idle_gaps`,
+  which the harness's own reduction of this run takes too). Nested spans would be counted
   twice, so each moment goes to the innermost span open on it: a span that
   holds children keeps only the time no child covers (``<name> (self)``).
 * per span name: count, total and own seconds, and the part of the traced
@@ -144,8 +145,8 @@ def report(path: str, cell: str, out_dir: str, load_xplane, look_inside=()) -> N
         "cell": cell,
         "window_s": trace_reduce.window_ns(trace) / 1e9,
         "busy_s": trace_reduce.busy_ns(first) / 1e9,
-        "idle_gaps_by_program_span": trace_reduce.idle_gaps(first, pieces, top=40),
-        "idle_gaps_by_bench_annotation": trace_reduce.idle_gaps(first, trace["host"], top=10),
+        "idle_gaps_by_program_span": idle_gaps(first, pieces, top=40),
+        "idle_gaps_by_bench_annotation": idle_gaps(first, trace["host"], top=10),
         "spans": span_table(host, pieces),
         "device_names": device_names(path),
     }
@@ -163,10 +164,40 @@ def report(path: str, cell: str, out_dir: str, load_xplane, look_inside=()) -> N
     print("span report:", json.dumps({k: doc[k] for k in doc if k != "device_names"}), flush=True)
 
 
+def idle_gaps(events, host, top: int = 10):
+    """``trace_reduce.idle_gaps``, to the same numbers, for a window of a
+    million device operations: the harness's own walks every host event for
+    every gap (ten minutes for 8 s of ``lm_big.serve_closed16`` since its
+    step takes 3 ms of the device, hours over the program's spans); this one
+    looks only at the host events that can reach the gap."""
+    import bisect
+
+    from benchmarks import trace_reduce
+
+    host = sorted(host, key=lambda e: e[1])
+    starts = [s for _, s, _ in host]
+    longest = max((d for _, _, d in host), default=0)
+    merged = trace_reduce.merge_intervals(events)
+    by_name = {}
+    for (_, e0), (s1, _) in zip(merged, merged[1:]):
+        left = s1 - e0
+        for name, hs, hd in host[bisect.bisect_left(starts, e0 - longest):
+                                 bisect.bisect_left(starts, s1)]:
+            cover = min(s1, hs + hd) - max(e0, hs)
+            if cover > 0:
+                by_name[name] = by_name.get(name, 0.0) + cover / 1e9
+                left -= cover
+        if left > 0:
+            by_name["unattributed"] = by_name.get("unattributed", 0.0) + left / 1e9
+    return [[n, s] for n, s in sorted(by_name.items(), key=lambda kv: -kv[1])[:top]]
+
+
 def print_set_once() -> None:
     """What the program says once about its own programs: the gauges that
-    tell whether a donation engaged, and each compile span's attributes (the
-    kernels' blocks, the bytes a program aliases)."""
+    tell whether a donation engaged, each compile span's attributes (the
+    kernels' blocks, the bytes a program aliases), over the paged steps still
+    in the span store the share of their tables' pages that were live, and
+    the loop's turns (:func:`loop_turns`)."""
     from paddle_tpu import tracing
     from paddle_tpu.core import profiler as prof
 
@@ -176,6 +207,43 @@ def print_set_once() -> None:
     for span in tracing.spans():
         if span.name == "executor.compile":
             print("executor.compile:", json.dumps(span.attrs, default=str), flush=True)
+    steps = [s.attrs for s in tracing.spans()
+             if s.name == "serving.decode.model_step" and "attend_live_pages" in s.attrs]
+    if steps:
+        live, table = (sum(a[f"attend_{w}_pages"] for a in steps) for w in ("live", "table"))
+        print("attend:", json.dumps({
+            "steps": len(steps), "live_pages": live, "table_pages": table,
+            "live_share": live / table, "kernel": steps[-1]["attend_kernel"]}), flush=True)
+    loop = loop_turns(tracing.spans())
+    if loop:
+        print("loop:", json.dumps(loop), flush=True)
+
+
+def loop_turns(spans) -> dict:
+    """Over the serving loop's turns still in the span store that held a model
+    step: the median milliseconds from the end of the turn before to the end
+    of this one, and of that less the ``.wait`` spans inside it. What the
+    benchmark's ``loop_iteration_ms`` and ``loop_host_ms`` read in a traced
+    window, here for an untraced run too (the drain after the window is in it:
+    medians, not sums)."""
+    import bisect
+    import statistics
+
+    held = {s.context.parent_id for s in spans if s.name == "serving.decode.model_step"}
+    turns = sorted((s for s in spans if s.name == "serving.decode.step"), key=lambda s: s.t1_us)
+    waits = sorted((s.t0_us, s.t1_us - s.t0_us) for s in spans if s.name.endswith(".wait"))
+    starts = [w[0] for w in waits]
+    took, host = [], []
+    for before, turn in zip(turns, turns[1:]):
+        if turn.context.span_id in held and turn.context.trace_id == before.context.trace_id:
+            lo, hi = (bisect.bisect_left(starts, t) for t in (before.t1_us, turn.t1_us))
+            took.append((turn.t1_us - before.t1_us) / 1e3)
+            host.append(took[-1] - sum(d for _, d in waits[lo:hi]) / 1e3)
+    if not took:
+        return {}
+    p95 = lambda v: sorted(v)[int(0.95 * (len(v) - 1))]
+    return {"turns": len(took), "turn_ms_p50": statistics.median(took), "turn_ms_p95": p95(took),
+            "host_ms_p50": statistics.median(host), "host_ms_p95": p95(host)}
 
 
 def main(argv) -> int:
@@ -206,11 +274,12 @@ def main(argv) -> int:
         return load(path, *a, **kw)
 
     trace_reduce.load_xplane = load_and_report
+    slow, trace_reduce.idle_gaps = trace_reduce.idle_gaps, idle_gaps
     try:
         return harness.main(["--workload", args.workload, "--seed", args.seed,
                              "--seconds", args.seconds, "--trace", args.trace], T_START)
     finally:
-        trace_reduce.load_xplane = load
+        trace_reduce.load_xplane, trace_reduce.idle_gaps = load, slow
         print_set_once()
 
 
