@@ -87,6 +87,16 @@ def set_enabled(value: Optional[bool]) -> None:
     _override = value
 
 
+# ``enabled()`` runs on every acquire of every named lock, a hundred times a
+# serving turn, and ``"X" in os.environ`` on a miss raises and catches a
+# KeyError inside (0.7 us): ask the mapping under it, which sees every write
+# through ``os.environ`` as it happens
+try:
+    _ENV, _PYTEST_KEY = os.environ._data, os.environ.encodekey("PYTEST_CURRENT_TEST")
+except AttributeError:  # an os.environ that is not CPython's
+    _ENV, _PYTEST_KEY = os.environ, "PYTEST_CURRENT_TEST"
+
+
 def enabled() -> bool:
     """Is lock-order checking currently active?"""
     ov = _override
@@ -94,7 +104,7 @@ def enabled() -> bool:
         return ov
     if config.flags().lock_check:
         return True
-    return "PYTEST_CURRENT_TEST" in os.environ
+    return _PYTEST_KEY in _ENV
 
 
 # ---------------------------------------------------------------------------
@@ -350,8 +360,7 @@ class Lock:
     def locked(self) -> bool:
         return self._lock.locked()
 
-    def __enter__(self) -> bool:
-        return self.acquire()
+    __enter__ = acquire  # not through a frame of its own: see enabled()
 
     def __exit__(self, *exc) -> None:
         self.release()

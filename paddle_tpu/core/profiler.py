@@ -35,10 +35,17 @@ _enabled: bool = False
 # legacy aggregate view (labeled children summed / last-write).
 
 
-def _registry():
-    from paddle_tpu.observability import metrics as obs_metrics
+_the_registry = None
 
-    return obs_metrics.default_registry()
+
+def _registry():
+    # looked up once: an ``import`` statement a write is a third of the write
+    global _the_registry
+    if _the_registry is None:
+        from paddle_tpu.observability import metrics as obs_metrics
+
+        _the_registry = obs_metrics.default_registry()
+    return _the_registry
 
 
 def inc_counter(name: str, value: float = 1.0, labels: dict | None = None) -> None:

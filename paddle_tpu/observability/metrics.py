@@ -51,6 +51,8 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
 )
 
 LabelTuple = Tuple[Tuple[str, str], ...]
+# label sets whose child key is remembered (engines, classes, devices: tens)
+_MAX_REMEMBERED_KEYS = 4096
 
 
 def exponential_buckets(start: float, factor: float, count: int) -> Tuple[float, ...]:
@@ -177,6 +179,8 @@ class MetricRegistry:
         # polling snapshots. Tuple (not list) so the hot-path read is one
         # attribute load; swap-on-change under the lock.
         self._subscribers: Tuple = ()
+        # tuple(labels.items()) -> child key, see _key()
+        self._keys: Dict[tuple, LabelTuple] = {}
 
     # -- subscriptions -----------------------------------------------------
 
@@ -241,56 +245,117 @@ class MetricRegistry:
                 fam.help = help_text
         return fam
 
-    def _child_key(self, fam: _Family, labels: Optional[Dict[str, str]]) -> LabelTuple:
-        key = _canon_labels(labels)
-        names = tuple(k for k, _ in key)
-        if fam.label_names is None:
-            fam.label_names = names
-        else:
-            enforce.enforce_eq(
-                fam.label_names, names,
-                f"metric {fam.name!r}: inconsistent label names "
-                f"{names} vs {fam.label_names}")
-        fam.last_labels = key
+    def _key(self, labels: Optional[Dict[str, str]]) -> LabelTuple:
+        """The child key of ``labels``. A caller hands the same labels call
+        after call, so the sorted tuple of a dict whose names and values are
+        all ``str`` is remembered on the dict's items (never on its ``id``: a
+        dict may be mutated or freed). No lock: a dict read and a dict write
+        are each atomic, and two threads that race write the same value."""
+        if not labels:
+            return ()
+        items = tuple(labels.items())
+        try:
+            key = self._keys.get(items)
+        except TypeError:  # a value that cannot be hashed: nothing to remember
+            return _canon_labels(labels)
+        if key is None:
+            key = _canon_labels(labels)
+            if (len(self._keys) < _MAX_REMEMBERED_KEYS
+                    and all(type(k) is str and type(v) is str for k, v in items)):
+                self._keys[items] = key
         return key
 
+    def _child(self, fam: _Family, key: LabelTuple):
+        """``fam``'s child under ``key``, made the first time it is asked
+        for; that is when the key's label names are held to the family's."""
+        child = fam.children.get(key)
+        if child is None:
+            names = tuple(k for k, _ in key)
+            if fam.label_names is None:
+                fam.label_names = names
+            else:
+                enforce.enforce_eq(
+                    fam.label_names, names,
+                    f"metric {fam.name!r}: inconsistent label names "
+                    f"{names} vs {fam.label_names}")
+            child = fam.children[key] = (
+                _Hist(len(fam.buckets)) if fam.kind == HISTOGRAM else 0.0)
+        return child
+
     # -- writes ------------------------------------------------------------
+    # The three below are the program's hot path (thirty a serving turn):
+    # a family on record with the kind asked for and a child already made
+    # is two dict reads under the lock. Whatever else goes through
+    # _family() and _child(), which hold kinds and label names to what the
+    # family has.
 
     def inc(self, name: str, value: float = 1.0,
             labels: Optional[Dict[str, str]] = None, help: str = "") -> None:
+        key = self._key(labels)
         with self._lock:
-            fam = self._family(name, COUNTER, help)
-            key = self._child_key(fam, labels)
-            fam.children[key] = fam.children.get(key, 0.0) + value
-        self._notify(name, COUNTER, value, labels)
+            fam = self._families.get(name)
+            if fam is None or fam.kind != COUNTER or (help and not fam.help):
+                fam = self._family(name, COUNTER, help)
+            children = fam.children
+            if key not in children:
+                self._child(fam, key)
+            children[key] += value
+            fam.last_labels = key
+        if self._subscribers:
+            self._notify(name, COUNTER, value, labels)
 
     def set(self, name: str, value: float,
             labels: Optional[Dict[str, str]] = None, help: str = "") -> None:
+        key = self._key(labels)
+        value = float(value)
         with self._lock:
-            fam = self._family(name, GAUGE, help)
-            key = self._child_key(fam, labels)
-            fam.children[key] = float(value)
-        self._notify(name, GAUGE, float(value), labels)
+            fam = self._families.get(name)
+            if fam is None or fam.kind != GAUGE or (help and not fam.help):
+                fam = self._family(name, GAUGE, help)
+            children = fam.children
+            if key not in children:
+                self._child(fam, key)
+            children[key] = value
+            fam.last_labels = key
+        if self._subscribers:
+            self._notify(name, GAUGE, value, labels)
 
     def observe(self, name: str, value: float,
                 labels: Optional[Dict[str, str]] = None, help: str = "") -> None:
+        key = self._key(labels)
+        value = float(value)
         with self._lock:
             fam = self._families.get(name)
-            if fam is None:
-                fam = self._family(name, HISTOGRAM, help,
-                                   buckets=DEFAULT_BUCKETS)
-            else:
-                enforce.enforce_eq(
-                    fam.kind, HISTOGRAM,
-                    f"metric {name!r} already registered as {fam.kind}, "
-                    f"cannot use as {HISTOGRAM}")
-            key = self._child_key(fam, labels)
+            if fam is None or fam.kind != HISTOGRAM:
+                fam = self._family(name, HISTOGRAM, help, buckets=DEFAULT_BUCKETS)
             child = fam.children.get(key)
             if child is None:
-                child = _Hist(len(fam.buckets))
-                fam.children[key] = child
-            child.observe(fam.buckets, float(value))
-        self._notify(name, HISTOGRAM, float(value), labels)
+                child = self._child(fam, key)
+            child.observe(fam.buckets, value)
+            fam.last_labels = key
+        if self._subscribers:
+            self._notify(name, HISTOGRAM, value, labels)
+
+    def observe_many(self, name: str, values: Sequence[float],
+                     labels: Optional[Dict[str, str]] = None, help: str = "") -> None:
+        """:meth:`observe` for each of ``values``, into one child under one
+        taking of the lock (a serving turn's per-token samples);
+        subscribers hear each."""
+        key = self._key(labels)
+        values = [float(v) for v in values]
+        with self._lock:
+            fam = self._families.get(name)
+            if fam is None or fam.kind != HISTOGRAM:
+                fam = self._family(name, HISTOGRAM, help, buckets=DEFAULT_BUCKETS)
+            child = fam.children.get(key)
+            if child is None:
+                child = self._child(fam, key)
+            for v in values:
+                child.observe(fam.buckets, v)
+            fam.last_labels = key
+        if self._subscribers:
+            for v in values:
+                self._notify(name, HISTOGRAM, v, labels)
 
     # -- reads -------------------------------------------------------------
 
@@ -343,7 +408,7 @@ class MetricRegistry:
         the family or child is absent — pass ``default=None`` to tell
         "never written" apart from a real 0.0 (the SLO engine does, so a
         gauge-bound objective cannot judge a gauge that does not exist yet)."""
-        key = _canon_labels(labels)
+        key = self._key(labels)
         with self._lock:
             fam = self._families.get(name)
             if fam is None or fam.kind == HISTOGRAM:
@@ -354,7 +419,7 @@ class MetricRegistry:
     def histogram_snapshot(self, name: str,
                            labels: Optional[Dict[str, str]] = None) -> Optional[dict]:
         """One histogram child as {edges, cumulative, sum, count}."""
-        key = _canon_labels(labels)
+        key = self._key(labels)
         with self._lock:
             fam = self._families.get(name)
             if fam is None or fam.kind != HISTOGRAM:

@@ -59,7 +59,7 @@ import threading
 import time
 import zlib
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -557,6 +557,10 @@ class DecodeEngine:
         # read yet: a later turn reads them, once they have run
         self._chunk_extras: Deque = deque()
         self._chunk_seq = 0
+        # the turn's account (_close_turn): the loop thread's CPU clock when
+        # the last turn closed, and the seconds of bookings since
+        self._turn_cpu0: Optional[float] = None
+        self._turn_telemetry = 0.0
         # roofline-instrumented: these jits bypass Executor.prepare(), so
         # they feed the cost ledger through their own wrapper (compiles
         # capture cost/memory analysis, later calls book wall seconds)
@@ -1219,20 +1223,61 @@ class DecodeEngine:
             tenant=req.tenant, cls=req.cls,
             generated=len(req.generated), **attrs)
 
-    def _wf_tokens(self, req: _DecodeRequest, t_pc: float, n: int,
-                   phase: str) -> None:
-        """Book ``n`` tokens landing at ``t_pc`` in the request's
-        waterfall and mirror the returned TTFT / per-token TPOT samples
-        into the labeled histogram families. Called BEFORE the tokens are
-        appended — an append can finish the request, and a finished
-        waterfall refuses further bookings."""
-        if req.rid is None:
+    def _wf_tokens(self, landed: "List[Tuple[_DecodeRequest, int]]",
+                   t_pc: float, phase: str) -> None:
+        """Book a turn's tokens, ``n`` of ``req`` for each ``(req, n)``
+        landing at ``t_pc``, in the requests' waterfalls (one taking of
+        its lock) and mirror the returned TTFT / per-token TPOT samples
+        into the labeled histogram families, the TPOT samples once a
+        class. Called BEFORE the tokens are appended — an append can
+        finish the request, and a finished waterfall refuses further
+        bookings."""
+        landed = [(req, n) for req, n in landed if req.rid is not None]
+        booked = waterfall.on_tokens_many(
+            [(req.rid, n) for req, n in landed], t_pc, phase=phase)
+        tpot: Dict[str, List[float]] = {}
+        for (req, _), (ttft, samples) in zip(landed, booked):
+            if ttft is not None:
+                self.metrics.record_ttft(ttft, cls=req.cls)
+            if samples:
+                tpot.setdefault(req.cls, []).extend(samples)
+        for cls, samples in tpot.items():
+            self.metrics.record_tpot(samples, cls=cls)
+
+    # -- the turn's account ------------------------------------------------
+
+    def _clock(self) -> Optional[float]:
+        """``perf_counter`` now, for :meth:`_booked`; with tracing off None,
+        and no clock is read."""
+        return time.perf_counter() if tracing.tracing_enabled() else None
+
+    def _booked(self, since: Optional[float]) -> None:
+        """Count the stretch from ``since`` to now as spent in the turn's
+        bookings (``telemetry_seconds``): the registry writes of
+        ``DecodeMetrics``, the waterfall, the cost model, the gauges of
+        ``.publish``. They are gathered into a few stretches a turn so
+        that a handful of clock reads times them; what a request books
+        once in its life (admission, its finish) is not among them."""
+        if since is not None and tracing.tracing_enabled():
+            self._turn_telemetry += time.perf_counter() - since
+
+    def _close_turn(self, step_span) -> None:
+        """Put the turn's account on its ``serving.decode.step`` span, which
+        ends the turn: ``cpu_seconds``, this thread's CPU time since the last
+        such span closed (one clock read; a wait for the device sleeps, so
+        it holds none), and ``telemetry_seconds``, what :meth:`_booked`
+        counted over the same stretch. Wall time less the ``.wait`` spans
+        less ``cpu_seconds`` is the loop thread off the CPU: another
+        thread's interpreter lock, a named lock, the scheduler."""
+        if not tracing.tracing_enabled():
+            self._turn_cpu0 = None
             return
-        ttft, samples = waterfall.on_tokens(req.rid, t_pc, n, phase=phase)
-        if ttft is not None:
-            self.metrics.record_ttft(ttft, cls=req.cls)
-        if samples:
-            self.metrics.record_tpot(samples, cls=req.cls)
+        now = time.thread_time()
+        if self._turn_cpu0 is not None:
+            step_span.set(cpu_seconds=now - self._turn_cpu0,
+                          telemetry_seconds=self._turn_telemetry)
+        self._turn_cpu0 = now
+        self._turn_telemetry = 0.0
 
     def _expire(self, req: _DecodeRequest) -> None:
         """Deadline lapsed while queued (scheduler callback) or mid-
@@ -1328,15 +1373,19 @@ class DecodeEngine:
                 did = self._decode_step() or did_promote
                 if did:
                     sp.set(active=len(self._active))
+                    self._close_turn(sp)
                 else:
                     sp.cancel()
             if did:
+                # after the span that ends the turn: the next turn's account
                 with tracing.start_span("serving.decode.publish", parent=loop):
+                    since = self._clock()
                     self._publish_cache()
                     self.metrics.set_active_slots(len(self._active))
                     self.metrics.set_load(self.load())
                     self.metrics.set_queue_depth(self._queue.qsize())
                     self._publish_digest()
+                    self._booked(since)
                 continue
             # idle: nothing to prefill or step — wait for work or drain out
             if self._in_flight():
@@ -1878,6 +1927,13 @@ class DecodeEngine:
                     continue
                 t1 = time.perf_counter()
                 self.metrics.record_prefill_chunk(t1 - t0)
+                if not last_chunk:
+                    self.cost.observe_chunk(t1 - t0)
+                    if req.trace is not None:
+                        tracing.record_span("serving.decode.prefill", t0, t1,
+                                            parent=req.trace, chunk=c,
+                                            engine=self.metrics.engine_label)
+                self._booked(t1)
                 req.chunks_done = c + 1
                 self._kv.seq_lens[req.slot] = min(chunk_end, len(req.seq))
                 budget -= 1
@@ -1887,14 +1943,10 @@ class DecodeEngine:
                     # back, and not here: the device would stand idle from
                     # the chunk's end until the next call reaches it.
                     # _land_first_tokens reads it once more work is queued
+                    # (and books the chunk for the cost model and the
+                    # request's trace then, with its true end)
                     req.phase = "first_token"
                     req.first_tok = (tok, t0, c)
-                    continue
-                self.cost.observe_chunk(t1 - t0)
-                if req.trace is not None:
-                    tracing.record_span("serving.decode.prefill", t0, t1,
-                                        parent=req.trace, chunk=c,
-                                        engine=self.metrics.engine_label)
         return progressed
 
     def _land_first_token(self, req: _DecodeRequest, in_step: bool) -> None:
@@ -1920,6 +1972,11 @@ class DecodeEngine:
             tracing.record_span("serving.decode.prefill", t0, t1,
                                 parent=req.trace, chunk=c,
                                 engine=self.metrics.engine_label)
+        # the final chunk's sample IS the next token after the
+        # prefilled sequence — the first (or, after a resume, the
+        # next) generated token
+        self._wf_tokens([(req, 1)], t1, "prefill")
+        self._booked(t1)
         if self._prefix is not None:
             # every fully-written page is immutable from here on
             # (decode writes land past len(seq)) — publish them
@@ -1932,10 +1989,6 @@ class DecodeEngine:
                 self._host_demote(req, n_full)
         req.phase = "decode"
         req.cur_len = len(req.seq)
-        # the final chunk's sample IS the next token after the
-        # prefilled sequence — the first (or, after a resume, the
-        # next) generated token
-        self._wf_tokens(req, t1, 1, "prefill")
         self._append_token(req, tok)
         # prefill role (serving.disagg): publish instead of
         # decoding here — unless that one sampled token already
@@ -2043,6 +2096,7 @@ class DecodeEngine:
                     tokens[req.slot] = req.last_tok
                     positions[req.slot] = req.cur_len
                 refs = self._slot_refs(decoding)
+                attend = None
                 if self._paged:
                     # the pages the decoding slots hold rows in, of the
                     # table the gather reads whole
@@ -2053,7 +2107,6 @@ class DecodeEngine:
                         "attend_table_pages": S * self._kv.pages_per_slot,
                         "attend_kernel": self._attend_kernel}
                     step_span.set(**attend)
-                    self.metrics.record_call_attrs(attend)
             t0 = time.perf_counter()
             try:
                 faults.inject(faults.DECODE_STEP,
@@ -2094,15 +2147,20 @@ class DecodeEngine:
             step_span.set(active=len(decoding), new_tokens=len(decoding),
                           seconds=seconds)
             with tracing.start_span("serving.decode.model_step.land"):
+                # the turn's bookings in one stretch, then the appends
+                since = self._clock()
                 self._land_chunk_extras(ran_before)
                 self._note_step_ok()
+                if attend is not None:
+                    self.metrics.record_call_attrs(attend)
                 self.metrics.record_step(len(decoding), S, seconds,
                                          len(decoding))
                 self.cost.observe_step(seconds)
+                self._wf_tokens([(req, 1) for req in decoding], t1, "decode")
+                self._booked(since)
                 for req in list(decoding):
                     req.cur_len += 1
                     self._kv.seq_lens[req.slot] = req.cur_len
-                    self._wf_tokens(req, t1, 1, "decode")
                     self._append_token(req, int(nxt[req.slot]))
         return True
 
@@ -2180,44 +2238,48 @@ class DecodeEngine:
             t1 = time.perf_counter()
             seconds = t1 - t0
             with tracing.start_span("serving.decode.verify.land"):
-                self._note_step_ok()
-                new_tokens = 0
-                drafts_accepted = 0
                 eos = self.decode_config.eos_id
-                for req in list(spec):
+                accepted = []  # (req, drafts accepted, tokens that land)
+                for req in spec:
                     row = out[req.slot]
                     n_acc = 0
                     while (n_acc < K
                            and int(draft_mat[req.slot, n_acc]) == int(row[n_acc])):
                         n_acc += 1
-                    drafts_accepted += n_acc
-                    # waterfall booking mirrors _append_token's finish
-                    # conditions exactly: the block truncates at eos /
-                    # budget, and the n tokens this iteration lands book n
-                    # TPOT samples of dt/n — the speculation-aware
-                    # accounting contract
+                    # mirrors _append_token's finish conditions exactly:
+                    # the block truncates at eos / budget, and the n tokens
+                    # this iteration lands book n TPOT samples of dt/n —
+                    # the speculation-aware accounting contract
                     n_land = min(n_acc + 1, req.mnt - len(req.generated))
                     if eos is not None:
                         for j in range(n_land):
                             if int(row[j]) == eos:
                                 n_land = j + 1
                                 break
-                    self._wf_tokens(req, t1, n_land, "verify")
+                    accepted.append((req, n_acc, n_land))
+                new_tokens = sum(n_land for _, _, n_land in accepted)
+                # the turn's bookings in one stretch, then the appends
+                since = self._clock()
+                self._note_step_ok()
+                self.metrics.record_verify_step(
+                    len(spec), S, seconds, new_tokens,
+                    drafts_proposed=len(spec) * K,
+                    drafts_accepted=sum(n_acc for _, n_acc, _ in accepted))
+                self.cost.observe_verify(seconds, new_tokens / len(spec))
+                self._wf_tokens([(req, n_land) for req, _, n_land in accepted],
+                                t1, "verify")
+                self._booked(since)
+                for req, n_acc, _ in accepted:
+                    row = out[req.slot]
                     for j in range(n_acc + 1):
                         if req not in self._active:
                             break  # finished (eos / budget) mid-block
                         req.cur_len += 1
                         self._kv.seq_lens[req.slot] = req.cur_len
                         self._append_token(req, int(row[j]))
-                        new_tokens += 1
                     if req in self._active:
                         # roll back pages granted for rejected draft positions
                         self._kv.trim(req.slot, req.cur_len)
-                self.metrics.record_verify_step(
-                    len(spec), S, seconds, new_tokens,
-                    drafts_proposed=len(spec) * K,
-                    drafts_accepted=drafts_accepted)
-                self.cost.observe_verify(seconds, new_tokens / len(spec))
             verify_span.set(slots=len(spec), accepted=new_tokens,
                             seconds=seconds)
         return True

@@ -310,14 +310,11 @@ class DecodeMetrics:
         self._lock = locks.Lock("serving.decode_metrics")
         self.engine_label = engine_label or f"decode{next(_ENGINE_SEQ)}"
         self._labels = {"engine": self.engine_label}
+        self._cls_labels: Dict[str, Dict[str, str]] = {}
         reg = obs_metrics.default_registry()
         reg.histogram(
             "serving.decode.step_seconds",
             help="Wall time of one jitted decode iteration (all slots).",
-            buckets=_LATENCY_BUCKETS)
-        reg.histogram(
-            "serving.decode.prefill_chunk_seconds",
-            help="Wall time of one prefill chunk.",
             buckets=_LATENCY_BUCKETS)
         reg.histogram(
             "serving.decode.batch_occupancy",
@@ -508,12 +505,13 @@ class DecodeMetrics:
                      active / max(max_slots, 1), labels=self._labels)
 
     def record_prefill_chunk(self, seconds: float) -> None:
+        """One chunk enqueued. ``seconds`` is the enqueue's, not the
+        chunk's (nothing waits for it): a reader that taps this call keeps
+        it, the registry counts the chunk and keeps no histogram of it."""
         with self._lock:
             self.prefill_chunks_total += 1
         prof.inc_counter("serving.decode.prefill_chunks_total",
                          labels=self._labels)
-        prof.observe("serving.decode.prefill_chunk_seconds", seconds,
-                     labels=self._labels)
 
     def record_response(self, latency_s: float) -> None:
         with self._lock:
@@ -525,13 +523,22 @@ class DecodeMetrics:
 
     # -- token-latency waterfall rollup (ttft/tpot families) -----------------
 
+    def _labels_of(self, cls: Optional[str]) -> Dict[str, str]:
+        """The engine's labels with the request class: one dict a class,
+        built once an engine (a turn books a class's samples every 5 ms)."""
+        cls = cls or "default"
+        labels = self._cls_labels.get(cls)
+        if labels is None:
+            labels = self._cls_labels[cls] = {**self._labels, "cls": cls}
+        return labels
+
     def record_ttft(self, seconds: float, cls: str = "default") -> None:
         """One request's time-to-first-token (booked by the waterfall on
         the iteration that produced the first generated token)."""
         with self._lock:
             self.ttft_observed_total += 1
         prof.observe("serving.decode.ttft_seconds", seconds,
-                     labels={**self._labels, "cls": cls or "default"})
+                     labels=self._labels_of(cls))
 
     def record_tpot(self, samples, cls: str = "default") -> None:
         """Book per-token latency samples — one per generated token after
@@ -541,9 +548,8 @@ class DecodeMetrics:
             return
         with self._lock:
             self.tpot_samples_total += len(samples)
-        labels = {**self._labels, "cls": cls or "default"}
-        for s in samples:
-            prof.observe("serving.decode.tpot_seconds", s, labels=labels)
+        obs_metrics.default_registry().observe_many(
+            "serving.decode.tpot_seconds", samples, labels=self._labels_of(cls))
 
     # -- speculative decoding (serving.decode.spec_* families) ---------------
 

@@ -28,6 +28,13 @@ clock. A retroactive ``record_span`` cannot: its ends are already past.
 
 With tracing disabled ``start_span``/``start_trace`` hand out one shared
 no-op scope: no ids, no ``Span``, no annotation, no lock.
+
+A scoped span is opened ten times in a serving turn of 5 ms, so what it
+costs is counted: ids come from one ``os.urandom`` draw a thread and a
+counter on it, the thread's name and id are read once a thread, and a
+finished span is appended to the store with no lock (``deque.append`` is
+atomic; readers take a ``list()`` of the store, which is too).
+``tools/span_report.py --span-cost`` times it.
 """
 
 from __future__ import annotations
@@ -36,11 +43,11 @@ import os
 import threading
 import time
 from collections import deque
+from time import perf_counter
 from typing import Dict, Iterable, List, Optional
 
 import jax
 
-from paddle_tpu.core import locks
 from paddle_tpu.core import profiler as prof
 from paddle_tpu.core.config import flags
 from paddle_tpu.core.enforce import enforce
@@ -105,22 +112,13 @@ class SpanContext:
         self.parent_id = parent_id
 
     @classmethod
-    def _minted(cls, trace_id: str, span_id: str,
-                parent_id: Optional[str]) -> "SpanContext":
-        # ids this module made itself need no check: a scoped span mints
-        # one on every hot-loop pass
-        ctx = object.__new__(cls)
-        ctx.trace_id, ctx.span_id, ctx.parent_id = trace_id, span_id, parent_id
-        return ctx
-
-    @classmethod
     def new_trace(cls) -> "SpanContext":
         """A fresh root context (no parent)."""
-        return cls._minted(os.urandom(16).hex(), os.urandom(8).hex(), None)
+        return _mint(_thread(), None)
 
     def child(self) -> "SpanContext":
         """A new context in the same trace, parented to this span."""
-        return self._minted(self.trace_id, os.urandom(8).hex(), self.span_id)
+        return _mint(_thread(), self)
 
     def to_traceparent(self) -> str:
         """W3C trace-context ``traceparent`` header value
@@ -182,13 +180,16 @@ class Span:
 
     def __init__(self, name: str, context: SpanContext, t0_us: float,
                  attrs: Optional[dict] = None):
+        """``attrs`` is kept as handed, not copied: every caller in this
+        module hands the fresh dict its own ``**attrs`` made."""
         self.name = name
         self.context = context
         self.t0_us = t0_us
         self.t1_us: Optional[float] = None
-        self.attrs = dict(attrs) if attrs else {}
-        self.tid = threading.get_ident()
-        self.thread_name = threading.current_thread().name
+        self.attrs = attrs if attrs is not None else {}
+        th = _thread()
+        self.tid = th.tid
+        self.thread_name = th.name
         self._cancelled = False
 
     @property
@@ -215,13 +216,68 @@ class Span:
 # Store + thread-local span stack
 # --------------------------------------------------------------------------
 
-_lock = locks.Lock("tracing.spans")
+# Finished spans, oldest first. No lock: ``append``, ``clear`` and
+# ``list(_store)`` are each one call into C, atomic under the interpreter
+# lock; a reader works on such a list, never on the deque itself (a deque
+# that grows under a Python-level iteration raises).
 _store: "deque[Span]" = deque(maxlen=max(1, int(flags().trace_max_spans)))
 _enabled = True
 _tls = threading.local()
 # Open spans across ALL threads, keyed by id(span) — the watchdog dumps this
 # on a stall to show what every thread was inside when it wedged.
 _open: Dict[int, Span] = {}
+_MASK64 = (1 << 64) - 1
+_MASK128 = (1 << 128) - 1
+
+
+class _Thread:
+    """What a thread needs to open a span, made on its first one: its open
+    spans innermost last, its id and name (read once: a thread renamed
+    later keeps the name its first span saw), and where its ids stand.
+    One ``os.urandom`` draw gives the thread a 128-bit and a 64-bit
+    starting point; every id after that is the one before plus one, so ids
+    are unique on a thread by construction and across threads and
+    processes as surely as two random 64-bit numbers lie more than a run's
+    spans apart."""
+
+    __slots__ = ("stack", "tid", "name", "_trace", "_span")
+
+    def __init__(self):
+        self.stack: List[Span] = []
+        self.tid = threading.get_ident()
+        self.name = threading.current_thread().name
+        seed = os.urandom(24)
+        self._trace = int.from_bytes(seed[:16], "big")
+        self._span = int.from_bytes(seed[16:], "big")
+
+    def next_trace_id(self) -> str:
+        self._trace = (self._trace + 1) & _MASK128 or 1  # never all zeros
+        return "%032x" % self._trace
+
+    def next_span_id(self) -> str:
+        self._span = (self._span + 1) & _MASK64 or 1
+        return "%016x" % self._span
+
+
+def _thread() -> _Thread:
+    try:
+        return _tls.thread
+    except AttributeError:
+        th = _tls.thread = _Thread()
+        return th
+
+
+def _mint(th: _Thread, parent: Optional[SpanContext]) -> SpanContext:
+    """A context of ``th``'s next ids: a child of ``parent``, or a new
+    trace's root. Ids this module made itself need no check, so not
+    through ``SpanContext.__init__``: a hot loop mints one a span."""
+    ctx = object.__new__(SpanContext)
+    if parent is None:
+        ctx.trace_id, ctx.parent_id = th.next_trace_id(), None
+    else:
+        ctx.trace_id, ctx.parent_id = parent.trace_id, parent.span_id
+    ctx.span_id = th.next_span_id()
+    return ctx
 
 
 def tracing_enabled() -> bool:
@@ -241,20 +297,12 @@ def disable_tracing() -> None:
 def reset_tracing() -> None:
     """Clear the span store (open spans in flight are unaffected — they
     simply land in the fresh store when they close)."""
-    with _lock:
-        _store.clear()
-
-
-def _stack() -> List[Span]:
-    st = getattr(_tls, "stack", None)
-    if st is None:
-        st = _tls.stack = []
-    return st
+    _store.clear()
 
 
 def current_context() -> Optional[SpanContext]:
     """The SpanContext of this thread's innermost open span, or None."""
-    st = getattr(_tls, "stack", None)
+    st = _thread().stack
     return st[-1].context if st else None
 
 
@@ -271,10 +319,10 @@ def _resolve_parent(parent) -> Optional[SpanContext]:
 
 
 def _commit(span: Span) -> None:
-    with _lock:
-        if len(_store) == _store.maxlen:
-            prof.inc_counter("tracing.spans_evicted")
-        _store.append(span)
+    store = _store
+    if len(store) == store.maxlen:
+        prof.inc_counter("tracing.spans_evicted")
+    store.append(span)
 
 
 class _SpanScope:
@@ -289,21 +337,25 @@ class _SpanScope:
 
     def __enter__(self) -> Span:
         self._annotation.__enter__()
-        _stack().append(self._span)
-        _open[id(self._span)] = self._span
-        return self._span
+        span = self._span
+        _thread().stack.append(span)
+        _open[id(span)] = span
+        return span
 
     def __exit__(self, exc_type, exc, tb):
         span = self._span
-        st = _stack()
-        # Tolerate exotic unwind orders (generators finalized late): remove
-        # this span wherever it sits rather than blindly popping the top.
-        for i in range(len(st) - 1, -1, -1):
-            if st[i] is span:
-                del st[i]
-                break
+        st = _thread().stack
+        if st and st[-1] is span:
+            st.pop()
+        else:
+            # Tolerate exotic unwind orders (generators finalized late):
+            # remove this span wherever it sits.
+            for i in range(len(st) - 2, -1, -1):
+                if st[i] is span:
+                    del st[i]
+                    break
         _open.pop(id(span), None)
-        span.t1_us = time.perf_counter() * 1e6
+        span.t1_us = perf_counter() * 1e6
         if exc_type is not None:
             span.attrs.setdefault("status", "error")
             span.attrs.setdefault("exception", exc_type.__name__)
@@ -346,9 +398,12 @@ def start_span(name: str, parent=None, **attrs):
     as ``with start_span("trainer.h2d") as sp: ...``."""
     if not _enabled:
         return _NOOP_SCOPE
-    pctx = _resolve_parent(parent)
-    ctx = pctx.child() if pctx is not None else SpanContext.new_trace()
-    return _SpanScope(Span(name, ctx, time.perf_counter() * 1e6, attrs))
+    th = _thread()
+    if parent is None:
+        pctx = th.stack[-1].context if th.stack else None
+    else:
+        pctx = _resolve_parent(parent)
+    return _SpanScope(Span(name, _mint(th, pctx), perf_counter() * 1e6, attrs))
 
 
 def start_trace(name: str, **attrs):
@@ -356,7 +411,7 @@ def start_trace(name: str, **attrs):
     open on this thread — one trace per training step / per request."""
     if not _enabled:
         return _NOOP_SCOPE
-    return _SpanScope(Span(name, SpanContext.new_trace(), time.perf_counter() * 1e6, attrs))
+    return _SpanScope(Span(name, _mint(_thread(), None), perf_counter() * 1e6, attrs))
 
 
 def record_span(
@@ -389,14 +444,12 @@ def record_span(
 
 def spans() -> List[Span]:
     """Snapshot of the span store (oldest first)."""
-    with _lock:
-        return list(_store)
+    return list(_store)
 
 
 def spans_for_trace(trace_id: str) -> List[Span]:
     """All stored spans of one trace, start-time ordered."""
-    with _lock:
-        got = [s for s in _store if s.context.trace_id == trace_id]
+    got = [s for s in list(_store) if s.context.trace_id == trace_id]
     got.sort(key=lambda s: s.t0_us)
     return got
 
@@ -411,10 +464,9 @@ def phase_totals(names: Iterable[str]) -> Dict[str, float]:
     per-phase breakdown bench.py reports (data_wait/h2d/compile/step)."""
     want = set(names)
     totals = {n: 0.0 for n in want}
-    with _lock:
-        for s in _store:
-            if s.name in want and s.t1_us is not None:
-                totals[s.name] += (s.t1_us - s.t0_us) / 1e6
+    for s in list(_store):
+        if s.name in want and s.t1_us is not None:
+            totals[s.name] += (s.t1_us - s.t0_us) / 1e6
     return totals
 
 

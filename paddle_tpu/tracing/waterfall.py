@@ -13,9 +13,9 @@ into the other, per request:
   produce one sample per generated token and stay comparable;
 - **jitter** — the population stdev of a request's TPOT samples.
 
-The engine calls :func:`start` at submit, :func:`on_tokens` once per
-iteration that appended tokens, and :func:`finish` at terminal state;
-:func:`on_tokens` returns the booked ``(ttft_s, tpot_samples)`` so the
+The engine calls :func:`start` at submit, :func:`on_tokens_many` once per
+iteration with the tokens of every request it landed, and :func:`finish` at
+terminal state; :func:`on_tokens` (one request's) returns the booked ``(ttft_s, tpot_samples)`` so the
 caller can feed the ``serving.decode.ttft_seconds`` /
 ``serving.decode.tpot_seconds`` histogram families without re-deriving
 them. Finished waterfall docs stay retrievable (bounded, oldest evicted)
@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import math
 from collections import OrderedDict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from paddle_tpu.core import locks
 
@@ -37,6 +37,7 @@ __all__ = [
     "MAX_DOCS",
     "start",
     "on_tokens",
+    "on_tokens_many",
     "finish",
     "doc",
     "rids",
@@ -82,6 +83,32 @@ def start(rid: str, t_submit_pc: float, **meta) -> None:
                           {k: str(v) for k, v in meta.items() if v})
 
 
+def _book(d: Optional[_Doc], t_pc: float, n: int,
+          phase: str) -> Tuple[Optional[float], List[float]]:
+    # under _lock
+    if n <= 0 or d is None or d.finished:
+        return None, []
+    ttft: Optional[float] = None
+    samples: List[float] = []
+    remaining = n
+    if d.t_first_token_pc is None:
+        d.t_first_token_pc = t_pc
+        ttft = d.ttft_s = max(0.0, t_pc - d.t_submit_pc)
+        remaining -= 1
+    if remaining > 0:
+        # dt since the previous token-landing iteration, split evenly
+        # over this iteration's tokens (the speculation contract)
+        dt = max(0.0, t_pc - (d.t_last_token_pc
+                              if d.t_last_token_pc is not None
+                              else d.t_first_token_pc))
+        samples = [dt / remaining] * remaining
+        d.tpot_s.extend(samples)
+    d.t_last_token_pc = t_pc
+    d.tokens += n
+    d.events.append({"t_pc": t_pc, "n": n, "phase": phase})
+    return ttft, samples
+
+
 def on_tokens(rid: str, t_pc: float, n: int,
               phase: str = "decode") -> Tuple[Optional[float], List[float]]:
     """Book ``n`` tokens landing at ``t_pc`` (one engine iteration).
@@ -89,32 +116,18 @@ def on_tokens(rid: str, t_pc: float, n: int,
     the iteration that produced the request's first token; every token
     after the first yields exactly one TPOT sample (``dt/n`` each for an
     ``n``-token iteration). Unknown rids are ignored."""
-    if n <= 0:
-        return None, []
+    return on_tokens_many([(rid, n)], t_pc, phase)[0]
+
+
+def on_tokens_many(rows: Sequence[Tuple[str, int]], t_pc: float,
+                   phase: str = "decode"
+                   ) -> List[Tuple[Optional[float], List[float]]]:
+    """:func:`on_tokens` for each ``(rid, n)`` of one engine iteration,
+    all landing at ``t_pc``, under one taking of the lock: what each would
+    have returned, in order."""
+    t_pc = float(t_pc)
     with _lock:
-        d = _docs.get(rid)
-        if d is None or d.finished:
-            return None, []
-        t_pc = float(t_pc)
-        ttft: Optional[float] = None
-        samples: List[float] = []
-        remaining = n
-        if d.t_first_token_pc is None:
-            d.t_first_token_pc = t_pc
-            ttft = d.ttft_s = max(0.0, t_pc - d.t_submit_pc)
-            remaining -= 1
-        if remaining > 0:
-            # dt since the previous token-landing iteration, split evenly
-            # over this iteration's tokens (the speculation contract)
-            dt = max(0.0, t_pc - (d.t_last_token_pc
-                                  if d.t_last_token_pc is not None
-                                  else d.t_first_token_pc))
-            samples = [dt / remaining] * remaining
-            d.tpot_s.extend(samples)
-        d.t_last_token_pc = t_pc
-        d.tokens += n
-        d.events.append({"t_pc": t_pc, "n": n, "phase": phase})
-        return ttft, samples
+        return [_book(_docs.get(rid), t_pc, n, phase) for rid, n in rows]
 
 
 def finish(rid: str, t_pc: float, reason: str) -> None:

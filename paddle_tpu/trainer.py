@@ -461,8 +461,6 @@ class Trainer:
         prof.observe("trainer.step_seconds", dt)
         prof.set_gauge("trainer.examples_per_sec", eps)
         prof.set_gauge("trainer.examples_per_sec_ema", self._ema_eps)
-        if loss is not None:
-            prof.set_gauge("trainer.loss", loss)
         self.goodput.record_good(dt)
         prof.set_gauge("trainer.goodput_frac", self.goodput.goodput_frac())
         if self._step_flops is None:

@@ -402,3 +402,76 @@ def test_explicit_engine_label_respected():
         eng.close(timeout=30)
 
 
+
+
+# ---- remembered child keys, the name check at a child's making (ISSUE 37) --
+
+
+def _write_all(r, labels_of):
+    r.histogram("unit.seconds", help="a histogram", buckets=(0.01, 0.1, 1.0))
+    for i in range(40):
+        r.inc("unit.calls_total", 1 + i % 3, labels=labels_of("decode0", "batch"))
+        r.inc("unit.calls_total", 2, labels=labels_of("decode1", "interactive"))
+        r.set("unit.level", i, labels=labels_of("decode0", "batch"))
+        r.observe("unit.seconds", 0.004 * (i + 1), labels=labels_of("decode0", "batch"))
+    r.observe_many("unit.seconds", [0.5, 5.0, 0.05], labels=labels_of("decode1", "batch"))
+    r.inc("unit.bare_total")
+
+
+def test_a_remembered_child_key_gives_the_same_families_buckets_and_text():
+    """A caller that hands one dict call after call (the registry remembers
+    its child key on the dict's items) against one that builds a new dict,
+    in the other order, every call: the same registry to the last digit."""
+    kept = {}
+
+    def same_dict(engine, cls):
+        return kept.setdefault((engine, cls), {"engine": engine, "cls": cls})
+
+    remembered, fresh = MetricRegistry(), MetricRegistry()
+    _write_all(remembered, same_dict)
+    _write_all(fresh, lambda engine, cls: {"cls": cls, "engine": engine})
+    assert remembered._keys and render_text(remembered) == render_text(fresh)
+    for a, b in zip(remembered.collect(), fresh.collect()):
+        assert (a.name, a.kind, a.help, a.buckets, a.samples) == \
+               (b.name, b.kind, b.help, b.buckets, b.samples)
+    snap = remembered.histogram_snapshot("unit.seconds", {"cls": "batch", "engine": "decode1"})
+    assert snap["count"] == 3 and snap["cumulative"] == [0, 1, 2] and snap["sum"] == 5.55
+    assert remembered.get("unit.calls_total", {"engine": "decode1", "cls": "interactive"}) == 80
+    assert remembered.flat_gauges() == {"unit.level": 39.0}
+
+
+def test_a_labels_dict_that_changes_between_writes_goes_to_the_child_it_names():
+    r = MetricRegistry()
+    labels = {"engine": "a"}
+    r.inc("unit.calls_total", labels=labels)
+    labels["engine"] = "b"  # remembered on the items, never on the dict's id
+    r.inc("unit.calls_total", 5, labels=labels)
+    r.inc("unit.calls_total", labels={"engine": 7})  # a value that is no str is not remembered
+    r.inc("unit.calls_total", labels={"engine": "7"})
+    assert {k: v for k, v in r.collect()[0].samples} == {
+        (("engine", "a"),): 1.0, (("engine", "b"),): 5.0, (("engine", "7"),): 2.0}
+    # the family's label names are held to a child when it is first made
+    with pytest.raises(EnforceError, match="inconsistent label names"):
+        r.inc("unit.calls_total", labels={"replica": "a"})
+    with pytest.raises(EnforceError, match="already registered as counter"):
+        r.set("unit.calls_total", 1.0, labels={"engine": "a"})
+
+
+def test_a_subscriber_added_after_a_write_hears_the_next_and_many_is_each():
+    r = MetricRegistry()
+    labels = {"engine": "decode0"}
+    r.inc("unit.calls_total", labels=labels)
+    heard = []
+    r.subscribe(lambda name, kind, value, lab: heard.append((name, kind, value, lab)))
+    r.inc("unit.calls_total", 2, labels=labels)
+    r.set("unit.level", 3, labels=labels)
+    r.observe_many("unit.seconds", [0.25, 0.5], labels=labels)
+    assert heard == [("unit.calls_total", "counter", 2, labels),
+                     ("unit.level", "gauge", 3.0, labels),
+                     ("unit.seconds", "histogram", 0.25, labels),
+                     ("unit.seconds", "histogram", 0.5, labels)]
+    one_by_one = MetricRegistry()
+    for v in (0.25, 0.5):
+        one_by_one.observe("unit.seconds", v, labels=labels)
+    assert one_by_one.histogram_snapshot("unit.seconds", labels) == \
+        r.histogram_snapshot("unit.seconds", labels)

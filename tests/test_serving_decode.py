@@ -193,6 +193,63 @@ def test_chunks_go_out_behind_the_step_and_first_tokens_behind_the_next(lm):
         assert "chunk" in rest[:this_step]
 
 
+@pytest.mark.parametrize("spec_tokens", [0, 3], ids=["plain", "verify"])
+def test_gathered_bookings_keep_every_sample_every_token_and_every_step(lm, spec_tokens):
+    """A turn books its tokens in one stretch before it appends them: each
+    request's waterfall still holds every generated token once (the one it
+    finishes on too), one TTFT and ``tokens - 1`` TPOT samples, the labelled
+    histograms count what the waterfalls hold, a tap on the instance's
+    ``record_step`` still sees every step, and the tokens are generate()'s."""
+    from paddle_tpu.tracing import waterfall
+
+    kw = dict(draft_variables=lm.variables, draft_cfg=lm.cfg) if spec_tokens else {}
+    engine = DecodeEngine(lm.variables, lm.cfg, decode=DecodeConfig(
+        max_slots=3, page_size=4, max_context=40, prefill_chunk=8, num_pages=14,
+        spec_tokens=spec_tokens), **kw)
+    tapped = []
+    record_step = engine.metrics.record_step
+
+    def tap(active, max_slots, seconds, new_tokens):
+        tapped.append(new_tokens)
+        return record_step(active, max_slots, seconds, new_tokens)
+
+    engine.metrics.record_step = tap
+    try:
+        known = set(waterfall.rids())
+        handles = [engine.submit(p, n) for p, n, _ in lm.cases]
+        rids = [r for r in waterfall.rids() if r not in known]
+        outs = [h.result(timeout=300) for h in handles]
+        snap = engine.metrics.snapshot()
+    finally:
+        engine.close()
+    engine.kv.assert_no_leaks()
+    assert len(rids) == len(lm.cases)
+    for (prompt, n, ref), out, rid in zip(lm.cases, outs, rids):
+        assert np.array_equal(out.tokens, ref)
+        doc = waterfall.doc(rid)
+        assert doc["finished"] and doc["reason"] == "length" and doc["ttft_s"] >= 0.0
+        assert doc["tokens"] == n and len(doc["tpot_s"]) == n - 1
+        landings = [e["n"] for e in doc["events"] if e["n"] > 0]
+        # the token a request finishes on is booked before the append that
+        # finishes it: the last landing comes right before the finish
+        assert sum(landings) == n and doc["events"][-1]["phase"] == "finish"
+        assert doc["events"][-2]["n"] > 0
+    n_tokens = sum(n for _, n, _ in lm.cases)
+    assert snap["ttft_observed_total"] == len(lm.cases)
+    assert snap["tpot_samples_total"] == n_tokens - len(lm.cases)
+    reg = obs_metrics.default_registry()
+    labels = {"engine": engine.metrics.engine_label, "cls": "interactive"}
+    assert reg.histogram_snapshot("serving.decode.tpot_seconds", labels)["count"] == \
+        n_tokens - len(lm.cases)
+    assert reg.histogram_snapshot("serving.decode.ttft_seconds", labels)["count"] == len(lm.cases)
+    assert len(tapped) == snap["steps_total"] and sum(tapped) == snap["tokens_total"]
+    # a verify step counts the tokens it lands before it appends them
+    assert snap["tokens_total"] <= n_tokens
+    assert reg.histogram_snapshot("serving.decode.prefill_chunk_seconds", {
+        "engine": engine.metrics.engine_label}) is None
+    assert snap["prefill_chunks_total"] >= len(lm.cases)
+
+
 def test_cache_dtype_bf16(lm):
     """Satellite: cache_dtype flows ServingConfig -> engine, and the
     DecodeConfig override wins; decode still runs end to end on a bf16

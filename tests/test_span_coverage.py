@@ -229,6 +229,85 @@ def test_engine_iteration_spans_and_the_seconds_the_metrics_were_handed(lm):
     assert len(request_chunks) == len(chunks)
 
 
+def _turns(loop):
+    """[(the step span before, this turn's step span, its model step)] of the
+    loop's turns that held a model step and have a turn before them."""
+    steps = sorted((s for s in loop if s.name == "serving.decode.step"), key=lambda s: s.t1_us)
+    model = {s.context.parent_id: s for s in loop if s.name == "serving.decode.model_step"}
+    return [(a, b, model[b.context.span_id]) for a, b in zip(steps, steps[1:])
+            if b.context.span_id in model]
+
+
+def test_a_turns_step_span_carries_its_cpu_and_its_bookings_seconds(lm):
+    """The span that ends a turn says what the turn cost the loop thread:
+    ``cpu_seconds`` (its CPU clock since the step span before closed) and
+    ``telemetry_seconds`` (the stretches the engine's bookings are gathered
+    into). Both lie inside the turn; the bookings inside its host part."""
+    engine = _engine(lm)
+    handed = []
+    record_step = engine.metrics.record_step
+
+    def tapped(active, max_slots, seconds, new_tokens):
+        handed.append(seconds)
+        return record_step(active, max_slots, seconds, new_tokens)
+
+    engine.metrics.record_step = tapped
+    try:
+        prompts = [np.arange(1, 12, dtype=np.int32), np.arange(3, 8, dtype=np.int32)]
+        for h in [engine.submit(p, 8) for p in prompts]:
+            h.result(timeout=300)
+    finally:
+        engine.close()
+    loop = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    turns = _turns(loop)
+    assert len(turns) >= 6
+    # the tree under a turn is what it was: the very float the metrics got
+    assert [m.attrs["seconds"] for _, _, m in turns] == handed[-len(turns):]
+    waits = [s for s in loop if s.name.endswith(".wait")]
+    for before, step, model in turns:
+        cpu, booked = step.attrs["cpu_seconds"], step.attrs["telemetry_seconds"]
+        turn = (step.t1_us - before.t1_us) / 1e6
+        waited = sum(w.t1_us - w.t0_us for w in waits
+                     if before.t1_us <= w.t0_us and w.t1_us <= step.t1_us) / 1e6
+        assert 0.0 < booked <= turn - waited
+        assert 0.0 <= cpu <= turn + 0.005  # two clocks, read a line apart
+        assert [c.name.rsplit(".", 1)[1] for c in _children(loop, model)
+                if c.name.startswith("serving.decode.model_step.")] == [
+            "pack", "dispatch", "wait", "land"]
+    # every committed step span after the engine's first has the account, a
+    # pass that only enqueued a chunk too; the first has no turn before it
+    steps = sorted((s for s in loop if s.name == "serving.decode.step"), key=lambda s: s.t1_us)
+    assert "cpu_seconds" not in steps[0].attrs
+    assert all({"cpu_seconds", "telemetry_seconds"} <= set(s.attrs) for s in steps[1:])
+
+
+def test_with_tracing_disabled_the_turn_reads_neither_clock(lm, monkeypatch):
+    from paddle_tpu.serving import decode as decode_mod
+
+    reads = []
+    real = decode_mod.time.thread_time
+    monkeypatch.setattr(decode_mod.time, "thread_time", lambda: reads.append(1) or real())
+    tracing.disable_tracing()
+    engine = _engine(lm)
+    try:
+        out = engine.submit(np.arange(1, 12, dtype=np.int32), 6).result(timeout=300)
+        assert len(out.tokens) == 6
+        assert engine._clock() is None
+    finally:
+        engine.close()
+    assert reads == [] and engine._turn_telemetry == 0.0 and engine._turn_cpu0 is None
+    assert tracing.spans() == []
+    # switched on in mid-run, the first turn only sets the clock: no account
+    # is ever read against a stretch that was not timed
+    tracing.enable_tracing()
+    engine = _engine(lm)
+    try:
+        engine.submit(np.arange(1, 12, dtype=np.int32), 6).result(timeout=300)
+    finally:
+        engine.close()
+    assert reads and _turns(tracing.spans_for_trace(engine._loop_trace.trace_id))
+
+
 def test_a_paged_step_carries_the_pages_its_slots_hold_and_the_table_it_was_handed(lm):
     """What the gather reads and what is live, on the step's span: the sum
     over the decoding slots of ``pos // page_size + 1``, the ``S * P`` pages
@@ -417,6 +496,49 @@ def test_loop_turns_reads_a_turn_and_its_host_part_from_the_spans(lm):
     assert len(steps) - 1 <= loop["turns"] <= len(steps)  # the first has no turn before it
     assert 0 < loop["host_ms_p50"] < loop["turn_ms_p50"] <= loop["turn_ms_p95"]
     assert _span_report().loop_turns([s for s in spans if s.name != "serving.decode.step"]) == {}
+
+
+def test_loop_turns_shares_a_turn_out_to_its_phases_and_reads_its_account(lm):
+    """``loop:``'s phases are the turn's moments under the innermost span, so
+    they add up to the turn; ``dispatch_ms`` is the step's dispatch and the
+    chunk's enqueue; CPU, off-CPU and bookings come from the step span."""
+    engine = _engine(lm)
+    try:
+        for h in [engine.submit(np.arange(1, 1 + n, dtype=np.int32), 8) for n in (11, 5)]:
+            h.result(timeout=300)
+    finally:
+        engine.close()
+    tool = _span_report()
+    spans = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    loop = tool.loop_turns(spans)
+    assert set(loop["phase_ms"]) == {name for name, _ in tool.LOOP_PHASES}
+    assert all(0.0 <= p50 <= p95 for p50, p95 in loop["phase_ms"].values())
+    one = tool.loop_turns([s for s in spans if s.t1_us <= sorted(
+        (t for t in spans if t.name == "serving.decode.step"), key=lambda t: t.t1_us)[2].t1_us])
+    assert one["turns"] == 1  # the engine's first turn has none before it, the second no account
+    assert sum(p50 for p50, _ in one["phase_ms"].values()) == pytest.approx(one["turn_ms_p50"])
+    assert one["dispatch_ms_p50"] == pytest.approx(
+        one["phase_ms"]["dispatch"][0] + one["phase_ms"]["chunk_enqueue"][0])
+    assert 0.0 < loop["telemetry_ms_p50"] <= loop["host_ms_p95"]
+    assert 0.0 <= loop["offcpu_ms"] <= loop["host_ms_p50"] and loop["spans_p50"] >= 8
+    assert loop["cpu_ms"] > 0.0 and loop["cpu_share"] > 0.0
+    assert loop["offcpu_ms"] == pytest.approx(
+        loop["host_ms_p50"] * max(0.0, 1.0 - loop["cpu_share"]))
+    # a program from before the account: the phases still read, the rest is absent
+    for s in spans:
+        s.attrs.pop("cpu_seconds", None)
+    assert "cpu_ms" not in tool.loop_turns(spans) and "phase_ms" in tool.loop_turns(spans)
+
+
+def test_span_cost_times_a_span_on_and_off_and_the_three_writes():
+    """What ``--span-cost`` prints (the costs are the chip runs' to report:
+    no number is held to anything here), and that it leaves tracing as it
+    found it."""
+    cost = _span_report().span_cost(calls=200, rounds=2)
+    assert set(cost) == {"calls", "rounds", "span_on_us", "span_off_us", "counter_us",
+                         "gauge_us", "histogram_us"}
+    assert all(v > 0 for v in cost.values())
+    assert tracing.tracing_enabled() and tracing.spans() == []
 
 
 def test_the_tools_idle_gaps_are_the_harness_s():

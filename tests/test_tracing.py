@@ -525,3 +525,115 @@ def test_watchdog_summarizes_open_spans():
     with tracing.start_trace("unit.wedged"):
         summary = StepWatchdog._active_span_summary()
     assert any(s.startswith("unit.wedged@") for s in summary)
+
+
+# ---- ids, the thread's state and the store without a lock (ISSUE 37) -------
+
+
+def _on_threads(n, fn):
+    out, errors = [None] * n, []
+
+    def run(i):
+        try:
+            out[i] = fn(i)
+        except BaseException as e:  # surface in the test's own thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=(i,), name=f"minter-{i}") for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors, errors
+    return out
+
+
+def test_a_hundred_thousand_ids_on_four_threads_are_unique_well_formed_and_round_trip():
+    """One ``os.urandom`` draw a thread and a counter on it: every trace id
+    is 32 and every span id 16 lowercase hex digits, none repeats within a
+    thread or across threads, and each context survives ``traceparent``."""
+    def mint(_):
+        got = []
+        for _ in range(6250):
+            root = tracing.SpanContext.new_trace()
+            kids = [root.child(), root.child(), root.child()]
+            assert all(k.trace_id == root.trace_id and k.parent_id == root.span_id for k in kids)
+            got += [root] + kids
+        return got
+
+    minted = [ctx for got in _on_threads(4, mint) for ctx in got]
+    assert len(minted) == 100_000
+    assert len({c.span_id for c in minted}) == 100_000
+    assert len({c.trace_id for c in minted}) == 25_000
+    hexdigits = set("0123456789abcdef")
+    for c in minted:
+        assert len(c.trace_id) == 32 and len(c.span_id) == 16
+        assert set(c.trace_id) <= hexdigits and set(c.span_id) <= hexdigits
+        back = tracing.SpanContext.from_traceparent(c.to_traceparent())
+        assert (back.trace_id, back.span_id) == (c.trace_id, c.span_id)
+
+
+def test_a_thread_draws_from_urandom_at_most_once(monkeypatch):
+    draws = []
+    real = trace_ctx.os.urandom
+    monkeypatch.setattr(trace_ctx.os, "urandom", lambda n: draws.append(n) or real(n))
+
+    def spans(_):
+        before = len(draws)
+        with tracing.start_trace("unit.root") as root:
+            for _ in range(50):
+                with tracing.start_span("unit.child"):
+                    pass
+        tracing.record_span("unit.after", 1.0, 2.0, parent=root)
+        with tracing.start_trace("unit.second_root"):
+            pass
+        return len(draws) - before
+
+    assert _on_threads(2, spans) == [1, 1]
+    assert spans(0) <= 1  # this thread may have drawn in an earlier test
+
+
+def test_a_span_carries_the_name_and_id_of_the_thread_that_opened_it():
+    def one(i):
+        with tracing.start_trace("unit.named") as sp:
+            pass
+        return sp.thread_name, sp.tid == threading.get_ident()
+
+    assert _on_threads(3, one) == [(f"minter-{i}", True) for i in range(3)]
+
+
+def test_the_store_is_read_while_other_threads_commit_to_it():
+    """No lock around the store: ``spans``, ``spans_for_trace`` and
+    ``phase_totals`` work on a ``list()`` of it, so a deque that grows under
+    them cannot raise, and every committed span is there at the end."""
+    stop = threading.Event()
+    roots = []
+
+    def commit(i):
+        with tracing.start_trace("unit.writer") as root:
+            roots.append(root)
+            n = 0
+            while not stop.is_set() and n < 20000:
+                with tracing.start_span("unit.written"):
+                    n += 1
+        return n
+
+    def read(_):
+        seen = 0
+        while not stop.is_set():
+            seen = max(seen, len(tracing.spans()))
+            for root in list(roots):
+                tracing.spans_for_trace(root.context.trace_id)
+            tracing.phase_totals(["unit.written"])
+            tracing.active_spans()
+            if seen > 2000:
+                stop.set()
+        return seen
+
+    out = _on_threads(3, lambda i: read(i) if i == 2 else commit(i))
+    assert out[2] > 2000
+    written = [s for s in tracing.spans() if s.name == "unit.written"]
+    assert len(written) == out[0] + out[1]
+    for root in roots:
+        assert tracing.validate_trace(tracing.spans_for_trace(root.context.trace_id)) == []
+    assert tracing.active_spans() == []

@@ -23,6 +23,17 @@ reads the ``.xplane.pb`` the harness captured, before the harness deletes it:
 ``--tracing off`` calls ``tracing.disable_tracing()`` first: the untraced
 run then measures the program without its spans (the cost of tracing is the
 difference; ``PERF.md`` has the runs). No option of the program is involved.
+
+After any run it prints ``loop:`` (:func:`loop_turns`): the serving loop's
+turn from the program's spans, phase by phase, with the thread's CPU time, its
+time off the CPU and the bookings' own share. ``--trace 0`` reads the true
+sizes; ``--trace 1`` reads what the ledger's traced runs read (the profiler's
+Python tracer inflates the host's part several times).
+
+    python tools/span_report.py --span-cost
+
+runs no model and needs no chip: it times a scoped span with tracing on and
+off and a labelled counter, gauge and histogram write (:func:`span_cost`).
 """
 
 from __future__ import annotations
@@ -219,34 +230,127 @@ def print_set_once() -> None:
         print("loop:", json.dumps(loop), flush=True)
 
 
+# a turn's phases: the name the ``loop:`` line gives each, and how its spans
+# are told (a name, or for the device waits a suffix)
+LOOP_PHASES = (
+    ("publish", "serving.decode.publish"), ("admit", "serving.decode.admit"),
+    ("pack", "serving.decode.model_step.pack"),
+    ("dispatch", "serving.decode.model_step.dispatch"),
+    ("chunk_enqueue", "serving.decode.prefill"), ("wait", ".wait"),
+    ("land", "serving.decode.model_step.land"),
+    ("model_step_self", "serving.decode.model_step (self)"),
+    ("step_self", "serving.decode.step (self)"),
+    ("between_spans", None))  # the loop's own lines from one pass's span to the next
+
+
 def loop_turns(spans) -> dict:
     """Over the serving loop's turns still in the span store that held a model
-    step: the median milliseconds from the end of the turn before to the end
-    of this one, and of that less the ``.wait`` spans inside it. What the
-    benchmark's ``loop_iteration_ms`` and ``loop_host_ms`` read in a traced
-    window, here for an untraced run too (the drain after the window is in it:
-    medians, not sums)."""
-    import bisect
+    step, each from the end of the turn before to the end of this one (the
+    drain after the window is in it: medians and 95th percentiles, not sums),
+    in milliseconds:
+
+    * ``turn_ms`` and ``host_ms`` (each ``_p50`` and ``_p95``, as all but the
+      phases), the turn and the turn less the ``.wait`` spans inside it: what
+      the benchmark's ``loop_iteration_ms`` and ``loop_host_ms`` read in a
+      traced window;
+    * ``phase_ms``, ``{phase: [median, 95th percentile]}``: every phase of
+      the turn, each moment under the innermost
+      span open on it (:func:`innermost`), so the phases add up to the turn:
+      the last turn's ``publish``, ``admit``, ``pack``, ``dispatch``, the
+      chunk's enqueue, the device waits, ``land``, what ``model_step`` and
+      ``step`` hold outside their children, and what lies between the passes'
+      spans;
+    * ``telemetry_ms`` from ``telemetry_seconds`` on the turn's
+      ``serving.decode.step`` span, the bookings' stretches: the benchmark's
+      ``loop_telemetry_ms``; ``cpu_ms``, ``cpu_share`` and ``offcpu_ms`` from
+      ``cpu_seconds`` beside it (all absent for a program from before them):
+      the loop thread's CPU time a turn as a mean (the chip's host ticks
+      ``time.thread_time()`` in steps of 10 ms, so one turn reads 0 or 10 and
+      only the sum over the turns is a measurement), that sum over the sum of
+      the host parts, and the median host part times what is left of the
+      share (floored at 0: the thread runnable and not running, or parked on
+      a lock), which is the benchmark's ``loop_offcpu_ms``;
+    * ``dispatch_ms``: ``dispatch`` plus ``chunk_enqueue``, the benchmark's
+      ``loop_dispatch_ms``; ``spans``: spans the turn committed."""
     import statistics
 
-    held = {s.context.parent_id for s in spans if s.name == "serving.decode.model_step"}
-    turns = sorted((s for s in spans if s.name == "serving.decode.step"), key=lambda s: s.t1_us)
-    waits = sorted((s.t0_us, s.t1_us - s.t0_us) for s in spans if s.name.endswith(".wait"))
-    starts = [w[0] for w in waits]
-    took, host = [], []
-    for before, turn in zip(turns, turns[1:]):
-        if turn.context.span_id in held and turn.context.trace_id == before.context.trace_id:
-            lo, hi = (bisect.bisect_left(starts, t) for t in (before.t1_us, turn.t1_us))
-            took.append((turn.t1_us - before.t1_us) / 1e3)
-            host.append(took[-1] - sum(d for _, d in waits[lo:hi]) / 1e3)
-    if not took:
+    from benchmarks import loop_spans
+
+    rows = []
+    for found in loop_spans.turns(spans):
+        turn, t0, t1, inside = found.step, found.t0_us, found.step.t1_us, found.inside
+        pieces = innermost([(s.name, s.t0_us, s.t1_us - s.t0_us) for s in inside])
+        phase = {name: sum(d for n, _, d in pieces
+                           if n == told or (told[0] == "." and n.endswith(told))) / 1e3
+                 for name, told in LOOP_PHASES if told}
+        row = {"turn_ms": (t1 - t0) / 1e3, "phase_ms": phase, "spans": float(len(inside))}
+        phase["between_spans"] = row["turn_ms"] - sum(phase.values())
+        row["host_ms"] = row["turn_ms"] - phase["wait"]
+        row["dispatch_ms"] = phase["dispatch"] + phase["chunk_enqueue"]
+        if "cpu_seconds" in turn.attrs:
+            row["cpu_ms"] = 1e3 * turn.attrs["cpu_seconds"]
+            row["telemetry_ms"] = 1e3 * turn.attrs["telemetry_seconds"]
+        rows.append(row)
+    if not rows:
         return {}
     p95 = lambda v: sorted(v)[int(0.95 * (len(v) - 1))]
-    return {"turns": len(took), "turn_ms_p50": statistics.median(took), "turn_ms_p95": p95(took),
-            "host_ms_p50": statistics.median(host), "host_ms_p95": p95(host)}
+    out = {"turns": len(rows)}
+    for key in ("turn_ms", "host_ms", "dispatch_ms", "telemetry_ms", "spans"):
+        values = [r[key] for r in rows if key in r]  # an engine's first turn has no account
+        if values:
+            out[key + "_p50"], out[key + "_p95"] = statistics.median(values), p95(values)
+    timed = [r for r in rows if "cpu_ms" in r]
+    if timed:
+        cpu, host = (sum(r[key] for r in timed) for key in ("cpu_ms", "host_ms"))
+        out["cpu_ms"], out["cpu_share"] = cpu / len(timed), cpu / host
+        out["offcpu_ms"] = out["host_ms_p50"] * max(0.0, 1.0 - cpu / host)
+    out["phase_ms"] = {name: [f([r["phase_ms"][name] for r in rows])
+                              for f in (statistics.median, p95)] for name, _ in LOOP_PHASES}
+    return out
+
+
+def span_cost(calls: int = 20000, rounds: int = 5) -> dict:
+    """Microseconds a call, the best of ``rounds`` rounds of ``calls`` calls
+    each, on this host: a scoped child span with tracing on and with tracing
+    off, and a counter, gauge and histogram write with one label as an engine
+    hands it (the same dict every call). No model, no device."""
+    from paddle_tpu import tracing
+    from paddle_tpu.core import profiler as prof
+
+    labels = {"engine": "span_cost"}
+
+    def span():
+        with tracing.start_span("tools.span_cost.child"):
+            pass
+
+    cases = {"span_on_us": span, "span_off_us": span,
+             "counter_us": lambda: prof.inc_counter("tools.span_cost.calls_total", labels=labels),
+             "gauge_us": lambda: prof.set_gauge("tools.span_cost.level", 1.0, labels=labels),
+             "histogram_us": lambda: prof.observe("tools.span_cost.seconds", 0.01, labels=labels)}
+    out = {"calls": calls, "rounds": rounds}
+    was_on = tracing.tracing_enabled()
+    try:
+        for name, call in cases.items():
+            (tracing.disable_tracing if name == "span_off_us" else tracing.enable_tracing)()
+            best = float("inf")
+            for _ in range(rounds):
+                tracing.reset_tracing()  # a store that is not full: no eviction is timed
+                with tracing.start_span("tools.span_cost"):
+                    t0 = time.perf_counter()
+                    for _ in range(calls):
+                        call()
+                    best = min(best, (time.perf_counter() - t0) / calls * 1e6)
+            out[name] = best
+    finally:
+        (tracing.enable_tracing if was_on else tracing.disable_tracing)()
+        tracing.reset_tracing()
+    return out
 
 
 def main(argv) -> int:
+    if argv == ["--span-cost"]:
+        print("span cost:", json.dumps(span_cost()), flush=True)
+        return 0
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", required=True)
