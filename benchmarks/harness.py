@@ -78,7 +78,9 @@ class Run:
             self.seconds = min(self.seconds, self.mix.get("trace_seconds", self.seconds))
         self.t_start = t_start
         self.setup_s: Optional[float] = None
-        self.trace_dir = os.path.join(scratch, "trace", self.cell["name"])
+        # a directory a process: two runs of one cell at once (the self-tests'
+        # workers) would each delete the other's trace
+        self.trace_dir = os.path.join(scratch, "trace", f"{self.cell['name']}.{os.getpid()}")
         self.trace_summary: Optional[dict] = None
         self._compiles = 0
         self.compiles_in_window: Optional[int] = None
@@ -94,14 +96,21 @@ class Run:
         jax.monitoring.register_event_duration_secs_listener(self._on_compile)
 
     def open_window(self) -> None:
-        """Set-up ends here. With ``--trace 1`` the profiler starts."""
+        """Set-up ends here. With ``--trace 1`` the profiler starts, without
+        its Python tracer: that hooks every Python call of every thread, which
+        made the host's part of a serving turn four times its untraced size
+        and left the device idle while it waited (PERF.md, PR 37 and 41). The
+        annotations (``bench.*``, the program's spans) are the runtime's own
+        trace events and stay on the host plane."""
         self.setup_s = time.perf_counter() - self.t_start
         self._compiles_at_open = self._compiles
         if self.trace:
             import jax
 
             shutil.rmtree(self.trace_dir, ignore_errors=True)
-            jax.profiler.start_trace(self.trace_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir, profiler_options=options)
 
     def close_window(self) -> None:
         """Counts the compiles the window saw; reduces the trace, if any."""

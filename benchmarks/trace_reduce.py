@@ -113,12 +113,27 @@ def idle_gaps(events: Sequence[Event], host: Sequence[Event], top: int = 10):
     """Device idle seconds by what the host was doing: each gap between
     device operations is shared out to the benchmark's host annotations by
     the time they cover of it; what none covers is the program's own host
-    code. Returns [[name, seconds], ...], largest first."""
+    code. Returns [[name, seconds], ...], largest first.
+
+    The gaps come in order of time, so the host events are sorted once and
+    swept beside them: ``reach`` holds, in order of their starts, the events
+    that began before the gap's end and have not ended by its start, which are
+    the only ones that can cover any of it. A window of a million gaps and
+    some thousand annotations reduces in a second; walking every host event
+    for every gap took nine minutes of a traced serve run (PERF.md, PR 36)."""
     merged = merge_intervals(events)
+    host = sorted(host, key=lambda e: e[1])
+    reach: List[Event] = []
+    taken = 0
     by_name: Dict[str, float] = {}
     for (_, e0), (s1, _) in zip(merged, merged[1:]):
+        while taken < len(host) and host[taken][1] < s1:
+            reach.append(host[taken])
+            taken += 1
+        if reach:
+            reach = [h for h in reach if h[1] + h[2] > e0]
         left = s1 - e0
-        for name, hs, hd in host:
+        for name, hs, hd in reach:
             cover = min(s1, hs + hd) - max(e0, hs)
             if cover > 0:
                 by_name[name] = by_name.get(name, 0.0) + cover / 1e9

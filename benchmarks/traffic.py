@@ -39,14 +39,22 @@ def closed_loop_requests(mix: dict, vocab: int, seed: int):
     send: ``rounds`` rounds of one request per client. Every round holds the
     same prompt lengths and the same output lengths (the mid-quantiles of the
     mix's two log-normals, one per client); the seed deals each round's
-    prompts and outputs to the clients, apart, and fills in the tokens."""
+    prompts and outputs to the clients, apart, and fills in the tokens.
+
+    A mix with a ``deal_seed`` is dealt by that number instead, the same for
+    every ``--seed``, which then fills in the tokens alone. It is for a cell
+    whose step reads the live contexts, so that a gap between tokens follows
+    their sum: which lengths meet in the slots at one time is then part of the
+    work, and the 95th percentile of the gaps over a window of some twenty
+    request lives moved by 1.6 % with the dealing alone (PERF.md, PR 41)."""
     clients, rounds = mix["clients"], mix["rounds"]
     p, o = mix["prompt_len"], mix["output_len"]
     p_len = lognormal_quantiles(clients, p["median"], p["sigma"], p["lo"], p["hi"])
     o_len = lognormal_quantiles(clients, o["median"], o["sigma"], o["lo"], o["hi"])
     rng = rng_of(seed, 3)
+    deal = rng_of(mix["deal_seed"], 4) if "deal_seed" in mix else rng
     per_client = [[] for _ in range(clients)]
     for _ in range(rounds):
-        for c, (n_p, n_o) in enumerate(zip(rng.permutation(p_len), rng.permutation(o_len))):
+        for c, (n_p, n_o) in enumerate(zip(deal.permutation(p_len), deal.permutation(o_len))):
             per_client[c].append((token_ids(rng, vocab, (int(n_p),)), int(n_o)))
     return per_client

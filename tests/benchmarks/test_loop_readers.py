@@ -23,7 +23,7 @@ from paddle_tpu import models, tracing  # noqa: E402
 from paddle_tpu.serving import DecodeConfig, DecodeEngine  # noqa: E402
 
 READERS = ("loop_dispatch_ms", "loop_telemetry_ms", "loop_offcpu_ms")
-SERVE_CELLS = ["lm_big.serve_closed16", "brumby_14b.serve_docs16", "sarvam_105b.serve_docs32",
+SERVE_CELLS = ["lm_big.serve_long", "brumby_14b.serve_docs16", "sarvam_105b.serve_docs32",
                "ouro_2_6b.serve_reason8"]
 
 
@@ -190,14 +190,17 @@ def test_loop_spans_finds_the_window_turn_for_turn_as_loop_iteration_ms_does(lm)
 def test_each_manifest_entry_has_its_file_and_its_four_cells():
     manifest = harness.load_json(ROOT, "BENCHMARK.json")
     entries = {m["name"]: m for m in manifest["per_layer"]}
-    assert [m["name"] for m in manifest["per_layer"][-3:]] == list(READERS)
+    # by name and relative order: a later PR appends its readers after these
+    assert [m["name"] for m in manifest["per_layer"] if m["name"] in READERS] == list(READERS)
     host = entries["loop_host_ms"]
     for name in READERS:
         m = entries[name]
         assert {k: m[k] for k in ("unit", "better", "source", "layer", "moves")} == {
             "unit": "ms", "better": "lower", "source": "program_span",
             "layer": host["layer"], "moves": "tpot_p95_ms"}
-        assert m["workloads"] == SERVE_CELLS == host["workloads"]
+        # the four serve cells of PR 37's benchmark (lm_big's is serve_long since PR 41),
+        # and whatever serve cell a later PR adds, as the host part's entry has them
+        assert m["workloads"] == host["workloads"] and m["workloads"][:4] == SERVE_CELLS
         assert os.path.isfile(os.path.join(ROOT, "benchmarks", "layer_metrics", name + ".py"))
 
 

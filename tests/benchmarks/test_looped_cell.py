@@ -147,16 +147,22 @@ def test_collective_exposed_share_on_a_recorded_trace_of_four_chips():
 def test_the_manifest_has_the_two_cells_the_configuration_and_the_two_readers_appended():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         m = json.load(f)
-    assert [w["name"] for w in m["workloads"]][-2:] == ["lm_big.train_2k_dp4",
-                                                        "ouro_2_6b.serve_reason8"]
-    assert m["workloads"][-2]["chips"] == 4 and m["workloads"][-1]["chips"] == 1
+    # by name and relative order: later PRs append cells, configurations and readers
+    cells = {w["name"]: w for w in m["workloads"]}
+    order = [w["name"] for w in m["workloads"]]
+    assert order.index("lm_big.train_2k_dp4") < order.index("ouro_2_6b.serve_reason8")
+    assert cells["lm_big.train_2k_dp4"]["chips"] == 4
+    assert cells["ouro_2_6b.serve_reason8"]["chips"] == 1
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    assert m["configs"][-1]["name"] == "ouro_2_6b" and m["configs"][-1]["reduced"] == []
-    assert [p["name"] for p in m["per_layer"]][-2:] == ["collective_exposed_share",
-                                                        "looped_hbm_roofline"]
+    (ouro,) = [c for c in m["configs"] if c["name"] == "ouro_2_6b"]
+    assert ouro["reduced"] == []
+    readers = [p["name"] for p in m["per_layer"]]
+    assert readers.index("collective_exposed_share") < readers.index("looped_hbm_roofline")
     serve = [p for p in m["per_layer"] if p["moves"] == "tpot_p95_ms"
-             and "lm_big.serve_closed16" in p["workloads"]]
-    assert len(serve) == 9 and all(p["workloads"][-1] == "ouro_2_6b.serve_reason8" for p in serve)
+             and "lm_big.serve_long" in p["workloads"]]
+    assert len(serve) >= 9 and all("ouro_2_6b.serve_reason8" in p["workloads"] for p in serve)
+    assert not any("serve_closed16" in w for p in m["per_layer"] + m["end_to_end"]
+                   for w in p.get("workloads", []))
     with open(os.path.join(ROOT, "benchmarks", "configs", "ouro_2_6b.json")) as f:
         config = json.load(f)
     assert config["reduced"] == []

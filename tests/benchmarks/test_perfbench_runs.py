@@ -99,8 +99,10 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(root, monkeypatc
     real = pt.Trainer._run_step
 
     def frozen(self, batch):
-        out = real(self, batch)
-        return out._replace(variables=self.variables)
+        # the step consumes the state it is handed (PR 32): what it is frozen
+        # at is a copy taken before it, as tests/test_trainer_donation.py does
+        kept = jax.tree_util.tree_map(jax.numpy.array, self.variables)
+        return real(self, batch)._replace(variables=kept)
 
     monkeypatch.setattr(pt.Trainer, "_run_step", frozen)
     line = drive(root, "lm_tiny.train_rows")

@@ -25,7 +25,7 @@ def _mix(name):
 # -- traffic ---------------------------------------------------------------
 
 def test_closed_loop_requests_are_a_function_of_the_seed():
-    mix = _mix("serve_closed16")
+    mix = _mix("serve_docs16")
     a = traffic.closed_loop_requests(mix, 32000, 2**31 + 5)
     b = traffic.closed_loop_requests(mix, 32000, 2**31 + 5)
     c = traffic.closed_loop_requests(mix, 32000, 2**31 + 6)
@@ -36,22 +36,55 @@ def test_closed_loop_requests_are_a_function_of_the_seed():
 
 
 def test_every_round_of_every_seed_holds_the_same_sizes_in_another_order():
-    mix = _mix("serve_closed16")
+    mix = _mix("serve_long")
+    mix.pop("deal_seed")  # the generator's default: the seed deals (next test: this mix is dealt once)
     a = traffic.closed_loop_requests(mix, 32000, 1)
     b = traffic.closed_loop_requests(mix, 32000, 99)
-    assert len(a) == mix["clients"] and all(len(c) == mix["rounds"] for c in a)
+    assert len(a) == mix["clients"] == mix["engine"]["max_slots"] == 48
+    assert all(len(c) == mix["rounds"] for c in a)
     rounds = lambda reqs, i: [[(len(c[r][0]), c[r][1])[i] for c in reqs] for r in range(mix["rounds"])]
-    for i, want in ((0, traffic.lognormal_quantiles(16, 96, 0.7, 32, 1024)),
-                    (1, traffic.lognormal_quantiles(16, 48, 0.5, 16, 256))):
+    for i, want in ((0, traffic.lognormal_quantiles(48, 1020, 0.6, 128, 1792)),
+                    (1, traffic.lognormal_quantiles(48, 129, 0.6, 16, 256))):
         assert all(sorted(r) == sorted(want) for reqs in (a, b) for r in rounds(reqs, i))
         assert rounds(a, i) != rounds(b, i) and rounds(a, i)[0] != rounds(a, i)[1]
-    assert sum(len(c[0][0]) for c in a) == 1922 and sum(c[0][1] for c in a) == 863
+    # the numbers the traffic file, the cell's `why` and PERF.md section 4 give
+    assert sum(len(c[0][0]) for c in a) == 52055 and sum(c[0][1] for c in a) == 6795
+    chunk = mix["engine"]["prefill_chunk"]
+    assert sum(-(-len(c[0][0]) // chunk) for c in a) == 126
     assert all(len(q) + n <= mix["engine"]["max_context"] for c in a for q, n in c)
+    assert max(len(q) for c in a for q, _ in c) + max(n for c in a for _, n in c) == 2048
+    assert set(mix["assumed"]) >= {"prompt_len.sigma", "output_len.sigma"}
+
+
+def test_a_mix_with_a_deal_seed_is_dealt_the_same_for_every_seed():
+    """``lm_big.serve_long``: which lengths meet in the slots is part of the
+    work there, so the seed fills in the tokens and deals nothing."""
+    mix = _mix("serve_long")
+    assert mix["deal_seed"] == 50
+    a = traffic.closed_loop_requests(mix, 32000, 2**31 + 5)
+    b = traffic.closed_loop_requests(mix, 32000, 2**31 + 6)
+    sizes = lambda reqs: [[(len(q), n) for q, n in c] for c in reqs]
+    assert sizes(a) == sizes(b)
+    assert not any(np.array_equal(p, q) for ca, cb in zip(a, b) for (p, _), (q, _) in zip(ca, cb))
+    again = traffic.closed_loop_requests(mix, 32000, 2**31 + 5)
+    assert all(np.array_equal(p, q) for ca, cb in zip(a, again) for (p, _), (q, _) in zip(ca, cb))
+    other = sizes(traffic.closed_loop_requests(dict(mix, deal_seed=51), 32000, 2**31 + 5))
+    assert other != sizes(a)  # another dealing of the same lengths
+    assert sorted(len(q) for c in a for q, _ in c) == sorted(n for c in other for n, _ in c)
+    # the mixes that name no deal_seed are dealt by the seed, draw for draw as before PR 41
+    docs = _mix("serve_docs16")
+    assert "deal_seed" not in docs
+    rng = traffic.rng_of(7, 3)
+    p_len = traffic.lognormal_quantiles(16, **{k: docs["prompt_len"][k] for k in ("median", "sigma", "lo", "hi")})
+    first = traffic.closed_loop_requests(docs, 1000, 7)
+    assert [len(c[0][0]) for c in first] == list(rng.permutation(p_len))
 
 
 def test_lognormal_quantiles_are_the_mid_quantiles_clipped():
     q = traffic.lognormal_quantiles(16, 96, 0.7, 32, 1024)
     assert list(q[[0, 7, 8, 15]]) == [32, 91, 101, 354] and (np.diff(q) > 0).all()
+    q = traffic.lognormal_quantiles(48, 1020, 0.6, 128, 1792)
+    assert list(q[[0, 23, 24, 39, 40, 47]]) == [255, 1004, 1036, 1778, 1792, 1792]
     assert list(traffic.lognormal_quantiles(3, 10, 0.0, 1, 100)) == [10, 10, 10]
 
 
@@ -175,6 +208,75 @@ def test_nested_events_count_once():
         {"while": 30e-9, "a": 20e-9, "b": 50e-9, "c": 10e-9})
 
 
+def plain_idle_gaps(events, host, top=10):
+    """``trace_reduce.idle_gaps`` as it stood until PR 41, kept as the plain
+    form: every host event is held against every gap."""
+    merged = trace_reduce.merge_intervals(events)
+    by_name = {}
+    for (_, e0), (s1, _) in zip(merged, merged[1:]):
+        left = s1 - e0
+        for name, hs, hd in host:
+            cover = min(s1, hs + hd) - max(e0, hs)
+            if cover > 0:
+                by_name[name] = by_name.get(name, 0.0) + cover / 1e9
+                left -= cover
+        if left > 0:
+            by_name["unattributed"] = by_name.get("unattributed", 0.0) + left / 1e9
+    return [[n, s] for n, s in sorted(by_name.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def _random_trace(rng, n_ops, n_host, long_event):
+    """Device operations with gaps of 1 ns to 40 us between them (some
+    overlapping or nested), and host events of a few names that overlap each
+    other, start inside operations and gaps alike and leave many gaps with no
+    event over them; ``long_event`` adds one that outlasts every gap."""
+    starts = np.cumsum(rng.integers(1, 60_000, n_ops))
+    events = [(f"op{i % 7}", int(s), int(rng.integers(1, 50_000))) for i, s in enumerate(starts)]
+    span = int(starts[-1]) + 50_000
+    host = [(f"bench.h{rng.integers(0, 4)}", int(rng.integers(-10_000, span)),
+             int(rng.integers(0, 90_000))) for _ in range(n_host)]
+    if long_event:
+        host.append(("bench.long", span // 5, span // 2))
+    return events, sorted(host, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("long_event", [False, True])
+def test_idle_gaps_by_the_sweep_are_the_plain_walks(seed, long_event):
+    rng = np.random.default_rng([41, seed])
+    events, host = _random_trace(rng, 400, int(rng.integers(0, 120)), long_event)
+    got, want = trace_reduce.idle_gaps(events, host, top=10), plain_idle_gaps(events, host, top=10)
+    assert got == want  # name for name, and the seconds to the last bit
+    if long_event:
+        assert dict(map(tuple, got))["bench.long"] > 0
+    assert trace_reduce.idle_gaps(events, []) == plain_idle_gaps(events, [])  # all unattributed
+    # handed the host events in any order, the same to a nanosecond
+    shuffled = [host[i] for i in rng.permutation(len(host))]
+    assert dict(map(tuple, trace_reduce.idle_gaps(events, shuffled))) == pytest.approx(
+        dict(map(tuple, want)), abs=1e-9)
+
+
+def test_idle_gaps_of_a_million_gaps_reduce_in_seconds():
+    """A traced serve window as PERF.md's PR 36 found it: a million device
+    operations a nanosecond or a few apart, three thousand annotations. The
+    plain walk is three thousand million comparisons; the limit is generous so
+    that a slow worker passes and a walk that came back does not."""
+    import time
+
+    n, n_host = 1_000_001, 3_000
+    events = [("op", 10 * i, 7 + i % 3) for i in range(n)]  # gaps of 1-3 ns
+    host = [(f"bench.h{i % 3}", 3_333 * i, 2_000) for i in range(n_host)]
+    host.append(("bench.long", 1_000_000, 5_000_000))
+    t0 = time.perf_counter()
+    got = dict(map(tuple, trace_reduce.idle_gaps(events, host)))
+    took = time.perf_counter() - t0
+    assert took < 60.0, took
+    gaps_ns = sum(10 - (7 + i % 3) for i in range(n - 1))
+    assert sum(got.values()) * 1e9 >= gaps_ns  # overlapping events cover a gap twice
+    assert got["unattributed"] > 0 and got["bench.long"] == pytest.approx(
+        sum(10 - (7 + i % 3) for i in range(100_000, 600_000)) / 1e9)
+
+
 def test_short_name_keeps_what_identifies_an_op():
     text = ('%closed_call.75 = (bf16[64,2048,64]{2,1,0}) custom-call(bf16[64,2048,64] %x), '
             'custom_call_target="tpu_custom_call"')
@@ -201,6 +303,17 @@ def test_manifest_names_resolve_to_files():
     for m in manifest["per_layer"]:
         assert m["moves"] in e2e
         assert hasattr(harness.load_reader(m["name"]), "read")
+
+
+def test_the_retired_cell_is_an_unknown_workload_and_none_of_its_files_is_left():
+    with pytest.raises(harness.BenchError, match="unknown workload 'lm_big.serve_closed16'"):
+        harness.load_cell("lm_big.serve_closed16")
+    bench = os.path.join(ROOT, "benchmarks")
+    left = [os.path.join(d, f) for d, _, files in os.walk(bench) for f in files
+            if "serve_closed16" in f]
+    assert not left
+    assert harness.main(["--workload", "lm_big.serve_closed16", "--seed", "1", "--seconds", "1"],
+                        0.0) == harness.EXIT_NO_DEVICE  # said on stderr, no result line
 
 
 def test_the_command_fails_and_prints_no_result_without_a_tpu():
