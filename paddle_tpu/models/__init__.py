@@ -53,14 +53,19 @@ class ServingPrograms:
     array, or one array of latent rows), ``slot_ref`` is a slot's
     page-table row and the programs also take ``page_size=``; with ``"state"`` they are recurrent
     states indexed by slot, ``slot_ref`` is the slot's index, and
-    ``slot_refs`` marks the slots that decode.
+    ``slot_refs`` marks the slots that decode. A model with layers of both
+    kinds (``models/hybrid_ssm_lm.py``: Mamba-2 layers beside attention
+    layers) brings ``"pages+state"``: ``cache_specs`` returns page arrays and
+    state arrays, ``state_args`` names the states among ``cache_args``,
+    ``slot_ref`` is the pair ``(page-table row, slot index)`` and
+    ``slot_refs`` the pair ``(page tables, the mask of decoding slots)``.
 
     Both programs may return small arrays after the cache, one per name in
     ``extras`` (an expert layer's tokens per expert); the engine reads them
     in the turn in which it reads the tokens and puts
     ``span_attrs(cfg, *extras)`` on the call's span."""
 
-    cache: str                      # "pages" | "state"
+    cache: str                      # "pages" | "state" | "pages+state"
     cache_args: Tuple[str, ...]     # the programs' names for the arrays
     # cache_specs(cfg, *, max_slots, num_pages, page_size, dtype)
     #   -> one jax.ShapeDtypeStruct per array
@@ -78,6 +83,21 @@ class ServingPrograms:
     span_attrs: Optional[Callable[..., Dict[str, Any]]] = None
     # gauges(cfg) -> {name: number}, published once as serving.decode.<name>
     gauges: Optional[Callable[[dict], Dict[str, float]]] = None
+    # with "pages+state": which of cache_args are states indexed by slot
+    # (the rest are page arrays). Not read for the two plain kinds
+    state_args: Tuple[str, ...] = ()
+
+    @property
+    def has_pages(self) -> bool:
+        return self.cache in ("pages", "pages+state")
+
+    @property
+    def has_state(self) -> bool:
+        return self.cache in ("state", "pages+state")
+
+    def is_state(self, arg: str) -> bool:
+        """Whether the cache array the programs call ``arg`` is a state."""
+        return self.cache == "state" or arg in self.state_args
 
 
 def serving_programs(cfg: dict) -> ServingPrograms:
@@ -158,6 +178,12 @@ def _looped_lm(**cfg):
     return looped_lm.get_model(**cfg)
 
 
+def _hybrid_ssm_lm(**cfg):
+    from paddle_tpu.models import hybrid_ssm_lm
+
+    return hybrid_ssm_lm.get_model(**cfg)
+
+
 def _transformer_lm(**cfg):
     from paddle_tpu.models import transformer_lm
 
@@ -174,6 +200,7 @@ MODELS: Dict[str, Callable[..., ModelSpec]] = {
     "retention_lm": _retention_lm,
     "latent_moe_lm": _latent_moe_lm,
     "looped_lm": _looped_lm,
+    "hybrid_ssm_lm": _hybrid_ssm_lm,
     "stacked_dynamic_lstm": _stacked_dynamic_lstm,
     "machine_translation": _machine_translation,
 }

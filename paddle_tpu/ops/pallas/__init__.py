@@ -8,7 +8,8 @@ that surface is left to XLA, and only attention-style blockwise-softmax
 fusions, the decode step's attention over a paged K and V cache (a slot's
 live pages read where they lie, where XLA gathers every table whole), the
 decode step of a power-retention layer (one pass over a recurrent state XLA
-would cross three times) and the grouped matmul of an expert layer (each hit
+would cross three times), the decode step of a Mamba-2 layer (the same, and
+an idle slot's state is not moved) and the grouped matmul of an expert layer (each hit
 expert's weights read once), get custom kernels. Kernels run in interpret
 mode off-TPU so tests exercise them on the CPU mesh."""
 
@@ -21,6 +22,7 @@ from paddle_tpu.ops.pallas.flash_attention import (  # noqa: F401
 from paddle_tpu.ops.pallas.moe import moe_gmm  # noqa: F401
 from paddle_tpu.ops.pallas.paged_attention import paged_attend_step  # noqa: F401
 from paddle_tpu.ops.pallas.retention import retention_step  # noqa: F401
+from paddle_tpu.ops.pallas.ssm import ssm_step  # noqa: F401
 
 __all__ = ["flash_attention", "flash_attention_bwd_block", "flash_attention_with_lse",
-           "moe_gmm", "paged_attend_step", "retention_step"]
+           "moe_gmm", "paged_attend_step", "retention_step", "ssm_step"]

@@ -294,6 +294,14 @@ class _DecodeRequest:
         self.first_tok = None
 
 
+def _on_device(refs):
+    """A step's slot references as device arrays: one array, or the pair a
+    model with pages and states is handed."""
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(jnp.asarray, refs)
+
+
 def _under_mesh(group, fn):
     """``fn`` as a replica group's engine traces it: under the group's mesh,
     so that code which cannot run as one program over several chips (a
@@ -394,10 +402,14 @@ class DecodeEngine:
     language model (params as created by its ``lm_forward``). The model
     brings its own programs (:func:`~paddle_tpu.models.serving_programs`):
     ``transformer_lm`` a paged KV cache, ``retention_lm`` one fixed
-    recurrent state per slot. The engine owns the cache arrays either way;
-    what needs pages that can be shared, copied or rolled back (prefix
-    cache, host tier, handoff, a draft model, a replica group) is refused
-    at construction for a model that keeps a state.
+    recurrent state per slot, ``hybrid_ssm_lm`` both (Mamba-2 layers' states
+    beside attention layers' pages, under one slot and page manager: a
+    state is indexed by the slot's number, a preempted or quarantined
+    request loses pages and state alike and prefills again from position
+    0). The engine owns the cache arrays in every case; what needs pages
+    that can be shared, copied or rolled back (prefix cache, host tier,
+    handoff, a draft model, a replica group) is refused at construction
+    for a model that keeps a state, alone or beside pages.
 
     ::
 
@@ -445,7 +457,12 @@ class DecodeEngine:
                 f"max_context ({dconf.max_context}) must be a multiple of "
                 f"page_size ({dconf.page_size})")
         self._programs = progs = serving_programs(self.model_cfg)
-        self._paged = progs.cache == "pages"
+        # a model brings pages, states, or both ("pages+state": layers of
+        # both kinds). With pages the slot and page manager is the paged
+        # one, which a state is indexed by the slot numbers of; the
+        # features that share, copy, ship or roll back pages are refused
+        # wherever a state would need a snapshot beside them
+        self._paged, self._stateful = progs.has_pages, progs.has_state
         # padded prompt chunks must stay inside the table they are handed —
         # a chunk running past it would clamp-scatter into the last page.
         # Where max_context is no multiple of prefill_chunk, a paged
@@ -525,7 +542,7 @@ class DecodeEngine:
                 group, pshape, progs.kv_heads(self.model_cfg))
             rep = self._layout.replicated(group)
         # The engine is the sole owner of its cache arrays (the model's K
-        # and V pages, or its per-slot states): every jit that returns a
+        # and V pages, its per-slot states, or both): every jit that returns a
         # new version of one takes the old one donated, so a write updates
         # the array in place instead of copying it. Every call site
         # rebinds the result and nothing else may hold a cache array
@@ -545,12 +562,19 @@ class DecodeEngine:
         sample_kw = dict(temperature=dconf.temperature, top_k=dconf.top_k,
                          top_p=dconf.top_p)
         model_kw = dict(sample_kw, cfg=self.model_cfg)
+        # which of the arrays are states indexed by slot; the rest are pages
+        self._is_state = [progs.is_state(a) for a in progs.cache_args]
         if self._paged:
             model_kw["page_size"] = dconf.page_size
-            self.metrics.set_cache_bytes_per_token(sum(
-                sp.shape[0] * sp.shape[3] * np.dtype(sp.dtype).itemsize for sp in specs))
-        else:
-            self.metrics.set_state_bytes(sum(c.nbytes for c in self._cache))
+            per_token = sum(sp.shape[0] * sp.shape[3] * np.dtype(sp.dtype).itemsize
+                            for sp, st in zip(specs, self._is_state) if not st)
+            self.metrics.set_cache_bytes_per_token(per_token)
+            # one page over every plane and array: what a live page costs a
+            # step that attends it (on the step's span, for the roofline)
+            self._page_bytes = dconf.page_size * per_token
+        if self._stateful:
+            self.metrics.set_state_bytes(sum(
+                c.nbytes for c, st in zip(self._cache, self._is_state) if st))
         if progs.gauges is not None:
             self.metrics.set_program_gauges(progs.gauges(self.model_cfg))
         # (chunk number, span, extras) of the chunks whose extras are not
@@ -839,7 +863,8 @@ class DecodeEngine:
 
     def _slot_ref(self, slot: int):
         """What a prefill chunk finds its slot's cache by: the page-table
-        row, or the slot's index into the state arrays."""
+        row, the slot's index into the state arrays, or the pair of them
+        for a model that keeps both."""
         import jax.numpy as jnp
 
         if not self._paged:
@@ -848,31 +873,38 @@ class DecodeEngine:
         if self._chunk_table_pad:  # the last chunk's overhang: scratch
             row = np.concatenate(
                 [row, np.full((self._chunk_table_pad,), SCRATCH_PAGE, row.dtype)])
+        if self._stateful:
+            return jnp.asarray(row), jnp.int32(slot)
         return jnp.asarray(row)
 
-    def _slot_refs(self, decoding) -> np.ndarray:
+    def _slot_refs(self, decoding):
         """The same for a decode step over ``decoding``: every other slot
-        gets a scratch table row, or a 0 that leaves its state alone."""
+        gets a scratch table row, or a 0 that leaves its state alone; a
+        model that keeps both gets the pair (tables, mask)."""
         S = self.decode_config.max_slots
+        active = tables = None
+        if self._stateful:
+            active = np.zeros((S,), np.int32)
+            active[[r.slot for r in decoding]] = 1
+        if self._paged:
+            tables = np.full((S, self._kv.pages_per_slot), SCRATCH_PAGE, np.int32)
+            for req in decoding:
+                tables[req.slot] = self._kv.page_tables[req.slot]
         if not self._paged:
-            refs = np.zeros((S,), np.int32)
-            refs[[r.slot for r in decoding]] = 1
-            return refs
-        refs = np.full((S, self._kv.pages_per_slot), SCRATCH_PAGE, np.int32)
-        for req in decoding:
-            refs[req.slot] = self._kv.page_tables[req.slot]
-        return refs
+            return active
+        return (tables, active) if self._stateful else tables
 
     def _publish_cache(self) -> None:
         if self._paged:
             self.metrics.set_pages(self._kv.pages_in_use, self._kv.pages_free)
-        else:
+        if self._stateful:
             self.metrics.set_state_slots_in_use(len(self._kv.active_slots()))
 
     def _warmup(self) -> None:
         """Compile every executable that writes the cache arrays before
         traffic arrives, and publish whether each consumed the arrays it
-        was handed (``serving.decode.pages_donated`` / ``state_donated``)
+        was handed (``serving.decode.pages_donated`` / ``state_donated``,
+        both for a model that keeps both, each judged on its own arrays)
         and whether the device holds the page arrays as the model spells
         them (``serving.decode.pages_row_major``).
         Warmup writes land on the scratch page (zero tables), or in slot
@@ -886,10 +918,14 @@ class DecodeEngine:
         slots0 = jnp.zeros((S,), jnp.int32)
         z = jnp.int32(SCRATCH_PAGE)  # page 0, and the chunk's position 0
         kept: List[str] = []  # write-jits that left a cache array alive
+        kept_kinds = set()    # ... and of which kind: "pages", "state"
 
-        def consumed(name, *pages):
-            if not all(p.is_deleted() for p in pages):
+        def consumed(name, *arrays):
+            alive = {"state" if st else "pages"
+                     for a, st in zip(arrays, self._is_state) if not a.is_deleted()}
+            if alive:
                 kept.append(name)
+                kept_kinds.update(alive)
 
         old = list(self._cache)
         self._take(self._prefill(
@@ -898,11 +934,13 @@ class DecodeEngine:
         consumed("prefill", *old)
         old = list(self._cache)
         out, _ = self._take(self._step(
-            self._params, slots0, slots0, jnp.asarray(self._slot_refs([])),
+            self._params, slots0, slots0, _on_device(self._slot_refs([])),
             *old, self._next_key()))
         consumed("step", *old)
         jax.block_until_ready(out)
-        if self._paged:
+        if self._paged and not self._stateful:
+            # what these serve (handoff, the host tier, the prefix cache, a
+            # draft) is refused beside a state
             self._warmup_page_jits(consumed, chunk0, slots0, z)
         for name in kept:
             ptlog.warn_once(
@@ -911,15 +949,15 @@ class DecodeEngine:
                 "the donation did not engage and every write copies the "
                 "whole array", name)
         if self._paged:
-            self.metrics.set_pages_donated(not kept)
-            pages = list(self._cache)
+            self.metrics.set_pages_donated("pages" not in kept_kinds)
+            pages = [c for c, st in zip(self._cache, self._is_state) if not st]
             if self._spec_k:
                 pages += [self._dk_pages, self._dv_pages]
             self.metrics.set_pages_row_major(all(
                 list(p.format.layout.major_to_minor) == list(range(p.ndim))
                 for p in pages))
-        else:
-            self.metrics.set_state_donated(not kept)
+        if self._stateful:
+            self.metrics.set_state_donated("state" not in kept_kinds)
         # persist the compiled keys so a restarted engine can prewarm
         from paddle_tpu.tune import warmup as tune_warmup
 
@@ -2105,6 +2143,7 @@ class DecodeEngine:
                             r.cur_len // self.decode_config.page_size + 1
                             for r in decoding)),
                         "attend_table_pages": S * self._kv.pages_per_slot,
+                        "attend_page_bytes": self._page_bytes,
                         "attend_kernel": self._attend_kernel}
                     step_span.set(**attend)
             t0 = time.perf_counter()
@@ -2114,7 +2153,7 @@ class DecodeEngine:
                 with tracing.start_span("serving.decode.model_step.dispatch"):
                     nxt, extras = self._take(self._step(
                         self._params, jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(refs),
+                        jnp.asarray(positions), _on_device(refs),
                         *self._cache, self._next_key()))
                 ran_before = self._chunk_seq  # the chunks queued ahead of this step
                 # the step is queued behind the last iteration's chunks: a

@@ -151,6 +151,12 @@ class PagedKVCache:
     (``pages_per_slot`` is the static page-table width ``P``). Pages are
     granted lazily by :meth:`ensure_capacity` as the sequence grows, so a
     short request never reserves worst-case HBM.
+
+    For a model with layers of both kinds this is also the states' manager:
+    a state array is indexed by the slot's number, needs no grant and cannot
+    run out; releasing the slot (a finish, a preemption, a quarantine) gives
+    the state up with the pages, and the next admission's first chunk starts
+    it over on the device. ``assert_no_leaks`` therefore covers both.
     """
 
     def __init__(self, *, max_slots: int, page_size: int, num_pages: int,
@@ -350,9 +356,13 @@ class PagedKVCache:
 
 
 class SlotStates:
-    """Host-side bookkeeping for a model whose cache is one fixed recurrent
-    state per slot (``models/retention_lm.py``): the slot-lifecycle half of
-    :class:`PagedKVCache` and nothing else. A state does not grow with the
+    """Host-side bookkeeping for a model whose *whole* cache is one fixed
+    recurrent state per slot (``models/retention_lm.py``): the slot-lifecycle
+    half of :class:`PagedKVCache` and nothing else. A model that keeps states
+    beside pages (``models/hybrid_ssm_lm.py``) does not use this class: its
+    one manager is :class:`PagedKVCache`, whose slot numbers index its state
+    arrays too, so that a slot's pages and its state are acquired, released,
+    preempted and checked for leaks together. A state does not grow with the
     sequence, so there are no pages to grant, run out of, preempt for or
     leak; ``seq_lens`` is kept for the engine's bookkeeping only. The
     device array is the engine's, as the pages are. A slot's state is

@@ -826,14 +826,20 @@ class DecodeMetrics:
 
     # a model that keeps a recurrent state per slot instead of KV pages
     def set_state_bytes(self, n: int) -> None:
+        """Bytes of the arrays indexed by slot: a state model's whole cache,
+        or, for a model that keeps states beside pages, its state arrays
+        alone (the pages have ``cache_bytes_per_token`` and ``pages_*``)."""
         prof.set_gauge("serving.decode.state_bytes", n, labels=self._labels)
 
     def set_state_slots_in_use(self, n: int) -> None:
+        """Slots whose state is held; published beside ``pages_in_use`` for
+        a model that keeps both."""
         prof.set_gauge("serving.decode.state_slots_in_use", n,
                        labels=self._labels)
 
     def set_state_donated(self, ok: bool) -> None:
-        """The twin of ``pages_donated`` for the state arrays."""
+        """The twin of ``pages_donated`` for the state arrays; a model that
+        keeps both publishes both, each judged on its own arrays."""
         prof.set_gauge("serving.decode.state_donated", int(ok),
                        labels=self._labels)
 

@@ -4,8 +4,8 @@ The TPU compiler is installed beside the CPU backend and compiles for a
 chip that is described, not attached. These are the programs
 ``chip_smoke.py`` runs — the flash kernel, the ``lm_large`` train step, the
 paged serving steps and the four-chip data-parallel step — and the two
-serving programs of the benchmark's ``brumby_14b``, ``sarvam_105b`` and
-``ouro_2_6b`` cells, so what the
+serving programs of the benchmark's ``brumby_14b``, ``sarvam_105b``,
+``ouro_2_6b`` and ``granite_4_0_h_micro`` cells, so what the
 chip's compiler would refuse (a kernel that cannot be partitioned, a
 program that does not fit HBM) fails here, at no chip time. Nothing runs:
 a passing compile says nothing about results or speed.
@@ -424,6 +424,77 @@ def test_ouro_serving_steps_fit_the_chip_and_keep_their_pages_in_place(one_chip,
     # the step attends through the kernel, once in the layers' body, the
     # plane a traced scalar; the chunk keeps the gather
     assert _mosaic_calls(text) == ["paged_attend_step"] * (which == "decode_step")
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_granite_serving_steps_fit_the_chip_and_alias_pages_and_states(one_chip, as_tpu, which):
+    """The cell granite_4_0_h_micro.serve_chat64 at its own shapes, nothing
+    cut: 40 layers at the published widths in bfloat16 (6.38 GB) beside 64
+    slots of SSM state and convolution tails (4.95 GB, float32) and 64 x 3072
+    positions of bfloat16 K and V pages in 4 planes (1.61 GB): 12.95 GB of
+    arguments. The engine donates all four cache arrays: each program must
+    alias every one to its output, copy none of them whole, hold each as the
+    model spells it, and fit the chip beside its temporaries (the step 0.04
+    GB, the chunk 0.60 GB by this compile). The step holds the ``ssm_step``
+    kernel once a Mamba-2 layer and ``paged_attend_step`` once an attention
+    layer; the chunk holds neither (its scan and its gather are XLA's)."""
+    import json
+    import re
+
+    from benchmarks.families import hybrid_ssm_lm as family
+    from paddle_tpu.models import hybrid_ssm_lm as hm
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(here, "configs", "granite_4_0_h_micro.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "traffic", "serve_chat64.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = dict(hm.BASE_CFG, **family.model_cfg(config))
+    assert cfg["max_len"] == engine["max_context"] == 3072
+    progs = models.serving_programs(cfg)
+    bf16 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = {k: bf16(shape) for k, shape in hm.param_shapes(cfg).items()}
+    slots, page = engine["max_slots"], engine["page_size"]
+    per_slot = engine["max_context"] // page
+    specs = progs.cache_specs(cfg, max_slots=slots, num_pages=1 + slots * per_slot,
+                              page_size=page, dtype=jnp.dtype(engine["cache_dtype"]))
+    assert [s.shape for s in specs] == [(4, 12289, 16, 512)] * 2 + [
+        (36, 64, 128, 4096), (36, 64, 3 * 4352)]
+    cache = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip) for s in specs]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    if which == "decode_step":
+        fn, args = progs.decode_step, (i32(slots), i32(slots), (i32(slots, per_slot), i32(slots)))
+    else:
+        fn, args = progs.prefill_chunk, (i32(engine["prefill_chunk"]), i32(), i32(),
+                                         (i32(per_slot), i32()))
+    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
+                       donate_argnames=progs.cache_args,
+                       ).lower(params, *args, *cache, None).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    nbytes = lambda a: int(np.prod(a.shape)) * a.dtype.itemsize
+    cache_bytes = sum(nbytes(c) for c in cache)
+    weight_bytes = sum(nbytes(p) for p in params.values())
+    assert weight_bytes == 2 * 3_191_396_096 and cache_bytes == 6_562_906_112
+    assert 12.94e9 < mem.argument_size_in_bytes < 12.96e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
+    for c in cache:
+        whole = ("f32[" if c.dtype == jnp.float32 else "bf16[") + ",".join(
+            str(d) for d in c.shape) + "]"
+        copies = [l.strip()[:120] for l in text.splitlines()
+                  if whole in l.split("=")[0] and " copy(" in l]
+        assert not copies, copies[:2]
+        layouts = set(re.findall(re.escape(whole) + r"\{([\d,]+)", entry))
+        assert layouts == {",".join(str(d) for d in reversed(range(len(c.shape))))}, (whole, layouts)
+    print(which, "temp", mem.temp_size_in_bytes, "arguments", mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < (0.2e9 if which == "decode_step" else 1.0e9)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    calls = _mosaic_calls(text)
+    if which == "decode_step":
+        assert sorted(set(calls)) == ["paged_attend_step", "ssm_step"], sorted(set(calls))
+        assert calls.count("ssm_step") == 36 and calls.count("paged_attend_step") == 4
+    else:
+        assert calls == []
 
 
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])

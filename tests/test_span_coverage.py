@@ -336,6 +336,51 @@ def test_a_paged_step_carries_the_pages_its_slots_hold_and_the_table_it_was_hand
     assert max(s.attrs["attend_live_pages"] for s in steps) >= 2 + 4
     assert {s.attrs["attend_table_pages"] for s in steps} == {3 * 10}
     assert {s.attrs["attend_kernel"] for s in steps} == {0}
+    # one page over every plane and both arrays: what a live page costs a step
+    per_token = sum(c.shape[0] * c.shape[3] * c.dtype.itemsize for c in engine._cache)
+    assert {s.attrs["attend_page_bytes"] for s in steps} == {4 * per_token}
+
+
+def test_a_hybrid_step_and_chunk_carry_the_states_they_moved_beside_the_pages():
+    """A model with pages and states (``models/hybrid_ssm_lm.py``): the step's
+    span has the attention layers' ``attend_*`` as a paged step has, and from
+    the program's extras the slots whose SSM states it updated, the Mamba-2
+    layers and the bytes of state that crossed HBM for them; a chunk's span
+    has the same for its one slot. Gauges, once: the states' bytes beside the
+    pages'."""
+    from paddle_tpu import models
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    spec = models.get_model(
+        "hybrid_ssm_lm", seq_len=16, vocab=97, d_model=32, d_inner=64, num_heads=2,
+        num_kv_heads=1, head_dim=16, ssm_heads=2, ssm_head_dim=16, ssm_state=8, ssm_chunk=4,
+        layer_types=("mamba", "attention", "mamba"), param_dtype="float32",
+        compute_dtype="float32")
+    ids, labels = spec.synth_batch(2, np.random.RandomState(0))
+    engine = _engine((spec.extra["cfg"], spec.model.init(0, ids, labels)))
+    try:
+        for h in [engine.submit(np.arange(1, 12, dtype=np.int32), 6),
+                  engine.submit(np.arange(3, 8, dtype=np.int32), 6)]:
+            h.result(timeout=300)
+    finally:
+        engine.close()
+    spans = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    steps = [s for s in spans if s.name == "serving.decode.model_step"]
+    chunks = [s for s in spans if s.name == "serving.decode.prefill"]
+    assert len(steps) >= 6 and len(chunks) >= 3
+    a_slot_layer = 4 * 8 * 32  # a state [N, d_ssm] of float32
+    for s in steps:
+        assert s.attrs["ssm_layers"] == 2 and 1 <= s.attrs["ssm_active_slots"] == s.attrs["active"]
+        assert s.attrs["ssm_state_bytes_moved"] == 2 * s.attrs["active"] * 2 * a_slot_layer
+        assert s.attrs["attend_live_pages"] >= s.attrs["active"] and s.attrs["attend_kernel"] == 0
+    for s in chunks:
+        assert (s.attrs["ssm_active_slots"], s.attrs["ssm_layers"]) == (1, 2)
+        assert s.attrs["ssm_state_bytes_moved"] == 2 * 2 * a_slot_layer
+    reg, label = obs_metrics.default_registry(), {"engine": engine.metrics.engine_label}
+    get = lambda name: reg.get(f"serving.decode.{name}", label, default=None)
+    assert get("state_bytes") == 2 * 3 * (a_slot_layer + 3 * 48 * 4)  # states and tails
+    assert get("cache_bytes_per_token") == 2 * 16 * 4 and get("pages_free") is not None
+    assert get("ssm.layers") == 2 and get("ssm.state_bytes_a_slot") == 2 * a_slot_layer
 
 
 def test_an_idle_engine_adds_nothing_to_the_store(lm):
