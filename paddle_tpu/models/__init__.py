@@ -78,6 +78,11 @@ class ServingPrograms:
     # pages by whole heads. None for a model without pages, or whose rows
     # are not heads side by side
     kv_heads: Optional[Callable[[dict], int]] = None
+    # attends_in_kernel(cfg, pages spec, page_size) -> which of the programs
+    # ("step", "chunk") attend over the pages a sequence holds, through a
+    # kernel, and not over a gathered table: the family's own rule, asked
+    # where the programs are traced. None: none has a kernel form
+    attends_in_kernel: Optional[Callable[..., Tuple[str, ...]]] = None
     extras: Tuple[str, ...] = ()     # names of the small outputs after the cache
     # span_attrs(cfg, *extras as numpy) -> {attribute: number}
     span_attrs: Optional[Callable[..., Dict[str, Any]]] = None

@@ -81,7 +81,7 @@ from paddle_tpu.models.retention_lm import (
     _enforce_sampling, _frame_params, _next_token_loss, _ops, _rms_norm,
 )
 from paddle_tpu.models.transformer_lm import (
-    _attend_cached, _live_mask, _paged_attend, kv_heads, sample_logits,
+    _attend_cached, _live_mask, _paged_attend, kv_attends_in_kernel, kv_heads, sample_logits,
 )
 
 __all__ = [
@@ -548,7 +548,8 @@ def serving_programs() -> ServingPrograms:
         decode_step=hybrid_decode_step, verify_step=None,
         mechanism="Mamba-2 layers with a recurrent state per slot beside attention "
                   "layers with KV pages",
-        kv_heads=kv_heads, extras=("active",), span_attrs=span_attrs,
+        kv_heads=kv_heads, attends_in_kernel=kv_attends_in_kernel, extras=("active",),
+        span_attrs=span_attrs,
         gauges=lambda cfg: {"ssm.layers": len(layers_of(cfg, MAMBA)),
                             "ssm.state_bytes_a_slot": (len(layers_of(cfg, MAMBA))
                                                        * state_bytes_a_slot(cfg))})

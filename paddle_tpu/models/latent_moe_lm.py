@@ -19,9 +19,9 @@ attention (:data:`CORES`):
   many queries share a context; training runs it.
 * absorbed: ``q_lat_h = W_kb_h q_nope_h``, score ``= [q_lat_h ; q_rope_h] .
   [c_s ; k_rope_s]``, ``ctx_h = sum_s a_s c_s``, ``o_h = W_vb_h^T ctx_h``: the
-  heads attend over the cached rows as they lie. A decode step runs it, and
-  a prefill chunk too (``latent_prefill_chunk``'s ``form``: faster on the
-  chip).
+  heads attend over the cached rows as they lie: one key-value head whose
+  value is the key's own row. A decode step runs it, and a prefill chunk
+  too (``latent_prefill_chunk``'s ``form``: faster on the chip).
 
 Under ``rope_scaling`` (YaRN, ``ops/attention.yarn_inv_freq``) the softmax
 scale is ``(nope + rope) ** -0.5 * yarn_mscale(factor, mscale_all_dim) ** 2``.
@@ -44,8 +44,11 @@ The block is written once, :func:`block`; training, a prefill chunk and a
 decode step differ in the ``attend`` they hand it: none of a cache, a slot's
 pages, every slot's pages. The paged ones are
 ``transformer_lm._paged_attend``, the one body that writes a row a position
-into ``[L, page, offset, row]`` and gathers a context back through a page
-table, with one array and this module's cores.
+into ``[L, page, offset, row]`` and attends back through a page table, with
+one array, this module's cores over the gathered table and, for the absorbed
+form, its kernel forms over the pages that are live
+(``ops/pallas/paged_attention.py``: ``latent_attend_step``,
+``latent_attend_chunk``), which a TPU's programs take.
 """
 
 from __future__ import annotations
@@ -64,7 +67,9 @@ from paddle_tpu.models.retention_lm import (
     _dict_params, _embed, _enforce_sampling, _frame_params, _logits,
     _next_token_loss, _ops,
 )
-from paddle_tpu.models.transformer_lm import _live_mask, _paged_attend, sample_logits
+from paddle_tpu.models.transformer_lm import (
+    _live_mask, _paged_attend, sample_logits, step_attends_in_kernel,
+)
 from paddle_tpu.ops import moe
 from paddle_tpu.ops.attention import apply_rope, rope_tables, yarn_mscale
 
@@ -133,6 +138,22 @@ def _softmax_rows(s, live):
     return jax.nn.softmax(jnp.where(live, s, -1e9), axis=-1)
 
 
+_mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+
+
+def _queries_as_rows(q, w_kb, width: int, cdt):
+    """The absorbed queries as rows of the cache: ``q`` [B, H, Q, nope +
+    rope] float32 -> ``[W_kb q_nope ; q_rope ; zeros]``, [B, H, Q, width] in
+    ``cdt``. ``W_kb`` is filled with zero rows to the width, so the product
+    comes out as the row it will be (float32 sums rounded once) and the
+    rotary part is written into its lanes: a concatenation and a pad were
+    two more passes over a chunk's 42 MB of queries."""
+    rank, _, nope = w_kb.shape
+    w = jnp.pad(w_kb.astype(cdt), ((0, width - rank), (0, 0), (0, 0)))
+    q_row = _mm("bhqd,chd->bhqc", q[..., :nope].astype(cdt), w).astype(cdt)
+    return q_row.at[..., rank:rank + q.shape[-1] - nope].set(q[..., nope:].astype(cdt))
+
+
 def _core_absorbed(q, rows, live, w_kb, w_vb, *, scale, cdt):
     """``q`` [B, H, Q, nope + rope] float32 over ``rows`` [B, T, row] as they
     lie in the cache (latent, rotary key, zeros); ``live`` [B, 1, Q, T];
@@ -140,15 +161,45 @@ def _core_absorbed(q, rows, live, w_kb, w_vb, *, scale, cdt):
     float32. Both sides contract the whole row (the query's is filled with
     zeros, the value's columns past the latent are dropped after): slicing
     the gathered context would copy it."""
-    nope, rank = w_kb.shape[-1], w_kb.shape[0]
-    mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    rank = w_kb.shape[0]
     rows = rows.astype(cdt)
-    q_lat = mm("bhqd,chd->bhqc", q[..., :nope].astype(cdt), w_kb.astype(cdt))
-    q_row = jnp.concatenate([q_lat, q[..., nope:]], -1).astype(cdt)
-    q_row = jnp.pad(q_row, ((0, 0),) * 3 + ((0, rows.shape[-1] - q_row.shape[-1]),))
-    a = _softmax_rows(mm("bhqr,btr->bhqt", q_row, rows) * scale, live)
-    ctx = mm("bhqt,btr->bhqr", a.astype(cdt), rows)[..., :rank]
-    return mm("bhqc,chd->bhqd", ctx.astype(cdt), w_vb.astype(cdt))
+    q_row = _queries_as_rows(q, w_kb, rows.shape[-1], cdt)
+    a = _softmax_rows(_mm("bhqr,btr->bhqt", q_row, rows) * scale, live)
+    ctx = _mm("bhqt,btr->bhqr", a.astype(cdt), rows)[..., :rank]
+    return _mm("bhqc,chd->bhqd", ctx.astype(cdt), w_vb.astype(cdt))
+
+
+# the serving programs whose absorbed attention has a kernel form
+KERNEL_PROGRAMS = ("step", "chunk")
+
+
+def _absorbed_in_kernel(cfg, program: str):
+    """The absorbed core's kernel form for ``program``, as ``_paged_attend``
+    takes it: the same products in the same dtypes over the pages a
+    sequence holds, copied where they lie, the softmax online
+    (``ops/pallas/paged_attention.py``); ``W_kb`` before and ``W_vb`` after
+    stay einsums. Each layer's call counts ``mla.kernel.<program>`` as it
+    is traced."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    scale, cdt, rank = softmax_scale(cfg), jnp.dtype(cfg["compute_dtype"]), cfg["kv_lora_rank"]
+    # the value is the row's latent: whole lane tiles of it, or the row
+    value_width = rank if rank % LANES == 0 else None
+
+    def over_live_pages(asked, pages, plane, page_tables, pos):
+        q, w_kb, w_vb = asked  # q [S, H, 1, .] of a step, [1, H, C, .] of a chunk
+        prof.inc_counter(f"mla.kernel.{program}")
+        q_row = _queries_as_rows(q, w_kb, pages[0].shape[-1], cdt)
+        with jax.named_scope("latent_attend"):
+            if program == "step":
+                ctx = pa.latent_attend_step(q_row[:, :, 0], pages[0], plane, page_tables, pos,
+                                            scale=scale, value_width=value_width)[:, :, None]
+            else:
+                ctx = pa.latent_attend_chunk(q_row[0], pages[0], plane, page_tables, pos[0],
+                                             scale=scale, value_width=value_width)[None]
+        return _mm("bhqc,chd->bhqd", ctx[..., :rank], w_vb.astype(cdt))  # ctx in cdt
+
+    return over_live_pages
 
 
 def _core_expanded(q, rows, live, w_kb, w_vb, *, scale, cdt):
@@ -156,13 +207,12 @@ def _core_expanded(q, rows, live, w_kb, w_vb, *, scale, cdt):
     value made from its latent first."""
     nope, rank = w_kb.shape[-1], w_kb.shape[0]
     rope = q.shape[-1] - nope
-    mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
     c, k_rope = rows[..., :rank].astype(cdt), rows[..., rank:rank + rope].astype(cdt)
-    k_nope = mm("btc,chd->bhtd", c, w_kb.astype(cdt)).astype(cdt)
-    v = mm("btc,chd->bhtd", c, w_vb.astype(cdt)).astype(cdt)
-    s = (mm("bhqd,bhtd->bhqt", q[..., :nope].astype(cdt), k_nope)
-         + mm("bhqd,btd->bhqt", q[..., nope:].astype(cdt), k_rope))
-    return mm("bhqt,bhtd->bhqd", _softmax_rows(s * scale, live).astype(cdt), v)
+    k_nope = _mm("btc,chd->bhtd", c, w_kb.astype(cdt)).astype(cdt)
+    v = _mm("btc,chd->bhtd", c, w_vb.astype(cdt)).astype(cdt)
+    s = (_mm("bhqd,bhtd->bhqt", q[..., :nope].astype(cdt), k_nope)
+         + _mm("bhqd,btd->bhqt", q[..., nope:].astype(cdt), k_rope))
+    return _mm("bhqt,bhtd->bhqd", _softmax_rows(s * scale, live).astype(cdt), v)
 
 
 CORES = {"absorbed": _core_absorbed, "expanded": _core_expanded}
@@ -171,13 +221,18 @@ _SCORE_BYTES = 512 * 1024 * 1024
 
 
 def head_block_for(batch: int, heads: int, queries: int, context: int) -> int:
-    """Heads scored at once: all of them while their float32 scores
-    ``[batch, heads, queries, context]`` stay under 512 MiB, else ``heads``
-    halved until they do. On the chip a chunk of 512 queries over 16384
-    gathered rows took 86.5 ms with 64 heads at once (2 GiB of scores),
-    74.5 with 16 (512 MiB) and 74.7 with 8 (PERF.md, PR 31): a block pays
-    where its scores stay resident, and every block reads the gathered rows
-    again, so a step, whose scores are 128 MiB, takes none."""
+    """Heads scored at once by the gathering cores: all of them while their
+    float32 scores ``[batch, heads, queries, context]`` stay under 512 MiB,
+    else ``heads`` halved until they do. On the chip a chunk of 512 queries
+    over 16384 gathered rows took 86.5 ms with 64 heads at once (2 GiB of
+    scores), 74.5 with 16 (512 MiB) and 74.7 with 8 (PERF.md, PR 31): a
+    block pays where its scores stay resident, and every block reads the
+    gathered rows again, so a step, whose scores are 128 MiB, takes none.
+    Since PR 44 a TPU's serving programs do not come here (their absorbed
+    core attends through the kernels over live pages and keeps no scores);
+    the rule is for what keeps the gather: the expanded form, which
+    training runs over its own rows, a replica group's programs, a page
+    array that does not lie in whole tiles, a CPU."""
     g = heads
     while g % 2 == 0 and 4 * batch * g * queries * context > _SCORE_BYTES:
         g //= 2
@@ -222,7 +277,9 @@ def _attend_train(cfg):
 def _attend_pages(cfg, form: str, pages: list, page_tables, pos, page_size: int):
     """Through ``pages[0]``, the engine's one latent page array
     [L, page, offset, row]: ``pos`` [C] with one table [P] (a chunk), or
-    [S] with a table a slot [S, P] (a step)."""
+    [S] with a table a slot [S, P] (a step). The absorbed form brings its
+    kernels beside the gathering core; which of the two a program takes is
+    ``_paged_attend``'s rule."""
     core = _core(cfg, form)
     width = pages[0].shape[-1]
 
@@ -233,8 +290,18 @@ def _attend_pages(cfg, form: str, pages: list, page_tables, pos, page_size: int)
 
     zeros_to_width = lambda r: jnp.pad(r, ((0, 0),) * (r.ndim - 1) + ((0, width - r.shape[-1]),))
     paged = _paged_attend(pages, page_tables, pos, page_size, None, core=over_pages,
-                          to_row=zeros_to_width)
+                          to_row=zeros_to_width,
+                          kernels={p: _absorbed_in_kernel(cfg, p) for p in KERNEL_PROGRAMS}
+                          if form == "absorbed" else None)
     return lambda i, q, row, w_kb, w_vb: paged(i, (q, w_kb, w_vb), row)
+
+
+def attends_in_kernel(cfg: dict, pages, page_size: int) -> tuple:
+    """``ServingPrograms.attends_in_kernel``: the serving programs that
+    attend through a kernel over page arrays shaped as ``pages`` (both run
+    absorbed), by ``_paged_attend``'s rule with the row as the one head."""
+    fits = step_attends_in_kernel(pages, page_size, pages.shape[-1], None)
+    return KERNEL_PROGRAMS if fits else ()
 
 
 # -- the block, written once -----------------------------------------------
@@ -394,13 +461,18 @@ def latent_prefill_chunk(params, tokens, pos0, last_index, page_table, latent_pa
     ``last_index`` are padding and reach no routed expert. Returns
     ``(next_token, latent_pages, expert_load)``.
 
-    ``form`` is the attention core's. By operations expanded wins from a
-    few hundred queries on (expanding costs T * rank * H * (nope + v) once,
-    absorbing C * T * H * (2 * rank - nope - v) more); on the chip, at 512
-    queries over 16384 gathered rows, absorbed was faster (74.5 against
-    99.5 ms a chunk, PERF.md PR 31): both are bound by the scores they
-    materialise, and the expanded keys and values are one more pass.
-    ``tools/moe_gmm_sweep.py`` times the other."""
+    ``form`` is the attention core's. Absorbed, on a TPU, the chunk's
+    queries attend through ``latent_attend_chunk`` over the pages the
+    sequence holds (the chunk's own rows are written first and read back
+    like any others), sixteen queries under every head a tile; nothing is
+    gathered and no score is kept (PERF.md, PR 44). Where the gather stays
+    (``_paged_attend``'s rule) both forms score all of the table's
+    positions: by operations expanded wins from a few hundred queries on
+    (expanding costs T * rank * H * (nope + v) once, absorbing C * T * H *
+    (2 * rank - nope - v) more), yet on the chip, at 512 queries over 16384
+    gathered rows, absorbed was faster (74.5 against 99.5 ms a chunk,
+    PERF.md PR 31): both were bound by the scores they materialised.
+    ``tools/moe_gmm_sweep.py`` times all three."""
     _enforce_sampling(temperature, rng, "latent decode")
     p = _dict_params(params)
     (C,) = tokens.shape
@@ -448,7 +520,7 @@ def serving_programs() -> ServingPrograms:
     return ServingPrograms(
         cache="pages", cache_args=("latent_pages",), cache_specs=latent_cache_specs,
         prefill_chunk=latent_prefill_chunk, decode_step=latent_decode_step,
-        verify_step=None,
+        verify_step=None, attends_in_kernel=attends_in_kernel,
         mechanism="latent attention: one page array whose row is a latent "
                   "and a shared rotary key, not a K and a V per head",
         extras=("expert_load",), span_attrs=span_attrs,

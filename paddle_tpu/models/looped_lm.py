@@ -54,7 +54,7 @@ from paddle_tpu.models.retention_lm import (
     _embed, _enforce_sampling, _frame_params, _next_token_loss, _ops,
 )
 from paddle_tpu.models.transformer_lm import (
-    _attend_cached, _live_mask, _paged_attend, kv_heads, sample_logits,
+    _attend_cached, _live_mask, _paged_attend, kv_attends_in_kernel, kv_heads, sample_logits,
 )
 from paddle_tpu.ops.attention import apply_rope, rope_tables
 
@@ -321,7 +321,8 @@ def serving_programs() -> ServingPrograms:
         verify_step=None,
         mechanism="a decoder whose stack runs several passes, each with K and V "
                   "pages of its own",
-        kv_heads=kv_heads, extras=("exit_cdf", "live_rows"), span_attrs=span_attrs,
+        kv_heads=kv_heads, attends_in_kernel=kv_attends_in_kernel,
+        extras=("exit_cdf", "live_rows"), span_attrs=span_attrs,
         gauges=lambda cfg: {"loop.passes": cfg["total_ut_steps"], "loop.planes": planes(cfg)})
 
 

@@ -701,8 +701,8 @@ def generate_beam(
 # (admit/evict between steps) never pays XLA again. How a page array is
 # indexed is spelled three times: ``paged_cache_shape``, ``_paged_attend``
 # (the page write and the gather) and the copies of
-# ``ops/pallas/paged_attention.py``, which a decode step on a TPU attends
-# through in the gather's place.
+# ``ops/pallas/paged_attention.py``, which a decode step on a TPU (and the
+# latent family's chunk) attends through in the gather's place.
 # Its form, ``[planes, num_pages, page_size, H_kv * dh]`` (planes being the
 # layers or, where a stack runs several passes, passes x layers:
 # ``models/looped_lm.py`` hands ``_paged_attend`` the plane ``r * L + i``
@@ -781,12 +781,14 @@ def _kv_core(q, gather, live):
 
 
 def step_attends_in_kernel(pages, page_size: int, head_dim: int, window) -> bool:
-    """Whether a decode step over the K and V page arrays shaped as ``pages``
-    attends through ``ops/pallas/paged_attention.py``'s kernel and not the
-    gather: on a TPU (``ops/moe.py``'s rule for ``moe_gmm``), with no sliding
-    window, pages that lie in whole tiles, and no mesh of several devices
-    around the trace (a Mosaic kernel cannot be partitioned automatically;
-    the engine traces a replica group's programs under the group's mesh)."""
+    """Whether a program over page arrays shaped as ``pages`` attends through
+    ``ops/pallas/paged_attention.py``'s kernels and not the gather (a decode
+    step over K and V pages; a step and a chunk over the latent family's one
+    array, whose row is one head): on a TPU (``ops/moe.py``'s rule for
+    ``moe_gmm``), with no sliding window, pages that lie in whole tiles, and
+    no mesh of several devices around the trace (a Mosaic kernel cannot be
+    partitioned automatically; the engine traces a replica group's programs
+    under the group's mesh)."""
     from paddle_tpu.ops.pallas.paged_attention import step_fits
 
     mesh = jax.sharding.get_abstract_mesh()
@@ -799,34 +801,63 @@ def _heads_last(new):  # [B, n, Q, dh] or [S, n, dh]: a position's heads side by
     return jnp.moveaxis(new, 1, -2)
 
 
+def _kv_step_in_kernel(q, pages, plane, page_tables, pos):
+    from paddle_tpu.ops.pallas.paged_attention import paged_attend_step
+
+    with jax.named_scope("paged_attend"):
+        ctx = paged_attend_step(q.reshape(pos.shape[0], -1, q.shape[-1]), *pages, plane,
+                                page_tables, pos)
+    return ctx.reshape(q.shape)
+
+
+# the programs of the K and V families that have a kernel form (ROADMAP A5
+# has the chunk and the verify block)
+KV_KERNELS = {"step": _kv_step_in_kernel}
+
+
+def kv_attends_in_kernel(cfg: dict, pages, page_size: int) -> tuple:
+    """``ServingPrograms.attends_in_kernel`` of the families whose page
+    arrays are a K and a V of whole heads (``kv_heads(cfg)`` of them)."""
+    fits = step_attends_in_kernel(pages, page_size, pages.shape[3] // kv_heads(cfg),
+                                  cfg.get("attention_window"))
+    return tuple(KV_KERNELS) if fits else ()
+
+
 def _paged_attend(pages: list, page_tables, pos, page_size: int, window,
-                  core=_kv_core, to_row=_heads_last):
+                  core=_kv_core, to_row=_heads_last, kernels=None):
     """``attend(i, q, *new)`` of queries at absolute positions ``pos``: [C]
-    of the one sequence whose table ``page_tables`` [P] is, or [S] or [S, Q]
-    with a table row a slot [S, P]. It writes the queries' ``new`` rows, one
-    per array of ``pages`` (K and V, the pre-rotated K exactly as generate()
-    stores it; or the one latent row of ``models/latent_moe_lm.py``), into
-    their pages, gathers each sequence's whole logical context
-    [0, P * page_size) back through its table (the rows just written
-    included) and attends under the live mask. ``pages`` is the list of page
-    arrays, each [L, page, offset, row]; it is read and rebound layer by
-    layer. ``to_row`` brings a ``new`` into the order of its rows (it is
-    then reshaped to ``pos.shape + (row,)``); ``core(q, gather, live)`` is
-    the attention itself, ``gather(j)`` the context of array ``j`` as the
-    table gathers it, ``page_tables.shape + (page_size, row)``, and ``live``
-    the mask [B, 1, 1, Q, t_eff].
+    of the one sequence whose table ``page_tables`` [P] is (a chunk), or [S]
+    (a step) or [S, Q] (a verify block) with a table row a slot [S, P]. It
+    writes the queries' ``new`` rows, one per array of ``pages`` (K and V,
+    the pre-rotated K exactly as generate() stores it; or the one latent row
+    of ``models/latent_moe_lm.py``), into their pages, gathers each
+    sequence's whole logical context [0, P * page_size) back through its
+    table (the rows just written included) and attends under the live mask.
+    ``pages`` is the list of page arrays, each [L, page, offset, row]; it is
+    read and rebound layer by layer. ``to_row`` brings a ``new`` into the
+    order of its rows (it is then reshaped to ``pos.shape + (row,)``);
+    ``core(q, gather, live)`` is the attention itself, ``gather(j)`` the
+    context of array ``j`` as the table gathers it, ``page_tables.shape +
+    (page_size, row)``, and ``live`` the mask [B, 1, 1, Q, t_eff].
 
     The gather materializes each sequence's [T_eff, row] context per layer,
-    the straightforward XLA lowering, whatever is live. A decode step over K
-    and V pages (one query a slot, a table row a slot) on a TPU attends
-    through the ``paged_attend_step`` kernel instead, which reads the slot's
-    live pages where they lie (:func:`step_attends_in_kernel`); a chunk, a
-    verify block, a latent core and every program lowered for a CPU keep
-    the gather (ROADMAP A5 has what is left)."""
+    the straightforward XLA lowering, whatever is live. ``kernels`` are the
+    core's forms that read the live pages where they lie instead, by
+    program (``"step"``, ``"chunk"``): ``kernels[program](q, pages, i,
+    page_tables, pos)``. ``_kv_core``'s is ``KV_KERNELS``, the decode step
+    through ``paged_attend_step``; the latent family hands the step's and the
+    chunk's beside its core. A program takes its kernel under
+    :func:`step_attends_in_kernel`'s rule, a one-array cache's row counting
+    as one head; a program whose core has none (a K and V chunk, a verify
+    block: ROADMAP A5) and every program lowered for a CPU keep the
+    gather."""
     P = page_tables.shape[-1]
     B, t_eff = page_tables.size // P, P * page_size
     page, off = pos // page_size, pos % page_size
-    one_query_a_slot = core is _kv_core and page_tables.ndim == 2 and pos.ndim == 1
+    if core is _kv_core:
+        kernels = KV_KERNELS
+    program = "chunk" if page_tables.ndim == 1 else "step" if pos.ndim == 1 else "verify"
+    kernel = (kernels or {}).get(program)
     if page_tables.ndim == 1:  # no slot axis to index: the chunk's program stays as it compiled
         phys = page_tables[page]
     else:
@@ -840,14 +871,10 @@ def _paged_attend(pages: list, page_tables, pos, page_size: int, window,
                 row = to_row(rows).reshape(pos.shape + (-1,))
                 pages[j] = pages[j].at[i, phys, off].set(row.astype(pages[j].dtype))
 
-        if one_query_a_slot and step_attends_in_kernel(
-                pages[0], page_size, q.shape[-1], window):
-            from paddle_tpu.ops.pallas.paged_attention import paged_attend_step
-
-            with jax.named_scope("paged_attend"):
-                ctx = paged_attend_step(q.reshape(B, -1, q.shape[-1]), *pages, i,
-                                        page_tables, pos)
-            return ctx.reshape(q.shape)
+        if kernel is not None and step_attends_in_kernel(
+                pages[0], page_size,
+                pages[0].shape[-1] if len(pages) == 1 else q.shape[-1], window):
+            return kernel(q, pages, i, page_tables, pos)
 
         def gather(j):
             # layer and page are one index into [L * num_pages, offset,
@@ -1047,7 +1074,8 @@ def serving_programs():
         cache="pages", cache_args=("k_pages", "v_pages"),
         cache_specs=paged_cache_specs, prefill_chunk=paged_prefill_chunk,
         decode_step=paged_decode_step, verify_step=paged_verify_step,
-        mechanism="softmax attention over a paged KV cache", kv_heads=kv_heads)
+        mechanism="softmax attention over a paged KV cache", kv_heads=kv_heads,
+        attends_in_kernel=kv_attends_in_kernel)
 
 
 def get_model(

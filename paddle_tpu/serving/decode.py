@@ -73,7 +73,6 @@ from paddle_tpu.core import profiler as prof
 from paddle_tpu.core import retry as retry_mod
 from paddle_tpu.core.enforce import enforce
 from paddle_tpu.models import serving_programs
-from paddle_tpu.models.transformer_lm import step_attends_in_kernel
 from paddle_tpu.observability import roofline, runlog
 from paddle_tpu.parallel import collective
 from paddle_tpu.tracing import waterfall
@@ -594,14 +593,13 @@ class DecodeEngine:
         self._prefill = roofline.instrument(
             "serving.decode.prefill", jax.jit(functools.partial(
                 progs.prefill_chunk, **model_kw), **jit_kw))
-        # whether the step attends through the paged_attend_step kernel: the
-        # model's own rule, asked where the step is traced
-        self._attend_kernel = int(
-            self._paged and progs.kv_heads is not None and _under_mesh(
-                group, step_attends_in_kernel)(
-                    specs[0], dconf.page_size,
-                    pshape[3] // progs.kv_heads(self.model_cfg),
-                    self.model_cfg.get("attention_window")))
+        # which programs attend over live pages through a kernel and not over
+        # a gathered table: the family's own rule, asked where they are traced
+        in_kernel = (_under_mesh(group, progs.attends_in_kernel)(
+            self.model_cfg, specs[0], dconf.page_size)
+            if self._paged and progs.attends_in_kernel is not None else ())
+        self._attend_kernel = int("step" in in_kernel)
+        self._chunk_attend_kernel = "chunk" in in_kernel
         # disagg KV handoff (serving.disagg): one page is the fixed-shape
         # [L, page_size, H_kv * dh] slice, so gather/implant compile once.
         # In group mode the gather's output is pinned replicated — the
@@ -1938,6 +1936,13 @@ class DecodeEngine:
             # copy (enqueue to sync) once the chunk has gone through
             with tracing.start_span("serving.decode.prefill", chunk=c,
                                     last_chunk=last_chunk) as chunk_span:
+                if self._chunk_attend_kernel:
+                    # as a step's: the pages its kernel reads, up to the
+                    # chunk's last query, of the table a gather would read
+                    chunk_span.set(
+                        attend_live_pages=-(-chunk_end // self.decode_config.page_size),
+                        attend_table_pages=self._kv.pages_per_slot,
+                        attend_page_bytes=self._page_bytes, attend_kernel=1)
                 chunk = np.zeros((C,), np.int32)
                 seg = req.seq[c * C:min((c + 1) * C, len(req.seq))]
                 chunk[:len(seg)] = seg

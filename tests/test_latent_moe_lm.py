@@ -67,8 +67,17 @@ def test_absorbed_and_expanded_attention_agree_on_the_same_cache(head_block, mon
     np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("form", ["expanded", "absorbed"])
-def test_chunks_then_steps_through_the_pages_give_the_full_forward_pass(form):
+@pytest.mark.parametrize("form", ["expanded", "absorbed", "absorbed_in_kernel"])
+def test_chunks_then_steps_through_the_pages_give_the_full_forward_pass(form, monkeypatch):
+    from paddle_tpu.core import profiler as prof
+    from paddle_tpu.models import transformer_lm
+
+    in_kernel, before = form == "absorbed_in_kernel", dict(prof.counters())
+    if in_kernel:
+        # the chunk and the step through latent_attend_chunk / _step, which a
+        # CPU interprets, as a TPU's programs take them
+        monkeypatch.setattr(transformer_lm, "step_attends_in_kernel", lambda *a: True)
+        form = "absorbed"
     spec = small_model(seq_len=24)
     cfg = spec.extra["cfg"]
     ids, labels, params = seeded(spec)
@@ -98,6 +107,11 @@ def test_chunks_then_steps_through_the_pages_give_the_full_forward_pass(form):
         params, jnp.asarray(seq[:C]), jnp.int32(0), jnp.int32(2), table, pages,
         cfg=cfg, page_size=page, form=form)
     assert int(load.sum()) == 3 * 2 * 2
+    traced = {k: v - before.get(k, 0) for k, v in prof.counters().items()
+              if k.startswith("mla.kernel.") and v != before.get(k, 0)}
+    # a layer's call of every trace (the calls are not jitted here): three
+    # chunks and eight steps through three layers, or none
+    assert traced == ({"mla.kernel.chunk": 3 * 3, "mla.kernel.step": 8 * 3} if in_kernel else {})
 
 
 # -- (b) YaRN -------------------------------------------------------------------

@@ -91,6 +91,41 @@ def test_served_tokens_are_the_references_through_admission_prefill_and_a_step_f
     assert reg.get("serving.decode.pages_donated", label, default=None) == 1.0
 
 
+def test_the_engine_serves_through_the_kernels_and_its_spans_say_so(lm, monkeypatch):
+    """As on a TPU: the step and the chunk attend through
+    ``latent_attend_step`` / ``latent_attend_chunk`` (interpreted here) over
+    the pages a sequence holds. The served tokens are the reference's still,
+    and the step's and the chunk's span carry what the kernel read beside
+    what a gather would have."""
+    from paddle_tpu.models import latent_moe_lm, transformer_lm
+
+    for module in (latent_moe_lm, transformer_lm):  # the rule, where each asks it
+        monkeypatch.setattr(module, "step_attends_in_kernel", lambda *a: True)
+    tracing.enable_tracing()
+    tracing.reset_tracing()
+    cases = cases_of(np.random.RandomState(5))[:4]
+    eng = DecodeEngine(lm.variables, lm.cfg, decode=DecodeConfig(**DECODE))
+    try:
+        outs = [h.result(timeout=300) for h in [eng.submit(p, m) for p, m in cases]]
+    finally:
+        eng.close()
+    eng.kv.assert_no_leaks()
+    for (prompt, budget), out in zip(cases, outs):
+        assert len(out.tokens) == budget and gap_to_reference(lm, prompt, out.tokens) < 1e-3
+    spans = tracing.spans_for_trace(eng._loop_trace.trace_id)
+    steps = [s.attrs for s in spans if s.name == "serving.decode.model_step"]
+    chunks = [s.attrs for s in spans if s.name == "serving.decode.prefill"]
+    page_bytes = 4 * 3 * 128 * 4  # a page of 4 rows over 3 planes of 128 float32
+    assert steps and {a["attend_kernel"] for a in steps} == {1}
+    assert all(0 < a["attend_live_pages"] < a["attend_table_pages"] == 3 * 16 for a in steps)
+    # 5-, 30-, 9- and 27-token prompts in chunks of 8: a chunk reads the pages up to its end
+    assert sorted(a["attend_live_pages"] for a in chunks) == sorted(
+        8 * (c + 1) // 4 for n in (5, 30, 9, 27) for c in range(-(-n // 8)))
+    assert {(a["attend_kernel"], a["attend_table_pages"], a["attend_page_bytes"])
+            for a in chunks} == {(1, 16, page_bytes)}
+    assert {a["attend_page_bytes"] for a in steps} == {page_bytes}
+
+
 def test_a_shared_prefix_is_adopted_and_copied_on_write_in_the_one_page_array(lm):
     rng = np.random.RandomState(6)
     stem = rng.randint(1, VOCAB, size=(22,)).astype(np.int32)  # 5 full pages, a straddled chunk

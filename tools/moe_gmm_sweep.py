@@ -7,7 +7,8 @@ Prints, per (rows an expert, K, N), milliseconds a call for every candidate
 ``tn`` and for XLA's ragged dot over the same rows: the winner is the row for
 ``ops/pallas/moe.py::_TUNED_BLOCKS``. With ``--programs 1`` it also compiles
 the cell's decode step and prefill chunk on seeded weights and times each
-(the chunk in both forms of the attention, heads a block). Results also go to
+(through the kernels over live pages and with the table gathered; the chunk
+at three positions and in its expanded form). Results also go to
 ``chiprun_out/moe_gmm_sweep.json``. On the chip only: there is no CPU
 fallback."""
 
@@ -94,16 +95,19 @@ def time_programs(workload, out):
     positions = jnp.asarray(rng.integers(1024, 13000, S).astype(np.int32))
     tokens = jnp.asarray(rng.integers(1, base["vocab"], S).astype(np.int32))
     chunk = jnp.asarray(rng.integers(1, base["vocab"], C).astype(np.int32))
-    # score_mib: the scores a head block may hold (head_block_for's constant,
-    # which this sweep is there to set): 2048 is all 64 heads of a chunk at once
-    variants = [("step", {}), ("chunk", {}), ("chunk", {"score_mib": 2048}),
-                ("chunk", {"score_mib": 256}), ("chunk", {"form": "expanded"}),
-                ("chunk", {"form": "expanded", "score_mib": 256})]
-    from paddle_tpu.models import latent_moe_lm
+    # the cell's programs as a TPU lowers them (the absorbed core through the
+    # kernels over live pages), then with the kernels' rule answered no: the
+    # table gathered and scored, 16 heads a block in the chunk (what the cell
+    # ran until PR 44), and the chunk's expanded form, which has no kernel
+    variants = [("step", {}), ("step", {"gather": 1}),
+                ("chunk", {"at": (3072, 8192, 12288 - C)}), ("chunk", {"gather": 1}),
+                ("chunk", {"form": "expanded"})]
+    from paddle_tpu.models import transformer_lm
 
-    cfg, rule = base, latent_moe_lm._SCORE_BYTES
+    cfg, rule = base, transformer_lm.step_attends_in_kernel
     for which, over in variants:
-        latent_moe_lm._SCORE_BYTES = over.get("score_mib", rule >> 20) << 20  # read at the trace
+        # read at the trace
+        transformer_lm.step_attends_in_kernel = (lambda *a: False) if over.get("gather") else rule
         (spec,) = progs.cache_specs(cfg, max_slots=S, num_pages=1 + S * P, page_size=page,
                                     dtype=jnp.bfloat16)
         pages = jnp.zeros(spec.shape, spec.dtype)
@@ -111,30 +115,33 @@ def time_programs(workload, out):
               else functools.partial(progs.prefill_chunk, form=over.get("form", "absorbed")))
         jitted = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
                          donate_argnames=progs.cache_args)
-        args = ((tokens, positions, tables) if which == "step"
-                else (chunk, jnp.int32(8192), jnp.int32(C - 1), tables[0]))
-        t0 = time.perf_counter()
-        try:
-            tok, pages, load = jitted(params, *args, pages, None)
-            jax.block_until_ready(tok)
-            compile_s = time.perf_counter() - t0
+        for pos0 in over.get("at", (8192,)):  # one program: a chunk's position is an argument
+            args = ((tokens, positions, tables) if which == "step"
+                    else (chunk, jnp.int32(pos0), jnp.int32(C - 1), tables[0]))
+            row = {"program": which, "over": {k: v for k, v in over.items() if k != "at"}}
+            if which == "chunk":
+                row["pos0"] = pos0
             t0 = time.perf_counter()
-            for _ in range(10):
+            try:
                 tok, pages, load = jitted(params, *args, pages, None)
-            jax.block_until_ready(tok)
-        except Exception as e:  # a variant that does not fit is a finding, not a stop
-            row = {"program": which, "over": over, "error": f"{type(e).__name__}: {e}"[:400]}
+                jax.block_until_ready(tok)
+                row["first_call_s"] = time.perf_counter() - t0
+                t0 = time.perf_counter()
+                for _ in range(10):
+                    tok, pages, load = jitted(params, *args, pages, None)
+                jax.block_until_ready(tok)
+            except Exception as e:  # a variant that does not fit is a finding, not a stop
+                row["error"] = f"{type(e).__name__}: {e}"[:400]
+                print(json.dumps(row), flush=True)
+                out.append(row)
+                break
+            row.update(ms=(time.perf_counter() - t0) / 10 * 1e3, pairs=int(np.asarray(load).sum()),
+                       hit=int(np.count_nonzero(np.asarray(load))),
+                       peak_bytes=(jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use"))
             print(json.dumps(row), flush=True)
             out.append(row)
-            del pages, jitted
-            continue
-        row = {"program": which, "over": over, "ms": (time.perf_counter() - t0) / 10 * 1e3,
-               "compile_s": compile_s, "pairs": int(np.asarray(load).sum()),
-               "hit": int(np.count_nonzero(np.asarray(load))),
-               "peak_bytes": (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")}
-        print(json.dumps(row), flush=True)
-        out.append(row)
         del pages, jitted
+    transformer_lm.step_attends_in_kernel = rule
 
 
 def main() -> int:
