@@ -55,7 +55,7 @@ class ServingPrograms:
     states indexed by slot, ``slot_ref`` is the slot's index, and
     ``slot_refs`` marks the slots that decode. A model with layers of both
     kinds (``models/hybrid_ssm_lm.py``: Mamba-2 layers beside attention
-    layers) brings ``"pages+state"``: ``cache_specs`` returns page arrays and
+    layers; ``models/hybrid_moe_lm.py``: the same beside expert layers) brings ``"pages+state"``: ``cache_specs`` returns page arrays and
     state arrays, ``state_args`` names the states among ``cache_args``,
     ``slot_ref`` is the pair ``(page-table row, slot index)`` and
     ``slot_refs`` the pair ``(page tables, the mask of decoding slots)``.
@@ -189,6 +189,12 @@ def _hybrid_ssm_lm(**cfg):
     return hybrid_ssm_lm.get_model(**cfg)
 
 
+def _hybrid_moe_lm(**cfg):
+    from paddle_tpu.models import hybrid_moe_lm
+
+    return hybrid_moe_lm.get_model(**cfg)
+
+
 def _transformer_lm(**cfg):
     from paddle_tpu.models import transformer_lm
 
@@ -206,6 +212,7 @@ MODELS: Dict[str, Callable[..., ModelSpec]] = {
     "latent_moe_lm": _latent_moe_lm,
     "looped_lm": _looped_lm,
     "hybrid_ssm_lm": _hybrid_ssm_lm,
+    "hybrid_moe_lm": _hybrid_moe_lm,
     "stacked_dynamic_lstm": _stacked_dynamic_lstm,
     "machine_translation": _machine_translation,
 }

@@ -21,17 +21,20 @@ The cached-attention code this family shares with ``transformer_lm`` scales
 by ``1 / sqrt(head_dim)``, so the query is scaled by the ratio here.
 
 **Mamba-2 mixer**: ``d_ssm = ssm_heads * ssm_head_dim`` channels in heads,
-``ssm_state`` state numbers a channel, one group (``B`` and ``C`` shared by
-all heads), a depthwise causal convolution of ``ssm_conv`` taps::
+``ssm_state`` state numbers a channel, ``G = ssm_groups`` groups of ``B`` and
+``C`` (head ``h`` reads group ``h // (heads / G)``; Granite has one, shared
+by all heads, Nemotron-H eight), a depthwise causal convolution of
+``ssm_conv`` taps::
 
-    [z ; xBC ; dt] = W_in n                        d_ssm + (d_ssm + 2 N) + heads
+    [z ; xBC ; dt] = W_in n                        d_ssm + (d_ssm + 2 G N) + heads
     xBC_t = silu(b_c + sum_j w_c[j] * xBC_{t - (K-1) + j})
-    [x ; B ; C] = xBC_t
+    [x ; B ; C] = xBC_t                                  B and C [G, N]
     dt_t = softplus(dt_t + dt_bias + ssm_dt_shift)       a head, float32
     a_t  = exp(dt_t * A),   A = -exp(A_log)              a head, float32
-    H_t  = a_t H_{t-1} + B_t (x) (dt_t * x_t)            [N, d_ssm], float32
-    y_t  = C_t . H_t + D * x_t
-    out  = W_o RMSNorm_w(y_t * silu(z_t))                the norm over all d_ssm
+    H_t  = a_t H_{t-1} + B_t[g] (x) (dt_t * x_t)         [N, d_ssm], float32
+    y_t  = C_t[g] . H_t + D * x_t
+    out  = W_o RMSNorm_w(y_t * silu(z_t))                the norm by group: over
+                                                         each group's d_ssm / G
 
 What a sequence keeps of such a layer is ``H`` and the last ``K - 1`` inputs
 of the convolution; of an attention layer, K and V rows in pages. **Both
@@ -85,13 +88,15 @@ from paddle_tpu.models.transformer_lm import (
 )
 
 __all__ = [
-    "ATTENTION", "BASE_CFG", "MAMBA", "block", "conv_width", "get_model",
-    "hybrid_cache_specs", "hybrid_decode_step", "hybrid_prefill_chunk",
-    "layers_of", "lm_forward", "param_shapes", "serving_programs", "span_attrs",
-    "ssm_chunked", "ssm_scan", "state_bytes_a_slot",
+    "ATTENTION", "BASE_CFG", "MAMBA", "attention_mixer", "block", "check_mixers", "conv_width",
+    "get_model",
+    "group_rms_norm", "hybrid_cache_specs", "hybrid_decode_step", "hybrid_prefill_chunk",
+    "layers_of", "lm_forward", "mamba_mixer", "mamba_param_shapes", "param_shapes",
+    "serving_programs", "span_attrs", "ssm_chunked", "ssm_scan", "state_bytes_a_slot",
 ]
 
 MAMBA, ATTENTION = "mamba", "attention"
+ATTN_OR_MAMBA = {ATTENTION: "attn", MAMBA: "mamba"}  # a mixer's parameters' prefix
 
 BASE_CFG = dict(
     family="hybrid_ssm_lm",
@@ -159,7 +164,8 @@ def _score_scale(cfg: dict) -> float:
 
 # -- the SSM core, one sequence ----------------------------------------------
 # x [T, D] (heads side by side), dt [T, H], a_neg [H] (= A, negative),
-# b and c [T, N], h [N, D]; everything float32
+# b and c [T, G, N] (channel d reads group d // (D / G)), h [N, D];
+# everything float32
 
 def _by_channel(per_head, P: int):
     """[..., H] a head -> [..., H * P] a channel."""
@@ -168,13 +174,15 @@ def _by_channel(per_head, P: int):
 
 def ssm_scan(x, dt, a_neg, b, c, h0):
     """The plain recurrence, a token at a time. Returns ``(y [T, D], h)``."""
-    P = x.shape[-1] // dt.shape[-1]
+    D = x.shape[-1]
+    P, Dg = D // dt.shape[-1], D // b.shape[-2]
+    columns = lambda v: jnp.repeat(v.T, Dg, axis=-1)  # [G, N] -> [N, D], a channel its group's
 
     def step(h, tok):
         x_t, dt_t, b_t, c_t = tok
         h = (_by_channel(jnp.exp(dt_t * a_neg), P)[None, :] * h
-             + b_t[:, None] * (_by_channel(dt_t, P) * x_t)[None, :])
-        return h, jnp.sum(c_t[:, None] * h, axis=0)
+             + columns(b_t) * (_by_channel(dt_t, P) * x_t)[None, :])
+        return h, jnp.sum(columns(c_t) * h, axis=0)
 
     h, y = jax.lax.scan(step, h0, (x, dt, b, c))
     return y, h
@@ -189,7 +197,7 @@ def ssm_chunked(x, dt, a_neg, b, c, h0, *, chunk: int, cdt=jnp.float32):
     A position whose ``dt`` is 0 adds nothing and decays nothing. Returns
     ``(y [T, D], h)``."""
     T, D = x.shape
-    H = dt.shape[-1]
+    H, G, N = dt.shape[-1], b.shape[-2], b.shape[-1]
     P = D // H
     Q = chunk if T % chunk == 0 else T
     mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
@@ -199,14 +207,18 @@ def ssm_chunked(x, dt, a_neg, b, c, h0, *, chunk: int, cdt=jnp.float32):
     def one_block(h, blk):
         xb, dtb, bb, cb = blk
         l = jnp.cumsum(dtb * a_neg, axis=0).T  # [H, Q]: log of the decay up to t
-        cb_bs = mm("tn,sn->ts", cb.astype(cdt), bb.astype(cdt))
+        cb_bs = mm("tgn,sgn->gts", cb.astype(cdt), bb.astype(cdt))
         decay = jnp.exp(jnp.where(causal, l[:, :, None] - l[:, None, :], -jnp.inf))
         xd = (xb.reshape(Q, H, P) * dtb[:, :, None]).transpose(1, 0, 2)  # [H, Q, P]
-        y = mm("hts,hsp->htp", (cb_bs[None] * decay).astype(cdt), xd.astype(cdt))
-        y = y + jnp.exp(l)[:, :, None] * exact("tn,nd->td", cb, h).reshape(Q, H, P).transpose(1, 0, 2)
+        # a head weighs its group's scores by its own decay
+        scores = (cb_bs[:, None] * decay.reshape(G, H // G, Q, Q)).reshape(H, Q, Q)
+        y = mm("hts,hsp->htp", scores.astype(cdt), xd.astype(cdt))
+        from_h = exact("tgn,ngd->tgd", cb, h.reshape(N, G, D // G))
+        y = y + jnp.exp(l)[:, :, None] * from_h.reshape(Q, H, P).transpose(1, 0, 2)
         to_end = jnp.exp(l[:, -1:] - l)  # [H, Q]
-        carried = (xd * to_end[:, :, None]).transpose(1, 0, 2).reshape(Q, D)
-        h = _by_channel(jnp.exp(l[:, -1]), P)[None, :] * h + exact("sn,sd->nd", bb, carried)
+        carried = (xd * to_end[:, :, None]).transpose(1, 0, 2).reshape(Q, G, D // G)
+        h = (_by_channel(jnp.exp(l[:, -1]), P)[None, :] * h
+             + exact("sgn,sgd->ngd", bb, carried).reshape(N, D))
         return h, y.transpose(1, 0, 2).reshape(Q, D)
 
     split = lambda v: v.reshape((T // Q, Q) + v.shape[1:])
@@ -324,40 +336,64 @@ def _via_step(cfg, cache: dict, page_tables, positions, active, page_size: int):
     return types.SimpleNamespace(window=window, scan=scan, attend=attend)
 
 
-# -- the block, written once ---------------------------------------------------
+# -- the mixers and the block, written once ------------------------------------
+# ``models/hybrid_moe_lm.py`` stacks the same two mixers one a layer, beside
+# an expert layer of its own: ``cfg`` holds this module's keys there too
+
+def group_rms_norm(x, scale, eps: float, groups: int):
+    """RMSNorm with a learned scale over each of ``groups`` equal runs of the
+    last axis separately (one group: over all of it)."""
+    if groups == 1:
+        return _rms_norm(x, scale, eps)
+    by_group = x.astype(jnp.float32).reshape(x.shape[:-1] + (groups, -1))
+    normed = by_group * jax.lax.rsqrt(jnp.mean(by_group * by_group, -1, keepdims=True) + eps)
+    return normed.reshape(x.shape) * scale.astype(jnp.float32)
+
+
+def attention_mixer(p, n, a: str, j: int, cfg: dict, via):
+    """The attention mixer named ``a`` on the normed stream ``n`` [N, T,
+    d_model], its K and V in plane ``j``."""
+    N_, T, _ = n.shape
+    proj, dh = _ops(p, cfg)[0], cfg["head_dim"]
+    heads = lambda y: y.reshape(N_, T, -1, dh).transpose(0, 2, 1, 3)
+    with jax.named_scope("attention"):
+        q, k, v = (heads(proj(n, f"{a}/{w}")) for w in "qkv")
+        ctx = via.attend(j, q * _score_scale(cfg), k, v)
+        return proj(ctx.transpose(0, 2, 1, 3).reshape(N_, T, -1), f"{a}/out")
+
+
+def mamba_mixer(p, n, m: str, j: int, cfg: dict, via):
+    """The Mamba-2 mixer named ``m`` on the normed stream ``n`` [N, T,
+    d_model], its state and tail in plane ``j``."""
+    D, N, H, P, ch = _dims(cfg)
+    G = cfg["ssm_groups"]
+    proj = _ops(p, cfg)[0]
+    f32 = lambda name: p(f"{m}/{name}").astype(jnp.float32)
+    with jax.named_scope("mamba"):
+        zxbcdt = proj(n, f"{m}/in")
+        z, xbc, dt = zxbcdt[..., :D], zxbcdt[..., D:D + ch], zxbcdt[..., D + ch:]
+        xbc = jax.nn.silu(_conv(via.window(j, xbc), p(f"{m}/conv/w"), p(f"{m}/conv/b"),
+                                cfg["ssm_conv_gain"]))
+        by_group = lambda v: v.reshape(v.shape[:-1] + (G, N))
+        xs, b, c = xbc[..., :D], by_group(xbc[..., D:D + G * N]), by_group(xbc[..., D + G * N:])
+        dt = jax.nn.softplus(dt + f32("dt/b") + cfg["ssm_dt_shift"])
+        y = via.scan(j, xs, dt, -jnp.exp(f32("a_log/bias")), b, c)
+        y = y + _by_channel(f32("d/scale"), P) * xs
+        gated = group_rms_norm(y * jax.nn.silu(z), p(f"{m}/norm/scale"), cfg["rms_eps"], G)
+        return proj(gated, f"{m}/out")
+
 
 def block(p, x, i: int, cfg: dict, via):
     """Layer ``i`` on the float32 residual stream ``x`` [N, T, d_model].
     ``p(name)`` yields a parameter; ``via`` reaches the layer's cache by
     whichever form the caller's arrays call for (see the ``_via_*``)."""
-    N_, T, _ = x.shape
     r = cfg["residual_multiplier"] * cfg["branch_gain"]
-    proj, norm, ffn = _ops(p, cfg)
+    _, norm, ffn = _ops(p, cfg)
     kind = cfg["layer_types"][i]
     j = layers_of(cfg, kind).index(i)  # the layer's plane among its kind
-    n = norm(x, f"layer_{i}/mixer_norm")
-    if kind == ATTENTION:
-        dh = cfg["head_dim"]
-        heads = lambda y: y.reshape(N_, T, -1, dh).transpose(0, 2, 1, 3)
-        with jax.named_scope("attention"):
-            q, k, v = (heads(proj(n, f"layer_{i}/attn/{w}")) for w in "qkv")
-            ctx = via.attend(j, q * _score_scale(cfg), k, v)
-            mixed = proj(ctx.transpose(0, 2, 1, 3).reshape(N_, T, -1), f"layer_{i}/attn/out")
-    else:
-        D, N, H, P, ch = _dims(cfg)
-        m = f"layer_{i}/mamba"
-        f32 = lambda name: p(f"{m}/{name}").astype(jnp.float32)
-        with jax.named_scope("mamba"):
-            zxbcdt = proj(n, f"{m}/in")
-            z, xbc, dt = zxbcdt[..., :D], zxbcdt[..., D:D + ch], zxbcdt[..., D + ch:]
-            xbc = jax.nn.silu(_conv(via.window(j, xbc), p(f"{m}/conv/w"), p(f"{m}/conv/b"),
-                                    cfg["ssm_conv_gain"]))
-            xs, b, c = xbc[..., :D], xbc[..., D:D + N], xbc[..., D + N:]
-            dt = jax.nn.softplus(dt + f32("dt/b") + cfg["ssm_dt_shift"])
-            y = via.scan(j, xs, dt, -jnp.exp(f32("a_log/bias")), b, c)
-            y = y + _by_channel(f32("d/scale"), P) * xs
-            gated = _rms_norm(y * jax.nn.silu(z), p(f"{m}/norm/scale"), cfg["rms_eps"])
-            mixed = proj(gated, f"{m}/out")
+    mixer = attention_mixer if kind == ATTENTION else mamba_mixer
+    mixed = mixer(p, norm(x, f"layer_{i}/mixer_norm"), f"layer_{i}/{ATTN_OR_MAMBA[kind]}",
+                  j, cfg, via)
     x = x + r * mixed
     with jax.named_scope("ffn"):
         return x + r * ffn(norm(x, f"layer_{i}/ffn_norm"), i)
@@ -391,25 +427,46 @@ def param_shapes(cfg: dict) -> dict:
     """{name: shape} of every parameter; leaves are named ``w``, ``b``,
     ``scale``, ``bias`` and ``word_emb`` (a Mamba-2 layer's ``dt_bias`` is
     ``dt/b``, its ``A_log`` ``a_log/bias``, its ``D`` ``d/scale``)."""
-    d, f, dh = cfg["d_model"], cfg["d_inner"], cfg["head_dim"]
-    Hq, Hkv = cfg["num_heads"], kv_heads(cfg)
-    D, N, H, _, ch = _dims(cfg)
+    d, f = cfg["d_model"], cfg["d_inner"]
     out = {"emb/word_emb": (cfg["vocab"], d), "final_norm/scale": (d,)}
     for i, kind in enumerate(cfg["layer_types"]):
         out.update({
             f"layer_{i}/mixer_norm/scale": (d,), f"layer_{i}/ffn_norm/scale": (d,),
             f"layer_{i}/ffn/fc1/w": (d, f), f"layer_{i}/ffn/gate/w": (d, f),
             f"layer_{i}/ffn/fc2/w": (f, d)})
-        if kind == ATTENTION:
-            a = f"layer_{i}/attn"
-            out.update({f"{a}/q/w": (d, Hq * dh), f"{a}/k/w": (d, Hkv * dh),
-                        f"{a}/v/w": (d, Hkv * dh), f"{a}/out/w": (Hq * dh, d)})
-        else:
-            m = f"layer_{i}/mamba"
-            out.update({f"{m}/in/w": (d, D + ch + H), f"{m}/conv/w": (cfg["ssm_conv"], ch),
-                        f"{m}/conv/b": (ch,), f"{m}/dt/b": (H,), f"{m}/a_log/bias": (H,),
-                        f"{m}/d/scale": (H,), f"{m}/norm/scale": (D,), f"{m}/out/w": (D, d)})
+        out.update(attention_param_shapes(cfg, f"layer_{i}/attn") if kind == ATTENTION
+                   else mamba_param_shapes(cfg, f"layer_{i}/mamba"))
     return out
+
+
+def attention_param_shapes(cfg: dict, a: str) -> dict:
+    d, dh, Hq, Hkv = cfg["d_model"], cfg["head_dim"], cfg["num_heads"], kv_heads(cfg)
+    return {f"{a}/q/w": (d, Hq * dh), f"{a}/k/w": (d, Hkv * dh),
+            f"{a}/v/w": (d, Hkv * dh), f"{a}/out/w": (Hq * dh, d)}
+
+
+def mamba_param_shapes(cfg: dict, m: str) -> dict:
+    d = cfg["d_model"]
+    D, _, H, _, ch = _dims(cfg)
+    return {f"{m}/in/w": (d, D + ch + H), f"{m}/conv/w": (cfg["ssm_conv"], ch),
+            f"{m}/conv/b": (ch,), f"{m}/dt/b": (H,), f"{m}/a_log/bias": (H,),
+            f"{m}/d/scale": (H,), f"{m}/norm/scale": (D,), f"{m}/out/w": (D, d)}
+
+
+def mamba_initializers(shapes: dict) -> dict:
+    """Mamba-2's own starting points among ``shapes``: dt near 0.01, A = -1,
+    taps of size 1/2."""
+    from paddle_tpu import initializer as init
+
+    own = {}
+    for n in shapes:
+        if n.endswith("/dt/b"):
+            own[n] = init.Constant(float(np.log(np.expm1(0.01))))
+        elif n.endswith("/a_log/bias") or n.endswith("/conv/b"):
+            own[n] = init.Constant(0.0)
+        elif n.endswith("/conv/w"):
+            own[n] = init.Normal(0.0, 0.3)
+    return own
 
 
 def _check(cfg: dict) -> None:
@@ -419,9 +476,14 @@ def _check(cfg: dict) -> None:
     enforce(MAMBA in kinds and ATTENTION in kinds,
             "hybrid_ssm_lm serves a model with layers of both kinds (its cache is pages "
             "and states); a stack of one kind is transformer_lm's or a state family's")
-    enforce(cfg["ssm_groups"] == 1,
-            f"hybrid_ssm_lm: ssm_groups {cfg['ssm_groups']}: B and C of several groups "
-            "are not built")
+    check_mixers(cfg, "hybrid_ssm_lm")
+
+
+def check_mixers(cfg: dict, family: str) -> None:
+    """What the two mixers ask of a configuration, whichever family stacks them."""
+    enforce(cfg["ssm_groups"] >= 1 and cfg["ssm_heads"] % cfg["ssm_groups"] == 0,
+            f"{family}: ssm_heads {cfg['ssm_heads']} do not fall into "
+            f"ssm_groups {cfg['ssm_groups']} equal groups")
     enforce(cfg["num_heads"] % kv_heads(cfg) == 0,
             f"num_heads {cfg['num_heads']} is not a multiple of num_kv_heads {kv_heads(cfg)}")
 
@@ -431,19 +493,9 @@ def _check(cfg: dict) -> None:
 def lm_forward(ids, labels, *, cfg):
     """Next-token training forward through the chunked form, differentiated
     by XLA: ``(loss, token count, logits)`` like ``transformer_lm``'s."""
-    from paddle_tpu import initializer as init
-
     shapes = param_shapes(cfg)
-    own = {}
-    for n in shapes:  # Mamba-2's own starting points: dt near 0.01, A = -1, taps of size 1/2
-        if n.endswith("/dt/b"):
-            own[n] = init.Constant(float(np.log(np.expm1(0.01))))
-        elif n.endswith("/a_log/bias") or n.endswith("/conv/b"):
-            own[n] = init.Constant(0.0)
-        elif n.endswith("/conv/w"):
-            own[n] = init.Normal(0.0, 0.3)
     # the embedding is read twice (the head is tied): made once
-    p = functools.lru_cache(maxsize=None)(_frame_params(cfg, shapes, own))
+    p = functools.lru_cache(maxsize=None)(_frame_params(cfg, shapes, mamba_initializers(shapes)))
     return _next_token_loss(_logits(p, _hidden(p, ids, cfg, _via_train(cfg)), cfg), labels)
 
 

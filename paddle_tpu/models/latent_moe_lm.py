@@ -338,15 +338,8 @@ def block(p, x, i: int, cfg: dict, rope, attend, loads: list, routed=None, kerne
             return x + ffn(n, i)
         m = f"layer_{i}/moe"
         flat = n.reshape(N * T, -1)
-        with jax.named_scope("router"):
-            scores = jax.nn.sigmoid(jnp.matmul(
-                flat, p(f"{m}/router/w").astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            route = moe.topk_route(scores, p(f"{m}/router/b"),
-                                   cfg["experts_per_token"], cfg["routed_scaling"])
-            if routed is not None:  # an index past the router's width is held nowhere
-                route = route._replace(experts=jnp.where(
-                    routed[:, None], route.experts, cfg["num_experts"]))
+        route = moe.sigmoid_route(flat, p(f"{m}/router/w"), p(f"{m}/router/b"),
+                                  cfg["experts_per_token"], cfg["routed_scaling"], routed)
         y, load = moe.expert_share_ffn(
             flat, route, {w: p(f"{m}/experts/{w}/w") for w in ("gate", "fc1", "fc2")},
             held_experts(cfg), compute_dtype=cfg["compute_dtype"], kernel=kernel,
@@ -414,16 +407,7 @@ def stack_experts(params: dict, cfg: dict) -> dict:
     are stacked in their order, every other leaf is passed on. ``params`` is
     emptied as it is read, so that the per-expert arrays go as their stacks
     come (at the published sizes they do not fit beside each other twice)."""
-    first, count = held_experts(cfg)
-    out = {}
-    for i in range(cfg["first_dense"], cfg["n_layers"]):
-        m = f"layer_{i}/moe/experts"
-        for w in ("gate", "fc1", "fc2"):
-            out[f"{m}/{w}/w"] = jnp.stack(
-                [params.pop(f"{m}/{first + j}/{w}/w") for j in range(count)])
-    out.update(params)
-    params.clear()
-    return out
+    return moe.stack_experts(params, held_experts(cfg))
 
 
 # -- training ---------------------------------------------------------------

@@ -11,10 +11,12 @@ the parts across chips is not here). With every expert held it is the whole
 layer.
 
 The token-expert pairs that land on held experts are sorted by expert, each
-expert's rows starting at a multiple of the row tile, and the three SwiGLU
-projections run as grouped matmuls over that layout: the ``moe_gmm`` kernel
-on the chip (``ops/pallas/moe.py``), XLA's ragged dot on the CPU and under
-differentiation.
+expert's rows starting at a multiple of the row tile, and the expert's
+projections (:data:`BODIES`: a SwiGLU's three, or the two of an up-relu^2-down
+expert) run as grouped matmuls over that layout: the ``moe_gmm`` kernel on
+the chip (``ops/pallas/moe.py``), XLA's ragged dot on the CPU and under
+differentiation. The layer works in whatever width it is handed: a model
+whose experts live in a latent space projects down before and up after.
 """
 
 from __future__ import annotations
@@ -26,7 +28,8 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.enforce import enforce
 
-__all__ = ["Route", "topk_route", "share_layout", "expert_share_ffn", "row_tile_for"]
+__all__ = ["BODIES", "Route", "topk_route", "sigmoid_route", "share_layout",
+           "expert_share_ffn", "row_tile_for", "stack_experts"]
 
 
 def row_tile_for(rows_an_expert: float) -> int:
@@ -56,6 +59,22 @@ def topk_route(scores, bias, k: int, scaling: float = 1.0) -> Route:
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / jnp.sum(chosen, -1, keepdims=True) * scaling
     return Route(experts.astype(jnp.int32), weights)
+
+
+def sigmoid_route(flat, router_w, router_b, k: int, scaling: float, routed=None) -> Route:
+    """The router of the DeepSeek-V3 kind: ``s = sigmoid(W_r n)`` in float32
+    over the router's full width (float32 operands, ``highest`` precision:
+    a rounded score flips a near tie), then :func:`topk_route`. ``routed``
+    [N] bool: the tokens whose pairs the expert layer computes (None: all);
+    the others' are given an index past the router's width, held nowhere."""
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            flat, router_w.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
+        route = topk_route(scores, router_b, k, scaling)
+        if routed is not None:
+            route = route._replace(experts=jnp.where(
+                routed[:, None], route.experts, router_w.shape[-1]))
+    return route
 
 
 class ShareLayout(NamedTuple):
@@ -102,12 +121,28 @@ def share_layout(experts, held: Tuple[int, int], tm: int) -> ShareLayout:
                        tile_expert, used.reshape(1).astype(jnp.int32))
 
 
+def _swiglu(gmm, rows, w):
+    return gmm(jax.nn.silu(gmm(rows, w["gate"])) * gmm(rows, w["fc1"]), w["fc2"])
+
+
+def _relu2(gmm, rows, w):
+    return gmm(jnp.square(jax.nn.relu(gmm(rows, w["fc1"]))), w["fc2"])
+
+
+# an expert's body over the sorted rows: ``gmm(rows, stacked weights)`` is the
+# grouped matmul, the activation between is float32
+BODIES = {"swiglu": _swiglu, "relu2": _relu2}
+
+
 def expert_share_ffn(x, route: Route, experts: dict, held: Tuple[int, int], *,
                      compute_dtype=jnp.bfloat16, kernel: Optional[bool] = None,
-                     row_tile: Optional[int] = None, rows_an_expert: float = 0.0):
-    """``sum_{e selected and held} w_e E_e(x)`` per token, ``E_e`` a SwiGLU:
-    ``x`` [N, d]; ``experts`` holds ``gate``, ``fc1`` [count, d, f] and
-    ``fc2`` [count, f, d], the held experts' weights stacked in their order.
+                     row_tile: Optional[int] = None, rows_an_expert: float = 0.0,
+                     body: str = "swiglu"):
+    """``sum_{e selected and held} w_e E_e(x)`` per token: ``x`` [N, d];
+    ``E_e`` is ``body`` of :data:`BODIES`, a SwiGLU (``experts`` holds
+    ``gate``, ``fc1`` [count, d, f] and ``fc2`` [count, f, d], the held
+    experts' weights stacked in their order) or ``W2 relu(W1 x)^2`` (``fc1``
+    and ``fc2`` alone); routing, layout and scatter are the same for both.
     Matmul operands are cast to ``compute_dtype``, sums are float32.
     ``kernel``: the ``moe_gmm`` Mosaic kernel (default: on a TPU backend),
     else XLA's ragged dot, which is also differentiable. ``row_tile``: rows
@@ -116,7 +151,7 @@ def expert_share_ffn(x, route: Route, experts: dict, held: Tuple[int, int], *,
     [count] int32)``; ``load`` is the tokens each held expert took."""
     from paddle_tpu.ops.pallas import moe as pmoe
 
-    count = experts["gate"].shape[0]
+    count = experts["fc1"].shape[0]
     enforce(held[1] == count, f"expert_share_ffn: {count} experts' weights "
             f"for a share of {held[1]}")
     if kernel is None:
@@ -130,10 +165,29 @@ def expert_share_ffn(x, route: Route, experts: dict, held: Tuple[int, int], *,
     else:
         gmm = lambda a, w: pmoe.moe_gmm_xla(a.astype(cdt), w.astype(cdt), lay.padded)
     with jax.named_scope("moe_experts"):
-        rows = jnp.take(x, lay.src, axis=0)
-        h = jax.nn.silu(gmm(rows, experts["gate"])) * gmm(rows, experts["fc1"])
-        out = gmm(h, experts["fc2"])  # [rows, d]
+        out = BODIES[body](gmm, jnp.take(x, lay.src, axis=0), experts)  # [rows, d]
         picked = jnp.take(out, jnp.minimum(lay.dest, out.shape[0] - 1), axis=0)  # [N, k, d]
         # where, not times zero: rows no tile wrote hold anything
         y = jnp.sum(jnp.where(lay.here[..., None], route.weights[..., None] * picked, 0.0), 1)
     return y, lay.load
+
+
+def stack_experts(params: dict, held: Tuple[int, int]) -> dict:
+    """The stacked leaves ``<m>/experts/<which>/w`` [count, a, b] from a
+    checkpoint that holds a matrix an expert, ``<m>/experts/<e>/<which>/w``
+    with ``e`` the expert's index in the router's width: the held experts'
+    are stacked in their order, every other leaf is passed on. ``params`` is
+    emptied as it is read, so that the per-expert arrays go as their stacks
+    come (at the published sizes they do not fit beside each other twice)."""
+    import re
+
+    first, count = held
+    one = re.compile(rf"(.+/experts)/{first}/(\w+)/w")
+    out = {}
+    for name in [n for n in params if one.fullmatch(n)]:
+        m, which = one.fullmatch(name).groups()
+        out[f"{m}/{which}/w"] = jnp.stack(
+            [params.pop(f"{m}/{first + j}/{which}/w") for j in range(count)])
+    out.update(params)
+    params.clear()
+    return out

@@ -52,6 +52,17 @@ _TUNED_BLOCKS: dict[tuple[int, int, int, int], int] = {
     (64, 4096, 2048, 2): 2048,
     # 0.828, 2048 0.855; 1.906
     (64, 2048, 4096, 2): 4096,
+    # 128 experts held in a latent of 1024, each token 22 of 512 (PERF.md,
+    # PR 45): a step, 64 tokens (358 pairs on 120 experts): 0.956, 896 1.006;
+    # 3.543 (least by bytes 0.812)
+    (16, 1024, 2688, 2): 2688,
+    # 0.940, 512 0.981; 2.671
+    (16, 2688, 1024, 2): 1024,
+    # a chunk, 512 tokens (2734 pairs on 128 experts) in tiles of 64, which
+    # the model takes: 1.155, 896 1.269; 3.289 (in tiles of 32: 1.095)
+    (64, 1024, 2688, 2): 2688,
+    # 1.104, 512 1.192; 2.537 (in tiles of 32: 1.064)
+    (64, 2688, 1024, 2): 1024,
 }
 
 _BLOCK_BYTES = 16 * 1024 * 1024  # one weight block; two are in flight

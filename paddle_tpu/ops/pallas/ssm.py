@@ -3,10 +3,14 @@
 
 A Mamba-2 layer (``models/hybrid_ssm_lm.py``) keeps per sequence a state
 ``H`` of ``d_state x d_ssm`` float32 numbers (2 MB a slot and layer at the
-Granite 4.0-H widths). One decode step has to decay it, add the new token's
-outer product and contract it with ``C``::
+Granite 4.0-H widths, 4 MB at Nemotron-H's). One decode step has to decay
+it, add the new token's outer product and contract it with ``C``::
 
     H = a * H + B (x) (dt * x)          y = C . H
+
+``B`` and ``C`` come in ``G`` groups: the channels are ``G`` equal runs of
+heads and a channel reads its run's ``B`` and ``C`` (``G`` 1: all heads share
+one).
 
 The state is far larger than everything else the step touches, so the step
 is bound by how often it crosses HBM. XLA's einsum form crosses it three
@@ -21,8 +25,10 @@ Layout. A slot's state is ``[d_state, d_ssm]`` with the channel axis
 a head's decay and ``dt * x`` are then lane vectors that broadcast along
 sublanes, ``B`` and ``C`` are columns that broadcast along lanes, and the
 contraction with ``C`` sums over sublanes: elementwise adds of whole
-registers. Everything is float32 on the vector unit; no product goes through
-the matrix unit, so nothing is rounded to bfloat16 on the way.
+registers. The body walks the channels in chunks of 512 lanes; a group is a
+whole number of chunks, so a chunk reads one group's columns. Everything is
+float32 on the vector unit; no product goes through the matrix unit, so
+nothing is rounded to bfloat16 on the way.
 """
 
 from __future__ import annotations
@@ -61,32 +67,34 @@ def _masked(active, xdt, decay, b, c):
     whatever the slot's row held."""
     on = (active != 0)[:, None]
     return (jnp.where(on, xdt, 0.0), jnp.where(on, decay, 1.0),
-            jnp.where(on, b, 0.0), jnp.where(on, c, 0.0))
+            jnp.where(on[..., None], b, 0.0), jnp.where(on[..., None], c, 0.0))
 
 
 def _step_kernel(ids_ref, meta_ref, u_ref, bc_ref, h_ref, y_ref, h_out_ref):
     """One entry of the active list: the slot's whole state ``[N, D]``.
     ``u_ref`` [2, D] holds ``dt * x`` and the decay a channel, ``bc_ref``
-    [2, N] holds ``B`` and ``C``. Entries past the active count name the last
-    active slot's blocks again and compute nothing: the pipeline keeps the
-    blocks it has and writes them back once."""
+    [2 G, N] holds the groups' ``B`` rows, then their ``C`` rows. Entries past
+    the active count name the last active slot's blocks again and compute
+    nothing: the pipeline keeps the blocks it has and writes them back once."""
     del ids_ref
 
     @pl.when(pl.program_id(0) < meta_ref[0])
     def _():
         N, D = h_ref.shape
+        G = bc_ref.shape[0] // 2
         # B and C as columns: the diagonal of a broadcast row, summed along
         # lanes (one term a row is not zero, so the sum is exact)
         eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
                == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1))
         col = lambda row: jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
-        b_col, c_col = col(bc_ref[0:1, :]), col(bc_ref[1:2, :])
-        W = LANE_CHUNK if D % LANE_CHUNK == 0 else D
-        for k in range(D // W):
-            at = slice(k * W, (k + 1) * W)
-            new = u_ref[1:2, at] * h_ref[:, at] + b_col * u_ref[0:1, at]
-            h_out_ref[:, at] = new
-            y_ref[0:1, at] = jnp.sum(new * c_col, axis=0, keepdims=True)
+        W = LANE_CHUNK if (D // G) % LANE_CHUNK == 0 else D // G
+        for g in range(G):
+            b_col, c_col = col(bc_ref[g:g + 1, :]), col(bc_ref[G + g:G + g + 1, :])
+            for k in range(g * (D // G) // W, (g + 1) * (D // G) // W):
+                at = slice(k * W, (k + 1) * W)
+                new = u_ref[1:2, at] * h_ref[:, at] + b_col * u_ref[0:1, at]
+                h_out_ref[:, at] = new
+                y_ref[0:1, at] = jnp.sum(new * c_col, axis=0, keepdims=True)
 
 
 def ssm_step(state, xdt, decay, b, c, active, *, layer,
@@ -95,8 +103,9 @@ def ssm_step(state, xdt, decay, b, c, active, *, layer,
 
     ``state`` [L, S, N, D] float32 (donate it: the output aliases it);
     ``xdt`` [S, D] the new token's ``dt * x`` a channel, ``decay`` [S, D]
-    its ``exp(dt * A)`` a channel, ``b`` and ``c`` [S, N], all float32;
-    ``active`` [S], non-zero where the slot decodes; ``layer`` an int,
+    its ``exp(dt * A)`` a channel, ``b`` and ``c`` [S, G, N] (channel ``d``
+    reads group ``d // (D / G)``), all float32; ``active`` [S], non-zero
+    where the slot decodes; ``layer`` an int,
     traced or not. Returns ``(y [S, D], state)``: ``y[s] = C_s . H_s`` after
     the update for an active slot and 0 for an idle one, whose state is
     left where it lies."""
@@ -111,16 +120,18 @@ def _ssm_step(state, xdt, decay, b, c, active, layer, *, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     L, S, N, D = state.shape
+    G = b.shape[1] if b.ndim == 3 else 0
     enforce(state.dtype == jnp.float32 and xdt.shape == decay.shape == (S, D)
-            and b.shape == c.shape == (S, N) and active.shape == (S,),
+            and b.shape == c.shape == (S, G, N) and active.shape == (S,)
+            and G >= 1 and D % G == 0,
             f"ssm_step: operands do not match state {state.shape} {state.dtype}: "
             f"{xdt.shape} {decay.shape} {b.shape} {c.shape} {active.shape}")
-    enforce(interpret or (D % LANES == 0 and N % 8 == 0),
-            f"ssm_step: a state tile [{N}, {D}] does not lie in whole tiles")
+    enforce(interpret or ((D // G) % LANES == 0 and N % 8 == 0),
+            f"ssm_step: a state tile [{N}, {D}] in {G} groups does not lie in whole tiles")
     xdt, decay, b, c = _masked(active, xdt, decay, b, c)
     ids, n = active_list(active)
     u = jnp.stack([xdt, decay], axis=1)  # [S, 2, D]
-    bc = jnp.stack([b, c], axis=1)       # [S, 2, N]
+    bc = jnp.concatenate([b, c], axis=1)  # [S, 2 G, N]
     by_slot = lambda rows, width: pl.BlockSpec(
         (None, rows, width), lambda j, ids, meta: (ids[j], 0, 0))
     tile = pl.BlockSpec((None, None, N, D), lambda j, ids, meta: (meta[1], ids[j], 0, 0))
@@ -130,7 +141,7 @@ def _ssm_step(state, xdt, decay, b, c, active, layer, *, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S,),
-            in_specs=[by_slot(2, D), by_slot(2, N), tile],
+            in_specs=[by_slot(2, D), by_slot(2 * G, N), tile],
             out_specs=[by_slot(1, D), tile]),
         out_shape=[jax.ShapeDtypeStruct((S, 1, D), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -150,6 +161,9 @@ def ssm_step_xla(state, xdt, decay, b, c, active, *, layer: int):
     the state, idle slots moved with the rest): what the kernel is tested
     against, and what a program lowered for anything but a TPU runs."""
     xdt, decay, b, c = _masked(active, xdt, decay, b, c)
-    new = decay[:, None, :] * state[layer] + b[:, :, None] * xdt[:, None, :]
-    y = jnp.einsum("sn,snd->sd", c, new, precision=jax.lax.Precision.HIGHEST)
-    return y, state.at[layer].set(new)
+    S, G, N = b.shape
+    by_group = lambda v: v.reshape(S, 1, G, -1)  # [S, D] -> [S, 1, G, D / G]
+    old = state[layer].reshape(S, N, G, -1)
+    new = by_group(decay) * old + jnp.swapaxes(b, 1, 2)[..., None] * by_group(xdt)
+    y = jnp.einsum("sgn,sngd->sgd", c, new, precision=jax.lax.Precision.HIGHEST)
+    return y.reshape(S, -1), state.at[layer].set(new.reshape(state.shape[1:]))

@@ -5,7 +5,8 @@ chip that is described, not attached. These are the programs
 ``chip_smoke.py`` runs — the flash kernel, the ``lm_large`` train step, the
 paged serving steps and the four-chip data-parallel step — and the two
 serving programs of the benchmark's ``brumby_14b``, ``sarvam_105b``,
-``ouro_2_6b`` and ``granite_4_0_h_micro`` cells, so what the
+``ouro_2_6b``, ``granite_4_0_h_micro`` and ``nemotron_3_super_120b_a12b``
+cells, so what the
 chip's compiler would refuse (a kernel that cannot be partitioned, a
 program that does not fit HBM) fails here, at no chip time. Nothing runs:
 a passing compile says nothing about results or speed.
@@ -502,6 +503,84 @@ def test_granite_serving_steps_fit_the_chip_and_alias_pages_and_states(one_chip,
         assert calls.count("ssm_step") == 36 and calls.count("paged_attend_step") == 4
     else:
         assert calls == []
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_nemotron_serving_steps_fit_the_chip_and_alias_pages_and_states(one_chip, as_tpu, which):
+    """The cell nemotron_3_super_120b_a12b.serve_chat64_moe at its own shapes:
+    one period of 11 single-mixer layers at the published widths in bfloat16,
+    128 of 512 experts held (9.30 GB), beside 64 slots of SSM state in 8
+    groups and convolution tails (1.38 GB, float32) and 64 x 3072 positions of
+    bfloat16 K and V pages in one plane (0.20 GB): 10.88 GB of arguments. Each
+    program must alias all four cache arrays to its outputs, copy none of them
+    whole, hold each as the model spells it and fit the chip beside its
+    temporaries (reported). The step holds ``ssm_step`` once a Mamba-2 layer
+    (a state tile of [128, 8192] in 8 groups), ``moe_gmm`` twice an expert
+    layer and ``paged_attend_step`` once; the chunk holds ``moe_gmm`` alone."""
+    import json
+    import re
+
+    from benchmarks.families import hybrid_moe_lm as family
+    from paddle_tpu.models import hybrid_moe_lm as hmm
+    from paddle_tpu.ops.pallas import moe as moe_kernel
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(here, "configs", "nemotron_3_super_120b_a12b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "traffic", "serve_chat64_moe.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = family._program_cfg(config)
+    assert cfg["max_len"] == engine["max_context"] == 3072 and cfg["experts_held"] == (0, 128)
+    progs = models.serving_programs(cfg)
+    bf16 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = {k: bf16(shape) for k, shape in hmm.param_shapes(cfg).items()}
+    slots, page = engine["max_slots"], engine["page_size"]
+    per_slot = engine["max_context"] // page
+    specs = progs.cache_specs(cfg, max_slots=slots, num_pages=1 + slots * per_slot,
+                              page_size=page, dtype=jnp.dtype(engine["cache_dtype"]))
+    assert [s.shape for s in specs] == [(1, 12289, 16, 256)] * 2 + [
+        (5, 64, 128, 8192), (5, 64, 3 * 10240)]
+    cache = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip) for s in specs]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    if which == "decode_step":
+        fn, args = progs.decode_step, (i32(slots), i32(slots), (i32(slots, per_slot), i32(slots)))
+    else:
+        fn, args = progs.prefill_chunk, (i32(engine["prefill_chunk"]), i32(), i32(),
+                                         (i32(per_slot), i32()))
+    moe_kernel.take_resolved()
+    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
+                       donate_argnames=progs.cache_args,
+                       ).lower(params, *args, *cache, None).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    nbytes = lambda a: int(np.prod(a.shape)) * a.dtype.itemsize
+    cache_bytes = sum(nbytes(c) for c in cache)
+    weight_bytes = sum(nbytes(p) for p in params.values())
+    assert weight_bytes == 2 * 4_648_163_712 and cache_bytes == 1_582_841_856
+    assert 10.87e9 < mem.argument_size_in_bytes < 10.89e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
+    for c in cache:
+        whole = ("f32[" if c.dtype == jnp.float32 else "bf16[") + ",".join(
+            str(d) for d in c.shape) + "]"
+        copies = [l.strip()[:120] for l in text.splitlines()
+                  if whole in l.split("=")[0] and " copy(" in l]
+        assert not copies, copies[:2]
+        layouts = set(re.findall(re.escape(whole) + r"\{([\d,]+)", entry))
+        assert layouts == {",".join(str(d) for d in reversed(range(len(c.shape))))}, (whole, layouts)
+    print(which, "temp", mem.temp_size_in_bytes, "arguments", mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    calls = _mosaic_calls(text)
+    # the two new shapes of the grouped matmul, in the row tile each program lays out
+    tm = 16 if which == "decode_step" else 64
+    assert moe_kernel.take_resolved() == {f"moe_gmm_{tm}x1024x2688": "2688 table",
+                                          f"moe_gmm_{tm}x2688x1024": "1024 table"}
+    if which == "decode_step":
+        assert sorted(set(calls)) == ["moe_gmm", "paged_attend_step", "ssm_step"]
+        assert (calls.count("ssm_step"), calls.count("moe_gmm"),
+                calls.count("paged_attend_step")) == (5, 10, 1)
+    else:
+        assert calls == ["moe_gmm"] * 10
 
 
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])

@@ -84,13 +84,14 @@ def gap_to_reference(lm, prompt, tokens) -> float:
 
 # -- (a) the SSM core: recurrence = chunked form = one-token step -------------
 
-@pytest.mark.parametrize("decays", ["near_0", "near_1", "mixed"])
-def test_the_three_forms_of_the_core_agree(decays):
-    T, H, P, N, Q = 22, 3, 8, 16, 8  # two whole blocks and one of 6, padded to 8
+@pytest.mark.parametrize("decays, G", [("near_0", 1), ("near_1", 1), ("mixed", 1),
+                                       ("mixed", 2), ("mixed", 8)])
+def test_the_three_forms_of_the_core_agree(decays, G):
+    T, H, P, N, Q = 22, 3 if G == 1 else 8, 8, 16, 8  # two whole blocks and one of 6, padded to 8
     D = H * P
     rng = np.random.default_rng(11)
     f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    x, b, c, h0 = f32(T, D), f32(T, N), f32(T, N), f32(N, D)
+    x, b, c, h0 = f32(T, D), f32(T, G, N), f32(T, G, N), f32(N, D)
     a_neg = -jnp.exp(0.3 * f32(H))
     dt = {"near_0": rng.uniform(2.0, 6.0, (T, H)), "near_1": rng.uniform(1e-4, 2e-3, (T, H)),
           "mixed": np.exp(rng.uniform(np.log(1e-3), np.log(3.0), (T, H)))}[decays]
@@ -101,7 +102,8 @@ def test_the_three_forms_of_the_core_agree(decays):
         # chunk by chunk as the engine's prefill walks a prompt: the state is
         # handed on, the last chunk is padded and a padded position has dt 0
         pad = -T % Q
-        xp, bp, cp = (jnp.pad(v, ((0, pad), (0, 0)), constant_values=7.0) for v in (x, b, c))
+        xp, bp, cp = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1), constant_values=7.0)
+                      for v in (x, b, c))
         dtp = jnp.pad(dt, ((0, pad), (0, 0)))
         ys, h = [], h0
         for at in range(0, T + pad, Q):
@@ -184,8 +186,34 @@ def test_the_model_is_in_the_registry_and_brings_pages_and_states():
     assert h.dtype == tail.dtype == jnp.float32
     with pytest.raises(Exception, match="layers of both kinds"):
         models.get_model("hybrid_ssm_lm", layer_types=("mamba", "mamba"))
-    with pytest.raises(Exception, match="ssm_groups 2"):
-        models.get_model("hybrid_ssm_lm", ssm_groups=2)
+    with pytest.raises(Exception, match="ssm_groups 3"):
+        models.get_model("hybrid_ssm_lm", ssm_groups=3)
+
+
+def test_two_groups_of_b_and_c_run_against_the_scan(monkeypatch):
+    """``ssm_groups`` 2 (refused until PR 45): the model's forward through the
+    chunked form against the same forward through the plain recurrence
+    (``ssm_scan``, which ``tests/test_ssm_kernel.py`` holds to the recurrence
+    spelled a channel at a time), on logits; the groups matter (every head
+    reading group 0's B and C gives other logits). The reference of this
+    family has one group; ``references/hybrid_moe_lm.py`` has them."""
+    two = _lm(ssm_groups=2)
+    assert two.cfg["ssm_groups"] == 2
+    assert two.variables.params["layer_0/mamba/in/w"].shape == (64, 64 + (64 + 2 * 2 * 8) + 4)
+    ids = np.random.RandomState(1).randint(1, VOCAB, size=(2, 19)).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        (_, _, logits), _ = two.spec.model.apply(two.variables, ids, ids)
+        chunked = hm.ssm_chunked
+        monkeypatch.setattr(hm, "ssm_chunked",
+                            lambda x, dt, a, b, c, h0, **_: hm.ssm_scan(x, dt, a, b, c, h0))
+        (_, _, want), _ = two.spec.model.apply(two.variables, ids, ids)
+        first = lambda v: jnp.repeat(v[..., :1, :], v.shape[-2], axis=-2)
+        monkeypatch.setattr(hm, "ssm_chunked", lambda x, dt, a, b, c, h0, **kw: chunked(
+            x, dt, a, first(b), first(c), h0, **kw))
+        (_, _, wrong), _ = two.spec.model.apply(two.variables, ids, ids)
+    # float32 both sides, the logits of order 1: sums in other orders
+    np.testing.assert_allclose(logits, want, rtol=1e-4, atol=2e-5)
+    assert np.abs(np.asarray(wrong) - np.asarray(want)).max() > 1e-2
 
 
 # -- (c) prefill then decode through the mixed cache, on logits ---------------

@@ -248,6 +248,58 @@ def test_moe_gmm_in_interpret_mode_is_xlas_ragged_dot(tm, tn):
     assert resolved[f"moe_gmm_{tm}x{K}x{Nout}"].endswith("caller" if tn else "rule")
 
 
+@pytest.mark.parametrize("K, Nout", [(1024, 2688), (2688, 1024)])
+def test_moe_gmm_at_the_latent_experts_shapes_is_xlas_ragged_dot(K, Nout):
+    """The two products of an up-relu^2-down expert in a latent of 1024 (a step
+    of 64 tokens, 22 of 512 a token, on 16 of the held experts, tiles of 16):
+    the whole matrix is one block (by the rule for float32 operands here, by
+    the table's chip-measured row for the chip's bfloat16)."""
+    rng = np.random.default_rng(5)
+    N, k, E, tm = 64, 22, 512, 16
+    held = (32, 16)
+    experts = jnp.asarray(np.stack([rng.choice(E, k, replace=False) for _ in range(N)]), jnp.int32)
+    lay = moe.share_layout(experts, held, tm)
+    x = jnp.asarray(rng.normal(size=(lay.src.shape[0], K)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(16, K, Nout)) * 0.03, jnp.float32)
+    got = kernel.moe_gmm(x, w, lay.tile_expert, lay.used, tm=tm, interpret=True)
+    want = kernel.moe_gmm_xla(x, w, lay.padded)
+    used = int(lay.used[0]) * tm
+    assert 0 < int(lay.load.sum()) == int(lay.here.sum()) <= used
+    np.testing.assert_allclose(got[:used], want[:used], rtol=2e-5, atol=2e-4)
+    assert kernel.take_resolved()[f"moe_gmm_{tm}x{K}x{Nout}"].split()[0] == str(Nout)
+    assert kernel.resolve_tiles(tm, K, Nout, 2) == (Nout, "table")  # bfloat16 on the chip
+    assert kernel.resolve_tiles(64, K, Nout, 2) == (Nout, "table")  # a chunk's tiles
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["ragged_dot", "kernel"])
+def test_the_relu2_body_shares_the_routing_layout_and_scatter(use_kernel):
+    """``expert_share_ffn`` with the up-relu^2-down body against a plain loop
+    over the held experts; the SwiGLU body over the same routing differs."""
+    rng = np.random.default_rng(6)
+    N, k, E, d, f = 20, 3, 12, 16, 24
+    held = (3, 6)
+    x = jnp.asarray(rng.normal(size=(N, d)), jnp.float32)
+    experts = jnp.asarray(np.stack([rng.choice(E, k, replace=False) for _ in range(N)]), jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(N, k)), jnp.float32)
+    w = {"fc1": jnp.asarray(rng.normal(size=(6, d, f)) * 0.3, jnp.float32),
+         "fc2": jnp.asarray(rng.normal(size=(6, f, d)) * 0.3, jnp.float32)}
+    with jax.default_matmul_precision("highest"):
+        y, load = moe.expert_share_ffn(x, moe.Route(experts, weights), w, held, body="relu2",
+                                       compute_dtype=jnp.float32, kernel=use_kernel,
+                                       row_tile=8 if use_kernel else None)
+        want = np.zeros((N, d), np.float32)
+        for i in range(N):
+            for e, we in zip(np.asarray(experts[i]), np.asarray(weights[i])):
+                if 3 <= e < 9:
+                    h = np.maximum(np.asarray(x[i]) @ np.asarray(w["fc1"][e - 3]), 0.0) ** 2
+                    want[i] += we * (h @ np.asarray(w["fc2"][e - 3]))
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+    assert load.tolist() == [int((np.asarray(experts) == e).sum()) for e in range(3, 9)]
+    with pytest.raises(KeyError):
+        moe.expert_share_ffn(x, moe.Route(experts, weights), w, held, body="swiglu",
+                             compute_dtype=jnp.float32, kernel=False)
+
+
 def test_no_pair_held_here_is_a_layer_that_adds_nothing():
     experts = jnp.zeros((5, 2), jnp.int32)  # every pair on expert 0, held elsewhere
     rng = np.random.default_rng(4)

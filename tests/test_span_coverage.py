@@ -383,6 +383,66 @@ def test_a_hybrid_step_and_chunk_carry_the_states_they_moved_beside_the_pages():
     assert get("ssm.layers") == 2 and get("ssm.state_bytes_a_slot") == 2 * a_slot_layer
 
 
+def test_a_hybrid_expert_step_and_chunk_carry_the_ssms_and_the_experts_counts_together():
+    """A model with pages, states and expert layers (``models/hybrid_moe_lm.py``):
+    both programs return two extras, ``active`` and ``expert_load``, and the
+    span of a call carries the SSM's counts and the expert layers' side by
+    side, beside the attention layer's ``attend_*``; the four gauges, once;
+    the lowered programs sit under the named scopes the trace is read by."""
+    from paddle_tpu import models
+    from paddle_tpu.models import hybrid_moe_lm as hmm
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    spec = models.get_model(
+        "hybrid_moe_lm", seq_len=16, vocab=97, d_model=32, pattern="M*EME", num_heads=2,
+        num_kv_heads=1, head_dim=16, ssm_heads=4, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
+        ssm_chunk=4, num_experts=8, experts_per_token=2, experts_held=(2, 4), moe_latent=16,
+        moe_d_inner=24, shared_d_inner=48, param_dtype="float32", compute_dtype="float32")
+    cfg = spec.extra["cfg"]
+    ids, labels = spec.synth_batch(2, np.random.RandomState(0))
+    variables = spec.model.init(0, ids, labels)
+    engine = _engine((cfg, variables))
+    try:
+        for h in [engine.submit(np.arange(1, 12, dtype=np.int32), 6),
+                  engine.submit(np.arange(3, 8, dtype=np.int32), 6)]:
+            h.result(timeout=300)
+    finally:
+        engine.close()
+    spans = tracing.spans_for_trace(engine._loop_trace.trace_id)
+    steps = [s for s in spans if s.name == "serving.decode.model_step"]
+    chunks = [s for s in spans if s.name == "serving.decode.prefill"]
+    assert len(steps) >= 6 and len(chunks) >= 3
+    a_slot_layer = 4 * 8 * 64  # a state [N, d_ssm] of float32
+    for s in steps:
+        assert s.attrs["ssm_layers"] == 2 and 1 <= s.attrs["ssm_active_slots"] == s.attrs["active"]
+        assert s.attrs["ssm_state_bytes_moved"] == 2 * s.attrs["active"] * 2 * a_slot_layer
+        assert s.attrs["attend_live_pages"] >= s.attrs["active"]
+        # 2 expert layers, 2 of 8 experts a token, 4 held: at most every pair lands here
+        assert 0 <= s.attrs["moe_pairs"] <= 2 * 2 * s.attrs["active"]
+        assert s.attrs["moe_experts_hit"] <= min(s.attrs["moe_pairs"], 2 * 4)
+        assert s.attrs["moe_max_load"] <= s.attrs["active"]
+    assert sum(s.attrs["moe_pairs"] for s in steps) > 0
+    for s in chunks:
+        assert (s.attrs["ssm_active_slots"], s.attrs["ssm_layers"]) == (1, 2)
+        assert 0 < s.attrs["moe_pairs"] <= 2 * 2 * 8 and s.attrs["moe_max_load"] <= 8
+    reg, label = obs_metrics.default_registry(), {"engine": engine.metrics.engine_label}
+    get = lambda name: reg.get(f"serving.decode.{name}", label, default=None)
+    assert get("ssm.layers") == 2 and get("ssm.state_bytes_a_slot") == 2 * a_slot_layer
+    assert get("moe.experts_held") == 4 and get("moe.router_width") == 8
+    slots, page, per_slot = 3, 4, 10
+    specs = hmm.serving_programs().cache_specs(cfg, max_slots=slots, num_pages=1 + slots * per_slot,
+                                               page_size=page, dtype=jnp.float32)
+    cache = [jnp.zeros(sp.shape, sp.dtype) for sp in specs]
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    for fn, args in ((hmm.hybrid_moe_decode_step,
+                      (i32(slots), i32(slots), (i32(slots, per_slot), i32(slots)))),
+                     (hmm.hybrid_moe_prefill_chunk, (i32(8), i32(), i32(), (i32(per_slot), i32())))):
+        assert _scopes_missing(
+            functools.partial(fn, cfg=cfg, page_size=page), (variables.params, *args, *cache),
+            ("embed", "mamba", "attention", "router", "latent_down", "moe_experts", "latent_up",
+             "shared_expert", "head", "sampling")) == []
+
+
 def test_an_idle_engine_adds_nothing_to_the_store(lm):
     engine = _engine(lm, idle_poll_s=0.005)
     try:

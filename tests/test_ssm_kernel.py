@@ -2,7 +2,9 @@
 CPU against its einsum twin: the numbers agree, a slot that does not decode
 keeps its state bit for bit (and is not on the kernel's list of slots to
 move), other planes are not touched, and garbage in an idle slot's operands
-reaches nothing."""
+reaches nothing; with ``B`` and ``C`` in groups a channel reads its own group's,
+at a small size and at Nemotron-H's ``[128, 8192]`` in 8 groups, and the step
+walks the plain recurrence of ``hybrid_ssm_lm.ssm_scan``."""
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +16,12 @@ from paddle_tpu.ops.pallas import ssm
 L, S, N, D = 3, 6, 16, 256
 
 
-def operands(seed=0):
+def operands(seed=0, groups=1, shape=(L, S, N, D)):
     k = jax.random.split(jax.random.PRNGKey(seed), 5)
-    return (jax.random.normal(k[0], (L, S, N, D)), jax.random.normal(k[1], (S, D)),
-            jax.random.uniform(k[2], (S, D), minval=0.5, maxval=1.0),
-            jax.random.normal(k[3], (S, N)), jax.random.normal(k[4], (S, N)))
+    _, s, n, d = shape
+    return (jax.random.normal(k[0], shape), jax.random.normal(k[1], (s, d)),
+            jax.random.uniform(k[2], (s, d), minval=0.5, maxval=1.0),
+            jax.random.normal(k[3], (s, groups, n)), jax.random.normal(k[4], (s, groups, n)))
 
 
 @pytest.mark.parametrize("active", [[1, 0, 1, 1, 0, 1], [0, 0, 0, 0, 0, 0], [1, 1, 1, 1, 1, 1],
@@ -42,6 +45,50 @@ def test_the_kernel_is_the_einsum_form_and_leaves_idle_slots_alone(active):
         return
     # an active slot's state did change
     assert (np.asarray(s_k[1])[~idle] != np.asarray(state[1])[~idle]).any()
+
+
+@pytest.mark.parametrize("groups, shape", [(2, (L, S, N, D)), (4, (L, S, N, D)),
+                                           (8, (1, 2, 128, 8192))],
+                         ids=["2_groups", "4_groups", "8_groups_at_128x8192"])
+def test_with_groups_a_channel_reads_its_own_groups_b_and_c(groups, shape):
+    """Kernel and twin against the recurrence spelled a channel at a time; the
+    last case is Nemotron-H's state tile, chunks of 512 lanes in groups of 1024."""
+    state, xdt, decay, b, c = operands(3, groups, shape)
+    on = jnp.ones((shape[1],), jnp.int32).at[0].set(0 if shape[1] > 2 else 1)
+    layer = shape[0] - 1
+    y_k, s_k = ssm.ssm_step(state, xdt, decay, b, c, on, layer=layer, interpret=True)
+    y_x, s_x = ssm.ssm_step_xla(state, xdt, decay, b, c, on, layer=layer)
+    of = lambda v: jnp.repeat(jnp.swapaxes(v, 1, 2), shape[3] // groups, axis=-1)  # [S, N, D]
+    live = (on != 0)[:, None, None]
+    want = jnp.where(live, decay[:, None] * state[layer] + of(b) * xdt[:, None], state[layer])
+    want_y = jnp.where(live[:, 0], jnp.sum(of(c) * want, axis=1), 0.0)
+    for got_y, got_s in ((y_k, s_k), (y_x, s_x)):
+        np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=2e-5)
+        np.testing.assert_allclose(got_s[layer], want, rtol=1e-6, atol=1e-6)
+    # every channel reading group 0's would not pass
+    wrong = jnp.sum(of(c[:, :1].repeat(groups, 1)) * want, axis=1)
+    assert np.abs(np.asarray(wrong - want_y))[np.asarray(on) != 0].max() > 1.0
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_the_step_walks_the_scan_a_token_at_a_time(groups):
+    from paddle_tpu.models import hybrid_ssm_lm as hm
+
+    T, H, P, n = 9, 4, 32, 16
+    rng = np.random.default_rng(5)
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    x, b, c, h0 = f32(T, H * P), f32(T, groups, n), f32(T, groups, n), f32(n, H * P)
+    dt = jnp.asarray(rng.uniform(0.01, 0.5, (T, H)), jnp.float32)
+    a_neg = -jnp.exp(0.3 * f32(H))
+    want_y, want_h = hm.ssm_scan(x, dt, a_neg, b, c, h0)
+    state, ys = h0[None, None], []
+    for t in range(T):
+        y, state = ssm.ssm_step(state, (hm._by_channel(dt[t], P) * x[t])[None],
+                                hm._by_channel(jnp.exp(dt[t] * a_neg), P)[None], b[t][None],
+                                c[t][None], jnp.ones((1,), jnp.int32), layer=0, interpret=True)
+        ys.append(y[0])
+    np.testing.assert_allclose(jnp.stack(ys), want_y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(state[0, 0], want_h, rtol=1e-5, atol=1e-5)
 
 
 def test_the_list_names_the_active_slots_first_and_repeats_the_last():

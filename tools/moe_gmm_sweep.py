@@ -2,6 +2,8 @@
 the two serving programs of a ``latent_moe_lm`` cell around it.
 
     python tools/moe_gmm_sweep.py [--workload sarvam_105b.serve_docs32] [--programs 1]
+    python tools/moe_gmm_sweep.py --shapes latent    (128 of 512 held, 22 a token,
+                                                      1024 x 2688 and 2688 x 1024)
 
 Prints, per (rows an expert, K, N), milliseconds a call for every candidate
 ``tn`` and for XLA's ragged dot over the same rows: the winner is the row for
@@ -33,7 +35,15 @@ def timed(fn, *args, n=20):
     return (time.perf_counter() - t0) / n * 1e3
 
 
-def sweep_kernel(out):
+# (experts held, the router's width, experts a token, (tokens, row tile) of a
+# step and of a chunk, the two products' (K, N)): a cell's calls
+SHAPES = {
+    "swiglu": (32, 128, 8, ((32, 16), (512, 32), (512, 64)), ((4096, 2048), (2048, 4096))),
+    "latent": (128, 512, 22, ((64, 16), (512, 32), (512, 64)), ((1024, 2688), (2688, 1024))),
+}
+
+
+def sweep_kernel(out, shapes="swiglu"):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -41,13 +51,13 @@ def sweep_kernel(out):
     from paddle_tpu.ops import moe
     from paddle_tpu.ops.pallas import moe as pmoe
 
-    E, per_token = 32, 8
+    E, width, per_token, calls, products = SHAPES[shapes]
     key = jax.random.PRNGKey(0)
-    for tokens, tm in ((32, 16), (512, 32), (512, 64)):
-        for k, n in ((4096, 2048), (2048, 4096)):
-            # the cell's routing: each token 8 of 128, a quarter of them held
+    for tokens, tm in calls:
+        for k, n in products:
+            # the cell's routing: each token its share of the router's width, a quarter held
             rng = np.random.default_rng(tokens)
-            experts = jnp.asarray(np.stack([rng.choice(128, per_token, replace=False)
+            experts = jnp.asarray(np.stack([rng.choice(width, per_token, replace=False)
                                             for _ in range(tokens)]).astype(np.int32))
             lay = moe.share_layout(experts, (0, E), tm)
             rows = lay.src.shape[0]
@@ -59,8 +69,8 @@ def sweep_kernel(out):
             valid = np.arange(rows) < int(lay.padded.sum())
             row = {"tokens": tokens, "tm": tm, "k": k, "n": n, "rows": rows,
                    "pairs": int(lay.load.sum()), "hit": int((lay.load > 0).sum()), "tn": {}}
-            for tn in (256, 512, 1024, 2048, 4096):
-                if n % tn or k * tn * 2 > 16 * 2**20:
+            for tn in (128, 256, 384, 512, 896, 1024, 2048, 2688, 4096):
+                if n % tn or k * tn * 2 > 16 * 2**20 or (tn < 256 and shapes == "swiglu"):
                     continue
                 fn = jax.jit(functools.partial(pmoe.moe_gmm, tm=tm, tn=tn))
                 got = np.asarray(fn(x, w, lay.tile_expert, lay.used))
@@ -149,6 +159,7 @@ def main() -> int:
     ap.add_argument("--workload", default="sarvam_105b.serve_docs32")
     ap.add_argument("--programs", type=int, default=0)
     ap.add_argument("--kernel", type=int, default=1)
+    ap.add_argument("--shapes", choices=sorted(SHAPES), default="swiglu")
     args = ap.parse_args()
     import jax
 
@@ -157,7 +168,7 @@ def main() -> int:
         return 3
     out = []
     if args.kernel:
-        sweep_kernel(out)
+        sweep_kernel(out, args.shapes)
     if args.programs:
         time_programs(args.workload, out)
     os.makedirs("chiprun_out", exist_ok=True)

@@ -30,6 +30,9 @@ def main() -> int:
     ap.add_argument("--state", type=int, default=128)
     ap.add_argument("--heads", type=int, default=64)
     ap.add_argument("--head-dim", type=int, default=64)
+    ap.add_argument("--groups", type=int, default=1,
+                    help="groups of B and C (8 with --heads 128 --layers 5: the tile of "
+                         "nemotron_3_super_120b_a12b)")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -45,13 +48,13 @@ def main() -> int:
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     xdt = 0.01 * jax.random.normal(keys[1], (S, D))
     decay = jnp.exp(-0.01 * jax.random.uniform(keys[2], (S, D)))
-    b, c = (jax.random.normal(k, (S, N)) for k in keys[3:5])
+    b, c = (jax.random.normal(k, (S, args.groups, N)) for k in keys[3:5])
     rng = np.random.default_rng(0)
     active = np.zeros((S,), np.int32)
     active[rng.permutation(S)[:args.active]] = 1
     active = jnp.asarray(active)
     out = {"shape": {"slots": S, "layers": L, "state": N, "channels": D,
-                     "active": args.active}}
+                     "groups": args.groups, "active": args.active}}
 
     small = jax.random.normal(keys[0], (2, S, N, D))
     with jax.default_matmul_precision("highest"):
