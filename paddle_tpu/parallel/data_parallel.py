@@ -35,6 +35,18 @@ class DataParallel:
         dp = DataParallel(model, optimizer, mesh=make_mesh(data=-1))
         variables, opt_state = dp.init(rng, *example_batch)
         out = dp.step(variables, opt_state, *batch)   # compiled once
+
+    A step moves across chips what the update needs, the gradients, and
+    nothing else. ``out.variables`` / ``out.opt_state`` come back in the
+    shardings they went in with; ``out.loss`` / ``out.finite`` replicated;
+    ``out.outputs`` (the model's outputs, e.g. the logits) stay on the chips
+    that computed them: one global ``jax.Array`` per leaf, sharded like the
+    batch (``[B/N, ...]`` a chip over the ``data`` axis), as ``eval_step``'s
+    are. On one process ``np.asarray(out.outputs)`` reads it whole as it
+    would a replicated array; a caller that wants every chip (or, across
+    processes, every host) to hold all of it asks at the read, where only
+    the steps that are read pay the all-gather:
+    ``jax.device_put(out.outputs, replicated(dp.mesh))``.
     """
 
     def __init__(
@@ -55,9 +67,10 @@ class DataParallel:
 
         ``zero_shard_optimizer`` (ZeRO-1, TPU-native form): optimizer slot
         buffers of replicated params are declared sharded over the data axis
-        (leading dim, where divisible) in the step's in/out_shardings — the
-        SPMD partitioner then materializes the reduce-scatter/all-gather
-        pattern, cutting optimizer-state HBM by the data-axis size. The
+        (leading dim, where divisible) in the step's in_shardings and in the
+        out_shardings of the state it returns — the SPMD partitioner then
+        materializes the reduce-scatter/all-gather pattern, cutting
+        optimizer-state HBM by the data-axis size. The
         reference's Reduce+Broadcast BuildStrategy
         (``multi_devices_graph_pass.cc:397-446``) solved the same problem by
         placing each param's update on one owner device."""
@@ -263,12 +276,17 @@ class DataParallel:
         var_sh, opt_sh = self._state_shardings(variables, opt_state)
         rep = replicated(self.mesh)
         in_sh = (var_sh, opt_sh, rep) + tuple(batch_shardings)
-        # pin outputs too: without this XLA may propagate a different
-        # sharding onto updated params (e.g. expert-sharded router
-        # weights) and the NEXT step's declared in_shardings would
-        # reject them. loss/outputs/finite replicate — FetchOpHandle
-        # gathered per-device outputs the same way (fetch_op_handle.cc)
-        out_sh = StepOutput(var_sh, opt_sh, rep, rep, rep)
+        # pin the STATE's outputs: without this XLA may propagate a
+        # different sharding onto updated params (e.g. expert-sharded
+        # router weights) and the NEXT step's declared in_shardings would
+        # reject them. loss/finite are scalars, already reduced: replicated.
+        # The model's outputs are left to the compiler (None): they stay
+        # where they were computed, sharded like the batch. Replicating
+        # them here (FetchOpHandle's per-device gather,
+        # fetch_op_handle.cc) would all-gather the global batch's logits
+        # every step, on the links the gradients' all-reduce needs, for
+        # an array the Trainer drops unread
+        out_sh = StepOutput(var_sh, opt_sh, rep, None, rep)
         return jax.jit(
             positional, donate_argnums=donate, in_shardings=in_sh,
             out_shardings=out_sh,

@@ -370,9 +370,12 @@ class Trainer:
                                 # bad step too (where the program computed
                                 # `finite` it kept the old values). The old
                                 # arrays' buffers went into the new ones;
-                                # dropping the step's outputs frees 1 GB of
-                                # logits that would sit in HBM beside the
-                                # next step's whole program
+                                # dropping the step's outputs frees the
+                                # logits (1 GB at lm_big's batch on one
+                                # chip; under DataParallel the chip's own
+                                # rows, 262 MB on four chips) that would
+                                # sit in HBM beside the next step's whole
+                                # program
                                 with tracing.start_span("trainer.commit"):
                                     self.variables, self.opt_state = out.variables, out.opt_state
                                     out = None

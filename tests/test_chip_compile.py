@@ -654,8 +654,28 @@ def test_data_parallel_step_compiles_on_four_chips(topo, as_tpu):
         ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    for kernel in ("flash_fwd_resident", "flash_bwd_dkv_resident", "flash_bwd_dq_resident"):
+        assert kernel in text
     assert batch_sh[0].spec[0] == "data"  # one sequence per chip
     assert _program_bytes(compiled) < HBM_BYTES
+    # the step moves the gradients across chips and nothing else: the
+    # logits stay on the chip that computed them. Gathered, f32[2048,32000]
+    # into f32[8192,32000], they are 786 MB into every chip every step, in
+    # flight across the backward pass on the links the all-reduces need
+    tokens, vocab = 4 * 2048, 32000
+    assert f"f32[{tokens // 4},{vocab}]" in text  # a chip's logits
+    assert "all-gather" not in text
+    assert f"f32[{tokens},{vocab}]" not in text and f"f32[4,2048,{vocab}]" not in text
+    state_bytes = sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree_util.tree_leaves((v, o)))
+    logits_bytes = tokens * vocab * 4
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    # the replicated state and one chip's quarter of the logits (with the
+    # loss and what else the model returns: small)
+    assert state_bytes + logits_bytes // 4 <= out_bytes < state_bytes + logits_bytes // 4 + 2**20
+    out_shardings = compiled.output_shardings
+    assert out_shardings.variables == var_sh and out_shardings.opt_state == opt_sh
+    assert out_shardings.loss.is_fully_replicated
 
 
 def test_trainer_step_aliases_its_state_at_lm_big_train_2k(topo, one_chip, as_tpu):

@@ -403,6 +403,122 @@ def test_dp8_vs_dp1_loss_trajectory(rng):
     np.testing.assert_allclose(base, dp8, rtol=5e-4, atol=1e-5)
 
 
+def _mnist_single_device_step(rng, rows):
+    """(spec, batch, initial variables, one single-device step's StepOutput)."""
+    from paddle_tpu import models
+
+    spec = models.get_model("mnist")
+    batch = spec.synth_batch(rows, rng)
+    v = spec.model.init(0, *batch)
+    opt = spec.optimizer()
+    base = jax.jit(opt.minimize(spec.model))(
+        v, opt.create_state(v.params), *[jnp.asarray(b) for b in batch],
+        rng=jax.random.PRNGKey(0))
+    return spec, batch, v, base
+
+
+def test_dp_step_leaves_outputs_sharded_over_the_batch(rng):
+    """A step moves the gradients across chips and nothing else: the
+    model's outputs stay on the chips that computed them, one global array
+    sharded over the batch axis (as ``eval_step``'s are), equal to the
+    single-device step's; ``loss`` / ``finite`` come back replicated."""
+    from paddle_tpu.core.config import flags, set_flags
+    from paddle_tpu.parallel.data_parallel import DataParallel
+    from paddle_tpu.parallel.sharding import replicated
+
+    prev = flags().check_nan_inf
+    set_flags(check_nan_inf=True)  # so that the step computes `finite`
+    try:
+        spec, batch, v, base = _mnist_single_device_step(rng, 16)
+        dp = DataParallel(spec.model, spec.optimizer(), mesh=make_mesh(data=-1), donate=False)
+        v8, o8 = dp.init(0, *batch, variables=v)
+        out = dp.step(v8, o8, *batch, rng=jax.random.PRNGKey(0))
+    finally:
+        set_flags(check_nan_inf=prev)
+
+    leaves = jax.tree_util.tree_leaves(out.outputs)
+    base_leaves = jax.tree_util.tree_leaves(base.outputs)
+    assert leaves and len(leaves) == len(base_leaves)
+    batched = [a for a in leaves if a.ndim and a.shape[0] == 16]
+    assert batched  # the logits among them
+    for a in batched:
+        assert not a.sharding.is_fully_replicated
+        assert a.sharding.spec[0] == "data"
+        assert {sh.data.shape[0] for sh in a.addressable_shards} == {2}  # 16 rows, 8 chips
+    for a, b in zip(leaves, base_leaves):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=1e-5)
+    assert out.loss.sharding.is_fully_replicated
+    assert out.finite is not None and out.finite.sharding.is_fully_replicated
+    assert bool(out.finite)
+    # a caller that wants every chip to hold the whole array asks at the read
+    whole = jax.device_put(batched[0], replicated(dp.mesh))
+    assert whole.sharding.is_fully_replicated
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(batched[0]))
+    # eval_step's outputs lie the same way
+    ev = [a for a in jax.tree_util.tree_leaves(dp.eval_step(v8, *batch))
+          if a.ndim and a.shape[0] == 16]
+    assert ev and all(not a.sharding.is_fully_replicated for a in ev)
+
+
+def test_dp_second_step_accepts_the_first_steps_state(rng):
+    """Why the state's out_shardings stay pinned: with an expert-sharded
+    parameter the compiler, left alone, may hand back an updated parameter
+    in another sharding, and the next step's declared in_shardings would
+    reject it. The state's shardings before and after a step are equal, a
+    second step takes the first's state as it is, and the outputs (left to
+    the compiler) are not gathered."""
+    from paddle_tpu.parallel import DataParallel
+
+    B, T, D, F, E = 4, 4, 8, 16, 4
+    mesh = make_mesh(expert=E, data=8 // E)
+
+    def net(x, y):
+        out = moe_ffn(x, num_experts=E, d_ff=F)
+        pred = jnp.mean(out.output, axis=(1, 2))
+        return jnp.mean((pred - y) ** 2) + 0.01 * out.aux_loss, out.output
+
+    model = pt.build(net)
+    x = jnp.asarray(rng.randn(B, T, D).astype(np.float32))
+    y = jnp.asarray(rng.randn(B).astype(np.float32))
+    dp = DataParallel(model, pt.optimizer.Adam(learning_rate=0.01), mesh=mesh)
+    variables, opt_state = dp.init(0, x, y)
+    assert "expert" in str(variables.params["moe/w_in"].sharding.spec)
+    before = jax.tree_util.tree_map(lambda a: a.sharding, (variables, opt_state))
+    dev_batch = dp.put_batch(x, y)
+    out1 = dp.step(variables, opt_state, *dev_batch)
+    same = jax.tree_util.tree_map(
+        lambda s, a: s.is_equivalent_to(a.sharding, a.ndim), before,
+        (out1.variables, out1.opt_state))
+    assert all(jax.tree_util.tree_leaves(same))
+    out2 = dp.step(out1.variables, out1.opt_state, *dev_batch)  # donated, unchanged
+    assert np.isfinite(float(out2.loss)) and float(out2.loss) != float(out1.loss)
+    assert dp._step_fn._cache_size() == 1  # the same compiled step took it
+    (moe_out,) = [a for a in jax.tree_util.tree_leaves(out2.outputs) if a.shape == (B, T, D)]
+    assert not moe_out.sharding.is_fully_replicated
+    assert out2.loss.sharding.is_fully_replicated
+
+
+def test_dp_step_ragged_outputs_as_before(rng):
+    """The ragged tail is fed replicated, so its outputs are computed
+    replicated: every chip holds the whole (small) array, equal to the
+    single-device step's, and the state keeps its mesh shardings."""
+    from paddle_tpu.parallel.data_parallel import DataParallel
+
+    spec, batch, v, base = _mnist_single_device_step(rng, 5)  # 5 rows do not divide 8 chips
+    dp = DataParallel(spec.model, spec.optimizer(), mesh=make_mesh(data=-1))
+    v8, o8 = dp.init(0, *batch, variables=v)
+    assert not dp.batch_divisible(*batch)
+    out = dp.step_ragged(v8, o8, *batch, rng=jax.random.PRNGKey(0))
+    leaves = jax.tree_util.tree_leaves(out.outputs)
+    assert any(a.ndim and a.shape[0] == 5 for a in leaves)
+    for a, b in zip(leaves, jax.tree_util.tree_leaves(base.outputs)):
+        assert a.sharding.is_fully_replicated
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=1e-5)
+    np.testing.assert_allclose(float(out.loss), float(base.loss), rtol=5e-4)
+    for name, p in out.variables.params.items():
+        assert p.sharding.is_equivalent_to(v8.params[name].sharding, p.ndim)
+
+
 # ----------------------------------------------------------------- ulysses
 @pytest.mark.parametrize("causal", [False, True])
 def test_ulysses_attention_matches_full(rng, causal):
