@@ -106,7 +106,9 @@ def block(lp, x, cfg: dict, rope, attend):
     is the (cos, sin) of the tokens' positions, broadcastable to [N, heads,
     T, dh / 2]; ``attend(q, k, v)`` (q [N, H, T, dh], k and v [N, H_kv, T,
     dh], q and k rotated) returns the context [N, H, T, dh] against whatever
-    cache the caller keeps for this pass and layer."""
+    cache the caller keeps for this pass and layer. The flat q, k and v stand
+    behind a barrier: without it XLA sinks the split into heads from q's and
+    k's result onto their weight, and copies both stacks transposed every call."""
     N, T, _ = x.shape
     dh = cfg["head_dim"]
     proj, norm, ffn = _ops(lambda name: lp(name[len(_LAYER):]), cfg)
@@ -114,7 +116,8 @@ def block(lp, x, cfg: dict, rope, attend):
     heads = lambda y: y.reshape(N, T, -1, dh).transpose(0, 2, 1, 3)
     with jax.named_scope("attention"):
         n = norm(x, _LAYER + "attn_norm")
-        q, k, v = (heads(proj(n, f"{a}/{w}")) for w in "qkv")
+        q, k, v = map(heads, jax.lax.optimization_barrier(
+            tuple(proj(n, f"{a}/{w}") for w in "qkv")))
         ctx = attend(apply_rope(q, *rope), apply_rope(k, *rope), v)
         o = proj(ctx.transpose(0, 2, 1, 3).reshape(N, T, -1), f"{a}/out")
         x = x + norm(o, _LAYER + "attn_post_norm")

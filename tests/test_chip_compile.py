@@ -111,6 +111,19 @@ def _mosaic_calls(text):
             for line in calls]
 
 
+def _results(lines):
+    """``[(name, what follows " = ")]`` of the instructions among ``lines``
+    of a compiled text: the second starts with the result's shape and layout."""
+    return [tuple(l.strip().split(" = ", 1)) for l in lines if " = " in l]
+
+
+def _copies_of(text, *shapes):
+    """The ``copy`` instructions of a compiled text whose result is one of
+    ``shapes`` (``"bf16[48,2048,2048]"``): a whole array moved, cut short."""
+    return [f"{name} = {rest[:100]}" for name, rest in _results(text.splitlines())
+            if rest.startswith(shapes) and " copy(" in rest]
+
+
 def _flash_calls(shape, backward, one_chip):
     from paddle_tpu.ops.pallas import flash_attention
 
@@ -241,10 +254,9 @@ def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, slots):
     assert "remat_" not in text
     whole, layer = ("f32[" + ",".join(str(d) for d in shape) + "]"
                     for shape in (pages.shape, pages.shape[1:]))
-    page_array_copies = [l.strip()[:120] for l in text.splitlines()
-                         if whole in l.split("=")[0] and " copy(" in l]
+    page_array_copies = _copies_of(text, whole)
     assert not page_array_copies, page_array_copies[:2]
-    layer_slices = [l.strip()[:120] for l in text.splitlines() if layer in l.split("=")[0]]
+    layer_slices = [name for name, rest in _results(text.splitlines()) if rest.startswith(layer)]
     assert not layer_slices, layer_slices[:2]
     # both page parameters enter (and leave) in the order they are spelled
     entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
@@ -348,8 +360,7 @@ def test_sarvam_serving_steps_fit_the_chip_and_alias_their_latent_pages(one_chip
     assert 3.3e9 < page_bytes < 3.4e9 and 9.0e9 < weight_bytes < 9.1e9
     assert compiled.memory_analysis().alias_size_in_bytes >= page_bytes
     whole = "bf16[" + ",".join(str(d) for d in pages.shape) + "]"
-    page_array_copies = [l.strip()[:120] for l in text.splitlines()
-                         if whole in l.split("=")[0] and " copy(" in l]
+    page_array_copies = _copies_of(text, whole)
     assert not page_array_copies and "remat_" not in text, page_array_copies[:2]
     entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
     layouts = re.findall(re.escape(whole) + r"\{([\d,]+)", entry)
@@ -364,20 +375,11 @@ def test_sarvam_serving_steps_fit_the_chip_and_alias_their_latent_pages(one_chip
         0.2e9 if which == "decode_step" else 0.4e9)
 
 
-@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
-def test_ouro_serving_steps_fit_the_chip_and_keep_their_pages_in_place(one_chip, as_tpu, which):
-    """The cell ouro_2_6b.serve_reason8 at its own shapes, nothing cut: 48
-    layers at the published widths in bfloat16 (5.34 GB) run four passes,
-    beside 8 slots x 640 positions of K and V pages in 192 planes (2 x 4.04
-    GB). The page arrays ride the carry of the passes' loop and of the
-    layers' loop inside it, and are both written and gathered in its body:
-    they must stay in place (no whole-array copy, aliased to the outputs,
-    held as the model spells them), so the temporaries stay under 1.5 GB and
-    arguments plus temporaries fit the chip. The chunk of 192 does not
-    divide the context of 640: its table row is the engine's, lengthened to
-    768 positions."""
+def _ouro_program(one_chip, which):
+    """``(compiled, pages, params)``: a serving program of the cell
+    ouro_2_6b.serve_reason8 at its own shapes, nothing cut, compiled as the
+    engine jits it (the page arrays donated)."""
     import json
-    import re
 
     from benchmarks.families import looped_lm as family
     from paddle_tpu.models import looped_lm
@@ -409,29 +411,86 @@ def test_ouro_serving_steps_fit_the_chip_and_keep_their_pages_in_place(one_chip,
     compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
                        donate_argnames=progs.cache_args,
                        ).lower(params, *args, pages, pages, None).compile()
+    return compiled, pages, params
+
+
+STACKED_PROJ = "bf16[48,2048,2048]"  # ouro_2_6b's layers/attn/{q,k,v,out}/w
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_ouro_serving_steps_fit_the_chip_and_keep_their_pages_in_place(one_chip, as_tpu, which):
+    """The cell ouro_2_6b.serve_reason8 at its own shapes, nothing cut: 48
+    layers at the published widths in bfloat16 (5.34 GB) run four passes,
+    beside 8 slots x 640 positions of K and V pages in 192 planes (2 x 4.04
+    GB). The page arrays ride the carry of the passes' loop and of the
+    layers' loop inside it, and are both written and gathered in its body:
+    they must stay in place (no whole-array copy, aliased to the outputs,
+    held as the model spells them). So must the stacked projections: none is
+    copied or held transposed, so the temporaries stay under 50 MB and
+    arguments plus temporaries fit the chip. The chunk of 192 does not
+    divide the context of 640: its table row is the engine's, lengthened to
+    768 positions."""
+    import re
+
+    compiled, pages, params = _ouro_program(one_chip, which)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     page_bytes = 2 * int(np.prod(pages.shape))
     weight_bytes = 2 * sum(int(np.prod(p.shape)) for p in params.values())
     assert page_bytes == 4_039_114_752 and 5.33e9 < weight_bytes < 5.34e9
     assert mem.alias_size_in_bytes >= 2 * page_bytes
     whole = "bf16[" + ",".join(str(d) for d in pages.shape) + "]"
-    page_array_copies = [l.strip()[:120] for l in text.splitlines()
-                         if whole in l.split("=")[0] and " copy(" in l]
-    assert not page_array_copies, page_array_copies[:2]
+    copies = _copies_of(text, whole, STACKED_PROJ)
+    assert not copies, copies[:2]
+    assert STACKED_PROJ + "{1,2,0" not in text
     entry = next(l for l in text.splitlines() if "entry_computation_layout" in l)
     layouts = re.findall(re.escape(whole) + r"\{([\d,]+)", entry)
     assert len(layouts) == 4 and set(layouts) == {"3,2,1,0"}, layouts
     print(which, "temp", mem.temp_size_in_bytes, "arguments", mem.argument_size_in_bytes)
-    # the step reads 0.81 GB of temp beside 13.41 GB of arguments, with the
-    # kernel as with the gather before PR 36 (its 8 x 640 gathered rows of a
-    # plane were 0.04 GB and never the peak): say 0.0 GB fell
-    assert mem.temp_size_in_bytes < 1.5e9
+    # beside 13.41 GB of arguments the step holds 0.5 MB of temp and the chunk
+    # 1.1 MB. Before PR 47 both held 0.81 GB: layers/attn/q/w and k/w, 403 MB
+    # each, copied whole to {1,2,0} in the entry computation at every call,
+    # because the split into heads had sunk from the projection onto its weight
+    assert mem.temp_size_in_bytes < 0.05e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     # the passes are one traced body and the layers another: two loops, whatever the passes
     assert text.count(" while(") == 2
     # the step attends through the kernel, once in the layers' body, the
     # plane a traced scalar; the chunk keeps the gather
     assert _mosaic_calls(text) == ["paged_attend_step"] * (which == "decode_step")
+
+
+@pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
+def test_ouro_projections_read_their_stacked_weights_inside_the_matmul(one_chip, as_tpu, which):
+    """In the layers' loop of ouro_2_6b.serve_reason8's programs the q, k, v
+    and out weights are the loop's own tuple elements, whole stacks, and each
+    is an operand of the fusion that multiplies it (a ``kOutput`` fusion: the
+    layer's slice is taken inside, under the matmul). No instruction of the
+    body itself yields one layer's weight for another to multiply: that was
+    the two weights' one read from HBM done as a copy of its own, 384 times a
+    step, before PR 47."""
+    import re
+
+    text = _ouro_program(one_chip, which)[0].as_text()
+    computations, name = {}, None
+    for line in text.splitlines():
+        opens = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if opens:
+            name = opens.group(1)
+            computations[name] = []
+        elif name is not None and not line.startswith("}"):
+            computations[name].append(line)
+    bodies = [computations[b] for b in re.findall(r" while\(.*?body=%([\w.\-]+)", text)]
+    (layers,) = [b for b in bodies if not any(" while(" in l for l in b)]  # the inner loop
+    layers = _results(layers)
+    a_layer = [n for n, rest in layers if re.match(r"bf16\[(1,)?2048,2048\]", rest)]
+    assert not a_layer, a_layer
+    stacks = [n for n, rest in layers
+              if rest.startswith(STACKED_PROJ + "{2,1,0") and " get-tuple-element(" in rest]
+    assert len(stacks) == 4, stacks
+    for stack in stacks:
+        uses = [rest for n, rest in layers
+                if re.search(re.escape(stack) + r"[,)]", rest) and not n.startswith("ROOT")]
+        assert len(uses) == 1 and " fusion(" in uses[0] and "kind=kOutput" in uses[0], uses
 
 
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
@@ -489,8 +548,7 @@ def test_granite_serving_steps_fit_the_chip_and_alias_pages_and_states(one_chip,
     for c in cache:
         whole = ("f32[" if c.dtype == jnp.float32 else "bf16[") + ",".join(
             str(d) for d in c.shape) + "]"
-        copies = [l.strip()[:120] for l in text.splitlines()
-                  if whole in l.split("=")[0] and " copy(" in l]
+        copies = _copies_of(text, whole)
         assert not copies, copies[:2]
         layouts = set(re.findall(re.escape(whole) + r"\{([\d,]+)", entry))
         assert layouts == {",".join(str(d) for d in reversed(range(len(c.shape))))}, (whole, layouts)
@@ -562,8 +620,7 @@ def test_nemotron_serving_steps_fit_the_chip_and_alias_pages_and_states(one_chip
     for c in cache:
         whole = ("f32[" if c.dtype == jnp.float32 else "bf16[") + ",".join(
             str(d) for d in c.shape) + "]"
-        copies = [l.strip()[:120] for l in text.splitlines()
-                  if whole in l.split("=")[0] and " copy(" in l]
+        copies = _copies_of(text, whole)
         assert not copies, copies[:2]
         layouts = set(re.findall(re.escape(whole) + r"\{([\d,]+)", entry))
         assert layouts == {",".join(str(d) for d in reversed(range(len(c.shape))))}, (whole, layouts)
@@ -622,8 +679,7 @@ def test_brumby_serving_steps_fit_the_chip_and_alias_their_state(one_chip, as_tp
     assert 5.0e9 < state_bytes < 5.2e9 and 8.3e9 < weight_bytes < 8.5e9
     assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
     dims = ",".join(str(d) for d in state.shape)
-    whole_state_copies = [l for l in text.splitlines()
-                          if f"f32[{dims}]" in l.split("=")[0] and " copy(" in l]
+    whole_state_copies = _copies_of(text, f"f32[{dims}]")
     assert not whole_state_copies, whole_state_copies[:2]
     assert text.count("tpu_custom_call") == (cfg["n_layers"] if which == "decode_step" else 0)
     assert ("retention_step" in text) == (which == "decode_step")

@@ -169,6 +169,49 @@ def test_trainer_loss_and_the_gradient_of_a_shared_weight_are_the_references(lm)
     assert not np.any(np.asarray(want_grad["exit_gate/w"]))  # the loss does not read the gate
 
 
+@pytest.mark.parametrize("T", [1, 8], ids=["step", "chunk"])
+def test_the_head_split_kept_off_the_weights_changes_no_logit_and_no_gradient(lm, T, monkeypatch):
+    """``block`` holds the flat q, k and v behind a barrier so that the head
+    split stays on the activation: at one row a slot and at a chunk's rows
+    the logits through the pages are the full forward pass's, and the
+    training loss's gradient with respect to the stacked q weight is the
+    reference's."""
+    cfg, params = lm.cfg, lm.stacked
+    monkeypatch.setattr(L, "sample_logits", lambda logits, *_: logits)  # the programs' logits
+    seq = np.random.RandomState(6).randint(1, VOCAB, size=(16 + T,)).astype(np.int32)
+    want = np.asarray(ref.forward(lm.params, jnp.asarray(seq), cfg, refc.mm_f32)[0])
+    page, P, C = 4, 8, 8
+    k_spec, v_spec = L.looped_cache_specs(cfg, num_pages=1 + P, page_size=page, dtype=jnp.float32)
+    k, v = jnp.zeros(k_spec.shape), jnp.zeros(v_spec.shape)
+    table = jnp.arange(1, 1 + P, dtype=jnp.int32)
+    for c in range(0, 16, C):
+        _, k, v, _, _ = L.looped_prefill_chunk(
+            params, jnp.asarray(seq[c:c + C]), jnp.int32(c), jnp.int32(C - 1), table, k, v,
+            cfg=cfg, page_size=page)
+    if T == 1:
+        got = L.looped_decode_step(
+            params, jnp.asarray(seq[16:]), jnp.asarray([16]), table[None], k, v,
+            cfg=cfg, page_size=page)[0][0]
+    else:
+        got = L.looped_prefill_chunk(
+            params, jnp.asarray(seq[16:]), jnp.int32(16), jnp.int32(T - 1), table, k, v,
+            cfg=cfg, page_size=page)[0]
+    np.testing.assert_allclose(got, want[-1], rtol=1e-4, atol=1e-4)
+
+    tok = np.random.RandomState(7).randint(1, VOCAB, size=(3, T + 5)).astype(np.int32)
+    ids, labels = tok[:, :-1], tok[:, 1:]
+    want_grad = jax.grad(lambda p: ref.loss_sum(p, ids, labels, cfg, refc.mm_f32) / labels.size)(
+        lm.params)
+    name = "layers/attn/q/w"
+    loss = lambda w: lm.spec.model.apply(
+        pt.framework.Variables(dict(params, **{name: w}), {}), ids, labels)[0][0]
+    got_grad = jax.grad(loss)(params[name])
+    assert float(jnp.abs(got_grad).max()) > 0
+    for i in range(LAYERS):
+        np.testing.assert_allclose(got_grad[i], want_grad[f"layer_{i}/attn/q/w"],
+                                   rtol=2e-3, atol=2e-6, err_msg=f"layer {i}")
+
+
 def test_the_model_is_in_the_registry_and_holds_bfloat16_by_default():
     spec = models.get_model("looped_lm", seq_len=8, vocab=97, d_model=32, d_inner=64,
                             num_heads=2, head_dim=16, n_layers=2, total_ut_steps=3)
