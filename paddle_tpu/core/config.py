@@ -158,9 +158,9 @@ def apply_compile_cache(default_dir: str = "") -> str:
     place that sets the directory, and returns the one in effect:
     ``JAX_COMPILATION_CACHE_DIR`` wins when it is set (JAX reads it
     itself, nothing is set in code), then ``flags().compilation_cache_dir``,
-    then ``default_dir`` (``bench.py`` and ``chip_smoke.py`` pass
-    ``<checkout>/.jax_cache``: the path is part of the cache key, so it is
-    fixed relative to the code); with none of them there is no cache.
+    then ``default_dir`` (``benchmarks/harness.py`` passes one under the
+    checkout: the path is part of the cache key, so it is fixed relative
+    to the code); with none of them there is no cache.
     Called from set_flags and from every framework entry that jits
     (Executor, Inferencer, DataParallel), so direct-jit workloads honor
     the flag too."""

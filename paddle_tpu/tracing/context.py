@@ -460,8 +460,8 @@ def active_spans() -> List[Span]:
 
 
 def phase_totals(names: Iterable[str]) -> Dict[str, float]:
-    """Total seconds spent in each named span across the store — the
-    per-phase breakdown bench.py reports (data_wait/h2d/compile/step)."""
+    """Total seconds spent in each named span across the store — a
+    per-phase breakdown (data_wait/h2d/compile/step)."""
     want = set(names)
     totals = {n: 0.0 for n in want}
     for s in list(_store):

@@ -1,8 +1,7 @@
 """Atomic, CRC-checked JSON store for autotuned kernel configs.
 
-Same persistence idiom as ``watch/baseline.py`` (tmp file + ``os.replace``
-so concurrent writers and crashes can never leave a torn file behind), but
-with two hardenings the baseline store doesn't need:
+Written through a tmp file + ``os.replace``, so concurrent writers and
+crashes can never leave a torn file behind, with two hardenings:
 
 * every payload carries a CRC32 of its canonical entries blob — a
   truncated or bit-rotted cache file is *detected* and treated as empty

@@ -9,9 +9,7 @@
 - :mod:`~paddle_tpu.watch.slo` — declarative SLOs with multi-window
   burn rates and error budgets, served at ``/slo``;
 - :mod:`~paddle_tpu.watch.watcher` — registry-subscription glue binding
-  detectors and SLO engines to live metric streams;
-- :mod:`~paddle_tpu.watch.baseline` — persistent perf baselines behind
-  ``tools/perf_gate.py``.
+  detectors and SLO engines to live metric streams.
 """
 
 from paddle_tpu.watch.alerts import (  # noqa: F401
@@ -20,12 +18,6 @@ from paddle_tpu.watch.alerts import (  # noqa: F401
     CRITICAL,
     WARNING,
     default_hub,
-)
-from paddle_tpu.watch.baseline import (  # noqa: F401
-    BaselineKey,
-    BaselineStore,
-    RollingStat,
-    metric_direction,
 )
 from paddle_tpu.watch.detectors import (  # noqa: F401
     DetectorResult,
@@ -56,10 +48,6 @@ __all__ = [
     "WARNING",
     "CRITICAL",
     "default_hub",
-    "BaselineKey",
-    "BaselineStore",
-    "RollingStat",
-    "metric_direction",
     "DetectorResult",
     "EwmaDetector",
     "RollingQuantileDetector",

@@ -1,13 +1,13 @@
 """Compiles of the main path at real size for a described TPU v5e.
 
 The TPU compiler is installed beside the CPU backend and compiles for a
-chip that is described, not attached. These are the programs
-``chip_smoke.py`` runs — the flash kernel, the ``lm_large`` train step, the
-paged serving steps and the four-chip data-parallel step — and the two
-serving programs of the benchmark's ``brumby_14b``, ``sarvam_105b``,
-``ouro_2_6b``, ``granite_4_0_h_micro`` and ``nemotron_3_super_120b_a12b``
-cells, so what the
-chip's compiler would refuse (a kernel that cannot be partitioned, a
+chip that is described, not attached. These are the programs of the
+benchmark's ``lm_big`` cells — the flash kernel and the train step of
+``lm_big.train_2k``, the paged serving steps of ``lm_big.serve_long``, the
+four-chip data-parallel step of ``lm_big.train_2k_dp4`` — and the two
+serving programs of its ``brumby_14b``, ``sarvam_105b``, ``ouro_2_6b``,
+``granite_4_0_h_micro`` and ``nemotron_3_super_120b_a12b`` cells, so what
+the chip's compiler would refuse (a kernel that cannot be partitioned, a
 program that does not fit HBM) fails here, at no chip time. Nothing runs:
 a passing compile says nothing about results or speed.
 
@@ -25,14 +25,31 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-import bench
 from paddle_tpu import models
 from paddle_tpu.core.config import flags, set_flags
 
 HBM_BYTES = 15.75 * 2**30  # what one v5e chip's allocator reports usable
-# chip_smoke.py's phase-2 engine: DecodeConfig(max_slots=16, page_size=16,
+# lm_big's widths (benchmarks/configs/lm_big.json) at the default vocabulary:
+# the 512-wide default underfills the MXU; one scanned body is one Mosaic
+# flash forward and backward to compile, not 12
+LM_LARGE_KWARGS = dict(
+    seq_len=2048, d_model=1024, d_inner=4096, num_heads=16, n_layers=12,
+    max_len=2048, scan_layers=True,
+)
+# the engine PR 22 brought up: DecodeConfig(max_slots=16, page_size=16,
 # max_context=2048), default prefill_chunk
 SLOTS, PAGE, CONTEXT, CHUNK = 16, 16, 2048, 32
+
+
+def _serve_long_engine() -> dict:
+    """lm_big.serve_long's own engine, as the cell's traffic file has it
+    today (48 slots, chunks of 512 on PR 48's tree): read, not pinned, so a
+    PR that changes the cell's engine compiles the new one here."""
+    import json
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks", "traffic",
+                           "serve_long.json")) as f:
+        return json.load(f)["engine"]
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +72,7 @@ def one_chip(topo):
 def as_tpu(monkeypatch):
     """Steer the code that asks ``jax.default_backend()`` (interpret-mode
     selection in ops/pallas/flash_attention.py) onto its TPU branch, with
-    the bench's flags on and the persistent compile cache off: an entry
+    the lm_big cells' flags on and the persistent compile cache off: an entry
     written for a described chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -89,7 +106,7 @@ def _program_bytes(compiled) -> int:
 
 
 def _lm_large():
-    return models.get_model("transformer_lm", **bench.LM_LARGE_KWARGS)
+    return models.get_model("transformer_lm", **LM_LARGE_KWARGS)
 
 
 def _abstract_state(spec, batch):
@@ -207,13 +224,19 @@ def test_lm_large_train_step_compiles(one_chip, as_tpu):
     assert _program_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("slots", [SLOTS, 32])
+@pytest.mark.parametrize("engine, program_limit", [
+    (dict(max_slots=SLOTS, page_size=PAGE, max_context=CONTEXT, prefill_chunk=CHUNK), 4.5e9),
+    (dict(max_slots=32, page_size=PAGE, max_context=CONTEXT, prefill_chunk=CHUNK), 8.0e9),
+    (_serve_long_engine(), HBM_BYTES),
+], ids=["16", "32", "serve_long"])
 @pytest.mark.parametrize("which", ["decode_step", "prefill_chunk"])
-def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, slots):
-    """The engine of chip_smoke.py's phase 2 and of lm_big.serve_closed16:
-    16 slots x 2048 positions of f32 pages, 1.6 GB each for K and V; and
-    one of 32 slots, which the chip refused (22.1 GB) while a program
-    converted its pages. The engine donates them, so the compiled program
+def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, engine, program_limit):
+    """lm_big.serve_long's engine as its traffic file has it (on PR 48's
+    tree 48 slots x 2048 positions of f32 pages, 4.83 GB each for K and V,
+    filled in chunks of 512), and the two the
+    path was brought up on in chunks of 32: 16 slots (1.6 GB each), and 32,
+    which the chip refused (22.1 GB) while a program converted its pages.
+    The engine donates them, so the compiled program
     must alias both to its outputs (undonated, PR 22 read alias 0), and it
     must take them as the model spells them,
     ``[L, num_pages, page_size, H_kv * dh]`` with the row of all heads
@@ -228,24 +251,27 @@ def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, slots):
     from paddle_tpu.models.transformer_lm import (
         paged_cache_shape, paged_decode_step, paged_prefill_chunk,
     )
+    from paddle_tpu.serving import DecodeConfig
 
+    dconf = DecodeConfig(**engine)  # the defaults of what a cell leaves out
+    slots, page, chunk = dconf.max_slots, dconf.page_size, dconf.prefill_chunk
     spec = _lm_large()
     cfg = dict(spec.extra["cfg"], scan_layers=False)
     params = jax.eval_shape(
         lambda: spec.model.init(0, *spec.synth_batch(1, np.random.RandomState(0)))
     ).params
-    per_slot = CONTEXT // PAGE
+    per_slot = dconf.max_context // page
+    num_pages = 1 + slots * per_slot if dconf.num_pages is None else dconf.num_pages
     pages = jax.ShapeDtypeStruct(
-        paged_cache_shape(cfg, 1 + slots * per_slot, PAGE), jnp.float32,
-        sharding=one_chip)
-    assert pages.shape == (12, 1 + slots * per_slot, PAGE, 1024)
+        paged_cache_shape(cfg, num_pages, page), jnp.float32, sharding=one_chip)
+    assert pages.shape == (12, num_pages, page, 1024)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     if which == "decode_step":
         fn, args = paged_decode_step, (i32(slots), i32(slots), i32(slots, per_slot))
     else:
-        fn, args = paged_prefill_chunk, (i32(CHUNK), i32(), i32(), i32(per_slot))
+        fn, args = paged_prefill_chunk, (i32(chunk), i32(), i32(), i32(per_slot))
     compiled = jax.jit(
-        functools.partial(fn, cfg=cfg, page_size=PAGE),
+        functools.partial(fn, cfg=cfg, page_size=page),
         donate_argnames=("k_pages", "v_pages"),  # as DecodeEngine.__init__
     ).lower(_shapes(params, one_chip), *args, pages, pages, None).compile()
     text = compiled.as_text()
@@ -266,11 +292,17 @@ def test_paged_serving_steps_alias_their_pages(one_chip, as_tpu, which, slots):
     # the chunk keeps the gather
     assert _mosaic_calls(text) == ["paged_attend_step"] * (12 if which == "decode_step" else 0)
     # 16 slots read 0.02 / 0.03 GB of temp in 4.11 / 4.12 GB; 32 slots
-    # 0.02 / 0.03 GB in 7.33 / 7.34 GB. The step's gathered context was its
-    # temp until PR 36: 0.69 GB in 4.78 at 16 slots, 1.36 GB in 8.67 at 32
-    temp_limit, program_limit = (0.1e9, 4.5e9) if slots == SLOTS else (0.1e9, 8.0e9)
+    # 0.02 / 0.03 GB in 7.33 / 7.34 GB; serve_long's 48 with its chunk of
+    # 512 0.02 / 0.07 GB in 10.55 / 10.60 GB. The step's gathered context
+    # was its temp until PR 36: 0.69 GB in 4.78 at 16 slots, 1.36 GB in 8.67
+    # at 32, three sevenths of a page array at any size. The two shapes the
+    # path was brought up on keep the limits they had; the cell's engine is
+    # held to what follows from its shapes, whatever they become: a
+    # sixteenth of a page array of temp (0.1 GB at 16 slots), the program
+    # inside the chip
+    temp_limit = 0.1e9 if program_limit < HBM_BYTES else page_bytes / 16
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
-    assert _program_bytes(compiled) < program_limit
+    assert _program_bytes(compiled) < program_limit <= HBM_BYTES
 
 
 # sha256 of the StableHLO text ``lm_big``'s two serving programs lower to (16

@@ -232,8 +232,8 @@ def test_persistent_compile_cache_flag(tmp_path, rng):
 
 def test_compile_cache_env_var_wins(tmp_path, monkeypatch):
     """With JAX_COMPILATION_CACHE_DIR set the program sets no directory in
-    code (JAX reads the variable itself); unset, bench.py / chip_smoke.py
-    fall to the default they pass."""
+    code (JAX reads the variable itself); unset, a caller falls to the
+    default it passes (benchmarks/harness.py: ``.bench_cache/jax``)."""
     cfg_mod = pt.core.config
     monkeypatch.setattr(cfg_mod, "_compile_cache_applied", False)
     before = jax.config.jax_compilation_cache_dir

@@ -232,11 +232,11 @@ def test_scan_decode_parity_modern_stack():
 
 
 def test_bench_lm_large_config_traces():
-    """bench.py's lm_large section (scan_layers + the MFU-representative
-    d_model=1024 / 12-layer / T=2048 config) only executes on a chip —
-    trace its full train step abstractly here (jax.eval_shape: no compile)
-    so a config/shape bug surfaces without a chip. Runs with the bench's
-    flag set (bf16 + flash routing)."""
+    """lm_big's widths (d_model 1024, 12 layers, 16 heads, T 2048) with
+    scan_layers, as the train cells build them, only execute on a chip —
+    trace the full train step abstractly here (jax.eval_shape: no compile)
+    so a config/shape bug surfaces without a chip. Runs with the cells'
+    flags set (bf16 + flash routing)."""
     import jax
 
     from paddle_tpu.core.config import flags, set_flags

@@ -646,24 +646,64 @@ def test_span_cost_times_a_span_on_and_off_and_the_three_writes():
     assert tracing.tracing_enabled() and tracing.spans() == []
 
 
-def test_the_tools_idle_gaps_are_the_harness_s():
-    """``tools/span_report.py::idle_gaps`` against ``trace_reduce.idle_gaps``
-    on device operations with gaps of every size and host events that nest,
-    overlap a gap's edge, span several gaps or touch none."""
+def test_the_report_shares_out_idle_gaps_as_the_harness_does(tmp_path, capsys):
+    """``tools/span_report.py::report`` holds no reduction of its own: on
+    device operations with gaps of every size and spans that nest, overlap a
+    gap's edge, span several gaps or touch none, what it writes is
+    ``trace_reduce.idle_gaps`` over the program's spans cut to their
+    innermost pieces, and over the benchmark's annotations as they are."""
     from benchmarks import trace_reduce
 
+    tool = _span_report()
     rng = np.random.RandomState(3)
     at, device = 0, []
     for i in range(400):
         at += int(rng.choice([0, 1, 3, 50, 4000]))
         device.append((f"op{i % 7}", at, int(rng.randint(1, 300))))
         at += device[-1][2]
-    host = [(f"h{i % 5}", int(rng.randint(0, at)), int(rng.choice([1, 40, 900, 60000])))
+    names = ["trainer.h0", "serving.h1", "executor.h2", "serving.h3", "bench.h4"]
+    host = [(names[i % 5], int(rng.randint(0, at)), int(rng.choice([1, 40, 900, 60000])))
             for i in range(300)]
-    want = trace_reduce.idle_gaps(device, host, top=10)
-    got = _span_report().idle_gaps(device, host, top=10)
-    assert [n for n, _ in got] == [n for n, _ in want] and len(want) == 6
-    assert [s for _, s in got] == pytest.approx([s for _, s in want], rel=1e-12)
+
+    def load(path, host_prefix="bench."):
+        return {"devices": {"/device:TPU:0": device},
+                "host": [e for e in host if e[0].startswith(host_prefix)]}
+
+    trace = tmp_path / "t.xplane.pb"
+    trace.write_bytes(b"")  # no plane: the device's names are read off the file itself
+    tool.report(str(trace), "a.cell", str(tmp_path / "out"), load)
+    capsys.readouterr()
+    with open(tmp_path / "out" / "a.cell.json") as f:
+        doc = json.load(f)
+    spans = [e for prefix in tool.PROGRAM_PREFIXES for e in host if e[0].startswith(prefix)]
+    want = trace_reduce.idle_gaps(device, tool.innermost(spans), top=40)
+    assert doc["idle_gaps_by_program_span"] == want
+    assert {n.replace(" (self)", "") for n, _ in want} == set(names[:4]) | {"unattributed"}
+    assert doc["idle_gaps_by_bench_annotation"] == trace_reduce.idle_gaps(
+        device, [e for e in host if e[0] == "bench.h4"], top=10)
+    assert doc["busy_s"] == trace_reduce.busy_ns(device) / 1e9
+    assert not hasattr(tool, "idle_gaps")
+
+
+def test_a_run_of_the_tool_rebinds_the_harness_s_trace_load_and_nothing_else(monkeypatch, capsys):
+    """While the harness runs, ``trace_reduce.load_xplane`` is the tool's
+    (it reads the trace before the harness deletes it) and the reduction is
+    the harness's own; afterwards the module is as it was."""
+    from benchmarks import harness, trace_reduce
+
+    tool = _span_report()
+    before = dict(vars(trace_reduce))
+    during = []
+
+    def run(argv, t_start):
+        during.append({k for k, v in vars(trace_reduce).items() if before.get(k) is not v})
+        return 0
+
+    monkeypatch.setattr(harness, "main", run)
+    assert tool.main(["--workload", "a.cell", "--seed", "1", "--seconds", "1"]) == 0
+    capsys.readouterr()
+    assert during == [{"load_xplane"}]
+    assert all(before[k] is v for k, v in vars(trace_reduce).items())
 
 
 def test_innermost_gives_every_moment_to_one_span():

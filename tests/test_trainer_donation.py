@@ -12,6 +12,9 @@ live arrays; an array a caller took from the Trainer is valid until the next
 step.
 """
 
+import json
+import os
+
 import jax
 import jax.monitoring
 import jax.numpy as jnp
@@ -20,13 +23,15 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import checkpoint_sharded as cks
-from paddle_tpu import tracing
+from paddle_tpu import models, tracing
 from paddle_tpu.core import logging as ptlog
 from paddle_tpu.core import profiler as prof
 from paddle_tpu.core.config import flags, set_flags
 from paddle_tpu.resilience import ResilienceConfig, faults
 from paddle_tpu.trainer import BeginStepEvent, CheckpointConfig, EndStepEvent, Trainer
 
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                       "benchmarks", "configs")
 OPTIMIZERS = {
     "adam": lambda: pt.optimizer.Adam(learning_rate=0.05),
     "sgd": lambda: pt.optimizer.SGD(learning_rate=0.1),
@@ -165,6 +170,45 @@ def test_ten_steps_compile_one_program():
         jax.monitoring.unregister_event_duration_listener(on_event)
     assert trainer.global_step == 10
     assert len(compiles) == 1
+
+
+# ---- the train programs of the repo alias the state they are handed -------
+
+
+def _cell_kwargs(config, seq_len):
+    """A benchmark configuration's model as its train cells build it."""
+    with open(os.path.join(CONFIGS, config + ".json")) as f:
+        config = json.load(f)
+    return dict(config["model"], **config["train"], seq_len=seq_len)
+
+
+@pytest.mark.parametrize("name, kwargs, bs", [
+    ("resnet", lambda: dict(dataset="flowers", depth=50, class_dim=1000), 2),
+    ("transformer_lm", lambda: _cell_kwargs("lm_big", 2048), 1),
+    ("transformer", lambda: _cell_kwargs("nmt_big", 64), 2),
+], ids=["resnet50", "lm_large", "nmt_big"])
+def test_a_real_train_step_aliases_its_state_as_the_trainer_prepares_it(name, kwargs, bs):
+    """The step as ``Trainer._compiled_step`` prepares it, compiled from
+    shapes at the sizes the train cells run (and ResNet-50, which has no
+    cell yet): the program must alias at least the parameters' bytes to its
+    outputs, or a step holds parameters and optimizer state twice and the
+    memory a cell reports from ``memory_analysis()`` is not what it needs."""
+    spec = models.get_model(name, **kwargs())
+    batch = spec.synth_batch(bs, np.random.RandomState(0))
+    opt = spec.optimizer()
+    variables = jax.eval_shape(lambda: spec.model.init(0, *batch))
+    opt_state = jax.eval_shape(opt.create_state, variables.params)
+    step = pt.Executor().prepare(
+        opt.minimize(spec.model), donate_argnums=(0, 1), key=("trainer_step", name))
+    compiled = step.lower(variables, opt_state, *batch).compile()
+    param_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
+                      for p in jax.tree_util.tree_leaves(variables.params))
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes > 0
+    assert mem.argument_size_in_bytes > param_bytes  # + optimizer state and batch
+    assert mem.alias_size_in_bytes >= param_bytes
+    # what the runtime enforces
+    assert "input_output_alias" in compiled.as_text()
 
 
 # ---- the arithmetic is the undonated step's --------------------------------

@@ -448,56 +448,6 @@ def test_serving_prewarm_no_compiles_under_traffic(tune_env, rng):
         eng2.close()
 
 
-# ---- perf gate: the tune metrics are regression-gated ----------------------
-
-_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-_TOOLS = os.path.join(os.path.dirname(_DATA), "..", "tools")
-
-
-def _perf_gate():
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(_TOOLS, "perf_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_speedup_metrics_classified_higher_better():
-    from paddle_tpu.watch import baseline as bl
-
-    assert bl.metric_direction("tuned_vs_default_speedup") == bl.HIGHER_BETTER
-    assert bl.metric_direction("warm_restart_compile_speedup") == bl.HIGHER_BETTER
-    assert bl.metric_direction("warm_restart_compile_seconds") == bl.LOWER_BETTER
-
-
-def test_perf_gate_passes_tune_fixture_and_fails_collapse(tmp_path):
-    """The committed baseline pins the PR's perf story: the fixture line
-    passes, a warm-restart speedup collapse (persistent cache or manifest
-    replay silently broken → compile cost comes back) fails, and so does a
-    tuned-vs-default collapse (autotuner no longer beating the default)."""
-    gate = _perf_gate()
-    base = os.path.join(_DATA, "perf_baseline.json")
-    fixture = os.path.join(_DATA, "perf_tune_line.json")
-    assert gate.main(["--baseline", base, "--bench-json", fixture]) == 0
-
-    with open(fixture) as f:
-        line = json.load(f)
-    line["warm_restart_compile_speedup"] = 3.0   # below the 5x acceptance
-    line["warm_restart_compile_seconds"] = 0.7
-    bad = str(tmp_path / "collapsed.json")
-    with open(bad, "w") as f:
-        json.dump(line, f)
-    assert gate.main(["--baseline", base, "--bench-json", bad]) == 1
-
-    with open(fixture) as f:
-        line = json.load(f)
-    line["value"] = 0.9   # tuned slower than the fitted default
-    bad2 = str(tmp_path / "untuned.json")
-    with open(bad2, "w") as f:
-        json.dump(line, f)
-    assert gate.main(["--baseline", base, "--bench-json", bad2]) == 1
-
-
 def test_prewarm_without_manifest_is_harmless(tune_env):
     from paddle_tpu.serving import DecodeConfig, DecodeEngine
 

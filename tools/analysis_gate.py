@@ -18,8 +18,7 @@ Three legs, all zero-FLOP (no devices are touched anywhere):
    caught in a fixture.
 
 Exit code 0 = every leg held; 1 = anything less. CI-registered next to
-``tools/chaos_smoke.py`` and ``tools/perf_gate.py`` (README "Static
-analysis").
+``tools/chaos_smoke.py`` (README "Static analysis").
 """
 
 import os

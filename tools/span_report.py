@@ -7,9 +7,8 @@ Runs ``benchmarks/run.py``'s harness in this process. With ``--trace 1`` it
 reads the ``.xplane.pb`` the harness captured, before the harness deletes it:
 
 * the device's idle gaps shared out to the program's spans, through
-  ``trace_reduce.load_xplane(path, host_prefix=...)`` as it is and
-  ``trace_reduce.idle_gaps``'s numbers by a shorter way (:func:`idle_gaps`,
-  which the harness's own reduction of this run takes too). Nested spans would be counted
+  ``trace_reduce.load_xplane(path, host_prefix=...)`` and
+  ``trace_reduce.idle_gaps`` as they are. Nested spans would be counted
   twice, so each moment goes to the innermost span open on it: a span that
   holds children keeps only the time no child covers (``<name> (self)``).
 * per span name: count, total and own seconds, and the part of the traced
@@ -156,8 +155,8 @@ def report(path: str, cell: str, out_dir: str, load_xplane, look_inside=()) -> N
         "cell": cell,
         "window_s": trace_reduce.window_ns(trace) / 1e9,
         "busy_s": trace_reduce.busy_ns(first) / 1e9,
-        "idle_gaps_by_program_span": idle_gaps(first, pieces, top=40),
-        "idle_gaps_by_bench_annotation": idle_gaps(first, trace["host"], top=10),
+        "idle_gaps_by_program_span": trace_reduce.idle_gaps(first, pieces, top=40),
+        "idle_gaps_by_bench_annotation": trace_reduce.idle_gaps(first, trace["host"], top=10),
         "spans": span_table(host, pieces),
         "device_names": device_names(path),
     }
@@ -173,34 +172,6 @@ def report(path: str, cell: str, out_dir: str, load_xplane, look_inside=()) -> N
     with open(os.path.join(out_dir, cell + ".json"), "w") as f:
         json.dump(doc, f, indent=1)
     print("span report:", json.dumps({k: doc[k] for k in doc if k != "device_names"}), flush=True)
-
-
-def idle_gaps(events, host, top: int = 10):
-    """``trace_reduce.idle_gaps``, to the same numbers, for a window of a
-    million device operations: the harness's own walks every host event for
-    every gap (ten minutes for 8 s of ``lm_big.serve_closed16`` since its
-    step takes 3 ms of the device, hours over the program's spans); this one
-    looks only at the host events that can reach the gap."""
-    import bisect
-
-    from benchmarks import trace_reduce
-
-    host = sorted(host, key=lambda e: e[1])
-    starts = [s for _, s, _ in host]
-    longest = max((d for _, _, d in host), default=0)
-    merged = trace_reduce.merge_intervals(events)
-    by_name = {}
-    for (_, e0), (s1, _) in zip(merged, merged[1:]):
-        left = s1 - e0
-        for name, hs, hd in host[bisect.bisect_left(starts, e0 - longest):
-                                 bisect.bisect_left(starts, s1)]:
-            cover = min(s1, hs + hd) - max(e0, hs)
-            if cover > 0:
-                by_name[name] = by_name.get(name, 0.0) + cover / 1e9
-                left -= cover
-        if left > 0:
-            by_name["unattributed"] = by_name.get("unattributed", 0.0) + left / 1e9
-    return [[n, s] for n, s in sorted(by_name.items(), key=lambda kv: -kv[1])[:top]]
 
 
 def print_set_once() -> None:
@@ -378,12 +349,11 @@ def main(argv) -> int:
         return load(path, *a, **kw)
 
     trace_reduce.load_xplane = load_and_report
-    slow, trace_reduce.idle_gaps = trace_reduce.idle_gaps, idle_gaps
     try:
         return harness.main(["--workload", args.workload, "--seed", args.seed,
                              "--seconds", args.seconds, "--trace", args.trace], T_START)
     finally:
-        trace_reduce.load_xplane, trace_reduce.idle_gaps = load, slow
+        trace_reduce.load_xplane = load
         print_set_once()
 
 
